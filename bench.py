@@ -55,6 +55,18 @@ persistent XLA cache — detail.warmproc_* shows what a worker restart
 actually pays (target: <= 1 compile per query). ``--prewarm`` (or
 BENCH_PREWARM=1) runs exec.shapes.prewarm() first and records its
 summary.
+
+Device: the JSON line names what it ran on (``device``: platform,
+device_kind, device_count as jax reports them). The core section runs
+queries in THIS process, which therefore holds the chip on a machine
+that has one — and a chip belongs to one process. The fleet sections
+(``--stage-admission``, ``--exchange``, ``--skew``, ``--serving``,
+``--chaos``, ``--recovery``, ``--write``) spawn worker processes that
+take jax's default backend, so they cannot run from a process whose
+backend is a TPU: asked for there, the run stops with an error before
+any section runs (rc 1, the JSON line carries it). On the CPU backend
+they run as before. Making those sections measure the chip is the
+benchmark issue's work, not this file's.
 """
 
 import argparse
@@ -244,6 +256,14 @@ def main(argv=None) -> int:
             detail["skipped_sections"] = skipped
         detail["budget_s"] = budget_s
         detail["elapsed_s"] = round(time.perf_counter() - t_start, 1)
+        from trino_tpu import profiler
+
+        info = profiler.device_info()
+        out["device"] = {
+            "platform": info["platform"],
+            "device_kind": info["device_kind"],
+            "device_count": info["device_count"],
+        }
         print(json.dumps(out))
     return rc
 
@@ -256,6 +276,31 @@ def _run_sections(args, sf, reps, schema, detail, out, fits, remaining) -> int:
         from trino_tpu.exec import shapes
 
         detail["prewarm"] = shapes.prewarm()
+
+    fleet_sections = [
+        name for name, on in (
+            ("stage-admission", args.stage_admission
+             or _section_enabled("BENCH_STAGE_ADMISSION", False)),
+            ("exchange", args.exchange
+             or _section_enabled("BENCH_EXCHANGE", False)),
+            ("skew", args.skew or _section_enabled("BENCH_SKEW", False)),
+            ("serving", args.serving
+             or _section_enabled("BENCH_SERVING", False)),
+            ("chaos", args.chaos or _section_enabled("BENCH_CHAOS", False)),
+            ("recovery", args.recovery
+             or _section_enabled("BENCH_RECOVERY", False)),
+            ("write", args.write or _section_enabled("BENCH_WRITE", False)),
+        ) if on
+    ]
+    if fleet_sections:
+        import jax
+
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                f"fleet sections {fleet_sections} spawn worker processes "
+                "that need the chip this process holds (one process per "
+                "chip): they cannot run from a process on the TPU backend"
+            )
 
     runner = QueryRunner.tpch(schema)
     conn = runner.metadata.connector("tpch")

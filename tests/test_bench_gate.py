@@ -1,11 +1,12 @@
-"""tools/bench_gate.py: regression gate over committed BENCH_r0x
-trajectories.
+"""tools/bench_gate.py: regression gate of a fresh bench JSON against
+a baseline trajectory.
 
-The gate must exit 0 when a fresh result matches the committed
-trajectory, 1 on a regression past the tolerance band, and 2 on
-unusable input (e.g. a trajectory wrapper whose run died before
-printing its JSON line). Exercised through the CLI exactly as CI
-invokes it.
+The gate must exit 0 when a fresh result matches the baseline, 1 on a
+regression past the tolerance band, and 2 on unusable input (e.g. a
+trajectory wrapper whose run died before printing its JSON line).
+Exercised through the CLI exactly as CI invokes it, against a small
+synthetic baseline (the wrapper shape and keys of a committed
+``BENCH_r0x.json``; the values are made up and measure nothing).
 """
 
 import json
@@ -13,23 +14,51 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 GATE = os.path.join(ROOT, "tools", "bench_gate.py")
-BASELINE = os.path.join(ROOT, "BENCH_r04.json")
+
+_SYNTHETIC = {
+    "metric": "tpch_sf1_q1_rows_per_sec",
+    "value": 1_000_000.0,
+    "unit": "rows/s",
+    "vs_baseline": 2.0,
+    "detail": {
+        "q01_ms": 400.0, "q03_ms": 900.0, "q18_ms": 1500.0,
+        "join_agg_rows_per_sec_chip": 2_000_000.0,
+        "join_agg_ms": 300.0,
+        "q01_warmup_compiles": 3, "q01_warm_compiles": 0,
+    },
+}
+
+
+@pytest.fixture(autouse=True)
+def baseline(tmp_path, monkeypatch):
+    path = tmp_path / "baseline.json"
+    path.write_text(json.dumps({
+        "n": 0, "cmd": "synthetic", "rc": 0, "tail": "",
+        "parsed": _SYNTHETIC,
+    }))
+    monkeypatch.setattr(sys.modules[__name__], "BASELINE", str(path))
+    return str(path)
+
+
+BASELINE = ""
 
 
 def _run(*args):
     return subprocess.run(
-        [sys.executable, GATE, *args], capture_output=True, text=True
+        [sys.executable, GATE, *args, "--baseline", BASELINE],
+        capture_output=True, text=True,
     )
 
 
 def _baseline_parsed() -> dict:
-    with open(BASELINE) as f:
-        return json.load(f)["parsed"]
+    return json.loads(json.dumps(_SYNTHETIC))
 
 
-def test_gate_passes_on_committed_trajectory():
+def test_gate_passes_on_the_baseline_itself():
     p = _run(BASELINE)
     assert p.returncode == 0, p.stdout + p.stderr
     assert "0 regression(s)" in p.stdout

@@ -24,7 +24,7 @@ def _no_leaked_injector():
 
 @pytest.fixture(scope="module")
 def chaos_workers():
-    procs, uris = chaos.spawn_workers(2)
+    procs, uris = chaos.spawn_workers(2, platform="cpu")
     yield uris
     chaos.stop_workers(procs)
 
@@ -384,7 +384,8 @@ def test_chaos_pipelined_speculative_producer_loses(
     )
 
     procs, uris = chaos.spawn_workers(
-        1, base_port=chaos.CHAOS_BASE_PORT + 10
+        1, base_port=chaos.CHAOS_BASE_PORT + 10,
+        platform="cpu"
     )
     victim = procs[0]
     try:
@@ -514,7 +515,9 @@ def test_cache_chaos_kill_worker_with_pinned_entries(tmp_path):
     oracle-exact, and the retry count matches the uncached twin —
     cache residency neither rescues nor amplifies the failure path
     (asserts live inside run_cache_chaos)."""
-    record = chaos.run_cache_chaos(seed=0, spool_root=str(tmp_path))
+    record = chaos.run_cache_chaos(
+        seed=0, spool_root=str(tmp_path), platform="cpu"
+    )
     by_name = {r["scenario"]: r for r in record["runs"]}
     assert by_name["kill-cached-worker"]["pinned_entries_lost"] > 0
     assert (

@@ -41,23 +41,8 @@ def check(runner, oracle, sql, abs_tol=1e-9):
     return result
 
 
-# Wrong rows under the jax<0.5 `experimental.shard_map` mesh semantics
-# (pre-existing at seed; see the ROADMAP mesh×fleet item). Kept out of
-# tier-1 on old jax — same treatment as the TPC-DS distributed subset —
-# with test_mesh_fleet_three_way_join_minimal_repro as the live canary.
-OLD_JAX_WRONG_ROWS = {"q05", "q08", "q09", "q13", "q14", "q20", "q21"}
-
-def _old_jax():
-    return tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5)
-
-
 @pytest.mark.parametrize("name", sorted(QUERIES))
 def test_tpch_query_distributed(runner, oracle, name):
-    if name in OLD_JAX_WRONG_ROWS and _old_jax():
-        pytest.skip(
-            "wrong rows on jax<0.5 mesh semantics (pre-existing; "
-            "ROADMAP mesh item, minimal repro in test_fleet_mesh)"
-        )
     check(runner, oracle, QUERIES[name], abs_tol=0.006)
 
 

@@ -275,7 +275,7 @@ def cluster():
     """(procs, uris) for 5 workers in one boot wave: uris[0:3] are the
     shared never-mutated pool, uris[3] the drain target, uris[4] the
     kill target (each destructive test owns its own worker)."""
-    procs, uris = chaos.spawn_workers(5, base_port=BASE_PORT)
+    procs, uris = chaos.spawn_workers(5, base_port=BASE_PORT, platform="cpu")
     yield procs, uris
     chaos.stop_workers(procs)
 

@@ -151,29 +151,15 @@ def test_mesh_fleet_partitioned_join(fleet, oracle):
     )
 
 
-def _old_jax() -> bool:
-    import jax
-
-    return tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5)
-
-
-@pytest.mark.xfail(
-    condition=_old_jax(), strict=False,
-    reason="mesh×fleet wrong-results class on jax 0.4.x: the "
-    "experimental.shard_map/check_rep compat shim drops rows in the "
-    "mesh exchange on a 3-way partitioned join (ROADMAP open item; "
-    "2-way joins are unaffected — see the probe notes there)",
-)
 def test_mesh_fleet_three_way_join_minimal_repro(fleet, oracle):
-    """Minimal repro of the q3/q5/q9 wrong-results class: the smallest
-    failing shape is customer⋈orders⋈lineitem hash-partitioned on the
-    mesh — no filters, no date arithmetic, plain sum/group/limit.
-    Either 2-way sub-join alone returns oracle-exact rows."""
+    """The smallest shape of the q3/q5/q9 family: customer⋈orders⋈
+    lineitem hash-partitioned on the mesh — no filters, no date
+    arithmetic, plain sum/group/limit."""
     fleet.session.properties["join_distribution_type"] = "PARTITIONED"
     # debug assertion (plan.validate): count rows across every
-    # exchange edge so when this xfails it names the edge that dropped
-    # rows (mesh collective or fleet spool edge) instead of just
-    # producing a wrong row set
+    # exchange edge so a failure names the edge that dropped rows
+    # (mesh collective or fleet spool edge) instead of just producing
+    # a wrong row set
     fleet.session.properties["check_exchange_coverage"] = True
     check(
         fleet, oracle,
@@ -185,12 +171,6 @@ def test_mesh_fleet_three_way_join_minimal_repro(fleet, oracle):
     )
 
 
-@pytest.mark.skipif(
-    _old_jax(),
-    reason="same jax 0.4.x mesh×fleet wrong-results class as the "
-    "minimal repro above, which stays as the tier-1 canary; this one "
-    "burns ~20s of wall-clock reproducing it a second time",
-)
 def test_mesh_fleet_tpch_q3(fleet, oracle):
     from trino_tpu.connectors.tpch.queries import QUERIES
 
@@ -203,11 +183,6 @@ def test_mesh_fleet_tpch_q18(fleet, oracle):
     check(fleet, oracle, QUERIES["q18"], abs_tol=0.006)
 
 
-@pytest.mark.skipif(
-    _old_jax(),
-    reason="jax 0.4.x mesh×fleet wrong-results class (ROADMAP open "
-    "item) — the retried query returns the same row subset as q3",
-)
 def test_mesh_fleet_survives_worker_kill9(workers, spool_root, oracle):
     """kill -9 a MESH-OWNING worker mid-query: retry from spooled
     inputs on the surviving mesh worker, oracle-exact results."""

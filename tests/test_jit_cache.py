@@ -24,19 +24,16 @@ BASE_PORT = 18910
 
 @pytest.fixture(autouse=True)
 def _isolate():
-    prev_cache = jax.config.jax_compilation_cache_dir
+    from jax._src import compilation_cache as cc
+
+    prev_enabled = jax.config.jax_enable_compilation_cache
     yield
     fault.deactivate()
     # a degrade flips process-global state; undo it for later modules
     # (reset_cache clears jax's memoized enablement so the restored
-    # dir actually takes effect on the next compile)
-    jax.config.update("jax_compilation_cache_dir", prev_cache)
-    try:
-        from jax._src import compilation_cache as cc
-
-        cc.reset_cache()
-    except Exception:
-        pass
+    # flag actually takes effect on the next compile)
+    jax.config.update("jax_enable_compilation_cache", prev_enabled)
+    cc.reset_cache()
     telemetry.PERSISTENT_CACHE_DEGRADED.set(0)
 
 
@@ -91,8 +88,9 @@ def test_wedged_deserialize_trips_watchdog_and_degrades():
     assert svc.degraded
     assert telemetry.COMPILE_DESERIALIZE_FALLBACKS.total() - f0 == 1
     assert telemetry.PERSISTENT_CACHE_DEGRADED.value() == 1
-    # degraded means in-memory-only: the persistent cache is off
-    assert not jax.config.jax_compilation_cache_dir
+    # degraded means in-memory-only: the persistent cache is off, by
+    # the enable flag — the directory is placed from outside and stays
+    assert not jax.config.jax_enable_compilation_cache
     # and every later submit short-circuits inline, no deadline wait
     t1 = time.monotonic()
     assert svc.submit(lambda: 2) == 2
@@ -114,6 +112,50 @@ def test_wedged_submit_returns_explicit_fallback():
 
 
 # ---------------------------------------------------------------------------
+# the reroute itself, called the way jax 0.9.0 calls it
+# ---------------------------------------------------------------------------
+
+
+def test_routed_cache_read_takes_jax_090_call(tmp_path):
+    """``compiler._cache_read`` passes (cache_key, compile_options,
+    backend, executable_devices) and swallows any exception into a
+    warning + miss — so a wrong signature here is a silently dead
+    cache. Write an entry, drop the in-memory executables, read it
+    back through the installed reroute with errors raised."""
+    import warnings
+
+    import jax.numpy as jnp
+    from jax._src import compilation_cache as cc
+
+    prev_dir = jax.config.jax_compilation_cache_dir
+    prev_raise = jax.config.jax_raise_persistent_cache_errors
+    jit_cache.install()
+    assert cc.get_executable_and_time.__name__ == "routed"
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_raise_persistent_cache_errors", True)
+    cc.reset_cache()
+    try:
+        def f(x):
+            return jnp.cumsum(x * 3 + 1)
+
+        x = jnp.arange(4099)
+        want = jax.jit(f)(x).block_until_ready()
+        assert list(tmp_path.iterdir()), "no cache entry written"
+        jax.clear_caches()
+        hits0 = telemetry.compile_snapshot()["persistent_hits"]
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            got = jax.jit(f)(x).block_until_ready()
+        assert (got == want).all()
+        assert telemetry.compile_snapshot()["persistent_hits"] - hits0 >= 1
+        assert not jit_cache.get().degraded
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev_dir)
+        jax.config.update("jax_raise_persistent_cache_errors", prev_raise)
+        cc.reset_cache()
+
+
+# ---------------------------------------------------------------------------
 # end-to-end: a real worker process survives the wedge
 # ---------------------------------------------------------------------------
 
@@ -129,7 +171,7 @@ def test_worker_wedge_degrades_without_failing_the_task(tmp_path_factory):
     # short watchdog deadline so the trip costs ~2s, not 60
     os.environ[jit_cache.DEADLINE_ENV] = "2"
     try:
-        procs, uris = chaos.spawn_workers(1, base_port=BASE_PORT)
+        procs, uris = chaos.spawn_workers(1, base_port=BASE_PORT, platform="cpu")
     finally:
         os.environ.pop(jit_cache.DEADLINE_ENV, None)
     try:

@@ -8,6 +8,7 @@ straightforward numpy computations.
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from trino_tpu.exec import kernels as K
 
@@ -148,3 +149,97 @@ def test_normalize_key_float_canonicalization():
     ba, _ = K.normalize_key(a, None)
     bb, _ = K.normalize_key(b, None)
     assert _np(ba == bb).all()
+
+
+# ---- packed single-operand sorts (PR 22) ------------------------------------
+# numpy is the loop-free reference: the arithmetic is integer and
+# unchanged, so results must be EQUAL, not close.
+
+
+@pytest.mark.parametrize("n", [7, 1000, 70_000])
+@pytest.mark.parametrize("bits,use_last", [
+    (0, True), (5, False), (5, True), (31, False), (31, True),
+    (40, False), (40, True), (64, False), (64, True),
+])
+def test_packed_argsort_is_a_stable_argsort(n, bits, use_last):
+    rng = np.random.default_rng(n + bits)
+    if bits == 0:
+        key = None
+    elif bits == 64:
+        key = rng.integers(
+            -(1 << 62), 1 << 62, n, dtype=np.int64
+        ).astype(np.uint64)
+    else:
+        key = rng.integers(0, 1 << min(bits, 62), n, dtype=np.int64).astype(
+            np.uint64
+        )
+    last = rng.random(n) < 0.3 if use_last else None
+    got = _np(K.packed_argsort(
+        None if key is None else jnp.asarray(key), bits,
+        None if last is None else jnp.asarray(last),
+    ))
+    want = np.lexsort((
+        np.arange(n),
+        np.zeros(n, np.uint64) if key is None else key,
+        np.zeros(n, bool) if last is None else last,
+    ))
+    assert (got == want).all()
+
+
+def test_compact_perm_keeps_row_order():
+    mask = np.array([0, 1, 1, 0, 1, 0, 0, 1], dtype=bool)
+    assert _np(K.compact_perm(jnp.asarray(mask))).tolist() == [
+        1, 2, 4, 7, 0, 3, 5, 6,
+    ]
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.int64, np.uint64, np.float64])
+@pytest.mark.parametrize("side", ["left", "right"])
+def test_searchsorted_merge_rank_matches_numpy(dtype, side):
+    rng = np.random.default_rng(3)
+    lo = 0 if dtype == np.uint64 else -1000
+    a = np.sort(rng.integers(lo, 1000, 50_000).astype(dtype))
+    # > 16384 queries takes the merged single-operand sort
+    v = rng.integers(lo, 1100, 40_000).astype(dtype)
+    got = _np(K.searchsorted(jnp.asarray(a), jnp.asarray(v), side))
+    assert (got == np.searchsorted(a, v, side)).all()
+
+
+@pytest.mark.parametrize("dtype", [np.int64, np.int32])
+def test_blocked_cumsum_is_exact(dtype):
+    rng = np.random.default_rng(4)
+    x = rng.integers(-(1 << 30), 1 << 30, 2048 * 512).astype(dtype)
+    got = _np(K.cumsum(jnp.asarray(x)))
+    assert got.dtype == x.dtype and (got == np.cumsum(x, dtype=dtype)).all()
+
+
+def test_floor_div_matches_python_floor_division():
+    rng = np.random.default_rng(5)
+    a = rng.integers(-(1 << 62), 1 << 62, 5000, dtype=np.int64)
+    b = rng.integers(1, 1 << 31, 5000, dtype=np.int64)
+    a[:6] = [0, -1, 1, np.iinfo(np.int64).min, np.iinfo(np.int64).max, -7]
+    b[:6] = [1, 1, 3, 1, 2, 7]
+    got = _np(K.floor_div(jnp.asarray(a), jnp.asarray(b)))
+    assert (got == a // b).all()
+
+
+def test_join_ranges_brute_force():
+    rng = np.random.default_rng(6)
+    nb, n_p = 5000, 30_000
+    top = np.uint64(0xFFFFFFFFFFFFFFFF)  # a LIVE key may be the max word
+    bk = rng.integers(0, 800, nb).astype(np.uint64)
+    bk[:5] = top
+    bl = rng.random(nb) < 0.8
+    pk = rng.integers(0, 900, n_p).astype(np.uint64)
+    pk[:7] = top
+    pl = rng.random(n_p) < 0.9
+    order, lo, cnt = map(_np, K.join_ranges(
+        jnp.asarray(bk), jnp.asarray(bl), jnp.asarray(pk), jnp.asarray(pl)
+    ))
+    n_live = int(bl.sum())
+    assert bl[order][:n_live].all() and not bl[order][n_live:].any()
+    assert (np.diff(bk[order][:n_live].astype(np.float64)) >= 0).all()
+    for i in range(0, n_p, 37):
+        want = int(((bk == pk[i]) & bl).sum()) if pl[i] else 0
+        assert cnt[i] == want
+        assert (bk[order[lo[i]:lo[i] + cnt[i]]] == pk[i]).all()

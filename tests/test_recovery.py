@@ -440,7 +440,7 @@ def test_coordinator_recover_rehydrates_and_fails_typed(tmp_path):
 
 @pytest.fixture(scope="module")
 def workers():
-    procs, uris = spawn_workers(2, base_port=BASE_PORT)
+    procs, uris = spawn_workers(2, base_port=BASE_PORT, platform="cpu")
     yield uris
     stop_workers(procs)
 
@@ -582,7 +582,7 @@ def test_recovery_chaos_kill9_and_orphan_reap(tmp_path):
     client rides through (asserts live inside run_recovery_chaos)."""
     from trino_tpu.testing import chaos
 
-    record = chaos.run_recovery_chaos(seed=0, spool_root=str(tmp_path))
+    record = chaos.run_recovery_chaos(seed=0, spool_root=str(tmp_path), platform="cpu")
     scenarios = {r["scenario"] for r in record["runs"]}
     assert scenarios == {"kill-mid-query", "orphan-reap"}
     kill = next(

@@ -60,7 +60,7 @@ MIX = [
 
 @pytest.fixture(scope="module")
 def workers():
-    procs, uris = chaos_mod.spawn_workers(2, base_port=BASE_PORT)
+    procs, uris = chaos_mod.spawn_workers(2, base_port=BASE_PORT, platform="cpu")
     yield uris
     chaos_mod.stop_workers(procs)
 

@@ -180,7 +180,7 @@ def test_validate_rejects_bad_adaptive_overrides():
 
 @pytest.fixture(scope="module")
 def workers():
-    procs, uris = chaos.spawn_workers(2, base_port=BASE_PORT)
+    procs, uris = chaos.spawn_workers(2, base_port=BASE_PORT, platform="cpu")
     yield uris
     chaos.stop_workers(procs)
 

@@ -65,9 +65,7 @@ def _dim_arrays():
     return dk, dk * 10
 
 
-@pytest.fixture(scope="module")
-def pq_root(tmp_path_factory):
-    root = str(tmp_path_factory.mktemp("pq"))
+def _write_fact(root):
     k, v, p = _fact_arrays()
     write_parquet_table(
         root, "default", "fact",
@@ -77,6 +75,25 @@ def pq_root(tmp_path_factory):
         {"k": k, "v": v, "p": p},
         row_group_size=25_000, partition_by=["p"],
     )
+
+
+@pytest.fixture()
+def cold_root(tmp_path):
+    """A fact table nobody has scanned: the process-wide scan caches
+    (``scan_cache.SHARED``/``SHARED_SPLITS``, ``cache.DEVICE``) key on
+    the connector fingerprint — for parquet the ROOT PATH — and are
+    shared across connector instances by design, so a test that must
+    observe real reads (bytes read, the scan-read fault gate) cannot
+    use the module's ``pq_root`` after another test has streamed it."""
+    root = str(tmp_path / "cold")
+    _write_fact(root)
+    return root
+
+
+@pytest.fixture(scope="module")
+def pq_root(tmp_path_factory):
+    root = str(tmp_path_factory.mktemp("pq"))
+    _write_fact(root)
     dk, w = _dim_arrays()
     write_parquet_table(
         root, "default", "dim",
@@ -134,8 +151,8 @@ def test_streamed_matches_resident_and_oracle(pq_root, oracle):
     assert entry["streamed"] and entry["batches"] >= 1
 
 
-def test_streamed_pruning_metrics_and_telemetry(pq_root, oracle):
-    runner = QueryRunner.parquet(pq_root)
+def test_streamed_pruning_metrics_and_telemetry(cold_root, oracle):
+    runner = QueryRunner.parquet(cold_root)
     runner.session.properties["hbm_budget_bytes"] = 1 << 20
     pruned0 = telemetry.SCAN_ROWGROUPS_PRUNED.total()
     batches0 = telemetry.SCAN_BATCHES.total()
@@ -276,10 +293,10 @@ def test_scan_read_chaos_retries_at_split_granularity(tmp_path):
     assert attempts and all(a == {0, 1} for a in attempts.values())
 
 
-def test_scan_read_exhaustion_fails(pq_root):
+def test_scan_read_exhaustion_fails(cold_root):
     from trino_tpu.exec.stream_scan import SCAN_READ_ATTEMPTS
 
-    runner = QueryRunner.parquet(pq_root)
+    runner = QueryRunner.parquet(cold_root)
     runner.session.properties["hbm_budget_bytes"] = 1 << 20
     inj = fault.FaultInjector(seed=0)
     inj.arm("scan-read", times=SCAN_READ_ATTEMPTS)

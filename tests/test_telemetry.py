@@ -702,3 +702,99 @@ def test_coordinator_query_info_endpoints():
         assert q.query_id in ids
     finally:
         coord.stop()
+
+
+# ---- device identity: peaks keyed by device_kind, /v1/info (PR 22) ---------
+
+
+class _FakeDevice:
+    platform = "tpu"
+
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+def test_peak_rates_v5e_row(monkeypatch):
+    import jax
+
+    from trino_tpu import profiler
+
+    monkeypatch.delenv("TRINO_TPU_PEAK_GFLOPS", raising=False)
+    monkeypatch.delenv("TRINO_TPU_PEAK_GBPS", raising=False)
+    # the kind string a v5e chip reports (seen on the chip, PR 22)
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [_FakeDevice("TPU v5 lite")]
+    )
+    assert profiler.peak_rates() == (197_000.0, 819.0)
+
+
+def test_peak_rates_unknown_device_raises(monkeypatch):
+    import jax
+
+    from trino_tpu import profiler
+
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [_FakeDevice("TPU v9 imaginary")]
+    )
+    with pytest.raises(LookupError, match="TPU v9 imaginary"):
+        profiler.peak_rates()
+
+
+def test_device_info_does_not_initialise_a_backend():
+    # a fresh interpreter: importing the engine must not take a device,
+    # and device_info() must not either (host-only roles answer
+    # /v1/info without one)
+    import subprocess
+    import sys
+
+    code = (
+        "import trino_tpu\n"
+        "from trino_tpu import profiler\n"
+        "from jax._src import xla_bridge\n"
+        "i = profiler.device_info()\n"
+        "assert i['platform'] is None and i['device_count'] == 0, i\n"
+        "assert not xla_bridge.backends_are_initialized()\n"
+        "import jax; jax.devices()\n"
+        "i = profiler.device_info()\n"
+        "assert i['platform'] == 'cpu' and i['device_kind'] == 'cpu', i\n"
+        "assert len(i['device_memory']) == i['device_count'] >= 1\n"
+    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True,
+        text=True,
+    )
+    assert p.returncode == 0, p.stderr[-2000:]
+
+
+def test_v1_info_names_the_device_on_both_node_types():
+    from trino_tpu.server.coordinator import Coordinator
+    from trino_tpu.server.worker import WorkerServer
+
+    runner = QueryRunner.tpch("tiny")
+    runner.execute("select count(*) from region")  # a backend exists
+    coord = Coordinator(runner=runner, port=0).start()
+    worker = WorkerServer(QueryRunner.tpch("tiny"), port=0)
+    worker.start()
+    try:
+        for uri in (coord.uri, f"http://127.0.0.1:{worker.port}"):
+            with urllib.request.urlopen(f"{uri}/v1/info", timeout=5) as r:
+                info = json.loads(r.read())
+            assert info["platform"] == "cpu"
+            assert info["device_kind"] == "cpu"
+            assert info["device_count"] >= 1
+            assert len(info["device_memory"]) == info["device_count"]
+    finally:
+        coord.stop()
+        worker.stop()
+
+
+def test_spawned_children_inherit_no_platform(monkeypatch):
+    from trino_tpu.testing import chaos
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    monkeypatch.setenv("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+    env = chaos.child_env(None)
+    assert "JAX_PLATFORMS" not in env and "XLA_FLAGS" not in env
+    assert chaos.child_env("cpu", {"A": "b"})["JAX_PLATFORMS"] == "cpu"
+    assert chaos.child_env("cpu", {"A": "b"})["A"] == "b"

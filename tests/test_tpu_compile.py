@@ -1,0 +1,175 @@
+"""The main path's kernels compile for a TPU v5e — no chip needed.
+
+The TPU compiler is installed wherever jax[tpu] is, and compiles for a
+chip that is described, not attached (``jax.experimental.topologies``).
+These are the kernels the four smoke queries (chip_smoke.py: TPC-H Q1,
+Q3, Q6, Q18) dispatch, at the SF1 bucket shapes (lineitem's 6,001,215
+rows pad to 6,291,456), kept to the ones that compile in a few seconds.
+What they guard, besides "the compiler accepts it": every sort below is
+a SINGLE-operand unstable sort — the one sort XLA:TPU compiles quickly —
+and no int64 ``div`` reaches the compiler (PR 22 findings, CHANGES.md).
+
+A compile that passes is not a chip run: nothing executes here.
+
+The topology is described inside a module-scoped fixture — never at
+import, never in conftest.py — so every xdist worker collects the same
+tests and only the worker that runs this file loads libtpu. The
+compilation cache is off around the compiles (an entry compiled for a
+described chip cannot be read back without one, and would warn).
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from trino_tpu.exec import kernels as K
+
+LINEITEM_SF1 = 6_291_456  # shapes.bucket(6_001_215)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(fn, one_chip, *specs):
+    avals = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in specs
+    ]
+    lowered = jax.jit(fn).lower(*avals)
+    return lowered, lowered.compile()
+
+
+def _sorts(lowered) -> list[tuple[int, bool]]:
+    """(operand count, is_stable) of every sort in the lowered module."""
+    txt = lowered.as_text()
+    out = []
+    for m in re.finditer(r'"?stablehlo\.sort"?\(([^)]*)\)[^\n]*', txt):
+        operands = [x for x in m.group(1).split(",") if x.strip()]
+        out.append((len(operands), "is_stable = true" in m.group(0)))
+    return out
+
+
+def test_device_is_a_v5e(topo):
+    assert topo.devices[0].platform == "tpu"
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+
+
+def test_compaction_at_lineitem_sf1(one_chip, no_compile_cache):
+    """exec/local.py _compact: live rows to the front, five columns."""
+    limit = 2_097_152
+
+    def compact(mask, a, b, c, d, e):
+        perm = K.compact_perm(mask)[:limit]
+        return [x[perm] for x in (a, b, c, d, e)], mask[perm]
+
+    n = LINEITEM_SF1
+    lowered, compiled = _compile(
+        compact, one_chip, ((n,), jnp.bool_),
+        ((n,), jnp.int64), ((n,), jnp.int64), ((n,), jnp.int64),
+        ((n,), jnp.int32), ((n,), jnp.int32),
+    )
+    assert _sorts(lowered) == [(1, False)]
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes + ma.output_size_in_bytes < 16 << 30
+
+
+def test_group_and_sum_at_lineitem_sf1(one_chip, no_compile_cache):
+    """Q1's shape: two narrow keys packed into one word, grouped by one
+    sort, int64 sums by blocked prefix sums."""
+
+    def group_sum(k1, k2, live, v):
+        info = K.sort_group(
+            (k1.astype(jnp.uint64), k2.astype(jnp.uint64)), (None, None),
+            live, 1024, widths=(2, 2),
+        )
+        vs = jnp.where(live[info.perm], v[info.perm], 0)
+        return K.seg_sum_ranges(vs, info), info.num_groups
+
+    n = LINEITEM_SF1
+    lowered, _ = _compile(
+        group_sum, one_chip, ((n,), jnp.int32), ((n,), jnp.int32),
+        ((n,), jnp.bool_), ((n,), jnp.int64),
+    )
+    sorts = _sorts(lowered)
+    assert sorts and all(s == (1, False) for s in sorts)
+
+
+def test_decimal_average_division(one_chip, no_compile_cache):
+    """avg(decimal) ends in a 96/64 long division per group; XLA:TPU's
+    own int64 div costs ~7 s of compile each and three averages in one
+    program crashed the compiler."""
+    from trino_tpu.exec.aggregates import _limb_div_round
+
+    def avg3(h1, l1, h2, l2, h3, l3, cnt):
+        return (
+            _limb_div_round(h1, l1, cnt), _limb_div_round(h2, l2, cnt),
+            _limb_div_round(h3, l3, cnt),
+        )
+
+    lowered, _ = _compile(
+        avg3, one_chip, *([((1024,), jnp.int64)] * 7)
+    )
+    assert "stablehlo.divide" not in lowered.as_text()
+    assert "stablehlo.remainder" not in lowered.as_text()
+
+
+def test_search_by_merged_sort(one_chip, no_compile_cache):
+    """kernels.searchsorted with many queries (expand_matches,
+    sort_group's run starts): one merged single-operand sort. Kept
+    small — the uint64 single-operand sort alone compiles 13-24 s at
+    the SF1 buckets, which is the join programs' cost (CHANGES.md)."""
+
+    def starts(gid_sorted):
+        return K.searchsorted(
+            gid_sorted, jnp.arange(24_576, dtype=jnp.int32), side="left"
+        )
+
+    lowered, _ = _compile(starts, one_chip, ((8192,), jnp.int32))
+    assert _sorts(lowered) == [(1, False)]
+
+
+def test_q6_scan_filter_sum_at_lineitem_sf1(one_chip, no_compile_cache):
+    """Q6's whole chain: compare, multiply, one masked global sum."""
+
+    def q6(shipdate, discount, quantity, price, mask):
+        keep = (
+            mask & (shipdate >= 8766) & (shipdate < 9131)
+            & (discount >= 5) & (discount <= 7) & (quantity < 2400)
+        )
+        return jnp.sum(jnp.where(keep, price * discount, 0)), K.count_true(keep)
+
+    n = LINEITEM_SF1
+    _, compiled = _compile(
+        q6, one_chip, ((n,), jnp.int32), ((n,), jnp.int64),
+        ((n,), jnp.int64), ((n,), jnp.int64), ((n,), jnp.bool_),
+    )
+    assert compiled.memory_analysis().argument_size_in_bytes > n * 8 * 3

@@ -333,6 +333,7 @@ def fleet_env():
         extra_env={
             "TRINO_TPU_WORKER_EXTRA_PARQUET": f"hive={hive_root}",
         },
+        platform="cpu",
     )
     yield {"uris": uris, "hive_root": hive_root, "spool": spool}
     stop_workers(procs)

@@ -1,14 +1,15 @@
 """Bench regression gate: compare a fresh bench.py JSON line against a
-committed BENCH_r0x trajectory file.
+baseline bench JSON named on the command line (no default: a gate
+against numbers from another machine class is not a gate).
 
-    python tools/bench_gate.py fresh.json [--baseline BENCH_r04.json]
+    python tools/bench_gate.py fresh.json --baseline BASE.json
                                [--tolerance 0.25]
 
 Both inputs may be either shape the repo produces:
   * the bare object bench.py prints (``{"metric", "value", "detail"}``)
   * the committed wrapper (``{"n", "cmd", "rc", "tail", "parsed": {...}}``)
 The wrapper is unwrapped through ``parsed``; a wrapper whose run died
-before emitting JSON (``parsed: null`` — e.g. BENCH_r05's timeout) is
+before emitting JSON (``parsed: null`` — a run that timed out) is
 rejected with exit code 2 so CI shows a config error, not a fake pass.
 
 Checked, each with the same fractional tolerance band:
@@ -35,11 +36,6 @@ import sys
 
 __all__ = ["compare", "load_bench", "main"]
 
-_HERE = os.path.dirname(os.path.abspath(__file__))
-_DEFAULT_BASELINE = os.path.join(
-    os.path.dirname(_HERE), "BENCH_r04.json"
-)
-
 #: (key, higher_is_better) — dotted keys index into detail. The
 #: serving keys (BENCH_r08+) SKIP against older baselines that
 #: predate ``bench.py --serving`` — SKIP-not-fail is the contract.
@@ -47,7 +43,7 @@ _RATE_KEYS = [
     ("value", True),
     ("vs_baseline", True),
     # single-chip floor vs the hand-vectorized numpy baseline
-    # (BENCH_r02+ emit it; SKIPs against baselines that predate it)
+    # (SKIPs against baselines that predate it)
     ("detail.vs_numpy_geomean", True),
     ("detail.q01_ms", False),
     ("detail.q03_ms", False),
@@ -179,9 +175,9 @@ def main(argv=None) -> int:
     )
     ap.add_argument("fresh", help="fresh bench JSON (bare or wrapped)")
     ap.add_argument(
-        "--baseline", default=_DEFAULT_BASELINE,
-        help="committed trajectory to gate against "
-        "(default: BENCH_r04.json)",
+        "--baseline", required=True,
+        help="trajectory to gate against (bare or wrapped bench JSON "
+        "from the same machine class as the fresh run)",
     )
     ap.add_argument(
         "--tolerance", type=float, default=0.25,

@@ -177,11 +177,10 @@ def capture_snapshot(out_path: str) -> int:
     sys.path.insert(0, os.path.dirname(_HERE))  # repo root
     # real compile wall, not a persistent-cache deserialize: a warm
     # machine would record ~6x-lower compile_s than the cold CI runner
-    # and the gate would flag phantom compile regressions. Only
-    # effective when trino_tpu is not yet imported — i.e. the CLI
-    # path, which is the only caller of --capture.
-    if "trino_tpu" not in sys.modules:
-        os.environ["TRINO_TPU_JIT_CACHE"] = "off"
+    # and the gate would flag phantom compile regressions.
+    import jax
+
+    jax.config.update("jax_enable_compilation_cache", False)
     from trino_tpu import program_catalog
     from trino_tpu.engine import QueryRunner
     from trino_tpu.connectors.tpch.queries import QUERIES

@@ -6,8 +6,8 @@ prints one JSON line of per-query compile accounting:
     {"q01": {"compiles": 0, "compile_s": 0.0, "persistent_hits": 7,
              "jit_hits": 0, "wall_ms": 412.3}, ...}
 
-Against a warm persistent XLA cache (TRINO_TPU_JIT_CACHE, default
-``.jax_cache/<cpu-fingerprint>`` at the repo root) and the default
+Against a warm persistent XLA cache (JAX_COMPILATION_CACHE_DIR, default
+``.jax_cache/<cpu-fingerprint>-g2`` at the repo root) and the default
 ``shape_bucketing=ON``, the second-ever execution of an operator mix
 should show ``compiles <= 1`` per query — every program deserializes
 instead of compiling. bench.py runs this as its cross-process warm

@@ -29,24 +29,20 @@ import jax
 # enable x64 globally at import.
 jax.config.update("jax_enable_x64", True)
 
-# jax.shard_map graduated from jax.experimental in newer releases
-# (where check_rep was also renamed check_vma); on older installs
-# (< 0.5) adapt the experimental implementation so the whole
-# distributed tier runs on either.
-if not hasattr(jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, *, check_vma=True, **kwargs):
-        return _exp_shard_map(f, check_rep=check_vma, **kwargs)
-
-    jax.shard_map = _shard_map
-
 # Persistent XLA compilation cache: query-shaped programs compile once
 # per machine, not once per process (the analog of the reference's
 # warmed JVM — its generated bytecode lives as long as the server).
-# XLA:CPU AOT artifacts embed the COMPILING host's CPU features and
-# SIGILL/SIGSEGV when loaded on a host without them (heterogeneous VM
-# pools hit this), so the cache keys on a fingerprint of the host CPU.
+#
+# Where the cache lives is decided from outside, by jax's own variable:
+# with JAX_COMPILATION_CACHE_DIR set, jax uses exactly that directory
+# and this module sets ``jax_compilation_cache_dir`` nowhere. Unset,
+# the cache is the fixed ``<checkout>/.jax_cache`` (the path is part of
+# the cache's key, so a directory that moves never hits), under a
+# per-host subdirectory: XLA:CPU AOT artifacts embed the COMPILING
+# host's CPU features and SIGILL/SIGSEGV when loaded on a host without
+# them (heterogeneous VM pools hit this), so the default path keys on a
+# fingerprint of the host CPU. ``jax_enable_compilation_cache=False``
+# (JAX_ENABLE_COMPILATION_CACHE=false) turns the cache off.
 
 
 def _cpu_fingerprint() -> str:
@@ -64,29 +60,28 @@ def _cpu_fingerprint() -> str:
     return hashlib.sha1(platform.processor().encode()).hexdigest()[:12]
 
 
-#: cache-namespace generation. jax's cache key deliberately strips
-#: location/metadata (so source-line edits don't bust the cache) —
-#: which means changes that only manifest in HLO metadata are
-#: invisible to it. Bump this when such metadata becomes load-bearing:
-#: g2 = operator ``jax.named_scope`` labels, which kernel-profile
-#: attribution reads back out of cached executables' HLO.
+#: cache-namespace generation of the default path. jax's cache key
+#: deliberately strips location/metadata (so source-line edits don't
+#: bust the cache) — which means changes that only manifest in HLO
+#: metadata are invisible to it. Bump this when such metadata becomes
+#: load-bearing: g2 = operator ``jax.named_scope`` labels, which
+#: kernel-profile attribution reads back out of cached executables' HLO.
 _CACHE_GEN = "g2"
 
-_cache_dir = os.environ.get(
-    "TRINO_TPU_JIT_CACHE",
-    os.path.join(os.path.dirname(os.path.dirname(__file__)), ".jax_cache"),
-)
-if _cache_dir != "off":
-    _cache_dir = os.path.join(
-        _cache_dir, f"{_cpu_fingerprint()}-{_CACHE_GEN}"
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update(
+        "jax_compilation_cache_dir",
+        os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache", f"{_cpu_fingerprint()}-{_CACHE_GEN}",
+        ),
     )
-    jax.config.update("jax_compilation_cache_dir", _cache_dir)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    # persist EVERY program: the warm-process target is compile delta
-    # <= 1 for a known operator mix, and the many sub-0.5s helper
-    # programs (compactions, dynamic filters) would otherwise recompile
-    # in every process forever
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+# persist EVERY program: the warm-process target is compile delta <= 1
+# for a known operator mix, and the many sub-0.5s helper programs
+# (compactions, dynamic filters) would otherwise recompile in every
+# process forever
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 __version__ = "0.1.0"
 

@@ -46,6 +46,18 @@ from trino_tpu.connectors.base import (
 
 __all__ = ["ParquetConnector", "write_parquet_table"]
 
+# Arrow's default allocator (mimalloc in pyarrow 25.0.0) segfaults
+# inside ``ParquetFile.__init__`` when a worker's task threads read
+# under CPU contention in a process that also hosts XLA — reproducible
+# with tests/test_storage_scan.py's fleet tests beside 12 busy cores:
+# SIGSEGV at libarrow+0x16f8b89, both workers die, the query fails with
+# "no live workers remain". The system allocator does not. Arrow reads
+# the variable when libarrow loads, and every pyarrow import in this
+# package is function-local, so setting it here is early enough;
+# ``pyarrow.set_memory_pool`` later is not (libarrow's own default pool
+# stays mimalloc).
+os.environ.setdefault("ARROW_DEFAULT_MEMORY_POOL", "system")
+
 
 def _arrow():
     import pyarrow

@@ -623,10 +623,10 @@ def _limb_div_round(hi, lo, cnt):
 
     Schoolbook 96/64 long division in two int64 steps: q1 = hi // cnt
     leaves r1 < cnt <= 2^31, so (r1 << 32) | lo fits int64."""
-    q1 = hi // cnt  # floor
+    q1 = K.floor_div(hi, cnt)
     r1 = hi - q1 * cnt  # in [0, cnt)
     rem = (r1 << jnp.int64(32)) | lo
-    q2 = rem // cnt
+    q2 = K.floor_div(rem, cnt)
     r2 = rem - q2 * cnt
     q = (q1 << jnp.int64(32)) + q2  # floor((hi*2^32+lo)/cnt)
     # round half away from zero on the floor quotient: positive values

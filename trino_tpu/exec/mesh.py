@@ -982,9 +982,7 @@ class MeshExecutor(LocalExecutor):
         prog_c = self._mesh_jit_cache.get(key_c)
         if prog_c is None:
             def fc(kp, *ls):
-                perm = jnp.argsort(
-                    (~kp).astype(jnp.int8), stable=True
-                )[:new_cap]
+                perm = K.compact_perm(kp)[:new_cap]
                 return [a[perm] for a in ls], kp[perm]
 
             prog_c = jax.jit(
@@ -1528,7 +1526,7 @@ class MeshExecutor(LocalExecutor):
                 p_env, p_mask = _env_from_leaves(list(ls[:n_p]), p_meta)
                 b_env, b_mask = _env_from_leaves(list(ls[n_p:]), b_meta)
                 n_live = jnp.sum(p_mask.astype(jnp.int32))
-                perm = jnp.argsort(~p_mask, stable=True)
+                perm = K.compact_perm(p_mask)
                 p_cap = p_mask.shape[0]
                 b_cap = b_mask.shape[0]
                 j = jnp.arange(out_cap)

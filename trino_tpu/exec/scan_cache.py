@@ -55,7 +55,12 @@ class ScanPageCache:
     """
 
     def __init__(self):
-        self._lock = threading.Lock()
+        # reentrant: ``_drop_ident`` is a weakref finalizer, and the
+        # collector can run it on THIS thread while ``table()`` holds
+        # the lock (any allocation inside the critical section may
+        # trigger a collection) — a plain Lock self-deadlocks there
+        # (seen: tests/test_write_path.py hung in a full tier-1 run)
+        self._lock = threading.RLock()
         #: ident -> [content, {(schema, table): page dict}]
         self._by_ident: dict[str, list] = {}
         #: idents with a registered instance finalizer
@@ -162,7 +167,7 @@ class SplitBatchCache:
 
     def __init__(self, max_bytes: int = 256 << 20):
         self.max_bytes = max_bytes
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()  # finalizer-reentrant, as above
         self._entries: OrderedDict = OrderedDict()
         self._bytes = 0
         self._watched: set[str] = set()

@@ -129,18 +129,16 @@ class CompileService:
         self.degraded = True
         import jax
 
-        if jax.config.jax_compilation_cache_dir:
-            jax.config.update("jax_compilation_cache_dir", None)
-        try:
-            # jax memoizes cache-enablement on first use; without the
-            # reset, clearing the dir would not stop deserializes —
-            # and inline compilation on task threads would hit the
-            # very wedge this fallback exists to avoid
-            from jax._src import compilation_cache as _cc
+        from jax._src import compilation_cache as _cc
 
-            _cc.reset_cache()
-        except Exception:
-            pass
+        # the cache directory is placed from outside (trino_tpu/__init__)
+        # and never reassigned in code: degrading flips the enable flag
+        jax.config.update("jax_enable_compilation_cache", False)
+        # jax memoizes cache-enablement on first use; without the
+        # reset, the flag would not stop deserializes — and inline
+        # compilation on task threads would hit the very wedge this
+        # fallback exists to avoid
+        _cc.reset_cache()
         telemetry.PERSISTENT_CACHE_DEGRADED.set(1)
         telemetry.COMPILE_DESERIALIZE_FALLBACKS.inc()
 
@@ -181,13 +179,13 @@ def _wrap_cache_read(svc: CompileService) -> None:
     function that deserializes cached executables) so it runs on the
     service thread. A deadline trip degrades the process and reports a
     cache miss — the caller compiles from scratch on its own thread."""
-    try:
-        from jax._src import compilation_cache as cc
-    except Exception:
-        return
+    from jax._src import compilation_cache as cc
+
     orig = cc.get_executable_and_time
 
-    def routed(cache_key, compile_options, backend):
+    # the signature of jax 0.9.0's compiler._cache_read call, which
+    # turns any exception raised here into a warning and a cache miss
+    def routed(cache_key, compile_options, backend, executable_devices):
         if svc.degraded:
             return None, None
         # span anchoring happens HERE, on the calling (task) thread —
@@ -203,7 +201,9 @@ def _wrap_cache_read(svc: CompileService) -> None:
         hit = False
         try:
             out = svc.submit(
-                lambda: orig(cache_key, compile_options, backend),
+                lambda: orig(
+                    cache_key, compile_options, backend, executable_devices
+                ),
                 tag=f"deserialize:{str(cache_key)[:16]}",
                 fallback=lambda: (None, None),
             )

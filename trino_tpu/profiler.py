@@ -31,29 +31,68 @@ from dataclasses import dataclass, field
 
 __all__ = [
     "OperatorProfiler", "OpRecord", "peak_rates", "roofline",
-    "attach_roofline", "tree_from_stats",
+    "attach_roofline", "tree_from_stats", "device_info",
 ]
 
-#: (peak GFLOP/s, peak GB/s) per jax backend — deliberately coarse
-#: defaults; deployments set TRINO_TPU_PEAK_GFLOPS/_PEAK_GBPS to the
-#: part they actually run on (v4 fp32, v5e bf16, ...)
-_BACKEND_PEAKS = {
-    "tpu": (275_000.0, 1_200.0),
-    "gpu": (19_500.0, 900.0),
+#: (peak GFLOP/s, peak GB/s) per ``device_kind`` as jax reports it.
+#: A device that is not in the table is an error, not a default: a
+#: roofline share against the wrong peaks is worse than none.
+_DEVICE_PEAKS = {
+    # TPU v5e, one chip: 197 TFLOP/s bf16, 819 GB/s HBM (16 GB) —
+    # Google Cloud documentation, "TPU v5e"
+    "TPU v5 lite": (197_000.0, 819.0),
+    # XLA:CPU, for the tests' virtual devices only — a nominal host,
+    # not a measured one; shares against it are not device metrics
     "cpu": (150.0, 50.0),
 }
 
 
-def peak_rates() -> tuple[float, float]:
-    """(peak_gflops, peak_gbps) for the roofline ceiling: env
-    overrides first, then the backend default table."""
-    try:
-        import jax
+def device_info() -> dict:
+    """What ``GET /v1/info`` says about the device: platform,
+    device_kind and device_count of the backend this process has
+    initialised, and per device the allocator's ``bytes_in_use`` /
+    ``peak_bytes_in_use`` (``None`` where the backend keeps no such
+    statistics, as XLA:CPU). Where the process has initialised no
+    backend everything is ``None``/0/[] — a host-only role must not
+    take a chip just to answer."""
+    from jax._src import xla_bridge
 
-        backend = jax.default_backend()
-    except Exception:  # pragma: no cover - jax always importable here
-        backend = "cpu"
-    gflops, gbps = _BACKEND_PEAKS.get(backend, _BACKEND_PEAKS["cpu"])
+    if not xla_bridge.backends_are_initialized():
+        return {
+            "platform": None, "device_kind": None, "device_count": 0,
+            "device_memory": [],
+        }
+    import jax
+
+    devices = jax.devices()
+    memory = []
+    for d in devices:
+        stats = d.memory_stats() or {}
+        memory.append({
+            "bytes_in_use": stats.get("bytes_in_use"),
+            "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+        })
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+        "device_memory": memory,
+    }
+
+
+def peak_rates() -> tuple[float, float]:
+    """(peak_gflops, peak_gbps) of the default device, for the roofline
+    ceiling. TRINO_TPU_PEAK_GFLOPS/_PEAK_GBPS override a known row;
+    an unknown ``device_kind`` raises."""
+    import jax
+
+    kind = jax.devices()[0].device_kind
+    if kind not in _DEVICE_PEAKS:
+        raise LookupError(
+            f"no peak rates for device_kind {kind!r}: add a sourced "
+            "row to trino_tpu.profiler._DEVICE_PEAKS"
+        )
+    gflops, gbps = _DEVICE_PEAKS[kind]
     gflops = float(os.environ.get("TRINO_TPU_PEAK_GFLOPS", gflops))
     gbps = float(os.environ.get("TRINO_TPU_PEAK_GBPS", gbps))
     return gflops, gbps

@@ -32,7 +32,7 @@ from dataclasses import dataclass, field
 from decimal import Decimal
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
-from trino_tpu import session_properties as sp
+from trino_tpu import profiler, session_properties as sp
 from trino_tpu.engine import QueryResult, QueryRunner
 from trino_tpu.tracker import QueryTracker
 
@@ -266,6 +266,7 @@ class Coordinator:
                         "nodeVersion": {"version": "trino-tpu-0.1"},
                         "coordinator": True,
                         "starting": False,
+                        **profiler.device_info(),
                     })
                     return
                 if self.path == "/v1/queries":
@@ -950,10 +951,6 @@ def main():
         help="session property override (repeatable)",
     )
     args = ap.parse_args()
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     journal = None
     if args.workers:
         from trino_tpu.connectors.tpch.connector import TpchConnector

@@ -806,36 +806,34 @@ def install_jax_compile_hook() -> bool:
     request) reroutes that sample to
     ``trino_persistent_cache_hits_total`` instead.
 
-    Idempotent; returns True when the hook is (already) active. Uses the
-    private ``jax._src.monitoring`` registration API (present on jax
-    0.4.x); degrades to a no-op when unavailable.
+    Idempotent; returns True. Uses the private ``jax._src.monitoring``
+    registration API of the installed jax (0.9.0): were it missing the
+    import raises — compile counters that silently read 0 would make
+    every process look warm.
     """
     global _hook_installed
     with _hook_lock:
         if _hook_installed:
             return True
-        try:
-            from jax._src import monitoring as _mon
+        from jax._src import monitoring as _mon
 
-            def _on_event(event: str, **kw: Any) -> None:
-                if event == _PCACHE_HIT_EVENT:
-                    _hook_tls.pcache_hit = True
+        def _on_event(event: str, **kw: Any) -> None:
+            if event == _PCACHE_HIT_EVENT:
+                _hook_tls.pcache_hit = True
 
-            def _on_duration(event: str, duration: float, **kw: Any) -> None:
-                if event == _COMPILE_EVENT:
-                    if getattr(_hook_tls, "pcache_hit", False):
-                        _hook_tls.pcache_hit = False
-                        PERSISTENT_CACHE_HITS.inc()
-                    else:
-                        XLA_COMPILES.inc()
-                        XLA_COMPILE_SECONDS.inc(duration)
+        def _on_duration(event: str, duration: float, **kw: Any) -> None:
+            if event == _COMPILE_EVENT:
+                if getattr(_hook_tls, "pcache_hit", False):
+                    _hook_tls.pcache_hit = False
+                    PERSISTENT_CACHE_HITS.inc()
+                else:
+                    XLA_COMPILES.inc()
+                    XLA_COMPILE_SECONDS.inc(duration)
 
-            _mon.register_event_listener(_on_event)
-            _mon.register_event_duration_secs_listener(_on_duration)
-            _hook_installed = True
-        except Exception:
-            _hook_installed = False
-        return _hook_installed
+        _mon.register_event_listener(_on_event)
+        _mon.register_event_duration_secs_listener(_on_duration)
+        _hook_installed = True
+        return True
 
 
 def compile_snapshot() -> Dict[str, float]:

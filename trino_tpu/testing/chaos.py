@@ -70,17 +70,31 @@ _JOIN_SQL = (
 )
 
 
+def child_env(platform: str | None, extra_env: dict | None = None) -> dict:
+    """Environment of a spawned server process. ``platform`` is the
+    child's ``JAX_PLATFORMS``; with ``None`` the child gets no such
+    variable — not even the parent's — and takes jax's default backend
+    (the chip, on a machine that has one: one process per chip, so the
+    caller must not hold it). Tests pass ``"cpu"``."""
+    env = os.environ.copy()
+    env.pop("JAX_PLATFORMS", None)
+    env.pop("XLA_FLAGS", None)
+    if platform is not None:
+        env["JAX_PLATFORMS"] = platform
+    env.update(extra_env or {})
+    return env
+
+
 def spawn_workers(
     n: int = 2, base_port: int = CHAOS_BASE_PORT,
     timeout_s: float = 120, extra_env: dict | None = None,
+    platform: str | None = None,
 ):
     """Start ``n`` worker processes; returns (procs, uris).
     ``extra_env`` overlays the inherited environment (e.g.
-    ``TRINO_TPU_ORPHAN_TTL_S`` to arm the orphan reaper)."""
-    env = os.environ.copy()
-    env["JAX_PLATFORMS"] = "cpu"
-    env.pop("XLA_FLAGS", None)
-    env.update(extra_env or {})
+    ``TRINO_TPU_ORPHAN_TTL_S`` to arm the orphan reaper);
+    ``platform`` as in :func:`child_env`."""
+    env = child_env(platform, extra_env)
     procs, uris = [], []
     for i in range(n):
         port = base_port + i
@@ -517,6 +531,7 @@ def run_skew_chaos(
 
 def run_elastic_chaos(
     seed: int = 0, base_port: int = 19360, spool_root: str | None = None,
+    platform: str | None = None,
 ) -> dict:
     """Elastic-fleet chaos (scale-down is not a crash): spawns its own
     3-worker fleets at ``base_port``+ so it can drain and kill them.
@@ -567,7 +582,7 @@ def run_elastic_chaos(
             return json.loads(r.read()).get("state", "?")
 
     # ---- scenario 1: graceful drain mid-query -----------------------
-    procs, uris = spawn_workers(3, base_port=base_port)
+    procs, uris = spawn_workers(3, base_port=base_port, platform=platform)
     try:
         root = spool_root or tempfile.mkdtemp(prefix="chaos-elastic")
         fleet = elastic_fleet(uris, root)
@@ -614,7 +629,9 @@ def run_elastic_chaos(
         stop_workers(procs)
 
     # ---- scenario 2: hard-kill a DRAINING worker --------------------
-    procs, uris = spawn_workers(3, base_port=base_port + 4)
+    procs, uris = spawn_workers(
+        3, base_port=base_port + 4, platform=platform
+    )
     try:
         root = spool_root or tempfile.mkdtemp(prefix="chaos-elastic")
         target = uris[-1]
@@ -651,6 +668,7 @@ def run_elastic_chaos(
 
 def run_cache_chaos(
     seed: int = 0, base_port: int = 19440, spool_root: str | None = None,
+    platform: str | None = None,
 ) -> dict:
     """Cache-tier chaos (a cache is never load-bearing): the same
     kill-mid-query round runs as twins — device cache OFF, then ON
@@ -695,7 +713,8 @@ def run_cache_chaos(
 
     for cached in (False, True):
         procs, uris = spawn_workers(
-            3, base_port=base_port + (4 if cached else 0)
+            3, base_port=base_port + (4 if cached else 0),
+            platform=platform,
         )
         try:
             root = spool_root or tempfile.mkdtemp(prefix="chaos-cache")
@@ -756,6 +775,7 @@ def run_cache_chaos(
 
 def run_recovery_chaos(
     seed: int = 0, base_port: int = 19520, spool_root: str | None = None,
+    platform: str | None = None,
 ) -> dict:
     """Coordinator crash-recovery chaos: a real coordinator *process*
     is ``kill -9``'d mid-FTE-query and restarted against the same
@@ -797,9 +817,7 @@ def run_recovery_chaos(
     record: dict = {"seed": seed, "runs": []}
 
     def spawn_coordinator(port, worker_uris, root, delay_ms):
-        env = os.environ.copy()
-        env["JAX_PLATFORMS"] = "cpu"
-        env.pop("XLA_FLAGS", None)
+        env = child_env(platform)
         proc = subprocess.Popen(
             [sys.executable, "-m", "trino_tpu.server.coordinator",
              "--port", str(port),
@@ -872,7 +890,7 @@ def run_recovery_chaos(
         return total
 
     # ---- scenario 1: kill -9 mid-query, restart, same client --------
-    procs, uris = spawn_workers(2, base_port=base_port)
+    procs, uris = spawn_workers(2, base_port=base_port, platform=platform)
     coord_proc = None
     try:
         # per-scenario subdirectory: the journal is part of the spool
@@ -974,7 +992,7 @@ def run_recovery_chaos(
     # ---- scenario 2: kill the coordinator, let the reaper clean up --
     procs, uris = spawn_workers(
         2, base_port=base_port + 16,
-        extra_env={"TRINO_TPU_ORPHAN_TTL_S": "0.5"},
+        extra_env={"TRINO_TPU_ORPHAN_TTL_S": "0.5"}, platform=platform,
     )
     coord_proc = None
     try:
@@ -1075,6 +1093,7 @@ _WRITE_SQL = (
 
 def run_write_chaos(
     seed: int = 0, base_port: int = 19720, spool_root: str | None = None,
+    platform: str | None = None,
 ) -> dict:
     """Write-path chaos: the exactly-once commit contract under the
     same fault model as reads. Spawns its own 2-worker fleets (hive
@@ -1160,7 +1179,7 @@ def run_write_chaos(
         "TRINO_TPU_WORKER_EXTRA_PARQUET": f"hive={hive_root}",
     }
     procs, uris = spawn_workers(
-        2, base_port=base_port, extra_env=extra_env
+        2, base_port=base_port, extra_env=extra_env, platform=platform
     )
     try:
         root = spool_root or tempfile.mkdtemp(prefix="chaos-write")
@@ -1197,7 +1216,8 @@ def run_write_chaos(
 
     # scenario 2: SIGKILL a worker as a writer-stage task lands on it
     procs, uris = spawn_workers(
-        2, base_port=base_port + 4, extra_env=extra_env
+        2, base_port=base_port + 4, extra_env=extra_env,
+        platform=platform,
     )
     try:
         root = spool_root or tempfile.mkdtemp(prefix="chaos-write")
