@@ -1,0 +1,105 @@
+"""The traffic generator: the seed orders and picks parameters, it never
+changes what a window is made of."""
+
+from collections import Counter
+
+import traffic
+
+
+def texts(sts):
+    return [(s.template, tuple(sorted(s.params.items())), round(s.due_s, 9))
+            for s in sts]
+
+
+def open_mix():
+    """No cell is open-loop today: the long templates at 2 a second."""
+    mix = traffic.load_mix("power")
+    mix.update(loop="open", rate_per_s=2.0, block={"long": 6},
+               classes={"long": ["q01", "q03", "q06", "q18"]})
+    return mix
+
+
+def test_same_seed_same_statements_and_due_times():
+    big = 2**31 + 12345  # the driver's seeds do not fit 32 signed bits
+    a = traffic.open_schedule(open_mix(), big, 51.0)
+    b = traffic.open_schedule(open_mix(), big, 51.0)
+    assert texts(a) == texts(b)
+
+
+def test_two_seeds_same_multiset_other_order_gaps_and_parameters():
+    a = traffic.open_schedule(open_mix(), 1, 51.0)
+    b = traffic.open_schedule(open_mix(), 2, 51.0)
+    assert Counter(s.template for s in a) == Counter(s.template for s in b)
+    assert [s.template for s in a] != [s.template for s in b]
+    assert [s.params for s in a] != [s.params for s in b]
+    assert [s.due_s for s in a] != [s.due_s for s in b]
+
+
+def test_open_window_composition_rate_and_due_inside_seconds():
+    mix = open_mix()
+    sts = traffic.open_schedule(mix, 7, 51.0)
+    per = Counter(s.template for s in sts)
+    assert len(set(per.values())) == 1, "the templates of a class take equal turns"
+    assert len(sts) % 12 == 0, "whole rotations of blocks only"
+    assert sts[0].due_s == 0.0 and sts[-1].due_s < 51.0
+    assert [s.due_s for s in sts] == sorted(s.due_s for s in sts)
+    gaps = [y.due_s - x.due_s for x, y in zip(sts, sts[1:])]
+    # exponential gaps, rescaled so that the offered rate is the mix's
+    assert max(gaps) > 3 * sum(gaps) / len(gaps), "a tail, not a metronome"
+    last = len(sts) / mix["rate_per_s"]
+    assert sts[-1].due_s < last <= 51.0
+
+
+def test_closed_loop_whole_passes_only():
+    mix = traffic.load_mix("power")
+    a, b = traffic.ClosedLoop(mix, 5), traffic.ClosedLoop(mix, 5)
+    c = traffic.ClosedLoop(mix, 6)
+    orders = set()
+    for k in range(12):
+        pa, pb, pc = a.next_pass(), b.next_pass(), c.next_pass()
+        assert texts(pa) == texts(pb)
+        assert sorted(s.template for s in pa) == sorted(mix["pass"])
+        assert sorted(s.template for s in pc) == sorted(mix["pass"])
+        assert all(s.group == k for s in pa)
+        orders.add(tuple(s.template for s in pa))
+    assert len(orders) > 1, "the seed permutes the order inside a pass"
+
+
+def test_streams_keep_their_own_order_and_the_seed_picks_parameters():
+    mix = traffic.load_mix("throughput")
+    assert mix["clients"] == len(mix["streams"]) == 2
+    # TPC-H Appendix A, streams 1 and 2, cut to the four queries
+    full = {
+        0: [21, 3, 18, 5, 11, 7, 6, 20, 17, 12, 16, 15, 13, 10, 2, 8, 14, 19, 9, 22, 1, 4],
+        1: [6, 17, 14, 16, 19, 10, 9, 2, 15, 8, 5, 22, 12, 7, 13, 18, 1, 4, 20, 3, 11, 21],
+    }
+    for caller, order in full.items():
+        assert sorted(order) == list(range(1, 23))
+        cut = [f"q{q:02d}" for q in order if q in (1, 3, 6, 18)]
+        assert mix["streams"][caller] == cut
+        for seed in (3, 4):
+            loop = traffic.ClosedLoop(mix, seed, caller)
+            for _ in range(5):
+                assert [s.template for s in loop.next_pass()] == cut
+    years = lambda seed: [s.params["DATE"] for _ in range(8) for s in
+                          traffic.ClosedLoop(mix, seed, 0).next_pass()
+                          if s.template == "q06"]
+    assert years(3) == years(3)
+    picks = {tuple(s.params["DATE"] for k in range(8)
+                   for s in [loop.next_pass()[2]])
+             for loop in (traffic.ClosedLoop(mix, sd, 0) for sd in range(6))}
+    assert len(picks) > 1, "seeds pick other parameter tuples"
+
+
+def test_every_statement_text_is_in_the_closed_set():
+    for name in ("power", "throughput"):
+        mix = traffic.load_mix(name)
+        closed = {s.sql for s in traffic.all_statements(mix)}
+        sent = set()
+        for caller in range(mix["clients"]):
+            loop = traffic.ClosedLoop(mix, 99 + caller, caller)
+            sent |= {s.sql for _ in range(20) for s in loop.next_pass()}
+        assert sent <= closed
+        assert "{" not in "".join(closed), "an unfilled placeholder"
+    sent = {s.sql for s in traffic.open_schedule(open_mix(), 99, 51.0)}
+    assert sent <= {s.sql for s in traffic.all_statements(open_mix())}
