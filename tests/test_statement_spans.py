@@ -1,0 +1,407 @@
+"""One span tree per statement (ISSUE 25): the tree a served statement
+yields, its nesting, the runner-lock wait, the flat totals on ``GET
+/v1/query``, the tree on ``GET /v1/query/{id}``, the protocol's stats,
+``build_trace`` on a jit miss, every device->host read inside a
+``host_sync`` or ``to_rows`` span, program names, and no backend
+initialised by opening spans."""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+import subprocess
+import sys
+import time
+import urllib.request
+
+import jax
+import pytest
+
+from trino_tpu import telemetry
+from trino_tpu.connectors.tpch.queries import QUERIES
+from trino_tpu.engine import QueryRunner
+from trino_tpu.server.coordinator import Coordinator
+
+#: the names of a served statement's tree (PERF.md section 3 lists them)
+ALWAYS = {"statement", "queued", "runner_wait", "parse", "plan",
+          "execute", "to_rows", "respond"}
+UNDER_EXECUTE = {"dispatch", "build_trace", "host_sync", "upload"}
+FLAT_FIELDS = (
+    "runner_wait_ms", "parse_ms", "plan_ms", "execute_ms",
+    "build_trace_ms", "host_sync_ms", "host_syncs", "dispatches",
+    "upload_ms", "to_rows_ms", "respond_ms", "rows_out_ms",
+)
+PROGRAM = re.compile(
+    r"^(chain_[A-Za-z_]+|join_count|join_bounds|join_expand|semi_join"
+    r"|compact)$"
+)
+FOUR = ("q01", "q03", "q06", "q18")
+
+
+@pytest.fixture(scope="module")
+def coord():
+    c = Coordinator(runner=QueryRunner.tpch("tiny"), port=0).start()
+    yield c
+    c.stop()
+
+
+def get(coord, path):
+    with urllib.request.urlopen(coord.uri + path, timeout=30) as r:
+        return json.loads(r.read())
+
+
+def serve(coord, sql):
+    """Run one statement through the protocol; the first response's id."""
+    req = urllib.request.Request(
+        coord.uri + "/v1/statement", data=sql.encode(), method="POST"
+    )
+    with urllib.request.urlopen(req, timeout=60) as r:
+        first = json.loads(r.read())
+    resp, last = first, first
+    while resp.get("nextUri"):
+        with urllib.request.urlopen(resp["nextUri"], timeout=60) as r:
+            resp = last = json.loads(r.read())
+    assert "error" not in last, last
+    return first["id"], last
+
+
+def row_of(coord, qid):
+    """The statement's row of ``GET /v1/query`` once its ``respond``
+    span is on it (the handler closes the span after the client has the
+    last byte, so a client can ask a moment too soon)."""
+    deadline = time.time() + 5
+    while True:
+        row = {q["query_id"]: q for q in get(coord, "/v1/query")}[qid]
+        if row.get("respond_ms") or time.time() > deadline:
+            return row
+        time.sleep(0.01)
+
+
+def walk(span, parent=None):
+    yield span, parent
+    for ch in span["children"]:
+        yield from walk(ch, span)
+
+
+def end_ms(span):
+    return span["start_ms"] + span["duration_ms"]
+
+
+def test_served_statement_yields_one_tree_of_documented_names(coord):
+    qid, _ = serve(coord, QUERIES["q03"])
+    row_of(coord, qid)
+    tree = get(coord, f"/v1/query/{qid}")["spans"]
+    spans = list(walk(tree))
+    names = {sp["name"] for sp, _ in spans}
+    assert ALWAYS <= names <= ALWAYS | UNDER_EXECUTE, names
+    assert tree["name"] == "statement" and tree["parent_id"] is None
+    ids = {sp["span_id"] for sp, _ in spans}
+    assert len(ids) == len(spans)
+    for sp, parent in spans:
+        assert sp["query_id"] == qid, sp
+        if parent is not None:
+            assert sp["parent_id"] == parent["span_id"] and \
+                sp["parent_id"] in ids
+    top = [sp["name"] for sp in tree["children"]]
+    assert top == ["queued", "runner_wait", "parse", "plan", "execute",
+                   "to_rows", "respond"], top
+    under = {sp["name"] for sp, par in spans
+             if par is not None and par["name"] == "execute"}
+    assert under <= UNDER_EXECUTE and "dispatch" in under
+    for sp, par in spans:
+        if sp["name"] == "build_trace":
+            assert par["name"] == "dispatch"
+
+
+def test_children_lie_inside_their_parents_and_add_up(coord):
+    qid, _ = serve(coord, QUERIES["q18"])
+    row_of(coord, qid)
+    tree = get(coord, f"/v1/query/{qid}")["spans"]
+    slack = 0.5  # ms: start is the wall clock, duration the monotonic
+    for sp, parent in walk(tree):
+        if parent is None or sp["name"] == "respond":
+            continue  # respond runs after ``statement`` closed
+        assert sp["start_ms"] >= parent["start_ms"] - slack, sp["name"]
+        assert end_ms(sp) <= end_ms(parent) + slack, sp["name"]
+    parts = sum(sp["duration_ms"] for sp in tree["children"]
+                if sp["name"] != "respond")
+    assert tree["duration_ms"] >= parts - slack
+    # nothing is timed twice: the layers of a statement, in order
+    kids = [sp for sp in tree["children"] if sp["name"] != "respond"]
+    for a, b in zip(kids, kids[1:]):
+        assert end_ms(a) <= b["start_ms"] + slack, (a["name"], b["name"])
+    respond = [sp for sp in tree["children"] if sp["name"] == "respond"]
+    assert respond and respond[0]["start_ms"] >= end_ms(tree) - slack
+
+
+def test_runner_wait_is_the_wait_for_the_statement_ahead():
+    runner = QueryRunner.tpch("tiny")
+    coord = Coordinator(runner=runner, port=0).start()
+    try:
+        serve(coord, "select count(*) from nation")  # warm
+        lone, _ = serve(coord, "select count(*) from nation")
+        row = {q["query_id"]: q for q in get(coord, "/v1/query")}[lone]
+        assert row["runner_wait_ms"] < 5.0, row
+        # two statements together against one runner: the second waits
+        # on the runner's lock for as long as the first executes
+        runner.session.properties["execution_delay_ms"] = 400.0
+        first = coord.submit("select count(*) from region")
+        deadline = time.time() + 10
+        while first.state != "RUNNING" and time.time() < deadline:
+            time.sleep(0.005)
+        second = coord.submit("select count(*) from region")
+        for q in (first, second):
+            while q.state in ("QUEUED", "RUNNING") and time.time() < deadline:
+                time.sleep(0.01)
+            assert q.state == "FINISHED", (q.state, q.error)
+        rows = {q["query_id"]: q for q in get(coord, "/v1/query")}
+        a, b = rows[first.query_id], rows[second.query_id]
+        assert a["runner_wait_ms"] < 5.0, a
+        # the first holds the lock for its sleep and its execution; the
+        # second came within milliseconds of the first's start
+        assert b["runner_wait_ms"] >= 400.0 - 100.0, (a, b)
+        assert b["runner_wait_ms"] >= a["execute_ms"] - 100.0, (a, b)
+        # both were RUNNING for the resource group all the while
+        assert b["queued_time_ms"] < 100.0, b
+    finally:
+        runner.session.properties.pop("execution_delay_ms", None)
+        coord.stop()
+
+
+def test_query_rows_carry_flat_fields_and_the_id_serves_the_tree(coord):
+    qid, _ = serve(coord, QUERIES["q06"])
+    row = row_of(coord, qid)
+    for f in FLAT_FIELDS + ("queued_time_ms",):
+        assert isinstance(row[f], (int, float)) and not isinstance(
+            row[f], bool), (f, row.get(f))
+        assert row[f] >= 0
+    assert row["plan_ms"] > 0 and row["execute_ms"] > 0
+    assert row["dispatches"] >= 1
+    assert row["rows_out_ms"] == pytest.approx(
+        row["to_rows_ms"] + row["respond_ms"])
+    info = get(coord, f"/v1/query/{qid}")
+    tree = info["spans"]
+    by_name: dict = {}
+    for sp, _ in walk(tree):
+        by_name[sp["name"]] = by_name.get(sp["name"], 0.0) + sp["duration_ms"]
+    for name in ("runner_wait", "parse", "plan", "execute", "to_rows",
+                 "respond"):
+        assert row[name + "_ms"] == pytest.approx(by_name[name]), name
+    assert info["plan_ms"] == row["plan_ms"]
+
+
+def test_protocol_stats_carry_queued_and_planning_time(coord):
+    coord.runner.session.properties["planning_delay_ms"] = 30.0
+    try:
+        _, last = serve(coord, "select count(*) from supplier")
+    finally:
+        coord.runner.session.properties.pop("planning_delay_ms", None)
+    stats = last["stats"]
+    assert stats["state"] == "FINISHED"
+    assert isinstance(stats["queuedTimeMillis"], int)
+    assert isinstance(stats["planningTimeMillis"], int)
+    assert stats["planningTimeMillis"] >= 30
+    assert stats["queuedTimeMillis"] <= stats["elapsedTimeMillis"]
+
+
+def test_a_jit_miss_has_a_build_trace_child_and_a_hit_none():
+    runner = QueryRunner.tpch("tiny")
+    sql = "select sum(s_acctbal) from supplier where s_suppkey < 37"
+    cold = runner.execute(sql).trace
+    warm = runner.execute(sql).trace
+    cold_d = cold.find(name="dispatch")
+    assert cold_d and all(d.attrs["miss"] for d in cold_d)
+    for d in cold_d:
+        assert [c.name for c in d.children] == ["build_trace"]
+        assert d.children[0].duration_ms <= d.duration_ms
+    warm_d = warm.find(name="dispatch")
+    assert len(warm_d) == len(cold_d)
+    assert not any(d.attrs["miss"] or d.children for d in warm_d)
+    assert not warm.find(name="build_trace")
+    # planning_ms is the plan span's duration (no hand-kept twin)
+    res = runner.execute(sql)
+    assert res.planning_ms == pytest.approx(
+        sum(s.duration_ms for s in res.trace.find(name="plan")))
+    assert res.trace.find(kind="planning")[0].name == "plan"
+    assert not hasattr(runner, "_plan_ms")
+
+
+@pytest.mark.parametrize("q", FOUR)
+def test_every_device_read_falls_inside_a_host_sync_or_to_rows(q, monkeypatch):
+    """The transfer guard is silent on the CPU backend, so this is the
+    CPU-side check: ``jax.device_get`` wrapped, every call made while
+    the statement runs must find a ``host_sync`` span open on its
+    thread (or be the result transfer inside ``to_rows``)."""
+    runner = QueryRunner.tpch("tiny")
+    runner.execute(QUERIES[q])  # learned capacities, programs built
+    real = jax.device_get
+    seen: list = []
+
+    def wrapped(x):
+        active = telemetry.active_span()
+        seen.append(active.name if active is not None else None)
+        return real(x)
+
+    monkeypatch.setattr(jax, "device_get", wrapped)
+    res = runner.execute(QUERIES[q])
+    monkeypatch.undo()
+    trace = res.trace
+    # ``to_rows`` is opened by the engine with no thread anchor: the one
+    # read with no active span is the result transfer inside it
+    outside = [s for s in seen if s != "host_sync"]
+    assert outside == [None], seen
+    assert len(trace.find(name="to_rows")) == 1
+    syncs = [s for s in trace.find(name="host_sync")]
+    # one more than the reads: the wait for the result page's programs
+    # (no transfer), the last thing under ``execute``
+    assert len(syncs) == seen.count("host_sync") + 1
+    assert [s.attrs["site"] for s in syncs].count("result") == 1
+    execute = trace.find(name="execute")[0]
+    assert execute.children[-1].attrs.get("site") == "result"
+    assert all(s in list(execute.walk()) for s in syncs)
+    assert all(s.attrs.get("site") for s in syncs)
+    totals = telemetry.span_totals(trace.root)
+    assert totals.get("host_syncs", 0) == len(syncs)
+
+
+def test_the_flight_recorder_reads_the_executors_spans_as_execution():
+    """``time_breakdown`` (EXPLAIN ANALYZE's footer, history's buckets,
+    the sentry's attribution) read ``execute``'s self time as
+    scan/compute before the executor's work was split into spans; the
+    spans under it and ``to_rows`` beside it are execution time too."""
+    from trino_tpu import telemetry_analysis
+
+    runner = QueryRunner.tpch("tiny")
+    runner.execute(QUERIES["q01"])
+    res = runner.execute(QUERIES["q01"])
+    execute = res.trace.find(name="execute")[0]
+    under = [s for s in execute.walk() if s is not execute]
+    assert under and {s.kind for s in under} == {"execution"}
+    assert res.trace.find(name="to_rows")[0].kind == "execution"
+    for sp in under:
+        assert telemetry_analysis._classify(sp) == telemetry_analysis._EXEC
+    b = res.time_breakdown["buckets"]
+    exec_ms = execute.duration_ms + res.trace.find(name="to_rows")[0].duration_ms
+    # what the statement spent executing is in scan + compute, not other
+    held = b["scan"] + b["compute"] + b["xla_compile"]
+    assert 0.9 * exec_ms <= held <= 1.01 * exec_ms, (b, exec_ms)
+    assert b["other"] < 0.5 * held, b
+
+
+def test_a_worker_tasks_executor_spans_keep_the_tasks_kind():
+    task = telemetry.Span(name="task t0", kind="task")
+    telemetry.set_active_span(task)
+    try:
+        with telemetry.child_span("dispatch", program="compact") as d:
+            with telemetry.child_span("build_trace") as b:
+                pass
+    finally:
+        telemetry.set_active_span(None)
+    assert d.kind == b.kind == "task" and d.attrs == {"program": "compact"}
+    assert telemetry.active_span() is None
+
+
+def test_a_page_fetched_again_adds_no_respond_span(coord):
+    qid, _ = serve(coord, "select n_name from nation order by 1")
+    row = row_of(coord, qid)
+    q = coord._queries[qid]
+    n = sum(1 for c in q.tracer.root.children if c.name == "respond")
+    assert n >= 1 and q.responded
+    again = urllib.request.urlopen(
+        f"{coord.uri}/v1/statement/executing/{qid}/{q.slug}/0", timeout=30
+    )
+    assert json.loads(again.read())["data"]
+    after = row_of(coord, qid)
+    assert sum(1 for c in q.tracer.root.children
+               if c.name == "respond") == n
+    assert after["respond_ms"] == row["respond_ms"]
+    # the planning total is kept at the seal, not walked out each poll
+    assert q.planning_ms == pytest.approx(after["plan_ms"])
+
+
+def test_every_program_of_the_four_queries_is_named_by_what_it_does():
+    runner = QueryRunner.tpch("tiny")
+    programs = set()
+    for q in FOUR:
+        trace = runner.execute(QUERIES[q]).trace
+        dispatches = trace.find(name="dispatch")
+        assert dispatches, q
+        programs |= {d.attrs["program"] for d in dispatches}
+    bad = sorted(p for p in programs if not PROGRAM.match(p))
+    assert not bad, bad
+    assert any(p.startswith("chain_") for p in programs)
+    assert {"join_count", "join_expand", "semi_join", "compact"} <= programs
+    # the name reaches XLA: a jitted program's module is jit_<name>
+    from trino_tpu.exec.local import _named_jit
+
+    def counted(x):
+        return x + 1
+
+    lowered = _named_jit(counted, "chain_Filter_Aggregate").lower(1.0)
+    assert "module @jit_chain_Filter_Aggregate" in lowered.as_text()
+
+
+def test_opening_spans_initialises_no_backend():
+    # a fresh interpreter, as the host-only fleet coordinator is one
+    code = (
+        "import trino_tpu\n"
+        "from trino_tpu import telemetry\n"
+        "from jax._src import xla_bridge\n"
+        "tr = telemetry.Tracer('q1', root_name='statement')\n"
+        "with tr.span('plan', 'planning'):\n"
+        "    telemetry.set_active_span(tr.root)\n"
+        "    with telemetry.child_span('host_sync', site='t') as sp:\n"
+        "        assert sp.query_id == 'q1'\n"
+        "trace = tr.finish()\n"
+        "assert [s.name for s in trace.spans()] == "
+        "['statement', 'plan', 'host_sync'], trace.spans()\n"
+        "assert not xla_bridge.backends_are_initialized()\n"
+    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True)
+    assert p.returncode == 0, p.stderr[-2000:]
+
+
+def test_spans_are_events_on_the_profilers_host_plane(tmp_path):
+    """One clock, two views: while a profiler session runs, a statement's
+    spans are annotations of the trace, each with the query id."""
+    import glob
+
+    import jax.profiler as jp
+    from jax.profiler import ProfileData
+
+    runner = QueryRunner.tpch("tiny")
+    sql = "select count(*) from nation where n_regionkey = 1"
+    runner.execute(sql)
+    opts = jp.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jp.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        res = runner.execute(sql, query_id="q_traced")
+    finally:
+        jp.stop_trace()
+    path = sorted(glob.glob(
+        str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")))[-1]
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                stats = dict(e.stats)
+                if stats.get("query_id") == "q_traced":
+                    events.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    want = {s.name for s in res.trace.spans()}
+    assert want <= set(events), (want, set(events))
+    # the spans nest in the trace as they do in the tree
+    (s_lo, s_hi), = events["statement"]
+    for name in ("parse", "plan", "execute", "to_rows"):
+        for lo, hi in events[name]:
+            assert s_lo <= lo and hi <= s_hi, name
+    (e_lo, e_hi), = events["execute"]
+    for lo, hi in events["dispatch"]:
+        assert e_lo <= lo and hi <= e_hi

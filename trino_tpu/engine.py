@@ -20,6 +20,7 @@ import time
 import uuid
 from dataclasses import dataclass, field
 
+from trino_tpu import telemetry
 from trino_tpu.analyzer.analyzer import Analyzer
 from trino_tpu.connectors.tpch.connector import TpchConnector
 from trino_tpu.exec.local import LocalExecutor
@@ -207,20 +208,12 @@ class QueryRunner:
 
     def plan_stmt(self, stmt: ast.Statement, optimized: bool = True) -> P.PlanNode:
         """Analyze + optimize one statement, timed into the active
-        query's planning span (when ``execute`` opened one)."""
+        statement's ``plan`` span (when ``execute`` opened a tree)."""
         tracer = getattr(self, "_tracer", None)
-        t_span = time.perf_counter()
-        try:
-            if tracer is not None:
-                with tracer.span("planning", "planning",
-                                 stmt=type(stmt).__name__):
-                    return self._plan_stmt_inner(stmt, optimized)
+        if tracer is None:
             return self._plan_stmt_inner(stmt, optimized)
-        finally:
-            self._plan_ms = (
-                getattr(self, "_plan_ms", 0.0)
-                + (time.perf_counter() - t_span) * 1e3
-            )
+        with tracer.span("plan", "planning", stmt=type(stmt).__name__):
+            return self._plan_stmt_inner(stmt, optimized)
 
     def _plan_stmt_inner(
         self, stmt: ast.Statement, optimized: bool = True
@@ -300,293 +293,333 @@ class QueryRunner:
 
     def execute(
         self, sql: str, cancel_event=None, query_id: str | None = None,
+        tracer=None,
+    ) -> QueryResult:
+        """``tracer``: the statement's span tree where the caller opened
+        one (the coordinator does, in ``submit``, so that the tree
+        starts before the queue and is sealed by it); without one the
+        runner opens and seals its own."""
+        query_id = query_id or uuid.uuid4().hex[:12]
+        own_tracer = tracer is None
+        if own_tracer:
+            tracer = telemetry.Tracer(query_id, root_name="statement")
+        # statements take the runner in turn (see __init__): the wait
+        # for the one ahead is the queue below the resource group's
+        wait = tracer.start("runner_wait")
+        try:
+            with self._lock:
+                wait.finish()
+                return self._execute_locked(
+                    sql, cancel_event, query_id, tracer, own_tracer
+                )
+        finally:
+            if own_tracer:
+                tracer.finish()  # a failed statement's tree too
+
+    def _execute_locked(
+        self, sql: str, cancel_event, query_id: str, tracer,
+        own_tracer: bool,
     ) -> QueryResult:
         from trino_tpu import session_properties
 
-        with self._lock:
-            self.executor.cancel_event = cancel_event
-            # absolute execution deadline: boundary checks inside the
-            # executor turn it into QueryDeadlineExceededError; the
-            # coordinator's QueryTracker reaps queries that wedge
-            # between boundaries
-            max_exec_s = session_properties.parse_duration(
-                session_properties.get(
-                    self.session, "query_max_execution_time"
-                )
+        self.executor.cancel_event = cancel_event
+        # absolute execution deadline: boundary checks inside the
+        # executor turn it into QueryDeadlineExceededError; the
+        # coordinator's QueryTracker reaps queries that wedge
+        # between boundaries
+        max_exec_s = session_properties.parse_duration(
+            session_properties.get(
+                self.session, "query_max_execution_time"
             )
-            self.executor.deadline = (
-                time.monotonic() + max_exec_s if max_exec_s > 0 else None
-            )
-            query_id = query_id or uuid.uuid4().hex[:12]
-            # per-query memory context: all executor reservations made
-            # by this statement attribute to this query's subtree of
-            # the pool (restored afterwards so ad-hoc executor use
-            # keeps its default context)
-            prev_ctx = self.executor.memory_ctx
-            qctx = self.executor.memory_pool.query_context(query_id)
-            self.executor.memory_ctx = qctx
-            from trino_tpu import telemetry, tracker
-            from trino_tpu.profiler import OperatorProfiler
+        )
+        self.executor.deadline = (
+            time.monotonic() + max_exec_s if max_exec_s > 0 else None
+        )
+        # per-query memory context: all executor reservations made
+        # by this statement attribute to this query's subtree of
+        # the pool (restored afterwards so ad-hoc executor use
+        # keeps its default context)
+        prev_ctx = self.executor.memory_ctx
+        qctx = self.executor.memory_pool.query_context(query_id)
+        self.executor.memory_ctx = qctx
+        from trino_tpu import tracker
+        from trino_tpu.profiler import OperatorProfiler
 
-            prev_tracer = getattr(self, "_tracer", None)
-            prev_plan_ms = getattr(self, "_plan_ms", 0.0)
-            tracer = telemetry.Tracer(query_id)
-            self._tracer = tracer
-            self._plan_ms = 0.0
-            tracker.QUERY_INFO.begin(
-                query_id, sql=sql, user=self.session.user
-            )
-            prev_prof = self.executor.profiler
-            self.executor.profiler = prof = OperatorProfiler()
-            from trino_tpu import cache as cache_mod
+        prev_tracer = getattr(self, "_tracer", None)
+        self._tracer = tracer
+        # the spans this call adds to the tree: the flight
+        # recorder's window is lock acquired -> here, whoever
+        # opened the tree and however long it waited before
+        root = tracer.root
+        n_before = len(root.children)
+        start_ms = time.time() * 1e3
+        tracker.QUERY_INFO.begin(
+            query_id, sql=sql, user=self.session.user
+        )
+        prev_prof = self.executor.profiler
+        self.executor.profiler = prof = OperatorProfiler()
+        from trino_tpu import cache as cache_mod
 
-            prev_cstats = getattr(self.executor, "cache_stats", None)
-            prev_self_cstats = getattr(self, "_cache_stats", None)
-            cstats = cache_mod.CacheStats()
-            self._cache_stats = cstats
-            self.executor.cache_stats = cstats
-            kp_mode = str(
-                session_properties.get(self.session, "kernel_profile")
-                or "OFF"
-            ).upper()
-            t0 = time.perf_counter()
-            # compile-counter baseline: the delta attributes THIS
-            # statement's backend compiles (hook is process-wide)
-            comp0 = telemetry.compile_snapshot()
-            error = None
-            result = None
-            try:
-                if kp_mode in ("ON", "AUTO"):
-                    # device-profile the statement; attribution lands
-                    # on QueryResult.kernel_profile (and, for AUTO, on
-                    # the slow-query record when the threshold fires)
-                    from trino_tpu import kernel_profile
+        prev_cstats = getattr(self.executor, "cache_stats", None)
+        prev_self_cstats = getattr(self, "_cache_stats", None)
+        cstats = cache_mod.CacheStats()
+        self._cache_stats = cstats
+        self.executor.cache_stats = cstats
+        kp_mode = str(
+            session_properties.get(self.session, "kernel_profile")
+            or "OFF"
+        ).upper()
+        t0 = time.perf_counter()
+        # compile-counter baseline: the delta attributes THIS
+        # statement's backend compiles (hook is process-wide)
+        comp0 = telemetry.compile_snapshot()
+        error = None
+        result = None
+        try:
+            if kp_mode in ("ON", "AUTO"):
+                # device-profile the statement; attribution lands
+                # on QueryResult.kernel_profile (and, for AUTO, on
+                # the slow-query record when the threshold fires)
+                from trino_tpu import kernel_profile
 
-                    with kernel_profile.Capture(
-                        trigger="session" if kp_mode == "ON" else "auto"
-                    ) as kp_cap:
-                        result = self._execute(sql)
-                    result.kernel_profile = kp_cap.summary()
-                else:
+                with kernel_profile.Capture(
+                    trigger="session" if kp_mode == "ON" else "auto"
+                ) as kp_cap:
                     result = self._execute(sql)
-                result.peak_memory_bytes = qctx.peak_bytes
-                if qctx.peak_bytes:
-                    result.peak_memory_per_node = {
-                        self.executor.memory_pool.node_id: qctx.peak_bytes
-                    }
-                return result
-            except Exception as e:
-                error = f"{type(e).__name__}: {e}"
-                raise
-            finally:
-                self.executor.cancel_event = None
-                self.executor.deadline = None
-                self.executor.memory_ctx = prev_ctx
-                self.executor.profiler = prev_prof
-                self.executor.cache_stats = prev_cstats
-                self._cache_stats = prev_self_cstats
-                if result is not None and result.cache_stats is None and (
-                    cstats.result_hit is not None
-                    or cstats.device_hits
-                    or cstats.device_misses
-                ):
-                    result.cache_stats = cstats.as_dict()
-                plan_ms = self._plan_ms
-                self._tracer = prev_tracer
-                self._plan_ms = prev_plan_ms
-                elapsed_ms = (time.perf_counter() - t0) * 1e3
-                state = "FAILED" if error else "FINISHED"
-                telemetry.QUERIES_TOTAL.inc(state=state)
-                node_id = self.executor.memory_pool.node_id
-                # timings-only seal for the live registry; the lazy
-                # QueryResult.query_info resolver is the path that pays
-                # for XLA cost analysis
-                op_stats = prof.finish(None)
-                for _row in op_stats:
-                    telemetry.OPERATOR_SELF_TIME.observe(
-                        _row.get("self_ms", 0.0) / 1e3,
-                        operator=_row.get("node_type", "?"),
+                result.kernel_profile = kp_cap.summary()
+            else:
+                result = self._execute(sql)
+            result.peak_memory_bytes = qctx.peak_bytes
+            if qctx.peak_bytes:
+                result.peak_memory_per_node = {
+                    self.executor.memory_pool.node_id: qctx.peak_bytes
+                }
+            return result
+        except Exception as e:
+            error = f"{type(e).__name__}: {e}"
+            raise
+        finally:
+            self.executor.cancel_event = None
+            self.executor.deadline = None
+            self.executor.memory_ctx = prev_ctx
+            self.executor.profiler = prev_prof
+            self.executor.cache_stats = prev_cstats
+            self._cache_stats = prev_self_cstats
+            if result is not None and result.cache_stats is None and (
+                cstats.result_hit is not None
+                or cstats.device_hits
+                or cstats.device_misses
+            ):
+                result.cache_stats = cstats.as_dict()
+            self._tracer = prev_tracer
+            mine = root.children[n_before:]
+            plan_ms = sum(
+                sp.duration_ms for top in mine for sp in top.walk()
+                if sp.name == "plan"
+            )
+            elapsed_ms = (time.perf_counter() - t0) * 1e3
+            state = "FAILED" if error else "FINISHED"
+            telemetry.QUERIES_TOTAL.inc(state=state)
+            node_id = self.executor.memory_pool.node_id
+            # timings-only seal for the live registry; the lazy
+            # QueryResult.query_info resolver is the path that pays
+            # for XLA cost analysis
+            op_stats = prof.finish(None)
+            for _row in op_stats:
+                telemetry.OPERATOR_SELF_TIME.observe(
+                    _row.get("self_ms", 0.0) / 1e3,
+                    operator=_row.get("node_type", "?"),
+                )
+            tracker.QUERY_INFO.finish(
+                query_id, state=state,
+                rows=len(result.rows) if result else None,
+                error=error,
+                peak_memory_bytes=qctx.peak_bytes,
+                operator_stats=op_stats,
+            )
+            if result is not None:
+                _ex, _prof, _qid = self.executor, prof, query_id
+                result._query_info_resolver = (
+                    lambda: _local_query_info(_ex, _prof, _qid)
+                )
+            comp1 = telemetry.compile_snapshot()
+            compiles_delta = int(
+                comp1.get("compiles", 0) - comp0.get("compiles", 0)
+            )
+            compile_ms_delta = max(
+                (
+                    comp1.get("compile_seconds", 0.0)
+                    - comp0.get("compile_seconds", 0.0)
+                ) * 1e3,
+                0.0,
+            )
+            plan_digest = None
+            fingerprint = None
+            if result is not None and result.plan is not None:
+                from trino_tpu import history as history_mod
+                from trino_tpu import journal as journal_mod
+
+                try:
+                    plan_digest = journal_mod.plan_digest(result.plan)
+                except Exception:
+                    plan_digest = None
+                fingerprint = history_mod.session_fingerprint(
+                    self.session
+                )
+            if result is not None:
+                # a tree handed in is sealed by who opened it
+                result.trace = (
+                    tracer.finish() if own_tracer
+                    else telemetry.Trace(root)
+                )
+                result.planning_ms = plan_ms
+                result.execution_ms = max(elapsed_ms - plan_ms, 0.0)
+                from trino_tpu import telemetry_analysis
+
+                result.time_breakdown = (
+                    telemetry_analysis.compute_time_breakdown(
+                        telemetry.Trace(telemetry.Span(
+                            name=root.name, kind=root.kind,
+                            start_ms=start_ms, duration_ms=elapsed_ms,
+                            children=mine, _open=False,
+                        )),
+                        elapsed_ms, op_stats=op_stats,
+                        compile_ms=compile_ms_delta,
                     )
-                tracker.QUERY_INFO.finish(
-                    query_id, state=state,
-                    rows=len(result.rows) if result else None,
+                )
+                if (
+                    result.time_breakdown
+                    and result.names == ["Query Plan"]
+                    and result.stage_stats
+                ):
+                    # local EXPLAIN ANALYZE (stage_stats filled by
+                    # _explain; plain EXPLAIN has none yet): the
+                    # breakdown footer rides the rendered plan
+                    result.rows.extend(
+                        (line,)
+                        for line in telemetry_analysis
+                        .format_breakdown(result.time_breakdown)
+                    )
+                    # sentry baseline footer — judged against
+                    # history that does NOT yet include this run
+                    # (completion fires below)
+                    from trino_tpu import sentry as sentry_mod
+
+                    _bf = sentry_mod.baseline_footer(
+                        plan_digest, fingerprint or "",
+                        elapsed_ms, result.time_breakdown,
+                    )
+                    if _bf:
+                        result.rows.append((_bf,))
+                if not result.stage_stats:
+                    # local execution is one pseudo-stage; the fleet
+                    # runner fills real per-stage aggregates instead
+                    result.stage_stats = [{
+                        "stage_id": "local",
+                        "tasks": 1,
+                        "rows_in": 0,
+                        "rows_out": len(result.rows),
+                        "bytes_out": 0,
+                        "elapsed_ms": elapsed_ms,
+                        "retries": 0,
+                        "peak_memory_bytes": qctx.peak_bytes,
+                        "admission_wait_ms": 0.0,
+                    }]
+                if not result.task_stats:
+                    # mirror the (possibly _explain-provided)
+                    # stage aggregate so system.runtime.tasks and
+                    # stage_stats always report the same numbers
+                    st = result.stage_stats[0]
+                    result.task_stats = [{
+                        "query_id": query_id,
+                        "stage_id": st["stage_id"],
+                        "task_id": f"{st['stage_id']}.0",
+                        "attempt": 0,
+                        "state": state,
+                        "worker": node_id,
+                        "elapsed_ms": st["elapsed_ms"],
+                        "rows_in": st["rows_in"],
+                        "rows_out": st["rows_out"],
+                        "bytes_out": st["bytes_out"],
+                        "peak_memory_bytes": st[
+                            "peak_memory_bytes"
+                        ],
+                    }]
+            listeners = getattr(self.metadata, "event_listeners", ())
+            if listeners:
+                from trino_tpu.events import (
+                    QueryCompletedEvent,
+                    fire_query_completed,
+                )
+
+                fire_query_completed(listeners, QueryCompletedEvent(
+                    query_id=query_id,
+                    user=self.session.user,
+                    sql=sql,
+                    state=state,
+                    elapsed_ms=elapsed_ms,
+                    rows=len(result.rows) if result else 0,
                     error=error,
                     peak_memory_bytes=qctx.peak_bytes,
-                    operator_stats=op_stats,
-                )
-                if result is not None:
-                    _ex, _prof, _qid = self.executor, prof, query_id
-                    result._query_info_resolver = (
-                        lambda: _local_query_info(_ex, _prof, _qid)
-                    )
-                comp1 = telemetry.compile_snapshot()
-                compiles_delta = int(
-                    comp1.get("compiles", 0) - comp0.get("compiles", 0)
-                )
-                compile_ms_delta = max(
-                    (
-                        comp1.get("compile_seconds", 0.0)
-                        - comp0.get("compile_seconds", 0.0)
-                    ) * 1e3,
-                    0.0,
-                )
-                plan_digest = None
-                fingerprint = None
-                if result is not None and result.plan is not None:
-                    from trino_tpu import history as history_mod
-                    from trino_tpu import journal as journal_mod
-
-                    try:
-                        plan_digest = journal_mod.plan_digest(result.plan)
-                    except Exception:
-                        plan_digest = None
-                    fingerprint = history_mod.session_fingerprint(
-                        self.session
-                    )
-                if result is not None:
-                    result.trace = tracer.finish()
-                    result.planning_ms = plan_ms
-                    result.execution_ms = max(elapsed_ms - plan_ms, 0.0)
-                    from trino_tpu import telemetry_analysis
-
-                    result.time_breakdown = (
-                        telemetry_analysis.compute_time_breakdown(
-                            result.trace, elapsed_ms, op_stats=op_stats,
-                            compile_ms=compile_ms_delta,
-                        )
-                    )
-                    if (
-                        result.time_breakdown
-                        and result.names == ["Query Plan"]
-                        and result.stage_stats
-                    ):
-                        # local EXPLAIN ANALYZE (stage_stats filled by
-                        # _explain; plain EXPLAIN has none yet): the
-                        # breakdown footer rides the rendered plan
-                        result.rows.extend(
-                            (line,)
-                            for line in telemetry_analysis
-                            .format_breakdown(result.time_breakdown)
-                        )
-                        # sentry baseline footer — judged against
-                        # history that does NOT yet include this run
-                        # (completion fires below)
-                        from trino_tpu import sentry as sentry_mod
-
-                        _bf = sentry_mod.baseline_footer(
-                            plan_digest, fingerprint or "",
-                            elapsed_ms, result.time_breakdown,
-                        )
-                        if _bf:
-                            result.rows.append((_bf,))
-                    if not result.stage_stats:
-                        # local execution is one pseudo-stage; the fleet
-                        # runner fills real per-stage aggregates instead
-                        result.stage_stats = [{
-                            "stage_id": "local",
-                            "tasks": 1,
-                            "rows_in": 0,
-                            "rows_out": len(result.rows),
-                            "bytes_out": 0,
-                            "elapsed_ms": elapsed_ms,
-                            "retries": 0,
-                            "peak_memory_bytes": qctx.peak_bytes,
-                            "admission_wait_ms": 0.0,
-                        }]
-                    if not result.task_stats:
-                        # mirror the (possibly _explain-provided)
-                        # stage aggregate so system.runtime.tasks and
-                        # stage_stats always report the same numbers
-                        st = result.stage_stats[0]
-                        result.task_stats = [{
-                            "query_id": query_id,
-                            "stage_id": st["stage_id"],
-                            "task_id": f"{st['stage_id']}.0",
-                            "attempt": 0,
-                            "state": state,
-                            "worker": node_id,
-                            "elapsed_ms": st["elapsed_ms"],
-                            "rows_in": st["rows_in"],
-                            "rows_out": st["rows_out"],
-                            "bytes_out": st["bytes_out"],
-                            "peak_memory_bytes": st[
-                                "peak_memory_bytes"
-                            ],
-                        }]
-                listeners = getattr(self.metadata, "event_listeners", ())
-                if listeners:
-                    from trino_tpu.events import (
-                        QueryCompletedEvent,
-                        fire_query_completed,
-                    )
-
-                    fire_query_completed(listeners, QueryCompletedEvent(
-                        query_id=query_id,
-                        user=self.session.user,
-                        sql=sql,
-                        state=state,
-                        elapsed_ms=elapsed_ms,
-                        rows=len(result.rows) if result else 0,
-                        error=error,
-                        peak_memory_bytes=qctx.peak_bytes,
-                        peak_memory_per_node=(
-                            (node_id, qctx.peak_bytes),
-                        ) if qctx.peak_bytes else (),
-                        planning_ms=plan_ms,
-                        execution_ms=max(elapsed_ms - plan_ms, 0.0),
-                        cpu_ms=max(elapsed_ms - plan_ms, 0.0),
-                        query_retries=(
-                            result.query_retries if result else 0
-                        ),
-                        tasks_retried=(
-                            result.tasks_retried if result else 0
-                        ),
-                        tasks_speculated=(
-                            result.tasks_speculated if result else 0
-                        ),
-                        speculation_wins=(
-                            result.speculation_wins if result else 0
-                        ),
-                        workers_readmitted=(
-                            result.workers_readmitted if result else 0
-                        ),
-                        plan_digest=plan_digest,
-                        session_fingerprint=fingerprint,
-                        cache_hit_tier=(
-                            "result"
-                            if result is not None
-                            and result.cache_stats
-                            and (
-                                result.cache_stats.get("result") or {}
-                            ).get("hit")
-                            else None
-                        ),
-                        compiles=compiles_delta,
-                        time_breakdown=(
-                            result.time_breakdown if result else None
-                        ),
-                        trace=result.trace if result else None,
-                        task_stats=tuple(
-                            result.task_stats if result else ()
-                        ),
-                    ))
-                from trino_tpu.events import maybe_log_slow_query
-
-                maybe_log_slow_query(
-                    listeners, self.session, query_id, sql,
-                    elapsed_ms, op_stats, state=state,
+                    peak_memory_per_node=(
+                        (node_id, qctx.peak_bytes),
+                    ) if qctx.peak_bytes else (),
+                    planning_ms=plan_ms,
+                    execution_ms=max(elapsed_ms - plan_ms, 0.0),
+                    cpu_ms=max(elapsed_ms - plan_ms, 0.0),
+                    query_retries=(
+                        result.query_retries if result else 0
+                    ),
+                    tasks_retried=(
+                        result.tasks_retried if result else 0
+                    ),
+                    tasks_speculated=(
+                        result.tasks_speculated if result else 0
+                    ),
+                    speculation_wins=(
+                        result.speculation_wins if result else 0
+                    ),
+                    workers_readmitted=(
+                        result.workers_readmitted if result else 0
+                    ),
+                    plan_digest=plan_digest,
+                    session_fingerprint=fingerprint,
+                    cache_hit_tier=(
+                        "result"
+                        if result is not None
+                        and result.cache_stats
+                        and (
+                            result.cache_stats.get("result") or {}
+                        ).get("hit")
+                        else None
+                    ),
+                    compiles=compiles_delta,
                     time_breakdown=(
                         result.time_breakdown if result else None
                     ),
-                    kernel_profile=(
-                        result.kernel_profile if result else None
+                    trace=result.trace if result else None,
+                    task_stats=tuple(
+                        result.task_stats if result else ()
                     ),
-                )
+                ))
+            from trino_tpu.events import maybe_log_slow_query
+
+            maybe_log_slow_query(
+                listeners, self.session, query_id, sql,
+                elapsed_ms, op_stats, state=state,
+                time_breakdown=(
+                    result.time_breakdown if result else None
+                ),
+                kernel_profile=(
+                    result.kernel_profile if result else None
+                ),
+            )
 
     def _execute(self, sql: str) -> QueryResult:
         from trino_tpu import session_properties
 
-        stmt = parse_statement(sql)
+        with self._tracer.span("parse"):
+            stmt = parse_statement(sql)
         if not isinstance(stmt, (ast.SessionSet, ast.SessionReset)):
             # inconsistent memory caps fail fast at statement time
             # (SET SESSION stays allowed so a bad combination can be
@@ -749,38 +782,44 @@ class QueryRunner:
                 )
             if cstats is not None:
                 cstats.result_hit = False
-        tracer = getattr(self, "_tracer", None)
-        exec_span = (
-            tracer.span("execute", "execution") if tracer is not None
-            else _NullCtx()
-        )
+        tracer = self._tracer
         self.executor._defer_ok = True
         try:
             done = False
-            with exec_span as _sp:
-                # anchor compile-kind work (persistent-cache reads,
-                # injected compile delays) under the local exec span —
-                # the worker task loop does the same for fleet tasks
-                from trino_tpu import jit_cache
-
-                if _sp is not None:
-                    jit_cache.set_active_span(_sp)
-                for _attempt in range(8):
-                    page = self.executor.execute(plan)
-                    pend = getattr(page, "pending_flags", None)
+            for _attempt in range(8):
+                with tracer.span("execute", "execution") as _sp:
+                    # anchor the executor's dispatches and host syncs,
+                    # and compile-kind work (persistent-cache reads,
+                    # injected compile delays), under the local exec
+                    # span — the worker task loop does the same for
+                    # fleet tasks
+                    telemetry.set_active_span(_sp)
+                    try:
+                        page = self.executor.execute(plan)
+                        pend = getattr(page, "pending_flags", None)
+                        # the wait for the device is a host sync of
+                        # the executor's, not the protocol's time
+                        page.block_until_ready(
+                            None if pend is None else pend[0]
+                        )
+                    finally:
+                        telemetry.set_active_span(None)
+                # the last device->host transfer and the Python rows:
+                # execution time to the flight recorder
+                with tracer.span("to_rows", "execution"):
                     if pend is None:
                         rows = page.to_pylist()
-                        done = True
-                        break
-                    # deferred final-chain sync: the result transfer
-                    # carries the overflow flags; a tripped capacity
-                    # re-runs the query with the bumped (persisted) size
-                    rows, flags = page.to_pylist(extra=pend[0])
-                    if not self.executor.note_deferred_overflow(
-                        (flags, pend[1], pend[2])
-                    ):
-                        done = True
-                        break
+                    else:
+                        # deferred final-chain sync: the result
+                        # transfer carries the overflow flags; a
+                        # tripped capacity re-runs the query with the
+                        # bumped (persisted) size
+                        rows, flags = page.to_pylist(extra=pend[0])
+                if pend is None or not self.executor.note_deferred_overflow(
+                    (flags, pend[1], pend[2])
+                ):
+                    done = True
+                    break
             if not done:
                 # never return rows from an overflowed execution
                 raise RuntimeError(
@@ -788,9 +827,6 @@ class QueryRunner:
                 )
         finally:
             self.executor._defer_ok = False
-            from trino_tpu import jit_cache
-
-            jit_cache.set_active_span(None)
         ordered = _has_order(plan)
         if rcache is not None:
             rcache.put(digest, list(page.names), rows, ordered, tokens)
@@ -1319,14 +1355,6 @@ def _local_query_info(executor, prof, query_id: str) -> dict:
         }],
     }]
     return info
-
-
-class _NullCtx:
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return None
 
 
 def _walk_plan(node: P.PlanNode):

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from trino_tpu import fault, jit_cache, memory, program_catalog, telemetry
+from trino_tpu import fault, memory, program_catalog, telemetry
 from trino_tpu import types as T
 from trino_tpu.exec import kernels as K
 from trino_tpu.exec import shapes, stage
@@ -94,7 +94,7 @@ def _maybe_compile_delay() -> None:
     delay = float(
         os.environ.get(COMPILE_DELAY_ENV, "") or DEFAULT_COMPILE_DELAY_S
     )
-    parent = jit_cache.active_span()
+    parent = telemetry.active_span()
     sp = (
         parent.child("injected-compile-delay", "compile")
         if parent is not None else None
@@ -104,6 +104,48 @@ def _maybe_compile_delay() -> None:
     finally:
         if sp is not None:
             sp.finish()
+
+
+def _named_jit(fn, name: str):
+    """``jax.jit`` of a program named by what it does: XLA's module, and
+    with it the ``XLA Modules`` line of a device trace, reads
+    ``jit_<name>`` (the fingerprint XLA appends tells two instances of
+    one name apart). A name holds node types and phases, never a
+    literal, a capacity or a hash."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
+def _chain_program_name(chain) -> str:
+    """``chain_TableScan_Filter_Aggregate``-style name from the node
+    list the catalog label is made of, capped in length."""
+    return ("chain_" + "_".join(type(n).__name__ for n in chain))[:96]
+
+
+class _dispatching:
+    """``with _dispatching(program, miss):`` around one program call: a
+    ``dispatch`` span and, on a jit-cache miss, a ``build_trace`` child
+    over what the miss costs the host (the program's Python build,
+    ``jax.jit``, and the first call: trace, lowering, a compile or its
+    cache read)."""
+
+    __slots__ = ("_outer", "_inner")
+
+    def __init__(self, program: str, miss: bool):
+        self._outer = telemetry.child_span(
+            "dispatch", program=program, miss=miss
+        )
+        self._inner = telemetry.child_span("build_trace") if miss else None
+
+    def __enter__(self):
+        self._outer.__enter__()
+        if self._inner is not None:
+            self._inner.__enter__()
+
+    def __exit__(self, *exc):
+        if self._inner is not None:
+            self._inner.__exit__(*exc)
+        self._outer.__exit__(*exc)
 
 
 class QueryCancelled(RuntimeError):
@@ -522,7 +564,8 @@ class LocalExecutor:
             # one host sync fetches overflow flags AND the live count,
             # so downstream consumers (compact, joins, result fetch)
             # never re-sync
-            vals, n_live = jax.device_get((flags, n_live_dev))
+            with telemetry.child_span("host_sync", site="chain_flags"):
+                vals, n_live = jax.device_get((flags, n_live_dev))
             if vals:
                 overflowed = [i for i, v in vals.items() if v]
                 if overflowed:
@@ -720,72 +763,74 @@ class LocalExecutor:
         )
         hit = self._jit_cache.get(key)
         was_miss = hit is None
-        if was_miss:
-            in_layout = stage.ChainLayout(
-                names=list(page.names),
-                types={
-                    n: c.type for n, c in zip(page.names, page.columns)
-                },
-                dicts={
-                    n: c.dictionary
-                    for n, c in zip(page.names, page.columns)
-                },
-                capacity=page.capacity,
-                pools={
-                    n: c.hash_pool
-                    for n, c in zip(page.names, page.columns)
-                    if c.hash_pool is not None
-                },
-                arrays={
-                    n: c.array_pool
-                    for n, c in zip(page.names, page.columns)
-                    if c.array_pool is not None
-                },
-            )
-            fn, out_layout = stage.build_chain(chain, in_layout, caps)
+        program = _chain_program_name(chain)
+        with _dispatching(program, was_miss):
+            if was_miss:
+                in_layout = stage.ChainLayout(
+                    names=list(page.names),
+                    types={
+                        n: c.type for n, c in zip(page.names, page.columns)
+                    },
+                    dicts={
+                        n: c.dictionary
+                        for n, c in zip(page.names, page.columns)
+                    },
+                    capacity=page.capacity,
+                    pools={
+                        n: c.hash_pool
+                        for n, c in zip(page.names, page.columns)
+                        if c.hash_pool is not None
+                    },
+                    arrays={
+                        n: c.array_pool
+                        for n, c in zip(page.names, page.columns)
+                        if c.array_pool is not None
+                    },
+                )
+                fn, out_layout = stage.build_chain(chain, in_layout, caps)
 
-            def counted(env, mask, _fn=fn):
-                env2, mask2, flags = _fn(env, mask)
-                return env2, mask2, flags, K.count_true(mask2)
+                def counted(env, mask, _fn=fn):
+                    env2, mask2, flags = _fn(env, mask)
+                    return env2, mask2, flags, K.count_true(mask2)
 
-            hit = (jax.jit(counted), out_layout)
-            self._jit_cache[key] = hit
-        fn, out_layout = hit
-        env_in = self._env(page)
-        if key not in self._chain_avals:
-            # shape metadata only — feeds lazy cost analysis without
-            # touching device data or the dispatch hot path
-            abstract = jax.tree_util.tree_map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                (env_in, page.mask),
-            )
-            self._chain_avals[key] = abstract
-        if was_miss:
-            # catalog the freshly built program; the resolver lowers at
-            # the recorded avals on first cost/memory/HLO read
-            program_catalog.CATALOG.register(
-                key, source="local",
-                label="→".join(type(n).__name__ for n in chain),
-                resolver=program_catalog.aot_resolver(
-                    fn, self._chain_avals[key]
-                ),
-            )
-        else:
-            program_catalog.CATALOG.note_hit(key)
-        if self.profiler is not None:
-            self.profiler.note_dispatch(key)
-        _maybe_compile_delay()
-        if was_miss:
-            # the first call pays jit trace + backend compile (or a
-            # persistent-cache deserialize) before the async dispatch
-            # returns — that wall IS the program's compile cost
-            t0 = time.perf_counter()
-            env, mask, flags, n_live_dev = fn(env_in, page.mask)
-            program_catalog.CATALOG.note_compile_seconds(
-                key, time.perf_counter() - t0
-            )
-        else:
-            env, mask, flags, n_live_dev = fn(env_in, page.mask)
+                hit = (_named_jit(counted, program), out_layout)
+                self._jit_cache[key] = hit
+            fn, out_layout = hit
+            env_in = self._env(page)
+            if key not in self._chain_avals:
+                # shape metadata only — feeds lazy cost analysis without
+                # touching device data or the dispatch hot path
+                abstract = jax.tree_util.tree_map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                    (env_in, page.mask),
+                )
+                self._chain_avals[key] = abstract
+            if was_miss:
+                # catalog the freshly built program; the resolver lowers
+                # at the recorded avals on first cost/memory/HLO read
+                program_catalog.CATALOG.register(
+                    key, source="local",
+                    label="→".join(type(n).__name__ for n in chain),
+                    resolver=program_catalog.aot_resolver(
+                        fn, self._chain_avals[key]
+                    ),
+                )
+            else:
+                program_catalog.CATALOG.note_hit(key)
+            if self.profiler is not None:
+                self.profiler.note_dispatch(key)
+            _maybe_compile_delay()
+            if was_miss:
+                # the first call pays jit trace + backend compile (or a
+                # persistent-cache deserialize) before the async dispatch
+                # returns — that wall IS the program's compile cost
+                t0 = time.perf_counter()
+                env, mask, flags, n_live_dev = fn(env_in, page.mask)
+                program_catalog.CATALOG.note_compile_seconds(
+                    key, time.perf_counter() - t0
+                )
+            else:
+                env, mask, flags, n_live_dev = fn(env_in, page.mask)
         if out_map is not None:
             # the cached program speaks canonical names; translate its
             # outputs back for this call (the cached out_layout is
@@ -967,29 +1012,34 @@ class LocalExecutor:
             if ckey(s, c) not in cache
         ]
         if missing or "" not in cache:
-            connector = self.metadata.connector(node.catalog)
-            cols = connector.scan(
-                node.schema, node.table, [c for _, c in missing]
-            )
-            if missing:
-                # row count from the scanned arrays themselves: a
-                # second row_count() call could see a DIFFERENT
-                # snapshot on live views (system tables)
-                first = cols[missing[0][1]]
-                n = len(first[0] if isinstance(first, tuple) else first)
-            else:
-                n = connector.row_count(node.schema, node.table)
-            cap = shapes.bucket(n, site="scan")
-            if "" not in cache:
-                mask = np.zeros(cap, dtype=np.bool_)
-                mask[:n] = True
-                cache[""] = jnp.asarray(mask)
-            for sym, cname in missing:
-                cache[ckey(sym, cname)] = _scan_column(
-                    node.outputs[sym], cols[cname], cap,
-                    hashed=sym in hashed_syms,
+            # columns not resident yet: the connector's read and the
+            # host->device copy
+            with telemetry.child_span("upload", table=node.table):
+                connector = self.metadata.connector(node.catalog)
+                cols = connector.scan(
+                    node.schema, node.table, [c for _, c in missing]
                 )
-            cache["#rows"] = n
+                if missing:
+                    # row count from the scanned arrays themselves: a
+                    # second row_count() call could see a DIFFERENT
+                    # snapshot on live views (system tables)
+                    first = cols[missing[0][1]]
+                    n = len(
+                        first[0] if isinstance(first, tuple) else first
+                    )
+                else:
+                    n = connector.row_count(node.schema, node.table)
+                cap = shapes.bucket(n, site="scan")
+                if "" not in cache:
+                    mask = np.zeros(cap, dtype=np.bool_)
+                    mask[:n] = True
+                    cache[""] = jnp.asarray(mask)
+                for sym, cname in missing:
+                    cache[ckey(sym, cname)] = _scan_column(
+                        node.outputs[sym], cols[cname], cap,
+                        hashed=sym in hashed_syms,
+                    )
+                cache["#rows"] = n
         names = list(node.assignments)
         columns = [
             cache[ckey(s, c)] for s, c in node.assignments.items()
@@ -1035,10 +1085,12 @@ class LocalExecutor:
         domains = {
             c: ColumnDomain(*dom) for c, dom in node.domains.items()
         }
-        cols = connector.scan(
-            node.schema, node.table, list(node.assignments.values()),
-            domains=domains,
-        )
+        with telemetry.child_span("upload", table=node.table):
+            cols = connector.scan(
+                node.schema, node.table, list(node.assignments.values()),
+                domains=domains,
+            )
+            page = self._scanned_page(node, cols, None)
         metrics = getattr(connector, "scan_metrics", None)
         if metrics:
             self.scan_log.append({
@@ -1057,9 +1109,26 @@ class LocalExecutor:
                 ),
             })
             del self.scan_log[:-100]  # bounded: executors outlive queries
-        first = cols[next(iter(node.assignments.values()))]
-        n = len(first[0] if isinstance(first, tuple) else first)
-        cap = shapes.bucket(n, site="scan")
+        if dkey is not None and tokens is not None:
+            from trino_tpu import cache as xcache
+
+            xcache.DEVICE.put(dkey, page, tokens, pool=self.memory_pool)
+        return page
+
+    def _scanned_page(self, node: P.TableScan, cols, split_rows) -> Page:
+        """Host columns of an uncached scan onto the device, as a packed
+        page. ``split_rows``: the split's row count (its capacity
+        bucket, and its length where no column is read); None for a
+        whole-table scan, sized by what came back."""
+        if node.assignments:
+            first = cols[next(iter(node.assignments.values()))]
+            n = len(first[0] if isinstance(first, tuple) else first)
+        else:
+            n = split_rows
+        cap = (
+            shapes.bucket(n, site="scan") if split_rows is None
+            else shapes.bucket(split_rows, site="scan-split")
+        )
         hashed_syms = set(node.hash_varchar or [])
         names, columns = [], []
         for sym, cname in node.assignments.items():
@@ -1070,14 +1139,9 @@ class LocalExecutor:
             ))
         mask = np.zeros(cap, dtype=np.bool_)
         mask[:n] = True
-        page = Page(
+        return Page(
             names, columns, jnp.asarray(mask), known_rows=n, packed=True,
         )
-        if dkey is not None and tokens is not None:
-            from trino_tpu import cache as xcache
-
-            xcache.DEVICE.put(dkey, page, tokens, pool=self.memory_pool)
-        return page
 
     def _scan_split(self, node: P.TableScan) -> Page:
         """Scan one row-range split of a table (fleet-mode source
@@ -1122,30 +1186,12 @@ class LocalExecutor:
             kw["domains"] = {
                 c: ColumnDomain(*dom) for c, dom in node.domains.items()
             }
-        cols = connector.scan(
-            node.schema, node.table, list(node.assignments.values()),
-            split=split, **kw,
-        )
-        if node.assignments:
-            first = cols[next(iter(node.assignments.values()))]
-            n = len(first[0] if isinstance(first, tuple) else first)
-        else:
-            n = count
-        cap = shapes.bucket(count, site="scan-split")
-        hashed_syms = set(node.hash_varchar or [])
-        names, columns = [], []
-        for sym, cname in node.assignments.items():
-            names.append(sym)
-            columns.append(_scan_column(
-                node.outputs[sym], cols[cname], cap,
-                hashed=sym in hashed_syms,
-            ))
-        mask = np.zeros(cap, dtype=np.bool_)
-        mask[:n] = True
-        page = Page(
-            names, columns, jnp.asarray(mask),
-            known_rows=n, packed=True,
-        )
+        with telemetry.child_span("upload", table=node.table):
+            cols = connector.scan(
+                node.schema, node.table, list(node.assignments.values()),
+                split=split, **kw,
+            )
+            page = self._scanned_page(node, cols, count)
         if dkey is not None and tokens is not None:
             from trino_tpu import cache as xcache
 
@@ -1329,26 +1375,29 @@ class LocalExecutor:
             keys = list(page.names)
         key = ("compact", sig, limit)
         fn = self._jit_cache.get(key)
-        if fn is None:
-            def compact_fn(env, mask):
-                # live rows to the front by one packed sort (measured on
-                # one v5e chip at 6.29M rows, PR 22: 92 ms, against
-                # 380 ms for cumsum+searchsorted and 438 ms for
-                # cumsum+scatter compactions)
-                perm = K.compact_perm(mask)[:limit]
-                env2 = {
-                    s: (
-                        d[perm],
-                        None if v is None else v[perm],
-                    )
-                    for s, (d, v) in env.items()
-                }
-                return env2, mask[perm]
+        with _dispatching("compact", fn is None):
+            if fn is None:
+                def compact_fn(env, mask):
+                    # live rows to the front by one packed sort (measured
+                    # on one v5e chip at 6.29M rows, PR 22: 92 ms, against
+                    # 380 ms for cumsum+searchsorted and 438 ms for
+                    # cumsum+scatter compactions)
+                    perm = K.compact_perm(mask)[:limit]
+                    env2 = {
+                        s: (
+                            d[perm],
+                            None if v is None else v[perm],
+                        )
+                        for s, (d, v) in env.items()
+                    }
+                    return env2, mask[perm]
 
-            fn = jax.jit(compact_fn)
-            self._jit_cache[key] = fn
-        env_in = {k: (c.data, c.valid) for k, c in zip(keys, page.columns)}
-        env2, mask2 = fn(env_in, page.mask)
+                fn = _named_jit(compact_fn, "compact")
+                self._jit_cache[key] = fn
+            env_in = {
+                k: (c.data, c.valid) for k, c in zip(keys, page.columns)
+            }
+            env2, mask2 = fn(env_in, page.mask)
         cols = [
             Column(c.type, *env2[k], c.dictionary, c.hash_pool, c.array_pool)
             for k, c in zip(keys, page.columns)
@@ -1497,7 +1546,8 @@ class LocalExecutor:
                 chain, base, {}
             )
             pending.append((top, chain, env, mask, n_live_dev, out_layout))
-        counts = jax.device_get([p[4] for p in pending])
+        with telemetry.child_span("host_sync", site="prefetch_counts"):
+            counts = jax.device_get([p[4] for p in pending])
         for (top, chain, env, mask, _d, out_layout), n_live in zip(
             pending, counts
         ):
@@ -1647,27 +1697,30 @@ class LocalExecutor:
             self._layout_sig(left), self._layout_sig(right),
         )
         fn = self._jit_cache.get(key)
-        if fn is None:
-            l_cap, r_cap = left.capacity, right.capacity
-            lnames, rnames = list(left.names), list(right.names)
+        with _dispatching("cross_join", fn is None):
+            if fn is None:
+                l_cap, r_cap = left.capacity, right.capacity
+                lnames, rnames = list(left.names), list(right.names)
 
-            def fx(lenv, renv):
-                j = jnp.arange(cap)
-                li = jnp.clip(j // max(n_r, 1), 0, max(l_cap - 1, 0))
-                ri = jnp.clip(j % max(n_r, 1), 0, max(r_cap - 1, 0))
-                out_live = j < n_l * n_r
-                env2 = {}
-                for names, env, idx in (
-                    (lnames, lenv, li), (rnames, renv, ri)
-                ):
-                    for nm in names:
-                        d, v = env[nm]
-                        env2[nm] = (d[idx], None if v is None else v[idx])
-                return env2, out_live
+                def fx(lenv, renv):
+                    j = jnp.arange(cap)
+                    li = jnp.clip(j // max(n_r, 1), 0, max(l_cap - 1, 0))
+                    ri = jnp.clip(j % max(n_r, 1), 0, max(r_cap - 1, 0))
+                    out_live = j < n_l * n_r
+                    env2 = {}
+                    for names, env, idx in (
+                        (lnames, lenv, li), (rnames, renv, ri)
+                    ):
+                        for nm in names:
+                            d, v = env[nm]
+                            env2[nm] = (
+                                d[idx], None if v is None else v[idx]
+                            )
+                    return env2, out_live
 
-            fn = jax.jit(fx)
-            self._jit_cache[key] = fn
-        env2, mask = fn(self._env(left), self._env(right))
+                fn = _named_jit(fx, "cross_join")
+                self._jit_cache[key] = fn
+            env2, mask = fn(self._env(left), self._env(right))
         names, cols = [], []
         for page in (left, right):
             for nm, c in zip(page.names, page.columns):
@@ -1872,27 +1925,30 @@ class LocalExecutor:
             self._layout_sig(probe), self._layout_sig(build),
         )
         fn = self._jit_cache.get(key)
-        if fn is None:
-            crit = list(criteria)
-            kinds = self._join_key_kinds(probe, build, crit)
+        with _dispatching("join_count", fn is None):
+            if fn is None:
+                crit = list(criteria)
+                kinds = self._join_key_kinds(probe, build, crit)
 
-            def fa(penv, pmask, benv, bmask):
-                pk, bk, pv, bv, _, _ = self._traced_join_keys(
-                    penv, benv, crit, kinds
-                )
-                probe_live = pmask if pv is None else (pmask & pv)
-                build_live = bmask if bv is None else (bmask & bv)
-                order, lo, cnt = K.join_ranges(
-                    bk, build_live, pk, probe_live
-                )
-                return order, lo, cnt, K.blocked_sum(cnt)
+                def fa(penv, pmask, benv, bmask):
+                    pk, bk, pv, bv, _, _ = self._traced_join_keys(
+                        penv, benv, crit, kinds
+                    )
+                    probe_live = pmask if pv is None else (pmask & pv)
+                    build_live = bmask if bv is None else (bmask & bv)
+                    order, lo, cnt = K.join_ranges(
+                        bk, build_live, pk, probe_live
+                    )
+                    return order, lo, cnt, K.blocked_sum(cnt)
 
-            fn = jax.jit(fa)
-            self._jit_cache[key] = fn
-        order, lo, cnt, total_dev = fn(
-            self._env(probe), probe.mask, self._env(build), build.mask
-        )
-        return order, lo, cnt, int(jax.device_get(total_dev))
+                fn = _named_jit(fa, "join_count")
+                self._jit_cache[key] = fn
+            order, lo, cnt, total_dev = fn(
+                self._env(probe), probe.mask, self._env(build), build.mask
+            )
+        with telemetry.child_span("host_sync", site="join_total"):
+            total = int(jax.device_get(total_dev))
+        return order, lo, cnt, total
 
     # ---- dynamic filtering (DynamicFilterService analog,
     # MAIN/server/DynamicFilterService.java:106: collect build-side key
@@ -1948,44 +2004,47 @@ class LocalExecutor:
             return probe
         key_a = ("dfA", tuple(r for _, r in pairs), self._layout_sig(build))
         fn_a = self._jit_cache.get(key_a)
-        if fn_a is None:
-            rsyms = [r for _, r in pairs]
+        with _dispatching("join_bounds", fn_a is None):
+            if fn_a is None:
+                rsyms = [r for _, r in pairs]
 
-            def fa(benv, bmask):
-                outs = []
-                for r in rsyms:
-                    d, v = benv[r]
-                    live = bmask if v is None else (bmask & v)
-                    big = jnp.iinfo(d.dtype).max
-                    small = jnp.iinfo(d.dtype).min
-                    outs.append(jnp.min(jnp.where(live, d, big)))
-                    outs.append(jnp.max(jnp.where(live, d, small)))
-                return jnp.stack([o.astype(jnp.int64) for o in outs])
+                def fa(benv, bmask):
+                    outs = []
+                    for r in rsyms:
+                        d, v = benv[r]
+                        live = bmask if v is None else (bmask & v)
+                        big = jnp.iinfo(d.dtype).max
+                        small = jnp.iinfo(d.dtype).min
+                        outs.append(jnp.min(jnp.where(live, d, big)))
+                        outs.append(jnp.max(jnp.where(live, d, small)))
+                    return jnp.stack([o.astype(jnp.int64) for o in outs])
 
-            fn_a = jax.jit(fa)
-            self._jit_cache[key_a] = fn_a
-        bounds = fn_a(self._env(build), build.mask)
+                fn_a = _named_jit(fa, "join_bounds")
+                self._jit_cache[key_a] = fn_a
+            bounds = fn_a(self._env(build), build.mask)
         key_b = ("dfB", tuple(l for l, _ in pairs), self._layout_sig(probe))
         fn_b = self._jit_cache.get(key_b)
-        if fn_b is None:
-            lsyms = [l for l, _ in pairs]
+        with _dispatching("join_bounds", fn_b is None):
+            if fn_b is None:
+                lsyms = [l for l, _ in pairs]
 
-            def fb(penv, pmask, bnds):
-                keep = pmask
-                for i, l in enumerate(lsyms):
-                    d, v = penv[l]
-                    lo = bnds[2 * i].astype(d.dtype)
-                    hi = bnds[2 * i + 1].astype(d.dtype)
-                    keep = keep & (d >= lo) & (d <= hi)
-                    if v is not None:
-                        keep = keep & v
-                return keep, K.count_true(keep)
+                def fb(penv, pmask, bnds):
+                    keep = pmask
+                    for i, l in enumerate(lsyms):
+                        d, v = penv[l]
+                        lo = bnds[2 * i].astype(d.dtype)
+                        hi = bnds[2 * i + 1].astype(d.dtype)
+                        keep = keep & (d >= lo) & (d <= hi)
+                        if v is not None:
+                            keep = keep & v
+                    return keep, K.count_true(keep)
 
-            fn_b = jax.jit(fb)
-            self._jit_cache[key_b] = fn_b
-        keep, kept_dev = fn_b(self._env(probe), probe.mask, bounds)
+                fn_b = _named_jit(fb, "join_bounds")
+                self._jit_cache[key_b] = fn_b
+            keep, kept_dev = fn_b(self._env(probe), probe.mask, bounds)
         in_rows = probe.num_rows()
-        kept = int(jax.device_get(kept_dev))
+        with telemetry.child_span("host_sync", site="join_bounds_kept"):
+            kept = int(jax.device_get(kept_dev))
         self.df_log.append(
             {"rows_in": in_rows, "rows_kept": kept, "pairs": pairs}
         )
@@ -2028,14 +2087,15 @@ class LocalExecutor:
             self._layout_sig(probe), self._layout_sig(build),
         )
         hit = self._jit_cache.get(key)
-        if hit is None:
-            hit = self._build_join_expand(node, probe, build, out_cap)
-            self._jit_cache[key] = hit
-        fn, out_meta = hit
-        env2, mask2 = fn(
-            self._env(probe), probe.mask, self._env(build), build.mask,
-            order, lo, cnt,
-        )
+        with _dispatching("join_expand", hit is None):
+            if hit is None:
+                hit = self._build_join_expand(node, probe, build, out_cap)
+                self._jit_cache[key] = hit
+            fn, out_meta = hit
+            env2, mask2 = fn(
+                self._env(probe), probe.mask, self._env(build), build.mask,
+                order, lo, cnt,
+            )
         cols = [
             Column(t, *env2[s], d, hp, ap) for s, _fp, t, d, hp, ap in out_meta
         ]
@@ -2141,7 +2201,7 @@ class LocalExecutor:
             mask2 = masks[0] if len(masks) == 1 else jnp.concatenate(masks)
             return env2, mask2
 
-        return jax.jit(fb), out_meta
+        return _named_jit(fb, "join_expand"), out_meta
 
     def _build_semi_expand(self, node: P.SemiJoin, source: Page, filt: Page, out_cap: int):
         """Semi-join expansion phase: verify hash-combined matches and
@@ -2176,7 +2236,7 @@ class LocalExecutor:
                 out_live = out_live & (fd if fv is None else (fd & fv))
             return K.range_any(cnt, out_live)
 
-        return jax.jit(fb)
+        return _named_jit(fb, "semi_join")
 
     # ---- window / set operations -----------------------------------------
 
@@ -2261,99 +2321,100 @@ class LocalExecutor:
             self._layout_sig(page),
         )
         hit = self._jit_cache.get(key)
-        if hit is None:
-            from trino_tpu.page import StringDictionary
+        with _dispatching("unnest", hit is None):
+            if hit is None:
+                from trino_tpu.page import StringDictionary
 
-            layout = self._layout(page)
-            producers = []  # per arg: list of ('expr', c) | ('code', int)
-            elem_dicts = []
-            for a, sym in zip(node.arrays, node.element_symbols):
-                cs = [compile_expr(e, layout) for e in a]
-                t = node.outputs[sym]
-                if isinstance(t, T.VarcharType):
-                    if all(
-                        c.is_literal and c.dictionary is not None
-                        for c in cs
-                    ):
-                        # one merged dictionary over the literal pool;
-                        # each element becomes a constant code
-                        merged = StringDictionary(np.unique(
-                            np.concatenate(
-                                [c.dictionary.values for c in cs]
-                            )
-                        ))
-                        producers.append([
-                            (
-                                "code",
-                                int(np.searchsorted(
-                                    merged.values,
-                                    c.dictionary.values[0],
-                                )),
-                            )
-                            for c in cs
-                        ])
-                        elem_dicts.append(merged)
-                        continue
-                    dict_ids = {id(c.dictionary) for c in cs}
-                    if len(dict_ids) != 1 or None in {
-                        c.dictionary for c in cs
-                    }:
-                        raise NotImplementedError(
-                            "UNNEST varchar elements must share one "
-                            "dictionary or all be literals"
-                        )
-                    elem_dicts.append(cs[0].dictionary)
-                else:
-                    elem_dicts.append(None)
-                producers.append([("expr", c) for c in cs])
-
-            def fx(env, mask):
-                idx = jnp.arange(out_cap, dtype=jnp.int32) // k
-                env2 = {}
-                for s, (d, v) in env.items():
-                    env2[s] = (
-                        d[idx], None if v is None else v[idx]
-                    )
-                for sym, prods in zip(node.element_symbols, producers):
+                layout = self._layout(page)
+                producers = []  # per arg: list of ('expr', c) | ('code', int)
+                elem_dicts = []
+                for a, sym in zip(node.arrays, node.element_symbols):
+                    cs = [compile_expr(e, layout) for e in a]
                     t = node.outputs[sym]
-                    cols = []
-                    vals = []
-                    for kind_, c in prods:
-                        if kind_ == "code":
-                            d = jnp.full((cap,), c, dtype=jnp.int32)
-                            v = None
-                        else:
-                            d, v = stage._bcast(*c.fn(env), cap)
-                        cols.append(d)
-                        vals.append(
-                            jnp.ones((cap,), dtype=jnp.bool_)
-                            if v is None else v
-                        )
-                    stacked = jnp.stack(cols, axis=1)  # [cap, k_m]
-                    svalid = jnp.stack(vals, axis=1)
-                    k_m = stacked.shape[1]
-                    if k_m < k:  # NULL-pad shorter zipped arrays
-                        pad = jnp.zeros((cap, k - k_m), dtype=stacked.dtype)
-                        stacked = jnp.concatenate([stacked, pad], axis=1)
-                        svalid = jnp.concatenate(
-                            [
-                                svalid,
-                                jnp.zeros(
-                                    (cap, k - k_m), dtype=jnp.bool_
-                                ),
-                            ],
-                            axis=1,
-                        )
-                    env2[sym] = (
-                        stacked.reshape(out_cap),
-                        svalid.reshape(out_cap),
-                    )
-                return env2, mask[idx]
+                    if isinstance(t, T.VarcharType):
+                        if all(
+                            c.is_literal and c.dictionary is not None
+                            for c in cs
+                        ):
+                            # one merged dictionary over the literal pool;
+                            # each element becomes a constant code
+                            merged = StringDictionary(np.unique(
+                                np.concatenate(
+                                    [c.dictionary.values for c in cs]
+                                )
+                            ))
+                            producers.append([
+                                (
+                                    "code",
+                                    int(np.searchsorted(
+                                        merged.values,
+                                        c.dictionary.values[0],
+                                    )),
+                                )
+                                for c in cs
+                            ])
+                            elem_dicts.append(merged)
+                            continue
+                        dict_ids = {id(c.dictionary) for c in cs}
+                        if len(dict_ids) != 1 or None in {
+                            c.dictionary for c in cs
+                        }:
+                            raise NotImplementedError(
+                                "UNNEST varchar elements must share one "
+                                "dictionary or all be literals"
+                            )
+                        elem_dicts.append(cs[0].dictionary)
+                    else:
+                        elem_dicts.append(None)
+                    producers.append([("expr", c) for c in cs])
 
-            hit = (jax.jit(fx), elem_dicts)
-            self._jit_cache[key] = hit
-        fn, elem_dicts = hit
-        env2, mask2 = fn(self._env(page), page.mask)
+                def fx(env, mask):
+                    idx = jnp.arange(out_cap, dtype=jnp.int32) // k
+                    env2 = {}
+                    for s, (d, v) in env.items():
+                        env2[s] = (
+                            d[idx], None if v is None else v[idx]
+                        )
+                    for sym, prods in zip(node.element_symbols, producers):
+                        t = node.outputs[sym]
+                        cols = []
+                        vals = []
+                        for kind_, c in prods:
+                            if kind_ == "code":
+                                d = jnp.full((cap,), c, dtype=jnp.int32)
+                                v = None
+                            else:
+                                d, v = stage._bcast(*c.fn(env), cap)
+                            cols.append(d)
+                            vals.append(
+                                jnp.ones((cap,), dtype=jnp.bool_)
+                                if v is None else v
+                            )
+                        stacked = jnp.stack(cols, axis=1)  # [cap, k_m]
+                        svalid = jnp.stack(vals, axis=1)
+                        k_m = stacked.shape[1]
+                        if k_m < k:  # NULL-pad shorter zipped arrays
+                            pad = jnp.zeros((cap, k - k_m), dtype=stacked.dtype)
+                            stacked = jnp.concatenate([stacked, pad], axis=1)
+                            svalid = jnp.concatenate(
+                                [
+                                    svalid,
+                                    jnp.zeros(
+                                        (cap, k - k_m), dtype=jnp.bool_
+                                    ),
+                                ],
+                                axis=1,
+                            )
+                        env2[sym] = (
+                            stacked.reshape(out_cap),
+                            svalid.reshape(out_cap),
+                        )
+                    return env2, mask[idx]
+
+                hit = (_named_jit(fx, "unnest"), elem_dicts)
+                self._jit_cache[key] = hit
+            fn, elem_dicts = hit
+            env2, mask2 = fn(self._env(page), page.mask)
         names, cols = [], []
         for nm, c in zip(page.names, page.columns):
             names.append(nm)
@@ -2491,18 +2552,19 @@ class LocalExecutor:
             self._layout_sig(page),
         )
         hit = self._jit_cache.get(key)
-        if hit is None:
-            types = {n: c.type for n, c in zip(page.names, page.columns)}
-            dicts = {
-                n: c.dictionary for n, c in zip(page.names, page.columns)
-            }
-            fn, out_meta = build_window_program(
-                node, types, dicts, page.capacity
-            )
-            hit = (jax.jit(fn), out_meta)
-            self._jit_cache[key] = hit
-        fn, out_meta = hit
-        env2 = fn(self._env(page), page.mask)
+        with _dispatching("window", hit is None):
+            if hit is None:
+                types = {n: c.type for n, c in zip(page.names, page.columns)}
+                dicts = {
+                    n: c.dictionary for n, c in zip(page.names, page.columns)
+                }
+                fn, out_meta = build_window_program(
+                    node, types, dicts, page.capacity
+                )
+                hit = (_named_jit(fn, "window"), out_meta)
+                self._jit_cache[key] = hit
+            fn, out_meta = hit
+            env2 = fn(self._env(page), page.mask)
         names = list(page.names)
         cols = list(page.columns)
         for sym, t, d in out_meta:
@@ -2619,38 +2681,47 @@ class LocalExecutor:
                 self._layout_sig(source), self._layout_sig(filt),
             )
             fn = self._jit_cache.get(key)
-            if fn is None:
-                fn = self._build_semi_expand(node, source, filt, out_cap)
-                self._jit_cache[key] = fn
-            matched = fn(
-                self._env(source), self._env(filt), order, lo, cnt
-            )
+            with _dispatching("semi_join", fn is None):
+                if fn is None:
+                    fn = self._build_semi_expand(
+                        node, source, filt, out_cap
+                    )
+                    self._jit_cache[key] = fn
+                matched = fn(
+                    self._env(source), self._env(filt), order, lo, cnt
+                )
         else:
             key = (
                 "semiA", tuple(node.keys),
                 self._layout_sig(source), self._layout_sig(filt),
             )
             fn = self._jit_cache.get(key)
-            if fn is None:
-                crit = list(node.keys)
-                kinds = self._join_key_kinds(source, filt, crit)
+            with _dispatching("semi_join", fn is None):
+                if fn is None:
+                    crit = list(node.keys)
+                    kinds = self._join_key_kinds(source, filt, crit)
 
-                def fa(penv, pmask, benv, bmask):
-                    pk, bk, pv2, bv2, _, _ = self._traced_join_keys(
-                        penv, benv, crit, kinds
-                    )
-                    probe_live = pmask if pv2 is None else (pmask & pv2)
-                    build_live = bmask if bv2 is None else (bmask & bv2)
-                    _, _, cnt = K.join_ranges(
-                        bk, build_live, pk, probe_live
-                    )
-                    return cnt > 0
+                    def fa(penv, pmask, benv, bmask):
+                        pk, bk, pv2, bv2, _, _ = self._traced_join_keys(
+                            penv, benv, crit, kinds
+                        )
+                        probe_live = (
+                            pmask if pv2 is None else (pmask & pv2)
+                        )
+                        build_live = (
+                            bmask if bv2 is None else (bmask & bv2)
+                        )
+                        _, _, cnt = K.join_ranges(
+                            bk, build_live, pk, probe_live
+                        )
+                        return cnt > 0
 
-                fn = jax.jit(fa)
-                self._jit_cache[key] = fn
-            matched = fn(
-                self._env(source), source.mask, self._env(filt), filt.mask
-            )
+                    fn = _named_jit(fa, "semi_join")
+                    self._jit_cache[key] = fn
+                matched = fn(
+                    self._env(source), source.mask,
+                    self._env(filt), filt.mask,
+                )
         valid = None
         if node.null_aware and filt.num_rows() == 0:
             # x IN (empty) is FALSE — even for NULL x (and NOT IN TRUE);

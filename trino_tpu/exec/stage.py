@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from trino_tpu import telemetry, types as T
+from trino_tpu import types as T
 from trino_tpu.exec import kernels as K
 from trino_tpu.exec.aggregates import (
     VARIANCE_FNS,
@@ -165,10 +165,6 @@ def build_chain(chain: list[P.PlanNode], layout: ChainLayout, caps: dict[int, li
     """Build (fn, out_layout): ``fn(env, mask) -> (env', mask', flags)``
     is pure and jittable; ``flags`` maps chain position -> overflow
     scalar for each grouped Aggregate."""
-    # each build feeds a fresh trace to jax.jit downstream: the count,
-    # against trino_xla_compile_total, shows how much chain churn turns
-    # into real backend compiles vs jit-cache hits
-    telemetry.CHAINS_BUILT.inc()
     steps = []
     for i, nd in enumerate(chain):
         # positional scope label: jax.named_scope stamps it into the

@@ -45,31 +45,10 @@ from trino_tpu import fault, telemetry
 
 __all__ = [
     "CompileService", "install", "get", "guarded", "DEADLINE_ENV",
-    "set_active_span", "active_span",
 ]
 
 DEADLINE_ENV = "TRINO_TPU_COMPILE_DEADLINE_S"
 DEFAULT_DEADLINE_S = 60.0
-
-#: per-thread trace anchor: persistent-cache reads hop to the
-#: CompileService thread, so a span opened THERE has no path back to
-#: the task span that caused it. The task thread registers its span
-#: here instead; the reroute reads it on the calling thread (before
-#: the hop) and attaches the timed compile/deserialize child to it —
-#: cross-thread work lands under the right parent in Chrome traces.
-_span_tls = threading.local()
-
-
-def set_active_span(span) -> None:
-    """Register the span under which this thread's compile/deserialize
-    work should be recorded (``None`` to clear)."""
-    _span_tls.span = span
-
-
-def active_span():
-    """The calling thread's registered trace anchor, or ``None``."""
-    return getattr(_span_tls, "span", None)
-
 
 class _Job:
     __slots__ = ("fn", "tag", "done", "result", "error")
@@ -188,9 +167,10 @@ def _wrap_cache_read(svc: CompileService) -> None:
     def routed(cache_key, compile_options, backend, executable_devices):
         if svc.degraded:
             return None, None
-        # span anchoring happens HERE, on the calling (task) thread —
-        # TLS would read nothing on the service thread
-        parent = active_span()
+        # span anchoring happens HERE, on the calling (task) thread:
+        # the read hops to the service thread, where the thread anchor
+        # (telemetry.set_active_span) would read nothing
+        parent = telemetry.active_span()
         sp = (
             parent.child(
                 "persistent-cache-read", "compile",

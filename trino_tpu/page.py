@@ -28,7 +28,7 @@ from typing import Sequence
 import jax.numpy as jnp
 import numpy as np
 
-from trino_tpu import types as T
+from trino_tpu import telemetry, types as T
 
 __all__ = [
     "StringDictionary", "HashStringPool", "HashCollision", "ArrayPool",
@@ -574,7 +574,8 @@ class Page:
         """Live row count (device sync unless already host-known)."""
         if self.known_rows is not None:
             return self.known_rows
-        return int(jnp.sum(self.mask))
+        with telemetry.child_span("host_sync", site="num_rows"):
+            return int(jnp.sum(self.mask))
 
     @staticmethod
     def from_arrays(
@@ -593,6 +594,18 @@ class Page:
         mask = np.zeros(cap, dtype=np.bool_)
         mask[:n] = True
         return Page(names, cols, jnp.asarray(mask))
+
+    def block_until_ready(self, extra=None) -> None:
+        """Wait, under a ``host_sync`` span, for the programs that fill
+        this page (and ``extra``): ``to_pylist`` after it is the
+        transfer and the Python rows, not the wait for a busy device."""
+        import jax
+
+        with telemetry.child_span("host_sync", site="result"):
+            jax.block_until_ready((
+                self.mask, [(c.data, c.valid) for c in self.columns],
+                extra,
+            ))
 
     def to_pylist(self, extra=None) -> list[tuple]:
         """Materialize live rows on host as python tuples (result fetch).

@@ -32,7 +32,7 @@ from dataclasses import dataclass, field
 from decimal import Decimal
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
-from trino_tpu import profiler, session_properties as sp
+from trino_tpu import profiler, session_properties as sp, telemetry
 from trino_tpu.engine import QueryResult, QueryRunner
 from trino_tpu.tracker import QueryTracker
 
@@ -95,6 +95,20 @@ class QueryState:
     #: (0 = unlimited); the QueryTracker reaper enforces them
     max_queued_s: float = 0.0
     max_exec_s: float = 0.0
+    #: the statement's span tree (telemetry.Tracer), opened in submit
+    #: and sealed at the terminal state; lives and goes with this entry
+    tracer: object = None
+    #: the sealed tree's totals by span name (telemetry.span_totals),
+    #: flat fields of this statement's ``GET /v1/query`` row
+    span_totals: dict | None = None
+    #: the sealed tree's planning-kind time (``planningTimeMillis``)
+    planning_ms: float | None = None
+    #: page tokens delivered once already: a page fetched again adds
+    #: no ``respond`` span to the sealed tree
+    responded: set = field(default_factory=set)
+    #: guards the sealed tree and ``span_totals``: HTTP handler threads
+    #: add ``respond`` to them while others read them out
+    span_lock: object = field(default_factory=threading.Lock)
 
 
 class Coordinator:
@@ -114,7 +128,7 @@ class Coordinator:
         self._queries: dict[str, QueryState] = {}
         self._lock = threading.Lock()
         #: query-state transitions notify this condition so protocol
-        #: threads parked in page() wake immediately (the reference's
+        #: threads parked in await_page() wake immediately (the reference's
         #: asyncResponse completion, not a sleep-poll)
         self._state_cond = threading.Condition()
         self._seq = 0
@@ -239,7 +253,9 @@ class Coordinator:
                 sql = self.rfile.read(n).decode()
                 user = self.headers.get("X-Trino-User") or "user"
                 q = coordinator.submit(sql, user=user)
-                self._send(200, coordinator.proto_response(q, 0, self._base()))
+                coordinator.respond(
+                    q, 0, self._base(), lambda p: self._send(200, p)
+                )
 
             def do_GET(self):
                 parts = self.path.strip("/").split("/")
@@ -248,8 +264,6 @@ class Coordinator:
                     # /v1/status JMX surface, flattened): query states,
                     # retry/speculation counters, memory gauges, RPC
                     # latency histograms
-                    from trino_tpu import telemetry
-
                     telemetry.refresh_process_gauges(node="coordinator")
                     body = telemetry.REGISTRY.render().encode()
                     self.send_response(200)
@@ -380,10 +394,14 @@ class Coordinator:
                     and parts[:3] == ["v1", "statement", "executing"]
                 ):
                     _, _, _, qid, slug, token = parts
-                    payload, code = coordinator.page(
-                        qid, slug, int(token), self._base()
-                    )
-                    self._send(code, payload)
+                    q = coordinator.await_page(qid, slug)
+                    if q is None:
+                        self._send(404, {"error": "query not found"})
+                    else:
+                        coordinator.respond(
+                            q, int(token), self._base(),
+                            lambda p: self._send(200, p),
+                        )
                     return
                 self._send(404, {"error": "not found"})
 
@@ -567,7 +585,7 @@ class Coordinator:
         return f"http://127.0.0.1:{self.port}"
 
     def _signal_state(self) -> None:
-        """Wake every protocol thread blocked in ``page()``. Called on
+        """Wake every protocol thread blocked in ``await_page()``. Called on
         every query-state transition (run(), cancel(), the reaper)."""
         with self._state_cond:
             self._state_cond.notify_all()
@@ -586,6 +604,10 @@ class Coordinator:
         q = QueryState(
             query_id=qid, slug=secrets.token_hex(8), sql=sql, user=user,
         )
+        # the statement's span tree starts here, before the queue; the
+        # runner adds its spans to it and _seal closes it
+        q.tracer = telemetry.Tracer(qid, root_name="statement")
+        queued = q.tracer.start("queued")
         if self.journal is not None:
             # WAL the protocol identity (qid + slug) so a restarted
             # coordinator can re-serve this query at its old
@@ -617,6 +639,7 @@ class Coordinator:
             q.state = "FAILED"
             q.error = f"{type(e).__name__}: {e}"
             q.finished_at = time.time()
+            self._seal(q)
             with self._lock:
                 self._queries[qid] = q
             return q
@@ -655,15 +678,18 @@ class Coordinator:
         def run():
             # wait for a running slot (FIFO within the group; immediate
             # when admission already granted one at submit)
-            if not self.resource_groups.acquire(
+            got_slot = self.resource_groups.acquire(
                 group, qid, lambda: q.cancelled, admitted=admitted
-            ):
+            )
+            queued.finish()
+            if not got_slot:
                 # the reaper (queued-deadline) and DELETE both set
                 # cancelled — keep whichever typed error got there first
                 q.state = "FAILED"
                 if q.error is None:
                     q.error = "Query was canceled while queued"
                 q.finished_at = time.time()
+                self._seal(q)
                 self._signal_state()
                 return
             try:
@@ -672,6 +698,7 @@ class Coordinator:
                     if q.error is None:
                         q.error = "Query was canceled while queued"
                     q.finished_at = time.time()
+                    self._seal(q)
                     self._signal_state()
                     return
                 q.state = "RUNNING"
@@ -703,9 +730,22 @@ class Coordinator:
                         # second time
                         if "admitted" in params:
                             kwargs["admitted"] = True
+                        # the embedded runner adds its spans to the
+                        # statement's tree; a runner that builds a
+                        # tree of its own (the fleet's, per execution
+                        # attempt) has it hung under the statement
+                        if "tracer" in params:
+                            kwargs["tracer"] = q.tracer
                     except (TypeError, ValueError):
                         pass
                     result = self.runner.execute(sql, **kwargs)
+                    own = getattr(result, "trace", None)
+                    if own is not None and own.root is not q.tracer.root:
+                        own.root.parent_id = q.tracer.root.span_id
+                        q.tracer.root.children.append(own.root)
+                    # the tree is sealed BEFORE the state says so: a
+                    # client that sees FINISHED finds the totals
+                    self._seal(q)
                     if q.cancelled or q.state == "FAILED":
                         q.state = "FAILED"
                     else:
@@ -717,6 +757,7 @@ class Coordinator:
                     if q.error is None:
                         q.error = f"{type(e).__name__}: {e}"
                         q.error_detail = traceback.format_exc()
+                    self._seal(q)
                     q.state = "FAILED"
                     q.result = None
                 # a FleetRunner-backed coordinator has no local
@@ -737,6 +778,49 @@ class Coordinator:
 
         threading.Thread(target=run, daemon=True).start()
         return q
+
+    #: the flat span fields every sealed statement's row carries, as
+    #: numbers, whether or not its tree holds such a span
+    SPAN_FIELDS = (
+        "runner_wait_ms", "parse_ms", "plan_ms", "execute_ms",
+        "build_trace_ms", "host_sync_ms", "host_syncs", "dispatches",
+        "upload_ms", "to_rows_ms", "respond_ms", "rows_out_ms",
+    )
+
+    def _seal(self, q: QueryState) -> None:
+        """Close the statement's tree (its terminal state is reached)
+        and put its totals by span name on the statement's row."""
+        root = q.tracer.finish().root
+        for sp_ in root.walk():
+            sp_.finish()  # what a failure or a cancel left open
+        totals = dict.fromkeys(self.SPAN_FIELDS, 0.0)
+        totals.update(telemetry.span_totals(root))
+        totals.pop("queued_ms", None)  # served as queued_time_ms
+        totals["rows_out_ms"] = totals["to_rows_ms"]
+        q.planning_ms = _planning_ms(root.walk())
+        q.span_totals = totals
+
+    def respond(self, q: QueryState, token: int, base: str, send) -> None:
+        """Build one protocol page and hand it to ``send``. A page that
+        carries columns or data is the statement's last layer: its
+        ``respond`` span joins the sealed tree (after ``statement``
+        closed, on the handler's thread) and the row's totals."""
+        sp = None
+        if q.state == "FINISHED" and q.result is not None and q.span_totals:
+            # (a statement rehydrated from the journal has no tree)
+            with q.span_lock:
+                if token not in q.responded:  # its first delivery only
+                    q.responded.add(token)
+                    sp = q.tracer.root.child("respond")
+        try:
+            send(self.proto_response(q, token, base))
+        finally:
+            if sp is not None:
+                sp.finish()
+                with q.span_lock:
+                    t = q.span_totals
+                    t["respond_ms"] += sp.duration_ms
+                    t["rows_out_ms"] = t["to_rows_ms"] + t["respond_ms"]
 
     def cancel(self, qid: str):
         q = self._queries.get(qid)
@@ -787,6 +871,8 @@ class Coordinator:
                 "peak_memory_bytes": r.get("peak_memory_bytes", 0),
                 "rows": r.get("rows"),
                 "error": q.error,
+                # the sealed span tree's totals by span name
+                **self._span_totals(q),
             })
         out.extend(live.values())
         return out
@@ -822,7 +908,17 @@ class Coordinator:
                 ((q.started_at or q.finished_at or time.time())
                  - q.created_at) * 1e3, 3,
             )
+            if q.tracer is not None:
+                # the statement's span tree, as far as it has got
+                with q.span_lock:
+                    info["spans"] = q.tracer.root.to_dict()
+                info.update(self._span_totals(q))
         return info
+
+    @staticmethod
+    def _span_totals(q: QueryState) -> dict:
+        with q.span_lock:
+            return dict(q.span_totals or {})
 
     def list_queries(self) -> list[dict]:
         with self._lock:
@@ -842,10 +938,12 @@ class Coordinator:
 
     # ---- protocol responses ----------------------------------------------
 
-    def page(self, qid: str, slug: str, token: int, base: str):
+    def await_page(self, qid: str, slug: str) -> QueryState | None:
+        """The statement a page request names, once it has left QUEUED
+        and RUNNING or a second has passed; None for an unknown one."""
         q = self._queries.get(qid)
         if q is None or q.slug != slug:
-            return {"error": "query not found"}, 404
+            return None
         # long-poll: wait server-side for a state transition like the
         # reference's asyncResponse (ExecutingStatementResource). The
         # condition is notified by run()/cancel()/the reaper, so a
@@ -859,7 +957,7 @@ class Coordinator:
                 if remaining <= 0:
                     break
                 self._state_cond.wait(timeout=remaining)
-        return self.proto_response(q, token, base), 200
+        return q
 
     def proto_response(self, q: QueryState, token: int, base: str) -> dict:
         uri = f"{base}/v1/statement/executing/{q.query_id}/{q.slug}"
@@ -871,6 +969,19 @@ class Coordinator:
                 "queued": q.state == "QUEUED",
                 "elapsedTimeMillis": int(
                     ((q.finished_at or time.time()) - q.created_at) * 1e3
+                ),
+                "queuedTimeMillis": int(
+                    ((q.started_at or q.finished_at or time.time())
+                     - q.created_at) * 1e3
+                ),
+                # the sealed tree's planning-kind spans (``plan``; a
+                # fleet attempt's ``planning``); before the seal, those
+                # the runner has put under the root so far
+                "planningTimeMillis": int(
+                    q.planning_ms if q.planning_ms is not None
+                    else _planning_ms(
+                        list(q.tracer.root.children) if q.tracer else ()
+                    )
                 ),
             },
         }
@@ -893,6 +1004,10 @@ class Coordinator:
         if hi < len(result.rows):
             resp["nextUri"] = f"{uri}/{token + 1}"
         return resp
+
+
+def _planning_ms(spans) -> float:
+    return sum(sp.duration_ms for sp in spans if sp.kind == "planning")
 
 
 def _proto_type(result: QueryResult, i: int) -> str:

@@ -1186,7 +1186,7 @@ class FleetRunner:
         self._tracer = tracer
         plan_ms = getattr(self, "_plan_ms", 0.0)
         if plan_ms:
-            psp = tracer.start("planning", "planning")
+            psp = tracer.start("planning", "planning").finish()
             # planning happened BEFORE this attempt's root opened:
             # backdate the synthetic span so the timeline is truthful
             # and the wall-clock decomposition (which clips children to
@@ -1195,7 +1195,6 @@ class FleetRunner:
             # stage spans it would otherwise overlap
             psp.start_ms -= plan_ms
             psp.duration_ms = plan_ms
-            psp._open = False
         self._stage_spans: dict[str, telemetry.Span] = {}
         self._task_stats: list[dict] = []
         self._retries_by_stage: dict[str, int] = {}
@@ -1556,20 +1555,30 @@ class FleetRunner:
             self._retry_budget.spend()
             self.stats["tasks_retried"] += 1
             telemetry.TASKS_RETRIED.inc()
-            while time.monotonic() < deadline:
-                try:
-                    state = self._poll_task(w, spec.task_id, attempt)
-                except Exception as e:
-                    last_err = f"worker died during re-run: {e}"
-                    break
-                if state["state"] == "FINISHED":
-                    return
-                if state["state"] in ("FAILED", "CANCELED"):
-                    last_err = state.get("error", "re-run failed")
-                    break
-                time.sleep(self.poll_s)
-            else:
-                raise TimeoutError("corruption-recovery re-run timed out")
+            wait_sp = (
+                self._tracer.start("task_poll_wait")
+                if self._tracer is not None else None
+            )
+            try:
+                while time.monotonic() < deadline:
+                    try:
+                        state = self._poll_task(w, spec.task_id, attempt)
+                    except Exception as e:
+                        last_err = f"worker died during re-run: {e}"
+                        break
+                    if state["state"] == "FINISHED":
+                        return
+                    if state["state"] in ("FAILED", "CANCELED"):
+                        last_err = state.get("error", "re-run failed")
+                        break
+                    time.sleep(self.poll_s)
+                else:
+                    raise TimeoutError(
+                        "corruption-recovery re-run timed out"
+                    )
+            finally:
+                if wait_sp is not None:
+                    wait_sp.finish()
         raise RuntimeError(
             f"task {task_id} corruption recovery failed: {last_err}"
         )
@@ -2368,6 +2377,10 @@ class FleetRunner:
             self.resume_stats["tasks_redispatched"] += 1
             return False
 
+        # the loop's waits, one ``task_poll_wait`` span a waiting
+        # period: from the first sleep with nothing to do but poll to
+        # the first progress (a task dispatched, finished or failed)
+        wait_sp = wait_sig = None
         while len(complete) < len(stages):
             if time.monotonic() > deadline:
                 raise TimeoutError("query stages timed out")
@@ -2968,11 +2981,20 @@ class FleetRunner:
             if inflight or not n_pending() or (
                 self.dispatcher is not None and not granted
             ):
+                sig = (len(inflight), len(complete), n_pending())
+                if wait_sp is not None and sig != wait_sig:
+                    wait_sp.finish()
+                    wait_sp = None
+                if wait_sp is None and self._tracer is not None:
+                    wait_sp = self._tracer.start("task_poll_wait")
+                    wait_sig = sig
                 if self.dispatcher is not None:
                     handle.wake.wait(self.poll_s * 5)
                     handle.wake.clear()
                 else:
                     time.sleep(self.poll_s)
+        if wait_sp is not None:
+            wait_sp.finish()
         self._last_specs = dict(spec_by_tid)
         # the pipelining win, as one number: seconds of consumer
         # runtime that overlapped a still-streaming producer stage

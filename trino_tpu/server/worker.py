@@ -928,6 +928,7 @@ class WorkerServer:
                     "task_id": req["task_id"],
                     "attempt": int(req["attempt"]),
                 },
+                query_id=task.query_id,
             )
             rows_in = 0
             out_stats = {"rows": 0, "bytes": 0}
@@ -965,8 +966,12 @@ class WorkerServer:
                 # worker process must never drive XLA:CPU from two
                 # threads at once (a concurrent compile +
                 # deserialize_executable wedges inside the backend;
-                # observed as a permanently stuck task thread)
+                # observed as a permanently stuck task thread) — so
+                # tasks take the worker in turn, and the wait for the
+                # one ahead is ``task_queue_wait``
+                queue_sp = tspan.child("task_queue_wait")
                 with self.runner._lock:
+                    queue_sp.finish()
                     # install the shipped chaos schedule for this
                     # task's duration: tasks serialize under the
                     # runner lock, so the process-global injector
@@ -1091,15 +1096,13 @@ class WorkerServer:
                         from trino_tpu.profiler import OperatorProfiler
 
                         ex.profiler = prof = OperatorProfiler()
-                        from trino_tpu import jit_cache
-
                         try:
                             exec_sp = tspan.child("execute", "execution")
                             # compile/deserialize hops to the
                             # CompileService thread attach here, not
                             # to a detached root (trace anchor is
                             # read on THIS thread by the reroute)
-                            jit_cache.set_active_span(exec_sp)
+                            telemetry.set_active_span(exec_sp)
                             if self.runner.mesh is not None:
                                 # fleet x mesh: the fragment runs SPMD
                                 # over this worker's device mesh
@@ -1118,7 +1121,7 @@ class WorkerServer:
                             # lock is still held: cost resolution may
                             # lower+compile through the persistent
                             # cache, which is XLA work
-                            jit_cache.set_active_span(tspan)
+                            telemetry.set_active_span(tspan)
                             op_stats = prof.finish(ex)
                             # coordinator-level dynamic filtering:
                             # min/max of the requested build-key
@@ -1180,7 +1183,7 @@ class WorkerServer:
                                     if k in out_stats
                                 })
                         finally:
-                            jit_cache.set_active_span(None)
+                            telemetry.set_active_span(None)
                             ex.profiler = None
                             peak_bytes = task_ctx.peak_bytes
                             write_stats = getattr(

@@ -8,6 +8,11 @@ OpenTelemetry tracing split):
   task → operator), serialisable so worker-side subtrees can ride back on
   task-status responses and stitch into the coordinator's query trace.
   Exportable as Chrome trace-event JSON (chrome://tracing / Perfetto).
+  Every live span is also a ``jax.profiler.TraceAnnotation`` carrying
+  its ``query_id``: while a profiler session runs, the span is an event
+  on the host plane of the same ``.xplane.pb`` as the device's
+  operations — one clock, one tree, two views. With no session running
+  the annotation is a flag test.
 * **MetricsRegistry** — labelled counters / gauges / histograms rendered
   in Prometheus text exposition format; a process-global ``REGISTRY`` is
   served at ``GET /v1/metrics`` by both coordinator and worker.
@@ -18,11 +23,11 @@ OpenTelemetry tracing split):
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
-import uuid
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -46,8 +51,29 @@ def _now_ms() -> float:
     return time.time() * 1000.0
 
 
+#: span ids are 16 hex digits: a prefix drawn once per process, then a
+#: count (a uuid4 a span was half a span's cost)
+_ID_PREFIX = os.urandom(4).hex()
+_ID_COUNT = itertools.count(1)
+
+
 def _new_id() -> str:
-    return uuid.uuid4().hex[:16]
+    return f"{_ID_PREFIX}{next(_ID_COUNT) & 0xFFFFFFFF:08x}"
+
+
+_TraceAnnotation = None
+
+
+def _annotate(name: str, query_id: str, attrs: Dict[str, Any]):
+    """A started ``jax.profiler.TraceAnnotation`` (the timer starts at
+    construction). The import is lazy and touches no backend: host-only
+    roles open spans too."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name, query_id=query_id, **attrs)
 
 
 # ---------------------------------------------------------------------------
@@ -67,18 +93,35 @@ class Span:
     node: str = ""  # which process produced this span ("" = coordinator)
     attrs: Dict[str, Any] = field(default_factory=dict)
     children: List["Span"] = field(default_factory=list)
+    #: the statement this span belongs to (children inherit it)
+    query_id: str = ""
     _t0: float = field(default_factory=time.perf_counter, repr=False)
     _open: bool = field(default=True, repr=False)
+
+    def __post_init__(self) -> None:
+        # a live span is an event on the profiler's host plane too. The
+        # profiler writes the event where the span is finished, so a
+        # span closed on another thread than it was opened on (the
+        # coordinator's ``statement`` and ``queued``) sits on the
+        # closing thread's line, around that thread's children.
+        self._ann = (
+            _annotate(self.name, self.query_id, self.attrs)
+            if self._open else None
+        )
 
     def finish(self) -> "Span":
         if self._open:
             self.duration_ms = (time.perf_counter() - self._t0) * 1000.0
             self._open = False
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+                self._ann = None
         return self
 
     def child(self, name: str, kind: str = "internal", **attrs: Any) -> "Span":
         sp = Span(name=name, kind=kind, parent_id=self.span_id,
-                  trace_id=self.trace_id, node=self.node, attrs=dict(attrs))
+                  trace_id=self.trace_id, node=self.node, attrs=attrs,
+                  query_id=self.query_id)
         self.children.append(sp)
         return sp
 
@@ -98,6 +141,7 @@ class Span:
             "duration_ms": self.duration_ms,
             "node": self.node,
             "attrs": dict(self.attrs),
+            "query_id": self.query_id,
             "children": [c.to_dict() for c in self.children],
         }
 
@@ -113,8 +157,9 @@ class Span:
             duration_ms=float(d.get("duration_ms", 0.0)),
             node=d.get("node", ""),
             attrs=dict(d.get("attrs") or {}),
+            query_id=d.get("query_id", ""),
+            _open=False,
         )
-        sp._open = False
         sp.children = [Span.from_dict(c) for c in d.get("children") or []]
         return sp
 
@@ -183,15 +228,16 @@ class Tracer:
     """
 
     def __init__(self, query_id: str = "", trace_id: Optional[str] = None,
-                 node: str = "") -> None:
+                 node: str = "", root_name: str = "") -> None:
         self.trace_id = trace_id or _new_id()
         self.node = node
+        self.query_id = query_id
         self.root: Optional[Span] = None
         self._stack: List[Span] = []
         if query_id:
-            self.root = Span(name=f"query {query_id}", kind="query",
-                             trace_id=self.trace_id, node=node,
-                             attrs={"query_id": query_id})
+            self.root = Span(name=root_name or f"query {query_id}",
+                             kind="query", trace_id=self.trace_id,
+                             node=node, query_id=query_id)
             self._stack = [self.root]
 
     # -- span lifecycle ----------------------------------------------------
@@ -204,7 +250,7 @@ class Tracer:
             sp = parent.child(name, kind, **attrs)
         else:
             sp = Span(name=name, kind=kind, trace_id=self.trace_id,
-                      node=self.node, attrs=dict(attrs))
+                      node=self.node, attrs=attrs, query_id=self.query_id)
             if self.root is None:
                 self.root = sp
         return sp
@@ -243,6 +289,76 @@ class Tracer:
                              node=self.node)
         self.root.finish()
         return Trace(self.root)
+
+
+#: per-thread span anchor. Work that has no tracer in reach (the
+#: executor's dispatches and host syncs, compile reads that hop to the
+#: CompileService thread) records under the span its thread registered
+#: here: the engine's ``execute`` span, the worker's task span.
+_active = threading.local()
+
+
+def set_active_span(span: Optional[Span]) -> None:
+    """Register the span under which this thread's executor and compile
+    work is recorded (``None`` to clear)."""
+    _active.span = span
+
+
+def active_span() -> Optional[Span]:
+    """The calling thread's registered span anchor, or ``None``."""
+    return getattr(_active, "span", None)
+
+
+class child_span:
+    """``with child_span(name, **attrs):`` — a child of this thread's
+    active span for the block, active itself meanwhile so that spans
+    opened inside nest under it. It takes its parent's kind (a
+    ``dispatch`` under ``execute`` is execution, one under a worker's
+    task span is task time), so the flight recorder's buckets read the
+    same whether or not the executor's work is split into spans.
+    Nothing where no span is active (direct executor use outside a
+    statement)."""
+
+    __slots__ = ("_name", "_attrs", "_prev", "span")
+
+    def __init__(self, name: str, **attrs: Any):
+        self._name = name
+        self._attrs = attrs
+        self.span: Optional[Span] = None
+
+    def __enter__(self) -> Optional[Span]:
+        parent = getattr(_active, "span", None)
+        if parent is not None:
+            self._prev = parent
+            self.span = _active.span = parent.child(
+                self._name, parent.kind, **self._attrs)
+        return self.span
+
+    def __exit__(self, *exc: Any) -> None:
+        if self.span is not None:
+            self.span.finish()
+            _active.span = self._prev
+
+
+#: span names whose occurrences are counted beside their time
+_COUNTED = {"host_sync": "host_syncs", "dispatch": "dispatches"}
+
+
+def span_totals(root: Span) -> Dict[str, float]:
+    """A sealed tree as flat numbers: ``<name>_ms`` summed over the
+    spans of each name, a name being the span's first word with ``-``
+    as ``_`` (``stage s0`` is ``stage``, ``spool-read`` ``spool_read``),
+    and ``host_syncs`` / ``dispatches`` counting those. The root is
+    left out: its time is the statement's ``elapsed_ms``."""
+    out: Dict[str, float] = {}
+    for sp in root.walk():
+        if sp is root:
+            continue
+        key = sp.name.split(" ", 1)[0].replace("-", "_")
+        out[key + "_ms"] = out.get(key + "_ms", 0.0) + sp.duration_ms
+        if key in _COUNTED:
+            out[_COUNTED[key]] = out.get(_COUNTED[key], 0) + 1
+    return out
 
 
 class _SpanCtx:
@@ -546,8 +662,6 @@ LISTENER_FAILURES = REGISTRY.counter(
     "trino_event_listener_failures_total", "EventListener callbacks that raised")
 WORKER_TASKS = REGISTRY.counter(
     "trino_worker_tasks_total", "Stage tasks executed by this worker, by state")
-CHAINS_BUILT = REGISTRY.counter(
-    "trino_chains_built_total", "Fused operator chains built for jit compilation")
 SCHED_ADMISSIONS = REGISTRY.counter(
     "trino_sched_admissions_total", "Fleet stage tasks admitted, by stage_admission mode")
 SCHED_ADMISSION_WAIT = REGISTRY.histogram(

@@ -154,7 +154,7 @@ class QueryTracker:
         wakeup = getattr(self.coordinator.resource_groups, "wakeup", None)
         if wakeup is not None:
             wakeup()
-        # clients long-polling page() must see the reap immediately
+        # clients long-polling await_page() must see the reap immediately
         signal = getattr(self.coordinator, "_signal_state", None)
         if signal is not None:
             signal()
