@@ -1,21 +1,29 @@
-"""Aggregate function evaluation as sorted segment reductions.
+"""Aggregate function evaluation, scatter-free, in three modes.
 
 The analog of the reference's accumulator layer
-(MAIN/operator/aggregation/, AccumulatorCompiler) — but scatter-free:
-``kernels.sort_group`` leaves each group as one contiguous run of the
-sorted row order, so every aggregate is a gather (into sorted order) +
-cumsum + boundary-difference, or a segmented associative scan for
-min/max. On TPU this replaces the serializing scatter that
-``segment_sum`` lowers to (~100 ms/op at 1M rows on v5e) with sorts,
-gathers and scans that each cost single-digit milliseconds.
+(MAIN/operator/aggregation/, AccumulatorCompiler). ``_Reducer`` holds
+the reductions every aggregate is built from, and picks their form by
+the group context it is given:
+
+- **global** (``info`` None): no grouping, dense masked reductions.
+- **slots** (``kernels.SlotInfo``): a key domain of a few bits — the
+  packed key is the group's slot and each reduction is one dense
+  masked pass per slot (``kernels.slot_reduce``). ``DENSE_AGGREGATES``
+  names the aggregates built on ``_Reducer`` alone, which this mode
+  serves.
+- **sorted segments** (``kernels.GroupInfo``): ``kernels.sort_group``
+  leaves each group as one contiguous run of the sorted row order, so
+  an aggregate is a gather (into sorted order) + cumsum +
+  boundary-difference, or a segmented associative scan for min/max.
+  Any key, any aggregate — at the price of a sort, a gather a column
+  and a full-width int64 prefix sum a limb, whatever the number of
+  groups (some 500 ms for Q1's ten aggregates at 6.29M rows on a v5e,
+  PERF.md PR 26).
 
 Work shared between the aggregates of one GROUP BY (the gather of a
 column into group order, the per-group row count, contribution masks)
 is deduplicated through a per-step ``share`` cache, the analog of the
 reference's shared GroupByHash + per-aggregate accumulators split.
-
-Global (ungrouped) aggregates skip the grouping entirely and lower to
-dense masked reductions.
 
 Distinct aggregates dedupe first: a second ``sort_group`` over
 (group keys + argument) keeps one representative row per distinct
@@ -32,11 +40,30 @@ from trino_tpu import types as T
 from trino_tpu.exec import kernels as K
 from trino_tpu.expr.compiler import _div_round_half_up
 
-__all__ = ["compute_aggregate", "VARIANCE_FNS"]
+__all__ = ["compute_aggregate", "dense_reducible", "VARIANCE_FNS"]
 
 VARIANCE_FNS = {
     "stddev", "stddev_samp", "stddev_pop", "variance", "var_samp", "var_pop",
 }
+
+#: aggregates built on ``_Reducer``'s reductions alone, so that any of
+#: its modes serves them. The others read the sorted-group context
+#: itself (DISTINCT's dedupe, the percentile and HLL sketches,
+#: max_by/min_by) and need ``kernels.sort_group``.
+DENSE_AGGREGATES = frozenset({
+    "count_all", "count", "count_if", "sum", "avg", "min", "max",
+    "bool_and", "bool_or", "any_value", "arbitrary",
+    "count_final", "avg_final", "decimal_sum_final", "decimal_avg_final",
+    "sum_hi32", "sum_lo32",
+}) | VARIANCE_FNS
+
+
+def dense_reducible(name: str, distinct: bool) -> bool:
+    """Whether ``compute_aggregate`` evaluates this call from
+    ``_Reducer``'s reductions alone (see ``DENSE_AGGREGATES``)."""
+    return not distinct and (
+        name in DENSE_AGGREGATES or name.startswith("var_final:")
+    )
 
 #: HLL register counts (relative standard error = 1.04/sqrt(m)):
 #: global approx_distinct gets 4096 registers (~1.6%); grouped gets 512
@@ -51,22 +78,24 @@ QUANT_GROUPED_POINTS = 256
 
 
 class _Reducer:
-    """Segment reductions for one GROUP BY (``info`` set) or one global
-    aggregate (``info`` None -> [1]-shaped dense reductions).
+    """Per-group reductions for one GROUP BY — sorted segments
+    (``info`` a ``GroupInfo``) or slots (a ``SlotInfo``) — or one
+    global aggregate (``info`` None -> [1]-shaped dense reductions).
 
     ``share`` caches device intermediates across the aggregates of one
     step, keyed by object identity (keys hold a reference to the keyed
     array so ids stay valid for the cache's lifetime).
     """
 
-    def __init__(self, info: K.GroupInfo | None, capacity: int, contrib,
-                 share: dict | None = None):
+    def __init__(self, info: K.GroupInfo | K.SlotInfo | None, capacity: int,
+                 contrib, share: dict | None = None):
         self.info = info
+        self.slots = isinstance(info, K.SlotInfo)
         self.capacity = capacity
         self.contrib = contrib
         self.share = share if share is not None else {}
         self.contrib_s = (
-            None if info is None else self._sorted(contrib)
+            None if info is None or self.slots else self._sorted(contrib)
         )
 
     def _sorted(self, x):
@@ -95,6 +124,12 @@ class _Reducer:
             x = data if dtype is None else data.astype(dtype)
             zero = jnp.zeros((), dtype=x.dtype)
             return jnp.sum(jnp.where(self.contrib, x, zero))[None]
+        if self.slots:
+            x = data if dtype is None else data.astype(dtype)
+            # floats accumulate in float64, as the sorted mode's scan
+            wide = jnp.issubdtype(x.dtype, jnp.floating)
+            acc = x.astype(jnp.float64) if wide else x
+            return self._slot(acc, 0).astype(x.dtype)
         xs = self._sorted(data)
         if dtype is not None:
             xs = xs.astype(dtype)
@@ -122,6 +157,11 @@ class _Reducer:
             hi = jnp.sum(masked >> jnp.int64(32))[None]
             lo = jnp.sum(masked & jnp.int64(0xFFFFFFFF))[None]
             return _limb_norm(hi, lo)
+        if self.slots:
+            if hi_in is None:
+                hi_in = data >> jnp.int64(32)
+                lo_in = data & jnp.int64(0xFFFFFFFF)
+            return _limb_norm(self._slot(hi_in, 0), self._slot(lo_in, 0))
         zero = jnp.int64(0)
         if hi_in is not None:
             hs = jnp.where(self.contrib_s, self._sorted(hi_in), zero)
@@ -144,6 +184,11 @@ class _Reducer:
         if hit is None or hit[0] is not self.contrib:
             if self.info is None:
                 cnt = jnp.sum(self.contrib.astype(jnp.int64))[None]
+            elif self.slots:
+                # a page holds under 2^31 rows: count in native lanes
+                cnt = self._slot(
+                    jnp.ones(self.contrib.shape, jnp.int32), 0
+                ).astype(jnp.int64)
             else:
                 cnt = K.seg_sum_ranges(
                     self.contrib_s.astype(jnp.int64), self.info,
@@ -158,6 +203,8 @@ class _Reducer:
             masked = jnp.where(self.contrib, data, fill)
             red = jnp.min if is_min else jnp.max
             return red(masked)[None]
+        if self.slots:
+            return self._slot(data, fill, "min" if is_min else "max")
         masked = jnp.where(self.contrib_s, self._sorted(data), fill)
         return K.seg_minmax_scan(masked, self.info, fill, is_min)
 
@@ -168,8 +215,21 @@ class _Reducer:
             idx = jnp.arange(n, dtype=jnp.int32)
             first = jnp.min(jnp.where(self.contrib, idx, n))[None]
             return data[jnp.clip(first, 0, max(n - 1, 0))]
-        rows, _ = K.seg_first_index(self.contrib_s, self.info)
+        if self.slots:
+            rows = self._slot(jnp.arange(n, dtype=jnp.int32), n, "min")
+        else:
+            rows, _ = K.seg_first_index(self.contrib_s, self.info)
         return data[jnp.clip(rows, 0, max(n - 1, 0))]
+
+    def _slot(self, vals, identity, op="sum"):
+        return K.slot_reduce(vals, self.contrib, self.info, identity, op)
+
+    def group_of_rows(self):
+        """Dense group id of every row (``capacity`` for dead rows)."""
+        if self.slots:
+            return jnp.take(self.info.rank, self.info.slot, mode="fill",
+                            fill_value=self.capacity)
+        return self.info.group
 
 
 def compute_aggregate(
@@ -371,7 +431,7 @@ def compute_aggregate(
                 at_ext = hi == m_hi[0]
             else:
                 at_ext = hi == m_hi[
-                    jnp.clip(info.group, 0, capacity - 1)
+                    jnp.clip(red.group_of_rows(), 0, capacity - 1)
                 ]
             red2 = _Reducer(info, capacity, red.contrib & at_ext, share)
             fill_lo = jnp.int64((1 << 32) if is_min else -1)

@@ -6,12 +6,13 @@ MAIN/operator/FlatHash.java:190; JoinProbe per-row lookup,
 MAIN/operator/join/JoinProbe.java:27), everything here is a
 whole-column computation XLA can tile onto the MXU/VPU:
 
-- ``assign_groups``: group-by key -> slot assignment via a vectorized
-  open-addressing claim-by-scatter loop (the FlatHash analog; the
-  control-byte probe of FlatHash.java:58 becomes a lane-parallel
-  scatter-min race).
-- ``segment_*``: aggregate accumulation as segment reductions (the
-  Accumulator analog, MAIN/operator/aggregation/).
+- ``sort_group``: group-by key -> dense group ids by one packed sort
+  (the FlatHash analog: exact, no probing, no scatter); ``seg_*``
+  reduce each group's contiguous run of the sorted order.
+- ``slot_group`` / ``slot_reduce``: group-by over a key domain of at
+  most ``2**SLOT_KEY_BITS`` packed values needs neither sort nor
+  scatter — the packed key word IS the group's slot, and an aggregate
+  is one dense masked reduction per slot.
 - ``join_expand``: equi-join via sort + searchsorted range expansion
   (the PagesHash/LookupSource analog, MAIN/operator/join/PagesHash.java:19).
 - ``sort_perm``: multi-key order-by via iterated stable argsort
@@ -47,6 +48,11 @@ __all__ = [
     "normalize_key",
     "GroupInfo",
     "sort_group",
+    "SLOT_KEY_BITS",
+    "SlotInfo",
+    "slot_key_bits",
+    "slot_group",
+    "slot_reduce",
     "assign_groups",
     "sort_perm",
     "join_ranges",
@@ -280,12 +286,15 @@ def hash_columns(cols: list[tuple[jnp.ndarray, jnp.ndarray | None]]) -> jnp.ndar
 # ---- group-by via sort ------------------------------------------------------
 #
 # TPU scatters serialize (measured ~115 ms per segment_sum over 1M rows
-# on v5e vs ~1-7 ms for sorts/gathers/cumsums), so the FlatHash-style
-# scatter-race table was replaced by sort-based grouping: lexsort the
-# key columns, mark group boundaries by adjacent compare, and derive
-# dense group ids by cumsum. This is exact (no hash collisions) and
-# every primitive it touches — argsort, gather, cumsum, searchsorted —
-# is fast on the MXU/VPU path.
+# on v5e), so the FlatHash-style scatter-race table was replaced by
+# sort-based grouping: one packed sort of the key columns, group
+# boundaries by adjacent compare, dense group ids by cumsum. This is
+# exact (no hash collisions) and needs no scatter — but it is not
+# cheap: at 6,291,456 rows the sort, the gathers into sorted order and
+# the int64 prefix sums of a Q1-shaped aggregate are some 500 ms on a
+# v5e whatever the number of groups (PERF.md, PR 26). It is the path
+# for keys whose domain is wide or unknown; a small known domain takes
+# ``slot_group`` below.
 
 
 class GroupInfo(NamedTuple):
@@ -474,6 +483,108 @@ def assign_groups(
     """
     info = sort_group(norm_bits, null_flags, live, capacity, widths=widths)
     return info.group, info.owner
+
+
+# ---- group-by over a small key domain: slot addressing ----------------------
+#
+# When the packed key word of ``_pack_words`` has only a few bits (two
+# dictionary-coded flags, a boolean, a narrow exact value range), the
+# word is the group's address: row i belongs to slot ``word[i]``, slot
+# order is the sort path's key order, and every aggregate is a dense
+# masked reduction ``reduce(where(slot == s & contrib, x, identity))``
+# per slot — one pass over the column on the VPU, no permutation, no
+# gather, no prefix sum. Integer sums add the same integers in another
+# order, so they equal the sort path's bit for bit.
+
+#: widest packed key (value bits + null flags) grouped by slot; wider
+#: keys sort. The dense reductions cost 2**bits passes of the VPU where
+#: the sort's cost does not depend on the groups: on a v5e at 6,291,456
+#: rows a Q1-shaped aggregate takes 5 / 12 / 43 / 163 ms at 4 / 6 / 8 /
+#: 10 bits against 406-462 ms sorted (tools/groupby_crossover.py;
+#: PERF.md, PR 26), so they would meet near 11-12 bits; 8 keeps a
+#: ninefold margin for aggregate shapes that table did not measure.
+SLOT_KEY_BITS = 8
+
+
+class SlotInfo(NamedTuple):
+    """Slot-addressed group context, ``sort_group``'s contract without
+    the sort: dense ids 0..num_groups-1 in key order, an occupied
+    prefix, ``owner[g]`` the first live row of group g (n when unused).
+
+    ``slot[i]`` is row i's packed key word (>= n_slots for dead rows);
+    ``rank[s]`` the dense id of slot s (capacity when no live row has
+    it); ``order[g]`` the slot of dense id g (n_slots when unused)."""
+
+    slot: jnp.ndarray
+    rank: jnp.ndarray
+    order: jnp.ndarray
+    owner: jnp.ndarray
+    num_groups: jnp.ndarray
+
+
+def slot_key_bits(widths, null_flags) -> int:
+    """Bits of the packed key word: value widths plus one per nullable
+    key. At most ``SLOT_KEY_BITS`` -> ``slot_group``; else ``sort_group``."""
+    return sum(widths) + sum(fl is not None for fl in null_flags)
+
+
+_SLOT_REDUCERS = {
+    # accumulate in vals' own dtype (jnp.sum would widen int32 counts)
+    "sum": partial(jnp.sum, promote_integers=False),
+    "min": jnp.min,
+    "max": jnp.max,
+}
+
+
+def _per_slot(slot, n_slots: int, vals, identity, op: str):
+    """[n_slots] reduction of ``vals`` over the rows of each slot, one
+    fused pass: the [n_slots, n] select is never materialized."""
+    ids = jnp.arange(n_slots, dtype=jnp.int32)
+    return _SLOT_REDUCERS[op](
+        jnp.where(slot[None, :] == ids[:, None], vals[None, :], identity),
+        axis=1, initial=identity,
+    )
+
+
+def slot_group(norm_bits, null_flags, live, capacity: int, widths) -> SlotInfo:
+    """Grouping for keys of ``slot_key_bits(...) <= SLOT_KEY_BITS``:
+    same arguments and the same groups, ids and owners as
+    ``sort_group``."""
+    words, _folded, key_bits = _pack_words(norm_bits, null_flags, live, widths)
+    # one word with liveness folded in above the key: dead rows hold a
+    # word >= n_slots and match no slot
+    slot = words[0].astype(jnp.int32)
+    n_slots = 1 << key_bits
+    n = live.shape[0]
+    first = _per_slot(
+        slot, n_slots, jnp.arange(n, dtype=jnp.int32), jnp.int32(n), "min"
+    )
+    occupied = first < n
+    num_groups = jnp.sum(occupied.astype(jnp.int32))
+    rank = jnp.where(
+        occupied, jnp.cumsum(occupied.astype(jnp.int32)) - 1, capacity
+    )
+    gids = jnp.arange(capacity, dtype=jnp.int32)
+    order = jnp.where(
+        gids < num_groups,
+        jnp.take(compact_perm(occupied), gids, mode="fill",
+                 fill_value=n_slots),
+        n_slots,
+    )
+    owner = jnp.take(first, order, mode="fill", fill_value=n)
+    return SlotInfo(slot, rank, order, owner, num_groups)
+
+
+def slot_reduce(vals, contrib, info: SlotInfo, identity, op: str = "sum"):
+    """Sum / min / max (``op``) of the contributing rows' ``vals`` per
+    group, as [capacity] in dense id order; ``identity`` where a group
+    is unused or nothing contributes."""
+    n_slots = info.rank.shape[0]
+    identity = jnp.asarray(identity, dtype=vals.dtype)
+    per_slot = _per_slot(
+        jnp.where(contrib, info.slot, n_slots), n_slots, vals, identity, op
+    )
+    return jnp.concatenate([per_slot, identity[None]])[info.order]
 
 
 # ---- segment reductions over sorted groups ---------------------------------

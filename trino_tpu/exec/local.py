@@ -127,7 +127,7 @@ class _dispatching:
     ``dispatch`` span and, on a jit-cache miss, a ``build_trace`` child
     over what the miss costs the host (the program's Python build,
     ``jax.jit``, and the first call: trace, lowering, a compile or its
-    cache read)."""
+    cache read). ``note`` adds attributes known only after the call."""
 
     __slots__ = ("_outer", "_inner")
 
@@ -141,6 +141,11 @@ class _dispatching:
         self._outer.__enter__()
         if self._inner is not None:
             self._inner.__enter__()
+        return self
+
+    def note(self, **attrs):
+        if self._outer.span is not None:
+            self._outer.span.attrs.update(attrs)
 
     def __exit__(self, *exc):
         if self._inner is not None:
@@ -764,7 +769,7 @@ class LocalExecutor:
         hit = self._jit_cache.get(key)
         was_miss = hit is None
         program = _chain_program_name(chain)
-        with _dispatching(program, was_miss):
+        with _dispatching(program, was_miss) as dispatch:
             if was_miss:
                 in_layout = stage.ChainLayout(
                     names=list(page.names),
@@ -831,6 +836,12 @@ class LocalExecutor:
                 )
             else:
                 env, mask, flags, n_live_dev = fn(env_in, page.mask)
+            if out_layout.groupbys:
+                # each grouped Aggregate's path, in chain order
+                # (telemetry.span_totals counts them onto the row)
+                dispatch.note(groupbys=[
+                    path for _pos, path in sorted(out_layout.groupbys.items())
+                ])
         if out_map is not None:
             # the cached program speaks canonical names; translate its
             # outputs back for this call (the cached out_layout is

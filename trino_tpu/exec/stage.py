@@ -32,6 +32,7 @@ from trino_tpu.exec import kernels as K
 from trino_tpu.exec.aggregates import (
     VARIANCE_FNS,
     compute_aggregate,
+    dense_reducible,
     dev_hash64,
 )
 from trino_tpu.expr.ir import InputRef
@@ -58,6 +59,11 @@ class ChainLayout:
     pools: dict = field(default_factory=dict)
     #: ARRAY-column pools by symbol (page.ArrayPool)
     arrays: dict = field(default_factory=dict)
+    #: on ``build_chain``'s output layout: chain position -> "direct" |
+    #: "sorted", the path of each grouped Aggregate of the program
+    #: (filled when the program is traced; kept beside the cached
+    #: program so that a warm dispatch reports it too)
+    groupbys: dict = field(default_factory=dict)
 
     def expr_layout(self) -> ColumnLayout:
         return ColumnLayout(
@@ -166,6 +172,7 @@ def build_chain(chain: list[P.PlanNode], layout: ChainLayout, caps: dict[int, li
     is pure and jittable; ``flags`` maps chain position -> overflow
     scalar for each grouped Aggregate."""
     steps = []
+    groupbys: dict[int, str] = {}
     for i, nd in enumerate(chain):
         # positional scope label: jax.named_scope stamps it into the
         # per-instruction HLO op_name metadata (fusions included), so
@@ -178,7 +185,9 @@ def build_chain(chain: list[P.PlanNode], layout: ChainLayout, caps: dict[int, li
             step, layout = _project_step(nd, layout)
             steps.append((scope, step))
         elif isinstance(nd, P.Aggregate):
-            step, layout = _aggregate_step(nd, layout, caps[i][0], i)
+            step, layout = _aggregate_step(
+                nd, layout, caps[i][0], i, groupbys
+            )
             steps.append((scope, step))
         elif isinstance(nd, (P.Sort, P.TopN)):
             step, layout = _sort_step(nd, layout)
@@ -195,7 +204,7 @@ def build_chain(chain: list[P.PlanNode], layout: ChainLayout, caps: dict[int, li
                 env, mask, flags = step(env, mask, flags)
         return env, mask, flags
 
-    return fn, layout
+    return fn, dc_replace(layout, groupbys=groupbys)
 
 
 def _filter_step(nd: P.Filter, layout: ChainLayout):
@@ -249,7 +258,10 @@ def _project_step(nd: P.Project, layout: ChainLayout):
     return step, out_layout
 
 
-def _aggregate_step(nd: P.Aggregate, layout: ChainLayout, capacity: int, pos: int):
+def _aggregate_step(
+    nd: P.Aggregate, layout: ChainLayout, capacity: int, pos: int,
+    groupbys: dict[int, str],
+):
     is_global = not nd.group_keys
     expr_layout = layout.expr_layout()
     agg_meta = []
@@ -316,6 +328,9 @@ def _aggregate_step(nd: P.Aggregate, layout: ChainLayout, capacity: int, pos: in
     )
 
     key_ranges = nd.key_ranges or {}
+    dense_aggs = all(
+        dense_reducible(call.name, call.distinct) for _s, call, *_ in agg_meta
+    )
 
     def step(env, mask, flags):
         if is_global:
@@ -344,9 +359,16 @@ def _aggregate_step(nd: P.Aggregate, layout: ChainLayout, capacity: int, pos: in
                 )
             norm = [_norm_opt(d, v) for d, v in shifted]
             widths = tuple(width_list)
-            info = K.sort_group(
-                tuple(b for b, _ in norm),
-                tuple(fl for _, fl in norm),
+            null_flags = tuple(fl for _, fl in norm)
+            # a key domain of a few bits is addressed, not sorted: the
+            # choice reads only what is static under jit (key widths,
+            # nullability, aggregate kinds), so it is part of the program
+            direct = dense_aggs and (
+                K.slot_key_bits(widths, null_flags) <= K.SLOT_KEY_BITS
+            )
+            groupbys[pos] = "direct" if direct else "sorted"
+            info = (K.slot_group if direct else K.sort_group)(
+                tuple(b for b, _ in norm), null_flags,
                 mask, capacity, widths=widths,
             )
             flags = {**flags, pos: info.num_groups > capacity}
@@ -424,7 +446,7 @@ def _aggregate_step(nd: P.Aggregate, layout: ChainLayout, capacity: int, pos: in
                 contrib = _dedupe(list(shifted), d_arg, contrib, in_cap,
                                   widths + (dwidth,))
             prepared.append((sym, call, arg, contrib))
-        if info is not None:
+        if isinstance(info, K.GroupInfo):
             _presort_shared(prepared, info, share)
         for sym, call, arg, contrib in prepared:
             data, valid = compute_aggregate(

@@ -348,8 +348,11 @@ def span_totals(root: Span) -> Dict[str, float]:
     """A sealed tree as flat numbers: ``<name>_ms`` summed over the
     spans of each name, a name being the span's first word with ``-``
     as ``_`` (``stage s0`` is ``stage``, ``spool-read`` ``spool_read``),
-    and ``host_syncs`` / ``dispatches`` counting those. The root is
-    left out: its time is the statement's ``elapsed_ms``."""
+    and ``host_syncs`` / ``dispatches`` counting those;
+    ``direct_groupbys`` / ``sorted_groupbys`` count the grouped
+    aggregates of the dispatched programs by the path each took (the
+    ``dispatch`` span's ``groupbys``). The root is left out: its time
+    is the statement's ``elapsed_ms``."""
     out: Dict[str, float] = {}
     for sp in root.walk():
         if sp is root:
@@ -358,6 +361,8 @@ def span_totals(root: Span) -> Dict[str, float]:
         out[key + "_ms"] = out.get(key + "_ms", 0.0) + sp.duration_ms
         if key in _COUNTED:
             out[_COUNTED[key]] = out.get(_COUNTED[key], 0) + 1
+        for path in sp.attrs.get("groupbys", ()):
+            out[path + "_groupbys"] = out.get(path + "_groupbys", 0) + 1
     return out
 
 
