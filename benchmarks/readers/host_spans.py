@@ -9,8 +9,10 @@ args: ``quantity``, ``span``
   "idle_share_unattributed"  100 * idle time that no span of the program
                              other than ``statement`` covers / all idle
                              time: the check that the spans are complete
-Nothing where the run has no device trace, or the trace holds no span
-of the program (a checkout from before the spans).
+Nothing where the run has no device trace. Where it has one (a traced
+run only gets here with the clock mark in it: ``trace_reduce.for_window``)
+and the trace holds no span of the program, the run fails: a change that
+drops or renames the spans must not make these metrics vanish.
 
 How it reads the trace. ``ctx.trace["xplane"]`` is the run's raw
 ``.xplane.pb``; ``timeline.json`` beside the trace directory holds the
@@ -38,9 +40,8 @@ A later PR adds a metric over another span as one data file: a
 {"quantity": "idle_share_in", "span": "<name>"}``; over a field of
 ``GET /v1/query`` (``trino_tpu/server/coordinator.py`` puts every
 span's total there as ``<name>_ms``) as one data file with ``"reader":
-"span_field"`` and ``"args": {"field": "<name>_ms"}``
-(``span_field.py`` is ``query_list.py`` that reports nothing, where
-that one fails the run, on a checkout whose rows lack the field).
+"query_list"`` and ``"args": {"field": "<name>_ms"}``, which fails the
+run where under nine tenths of the window's statements carry the field.
 """
 
 from __future__ import annotations
@@ -164,13 +165,11 @@ def idle_by_span(ctx) -> dict | None:
             return None
         spans = host_spans(tr["xplane"])
         if not spans:
-            # the program opens no spans (a checkout from before them):
-            # nothing to read, which is not a fault of the run
-            cached = {}
-        else:
-            idle = idle_intervals(
-                devices, window["lo_ns"], window["hi_ns"])
-            cached = charge(idle, spans)
+            raise RuntimeError(
+                "the device trace holds no span of the program (no host "
+                "event with a query_id): the idle time cannot be charged")
+        idle = idle_intervals(devices, window["lo_ns"], window["hi_ns"])
+        cached = charge(idle, spans)
         ctx._idle_by_span = cached
     return cached or None
 

@@ -65,7 +65,9 @@ def log(msg: str) -> None:
 
 class Context:
     """What a per-layer reader may read. Readers take from here; they
-    never start anything but statements through ``client()``."""
+    start nothing (one that has to send a statement after the window
+    makes its client as the window's did: ``loadgen.timed_client`` over
+    ``client_mod`` and ``servers.entry_uri``)."""
 
     def __init__(self):
         self.cell: dict = {}
@@ -83,11 +85,6 @@ class Context:
         self.trace: dict | None = None   # trace_reduce.reduce(...)
         self.peaks: dict = {}
         self.client_mod = None
-        self.timeout = 300.0
-
-    def client(self):
-        return loadgen.timed_client(self.client_mod, self.servers.entry_uri,
-                                    self.timeout)
 
     def latency_ms(self, st) -> float:
         return e2e.latency_ms(st, self.from_due, self.t0)

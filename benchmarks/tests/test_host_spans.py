@@ -1,6 +1,8 @@
-"""readers/host_spans.py and readers/span_field.py on plain lists (no
-trace file), and the new metric files against what the server's row of
-``GET /v1/query`` really has."""
+"""readers/host_spans.py on plain lists (no trace file), the metric
+files over the program's spans against what the server's row of
+``GET /v1/query`` really has, and that a row list without a metric's
+field, or a marked trace without a span of the program, fails the run
+instead of leaving the metric out."""
 
 import importlib.util
 import json
@@ -19,6 +21,8 @@ NEW = (
     "executor.retrace_ms_per_stmt", "protocol.rows_out_ms",
     "device.idle_in_host_sync_share", "device.idle_unattributed_share",
 )
+#: PR 26's, a count on the same rows
+ROW_FIELDS = NEW + ("executor.direct_groupbys_per_stmt",)
 
 
 def reader(name):
@@ -120,13 +124,21 @@ def test_idle_intervals_are_trace_reduces():
         (0.0, 10.0), (30.0, 40.0), (45.0, 60.0)]
 
 
-def test_reads_a_run_once_and_finds_the_timeline(tmp_path, monkeypatch):
+def placed_trace(tmp_path):
+    """An (empty) raw trace where a run keeps it, with the window placed
+    beside it as ``trace_reduce.for_window`` does once it has found the
+    clock mark."""
     run_dir = tmp_path / "trace" / "plugins" / "profile" / "r1"
     run_dir.mkdir(parents=True)
     xplane = run_dir / "h.xplane.pb"
     xplane.write_bytes(b"")
     (tmp_path / "timeline.json").write_text(
         json.dumps({"lo_ns": 0.0, "hi_ns": 1000.0}))
+    return xplane
+
+
+def test_reads_a_run_once_and_finds_the_timeline(tmp_path, monkeypatch):
+    xplane = placed_trace(tmp_path)
     assert hs.find_timeline(str(xplane)) == str(tmp_path / "timeline.json")
     calls = []
     monkeypatch.setattr(hs.trace_reduce, "load", lambda p: calls.append(p) or {
@@ -138,37 +150,58 @@ def test_reads_a_run_once_and_finds_the_timeline(tmp_path, monkeypatch):
     assert hs.read(ctx, "idle_share_in", "host_sync") == 100.0
     assert hs.read(ctx, "idle_share_unattributed") == 0.0
     assert len(calls) == 1
-    # a checkout from before the spans: nothing, and no failure
+
+
+def test_a_marked_trace_without_a_span_of_the_program_fails_the_run(
+        tmp_path, monkeypatch):
+    """The window is placed and the device worked, but no host event
+    carries a query id: the program dropped or renamed its spans."""
+    xplane = placed_trace(tmp_path)
+    monkeypatch.setattr(hs.trace_reduce, "load", lambda p: {
+        "devices": {"d": {"modules": [], "ops": [("x", 0.0, 200.0)]}}})
     monkeypatch.setattr(hs, "host_spans", lambda p: [])
-    ctx = types.SimpleNamespace(
-        trace={"devices": 1, "xplane": str(xplane)})
-    assert hs.read(ctx, "idle_share_unattributed") is None
+    for quantity, span in (("idle_share_in", "host_sync"),
+                           ("idle_share_unattributed", None)):
+        ctx = types.SimpleNamespace(
+            trace={"devices": 1, "xplane": str(xplane)})
+        with pytest.raises(RuntimeError, match="no span of the program"):
+            hs.read(ctx, quantity, span)
 
 
 def stmt(qid, cls="long"):
     return types.SimpleNamespace(query_id=qid, cls=cls)
 
 
-def test_span_field_is_query_list_that_reports_nothing_without_the_field():
-    sf = reader("span_field")
-    ctx = types.SimpleNamespace(
-        statements=[stmt("a"), stmt("b")],
-        query_list=[{"query_id": "a", "plan_ms": 2.0},
-                    {"query_id": "b", "plan_ms": 4.0}])
-    assert sf.read(ctx, "plan_ms") == 3.0
-    # no row carries the field: the program serves no such span
-    assert sf.read(ctx, "runner_wait_ms") is None
-    # some do, too few: query_list's rule fails the run
+def test_a_row_list_without_a_metrics_field_fails_the_run():
+    assert not os.path.exists(os.path.join(BENCH, "readers", "span_field.py"))
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        bench = json.load(fh)
+    ctx = bench_run.Context()
+    ctx.statements = [stmt("a"), stmt("b")]
+    ctx.query_list = [{"query_id": "a", "plan_ms": 2.0},
+                      {"query_id": "b", "plan_ms": 4.0}]
+    only = lambda name: dict(bench, per_layer=[
+        m for m in bench["per_layer"] if m["name"] == name])
+    assert bench_run.per_layer(only("frontend.plan_ms"), "sf1_power", ctx) == {
+        "frontend.plan_ms": {"value": 3.0, "unit": "ms"}}
+    # no row carries the field (the program dropped or renamed the
+    # span): the run fails, the metric does not vanish from the line
+    for name in ROW_FIELDS:
+        if not name.startswith("device.") and name != "frontend.plan_ms":
+            with pytest.raises(RuntimeError, match="0 of the window's 2"):
+                bench_run.per_layer(only(name), "sf1_power", ctx)
+    # some rows carry it, too few
     ctx.query_list[1].pop("plan_ms")
-    with pytest.raises(RuntimeError):
-        sf.read(ctx, "plan_ms")
+    with pytest.raises(RuntimeError, match="1 of the window's 2"):
+        bench_run.per_layer(only("frontend.plan_ms"), "sf1_power", ctx)
 
 
 def test_new_metric_files_name_a_reader_and_a_quantity_it_knows():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
+    # looked up by name: a later PR appends its metrics after these
     entries = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"]][-len(NEW):] == list(NEW)
+    assert set(ROW_FIELDS) <= set(entries)
     for name in NEW:
         m = entries[name]
         assert m["workloads"] == ["sf1_power", "sf1_throughput"]
@@ -176,7 +209,7 @@ def test_new_metric_files_name_a_reader_and_a_quantity_it_knows():
         assert m["source"] == "program_span" and m["better"] == "lower"
         with open(os.path.join(BENCH, "metrics", name + ".json")) as fh:
             spec = json.load(fh)
-        assert spec["reader"] in ("span_field", "host_spans")
+        assert spec["reader"] in ("query_list", "host_spans")
         assert os.path.exists(
             os.path.join(BENCH, "readers", spec["reader"] + ".py"))
         if spec["reader"] == "host_spans":
@@ -185,18 +218,18 @@ def test_new_metric_files_name_a_reader_and_a_quantity_it_knows():
 
 
 def test_new_metric_fields_are_on_the_servers_row(tmp_path):
-    """Each ``span_field`` metric reads a field that a statement's row
+    """Each metric over a row's field reads one that a statement's row
     of ``GET /v1/query`` really has, as a number (a Coordinator at
     ``tiny`` in a CPU-pinned child: this process imports no jax)."""
     import subprocess
 
     fields = []
-    for name in NEW:
+    for name in ROW_FIELDS:
         with open(os.path.join(BENCH, "metrics", name + ".json")) as fh:
             spec = json.load(fh)
-        if spec["reader"] == "span_field":
+        if spec["reader"] == "query_list":
             fields.append(spec["args"]["field"])
-    assert len(fields) == 6
+    assert len(fields) == 7
     code = (
         "import json, urllib.request\n"
         "from trino_tpu.engine import QueryRunner\n"
@@ -226,6 +259,6 @@ def test_new_metric_fields_are_on_the_servers_row(tmp_path):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
     bench["per_layer"] = [m for m in bench["per_layer"]
-                          if m["name"] in NEW]
+                          if m["name"] in ROW_FIELDS]
     got = bench_run.per_layer(bench, "sf1_power", ctx)
-    assert set(got) == {n for n in NEW if not n.startswith("device.")}
+    assert set(got) == {n for n in ROW_FIELDS if not n.startswith("device.")}
