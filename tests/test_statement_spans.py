@@ -31,7 +31,7 @@ FLAT_FIELDS = (
     "runner_wait_ms", "parse_ms", "plan_ms", "execute_ms",
     "build_trace_ms", "host_sync_ms", "host_syncs", "dispatches",
     "upload_ms", "to_rows_ms", "respond_ms", "rows_out_ms",
-    "direct_groupbys", "sorted_groupbys",
+    "direct_groupbys", "sorted_groupbys", "streamed_groupbys",
 )
 PROGRAM = re.compile(
     r"^(chain_[A-Za-z_]+|join_count|join_bounds|join_expand|semi_join"
@@ -192,19 +192,21 @@ def test_query_rows_carry_flat_fields_and_the_id_serves_the_tree(coord):
     assert info["plan_ms"] == row["plan_ms"]
 
 
-@pytest.mark.parametrize("q,direct,by_sort", [
-    ("q01", 1, 0), ("q03", 0, 1), ("q06", 0, 0), ("q18", 0, 2),
+@pytest.mark.parametrize("q,direct,by_sort,streamed", [
+    ("q01", 1, 0, 0), ("q03", 0, 1, 0), ("q06", 0, 0, 0), ("q18", 0, 1, 1),
 ])
 def test_query_rows_count_grouped_aggregates_by_their_path(
-        coord, q, direct, by_sort):
-    """A key domain of a few bits is addressed by slot, any other
-    sorted (ISSUE 26); a warm dispatch reports its program's paths
-    too, so the second run of a statement reads the same."""
+        coord, q, direct, by_sort, streamed):
+    """A key domain of a few bits is addressed by slot (ISSUE 26), a
+    whole table scanned in its declared key order is grouped by its
+    runs (Q18's inner ``group by l_orderkey``, ISSUE 31), any other
+    sorted; a warm dispatch reports its program's paths too, so the
+    second run of a statement reads the same."""
     for _ in range(2):
         qid, _ = serve(coord, QUERIES[q])
         row = row_of(coord, qid)
-        assert (row["direct_groupbys"], row["sorted_groupbys"]) == \
-            (direct, by_sort), row
+        assert (row["direct_groupbys"], row["sorted_groupbys"],
+                row["streamed_groupbys"]) == (direct, by_sort, streamed), row
 
 
 def test_protocol_stats_carry_queued_and_planning_time(coord):

@@ -123,6 +123,52 @@ def test_group_and_sum_at_lineitem_sf1(one_chip, no_compile_cache):
     assert sorts and all(s == (1, False) for s in sorts)
 
 
+def test_q18_group_by_runs_at_lineitem_sf1(one_chip, no_compile_cache):
+    """Q18's inner ``group by l_orderkey`` over a page that arrives in
+    key order, as ``stage.build_chain`` lowers it (``kernels.run_group``,
+    ISSUE 31): ONE sort, single-operand and of uint32 words (the
+    boundary rows' compaction) — no sort keyed by the group key, no
+    ``_merge_rank`` — and every gather reads ``capacity`` entries, none
+    a row-sized column."""
+    from trino_tpu import types as T
+    from trino_tpu.exec import stage
+    from trino_tpu.expr.ir import AggCall, InputRef
+    from trino_tpu.plan import nodes as P
+
+    n, capacity = LINEITEM_SF1, 2_097_152  # shapes.table_bucket(1.5M)
+    dec = T.DecimalType(15, 2)
+    node = P.Aggregate(
+        outputs={"k": T.BIGINT, "s": T.DecimalType(38, 2)}, source=None,
+        group_keys=["k"], key_ranges={"k": (1, 6_000_000)},
+        aggregates={
+            "s": AggCall("sum", (InputRef(dec, "q"),), T.DecimalType(38, 2))
+        },
+    )
+    layout = stage.ChainLayout(
+        names=["k", "q"], types={"k": T.BIGINT, "q": dec},
+        dicts={"k": None, "q": None}, capacity=n, ordered_on="k",
+    )
+    fn, out = stage.build_chain([node], layout, {0: [capacity, capacity]})
+
+    def step(k, q, mask):
+        return fn({"k": (k, None), "q": (q, None)}, mask)
+
+    lowered, compiled = _compile(
+        step, one_chip, ((n,), jnp.int64), ((n,), jnp.int64), ((n,), jnp.bool_)
+    )
+    assert out.groupbys == {0: "streamed"}  # filled as the step is traced
+    assert _sorts(lowered) == [(1, False)]
+    txt = lowered.as_text()
+    assert re.findall(
+        r"^\s*\}\) : \(tensor<(\w+)>\) -> tensor<\w+>$", txt, re.M
+    ) == [f"{n}xui32"]  # the sort's one operand
+    gathered = re.findall(r"stablehlo\.gather.*-> tensor<(\d+)x", txt)
+    # (and the one-entry reads of the prefix sums' totals)
+    assert set(gathered) == {str(capacity), "1"}
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 195 << 20  # the sort path's step held more
+
+
 def test_decimal_average_division(one_chip, no_compile_cache):
     """avg(decimal) ends in a 96/64 long division per group; XLA:TPU's
     own int64 div costs ~7 s of compile each and three averages in one

@@ -1,13 +1,23 @@
 #!/usr/bin/env python
-"""Where the crossover between slot-addressed and sorted group-by lies:
-device time of a Q1-shaped grouped aggregate (four decimal sums, three
-decimal averages, a count, over five int64 columns) by both paths of
-``exec/stage.py:_aggregate_step`` at each packed key width.
+"""Where the crossovers between the group-by paths lie, as device time
+of the step ``exec/stage.py:_aggregate_step`` builds.
 
-``kernels.SLOT_KEY_BITS`` is set from this table (PERF.md, PR 26). Run
-it on the chip: ``chiprun -- python tools/groupby_crossover.py``. On a
-CPU it checks that both paths agree and prints host times, which are
-no device metric.
+``--shape q1`` (the default): a Q1-shaped grouped aggregate (four
+decimal sums, three decimal averages, a count, over five int64
+columns) slot-addressed against sorted at each packed key width.
+``kernels.SLOT_KEY_BITS`` is set from this table (PERF.md, PR 26).
+
+``--shape q18``: Q18's inner ``group by l_orderkey`` (one decimal sum;
+6,291,456 rows of which 6.0M live, 1.5M groups of 1-7 rows arriving in
+key order, the planner's capacity 2,097,152) sorted against streamed
+(``kernels.run_group``), then the streamed step with its run starts
+ranked as the sort path ranks them (``kernels.searchsorted``) instead
+of compacted, and the pieces of both on their own — the table of
+PERF.md, PR 31.
+
+Run it on the chip: ``chiprun -- python tools/groupby_crossover.py``.
+On a CPU it checks that the paths agree and prints host times, which
+are no device metric.
 """
 
 from __future__ import annotations
@@ -50,24 +60,29 @@ def inputs(rows: int, bits: int, seed: int):
     return jnp.asarray(key), [jnp.asarray(c) for c in cols], jnp.asarray(mask)
 
 
-def engine_path(group_fn, bits: int):
-    """The step as ``_aggregate_step`` builds it, by one grouping path."""
+def engine_path(group_fn, bits: int, capacity: int = CAPACITY, aggs=AGGS):
+    """The step as ``_aggregate_step`` builds it, by one grouping path:
+    (aggregates + the key at each owner, owners, groups, order fault)."""
 
     def prog(key, cols, mask):
         kbits, _ = K.normalize_key(key, None)
-        info = group_fn((kbits,), (None,), mask, CAPACITY, widths=(bits,))
+        info = group_fn((kbits,), (None,), mask, capacity, widths=(bits,))
+        unordered = jnp.bool_(False)
+        if group_fn is K.run_group:
+            info, unordered = info
         share = {"#mask": mask}
         prepared = [
             (None, None, None if c is None else (cols[c], None), mask)
-            for _n, _t, c in AGGS
+            for _n, _t, c in aggs
         ]
-        if isinstance(info, K.GroupInfo):
+        if isinstance(info, K.GroupInfo) and info.perm is not None:
             _presort_shared(prepared, info, share)
         out = [
-            compute_aggregate(name, typ, arg, info, CAPACITY, mask, share=share)
-            for (name, typ, _c), (_s, _k, arg, _m) in zip(AGGS, prepared)
+            compute_aggregate(name, typ, arg, info, capacity, mask, share=share)
+            for (name, typ, _c), (_s, _k, arg, _m) in zip(aggs, prepared)
         ]
-        return out, info.owner, info.num_groups
+        own = jnp.clip(info.owner, 0, key.shape[0] - 1)
+        return out + [(key[own], None)], info.owner, info.num_groups, unordered
 
     return prog
 
@@ -89,11 +104,120 @@ def timed(prog, args, reps: int):
                  "ms_min": min(times), "ms_median": statistics.median(times)}
 
 
+Q18_CAPACITY = 2_097_152  # shapes.table_bucket(1.5M groups) at SF1
+Q18_KEY_BITS = 23  # l_orderkey's exact range at SF1, shifted to 0
+Q18_AGGS = [("sum", T.DecimalType(38, 2), 0)]
+
+
+def q18_inputs(rows: int, seed: int):
+    """Orders of 1-7 lines in key order (sparse keys, as dbgen's),
+    quantities of decimal(15,2), live rows a prefix."""
+    rng = np.random.default_rng(seed)
+    n_live = rows * 6_001_215 // 6_291_456
+    lines = rng.integers(1, 8, n_live // 3 + 8)  # 4 a key: enough
+    key = np.repeat(np.arange(len(lines), dtype=np.int64), lines)[:n_live]
+    key = (key // 8) * 32 + key % 8  # dbgen: 8 keys used, 24 skipped
+    key = np.concatenate([key, np.zeros(rows - n_live, np.int64)])
+    qty = rng.integers(1, 51, rows, dtype=np.int64) * 100
+    mask = np.arange(rows) < n_live
+    return jnp.asarray(key), [jnp.asarray(qty)], jnp.asarray(mask)
+
+
+def _ranked_run_group(norm_bits, null_flags, live, capacity, widths):
+    """``run_group`` with the run starts ranked among the rows by
+    ``searchsorted`` (``_merge_rank`` at this capacity), as
+    ``sort_group`` derives them: what that derivation alone costs."""
+    info, _unordered = K.run_group(
+        norm_bits, null_flags, live, capacity, widths)
+    n = live.shape[0]
+    sids = jnp.arange(capacity, dtype=jnp.int32)
+    starts = K.searchsorted(info.gid_sorted, sids).astype(jnp.int32)
+    ends = jnp.concatenate([starts[1:], info.ends[-1:]])
+    owner = jnp.where(sids < info.num_groups, starts, n).astype(jnp.int32)
+    return info._replace(starts=starts, ends=ends, owner=owner)
+
+
+def q18_pieces(rows: int, capacity: int):
+    """name -> (fn, args): the primitives the two paths are made of,
+    each alone at Q18's shape."""
+    rng = np.random.default_rng(1)
+    u64 = jnp.asarray(rng.integers(0, 1 << 47, rows, dtype=np.uint64))
+    i64 = jnp.asarray(rng.integers(0, 5001, rows, dtype=np.int64))
+    perm = jnp.asarray(rng.permutation(rows).astype(np.int32))
+    gid = jnp.asarray(np.sort(rng.integers(0, capacity, rows)).astype(np.int32))
+    flag = jnp.asarray(rng.random(rows) < 0.25)
+    at = jnp.asarray(np.sort(rng.integers(0, rows, capacity)).astype(np.int32))
+    sids = jnp.arange(capacity, dtype=jnp.int32)
+    return {
+        "sort_rows_uint64 (sort path: the packed row sort)":
+            (lambda w: K.packed_argsort(w, 47), (u64,)),
+        "gather_rows_int64 (sort path: one column into sorted order)":
+            (lambda x, p: x[p], (i64, perm)),
+        "inverse_perm_uint32_sort (sort path: group of rows)":
+            (lambda p: K.packed_argsort(p, K._idx_bits(rows)), (perm,)),
+        "merge_rank (sort path: starts of capacity ids among the rows)":
+            (lambda g: K.searchsorted(g, sids), (gid,)),
+        "compact_uint32_sort (streamed: boundary rows to the front)":
+            (K.compact_perm, (flag,)),
+        "cumsum_int64 (both: one limb's prefix sum)": (K.cumsum, (i64,)),
+        "cumsum_int32 (both: dense ids)":
+            (lambda f: K.cumsum(f.astype(jnp.int32)), (flag,)),
+        "gather_capacity_int64 (both: prefix sums at the run starts)":
+            (lambda x, a: x[a], (i64, at)),
+        # what the next step could buy: both limbs' prefix sums read in
+        # one gather of [rows, 2]; the same read on 32-bit lanes
+        "gather_capacity_int64_x2_stacked":
+            (lambda x, a: jnp.stack([x, x + 1], axis=1)[a], (i64, at)),
+        "gather_capacity_uint32":
+            (lambda x, a: x.astype(jnp.uint32)[a], (i64, at)),
+    }
+
+
+def write(rec: dict, out: str) -> None:
+    os.makedirs(os.path.dirname(out) or ".", exist_ok=True)
+    with open(out, "w") as fh:
+        json.dump(rec, fh, indent=1)
+    print(json.dumps(rec))
+
+
+def q18_main(a) -> int:
+    dev = jax.devices()[0]
+    rec = {"platform": dev.platform, "device_kind": dev.device_kind,
+           "shape": "q18", "rows": a.rows, "capacity": a.capacity,
+           "key_bits": Q18_KEY_BITS, "runs": [], "pieces": []}
+    args = q18_inputs(a.rows, a.seed)
+    results = {}
+    for path, fn in (("sorted", K.sort_group), ("streamed", K.run_group),
+                     ("streamed_ranked_starts", _ranked_run_group)):
+        out, t = timed(
+            engine_path(fn, Q18_KEY_BITS, a.capacity, Q18_AGGS), args, a.reps)
+        results[path] = out
+        rec["runs"].append({"path": path, **t})
+        print(rec["runs"][-1], flush=True)
+    ref = results["sorted"]
+    ok = ref is not None
+    for run in rec["runs"][1:]:
+        got = results[run["path"]]
+        run["agrees"] = None not in (ref, got) and same(got, ref)
+        ok &= run["agrees"]
+    if ok:
+        rec["groups"] = int(ref[2])
+    for name, (fn, fargs) in q18_pieces(a.rows, a.capacity).items():
+        _out, t = timed(fn, fargs, a.reps)
+        rec["pieces"].append({"piece": name, **t})
+        print(rec["pieces"][-1], flush=True)
+    write(rec, a.out)
+    return 0 if ok else 1
+
+
 def same(a, b) -> bool:
-    """Bit for bit on the occupied prefix (values, validity, owners)."""
-    (outs_a, own_a, n_a), (outs_b, own_b, n_b) = a, b
+    """Bit for bit on the occupied prefix (values, validity, owners),
+    and no order fault on either side."""
+    (outs_a, own_a, n_a, bad_a), (outs_b, own_b, n_b, bad_b) = a, b
     g = int(n_a)
-    if g != int(n_b) or not np.array_equal(own_a[:g], own_b[:g]):
+    if bool(bad_a) or bool(bad_b) or g != int(n_b) or not np.array_equal(
+        own_a[:g], own_b[:g]
+    ):
         return False
     for (da, va), (db, vb) in zip(outs_a, outs_b):
         if not np.array_equal(np.asarray(da)[:g], np.asarray(db)[:g]):
@@ -109,6 +233,9 @@ def same(a, b) -> bool:
 
 def main() -> int:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--shape", choices=("q1", "q18"), default="q1")
+    ap.add_argument("--capacity", type=int, default=Q18_CAPACITY,
+                    help="group-table capacity of --shape q18")
     ap.add_argument("--rows", type=int, default=6_291_456)
     ap.add_argument("--bits", default="2,4,6,8,10")
     ap.add_argument("--sorted-bits", default="4,8",
@@ -117,6 +244,10 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=26)
     ap.add_argument("--out", default="chiprun_out/groupby_crossover.json")
     a = ap.parse_args()
+    if a.shape == "q18":
+        if a.out == ap.get_default("out"):
+            a.out = "chiprun_out/groupby_crossover_q18.json"
+        return q18_main(a)
     dev = jax.devices()[0]
     rec = {"platform": dev.platform, "device_kind": dev.device_kind,
            "rows": a.rows, "capacity": CAPACITY, "runs": []}
@@ -134,10 +265,7 @@ def main() -> int:
             rec["runs"].append(
                 {"bits": bits, "path": "sorted", "agrees": agree, **t})
             print(rec["runs"][-1], flush=True)
-    os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
-    with open(a.out, "w") as fh:
-        json.dump(rec, fh, indent=1)
-    print(json.dumps(rec))
+    write(rec, a.out)
     return 0 if ok else 1
 
 

@@ -6,6 +6,16 @@ path. The TPU twist: a scan yields *host numpy columns* (optionally a
 row range of the table, the analog of a ConnectorSplit) which the
 engine marshals to device pages; pruned columns are never produced
 (projection pushdown, the analog of ConnectorMetadata.applyProjection).
+
+A connector may also declare that a table's rows come back ascending
+on one column (``Connector.sorted_by`` — the single-column case of the
+reference's local properties, ConnectorTableProperties /
+SortingProperty, which its ``tpch`` connector reports for ``orders``
+and ``lineitem``). A GROUP BY over exactly that column of the whole
+resident table then needs no sort: the runs are the groups
+(``exec/kernels.py:run_group``, the StreamingAggregationOperator
+analog). The declaration is checked on the device, and a table that
+breaks it is grouped by sort — slower, never wrong.
 """
 
 from __future__ import annotations
@@ -198,6 +208,14 @@ class Connector:
 
     def row_count(self, schema: str, table: str) -> int:
         raise NotImplementedError
+
+    def sorted_by(self, schema: str, table: str) -> str | None:
+        """The column on which ``scan`` returns the table's rows in
+        ascending order (of a whole-table scan; equal values adjacent),
+        or None — the default — when no order is promised. A sort
+        order, not just clustering: monotonicity is what the engine can
+        check as it reads."""
+        return None
 
     def table_stats(self, schema: str, table: str) -> TableStats:
         """Statistics for the planner (ConnectorMetadata.getTableStatistics

@@ -51,6 +51,14 @@ class TpchConnector(Connector):
     def table_schema(self, schema: str, table: str) -> TableSchema:
         return SCHEMAS[table]
 
+    #: dbgen writes ``orders`` by ascending key and ``lineitem`` order by
+    #: order (``generator.py``: ``np.repeat(orders.orderkey, ...)``) —
+    #: what the reference's TpchMetadata.getTableProperties declares
+    _SORTED_BY = {"orders": "o_orderkey", "lineitem": "l_orderkey"}
+
+    def sorted_by(self, schema: str, table: str) -> str | None:
+        return self._SORTED_BY.get(table)
+
     def row_count(self, schema: str, table: str) -> int:
         return self.data(schema).row_count(table)
 

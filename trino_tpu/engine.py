@@ -815,8 +815,8 @@ class QueryRunner:
                         # tripped capacity re-runs the query with the
                         # bumped (persisted) size
                         rows, flags = page.to_pylist(extra=pend[0])
-                if pend is None or not self.executor.note_deferred_overflow(
-                    (flags, pend[1], pend[2])
+                if pend is None or not self.executor.note_chain_flags(
+                    flags, pend[1], pend[2]
                 ):
                     done = True
                     break

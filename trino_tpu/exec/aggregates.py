@@ -18,7 +18,10 @@ the group context it is given:
   Any key, any aggregate — at the price of a sort, a gather a column
   and a full-width int64 prefix sum a limb, whatever the number of
   groups (some 500 ms for Q1's ten aggregates at 6.29M rows on a v5e,
-  PERF.md PR 26).
+  PERF.md PR 26). Rows that already arrive in key order
+  (``kernels.run_group``) are their own sorted order: the same
+  context with no permutation, so the sort and every gather fall away
+  and the prefix sums run over the columns in place.
 
 Work shared between the aggregates of one GROUP BY (the gather of a
 column into group order, the per-group row count, contribution masks)
@@ -102,7 +105,7 @@ class _Reducer:
         key = ("sorted", id(x))
         hit = self.share.get(key)
         if hit is None or hit[0] is not x:
-            hit = (x, x[self.info.perm])
+            hit = (x, self.info.in_order(x))
             self.share[key] = hit
         return hit[1]
 
@@ -189,6 +192,15 @@ class _Reducer:
                 cnt = self._slot(
                     jnp.ones(self.contrib.shape, jnp.int32), 0
                 ).astype(jnp.int64)
+            elif self.info.perm is None and (
+                self.contrib is self.share.get("#mask")
+            ):
+                # rows grouped in place, counting the live rows
+                # themselves: a group's count is its run's length — no
+                # prefix sum, no reads at the run starts. (True of the
+                # sort path as well, dead rows sorting last; left as it
+                # was there: ROADMAP S2.)
+                cnt = (self.info.ends - self.info.starts).astype(jnp.int64)
             else:
                 cnt = K.seg_sum_ranges(
                     self.contrib_s.astype(jnp.int64), self.info,

@@ -13,6 +13,10 @@ whole-column computation XLA can tile onto the MXU/VPU:
   most ``2**SLOT_KEY_BITS`` packed values needs neither sort nor
   scatter — the packed key word IS the group's slot, and an aggregate
   is one dense masked reduction per slot.
+- ``run_group``: group-by over rows that already arrive in key order
+  (a connector's declared sort order) — the runs are the groups, in
+  place: ``sort_group``'s contract with the identity as the
+  permutation, and a device check that the order holds.
 - ``join_expand``: equi-join via sort + searchsorted range expansion
   (the PagesHash/LookupSource analog, MAIN/operator/join/PagesHash.java:19).
 - ``sort_perm``: multi-key order-by via iterated stable argsort
@@ -48,6 +52,7 @@ __all__ = [
     "normalize_key",
     "GroupInfo",
     "sort_group",
+    "run_group",
     "SLOT_KEY_BITS",
     "SlotInfo",
     "slot_key_bits",
@@ -301,20 +306,30 @@ class GroupInfo(NamedTuple):
     """Sorted-group context shared by every aggregate over one GROUP BY.
 
     ``perm`` sorts rows so each group is one contiguous run (dead rows
-    last); ``gid_sorted[p]`` is the dense group id at sorted position p
+    last) — None where the rows already are in that order
+    (``run_group``: sorted position == row, nothing is gathered);
+    ``gid_sorted[p]`` is the dense group id at sorted position p
     (== capacity for dead/overflowed rows); ``group[i]`` maps original
     rows to ids; ``starts``/``ends`` delimit each id's run in sorted
     order; ``owner[s]`` is the first (original-index) row of group s or
     n when s is unused; ``num_groups`` is the exact distinct count.
     """
 
-    perm: jnp.ndarray
+    perm: jnp.ndarray | None
     gid_sorted: jnp.ndarray
     group: jnp.ndarray
     starts: jnp.ndarray
     ends: jnp.ndarray
     owner: jnp.ndarray
     num_groups: jnp.ndarray
+
+    def in_order(self, x: jnp.ndarray) -> jnp.ndarray:
+        """A row-ordered column in group-sorted order."""
+        return x if self.perm is None else x[self.perm]
+
+    def rows_at(self, pos: jnp.ndarray) -> jnp.ndarray:
+        """Original row index of sorted positions ``pos``."""
+        return pos if self.perm is None else self.perm[pos]
 
 
 @partial(jax.jit, static_argnames=("capacity", "widths"))
@@ -426,7 +441,7 @@ def _pack_words(norm_bits, null_flags, live, widths):
     values (the low ``w`` bits of each key's normalized bits are
     injective for values of that width). When the whole pack is one
     word with a 65th bit free, liveness folds in as the MSB so dead
-    rows sort last with no extra pass."""
+    rows sort last with no extra pass (``live`` None: never folded)."""
     if widths is None:
         return None
     keys = list(zip(norm_bits, null_flags, widths))
@@ -446,7 +461,7 @@ def _pack_words(norm_bits, null_flags, live, widths):
     if cur:
         chunks.append(cur)
     one_word = len(chunks) == 1
-    live_folded = one_word and cur_bits + 1 <= 64
+    live_folded = live is not None and one_word and cur_bits + 1 <= 64
     words = []
     for ci, chunk in enumerate(chunks):
         # start from the liveness bit (or the first key) rather than a
@@ -483,6 +498,67 @@ def assign_groups(
     """
     info = sort_group(norm_bits, null_flags, live, capacity, widths=widths)
     return info.group, info.owner
+
+
+# ---- group-by over rows already in key order: runs in place ------------------
+#
+# A table scanned in its connector's declared sort order (TPC-H's
+# ``lineitem`` on ``l_orderkey``: dbgen writes it order by order) is
+# already what ``sort_group`` sorts it into: each key one contiguous
+# run, runs ascending, dead rows last. Then the permutation is the
+# identity and nothing has to move — no row sort, no gather of a key or
+# an argument column into sorted order, no inverse permutation — and a
+# group's start is the position of its boundary row, which one
+# single-operand uint32 sort of the boundary mask compacts (where
+# ``sort_group`` ranks ``capacity`` ids among the rows by a second
+# uint64 sort, a prefix sum and a scatter: ``_merge_rank``). The order
+# is a declaration, so it is checked on the way.
+
+
+def run_group(
+    norm_bits: tuple[jnp.ndarray, ...],
+    null_flags: tuple[jnp.ndarray, ...],
+    live: jnp.ndarray,
+    capacity: int,
+    widths: tuple[int, ...],
+) -> tuple[GroupInfo, jnp.ndarray]:
+    """(info, unordered): grouping by the runs the rows already form.
+    Same arguments as ``sort_group`` for keys that pack into one word,
+    and — where ``unordered`` is False — the same groups, ids, starts,
+    ends and owners, with ``perm`` None (the identity).
+
+    ``unordered`` is True when the live rows are not a prefix of the
+    page or their packed key words descend somewhere (the order the
+    sort path would give them): the runs are then not the groups, the
+    rest of ``info`` means nothing, and the caller must group by
+    ``sort_group`` instead."""
+    n = live.shape[0]
+    (word,), _folded, bits = _pack_words(norm_bits, null_flags, None, widths)
+    if bits <= 32:
+        word = word.astype(jnp.uint32)  # compare in native lanes
+    prev = jnp.roll(word, 1)
+    live_prev = jnp.roll(live, 1)
+    first = jnp.arange(n, dtype=jnp.int32) == 0
+    unordered = jnp.any(live & ~first & (~live_prev | (word < prev)))
+    boundary = live & (first | (word != prev))
+    gid1 = cumsum(boundary.astype(jnp.int32))  # 1-based within live
+    num_groups = gid1[-1] if n else jnp.int32(0)
+    gid = jnp.minimum(jnp.where(live, gid1 - 1, capacity), capacity)
+    n_live = jnp.sum(live.astype(jnp.int32))
+    sids = jnp.arange(capacity, dtype=jnp.int32)
+    used = sids < num_groups
+    # the g-th boundary row is where group g starts: boundary rows to
+    # the front, in row order, by one packed uint32 sort
+    at_boundary = compact_perm(boundary)[:capacity]
+    if capacity > n:
+        at_boundary = jnp.concatenate(
+            [at_boundary, jnp.full((capacity - n,), n, jnp.int32)]
+        )
+    starts = jnp.where(used, at_boundary, n_live)
+    ends = jnp.concatenate([starts[1:], n_live.reshape(1)])
+    owner = jnp.where(used, at_boundary, n).astype(jnp.int32)
+    info = GroupInfo(None, gid, gid, starts, ends, owner, num_groups)
+    return info, unordered
 
 
 # ---- group-by over a small key domain: slot addressing ----------------------
@@ -699,7 +775,7 @@ def seg_arg_extreme(
     )
     at = jnp.clip(info.ends - 1, 0, max(n - 1, 0))
     bp = best[at]
-    return info.perm[jnp.clip(bp, 0, max(n - 1, 0))]
+    return info.rows_at(jnp.clip(bp, 0, max(n - 1, 0)))
 
 
 def seg_first_index(contrib_sorted, info: GroupInfo):
@@ -710,7 +786,7 @@ def seg_first_index(contrib_sorted, info: GroupInfo):
     masked = jnp.where(contrib_sorted, pos, n)
     first_pos = seg_minmax_scan(masked, info, jnp.int32(n), is_min=True)
     has = first_pos < n
-    rows = info.perm[jnp.clip(first_pos, 0, max(n - 1, 0))]
+    rows = info.rows_at(jnp.clip(first_pos, 0, max(n - 1, 0)))
     return jnp.where(has, rows, n), has
 
 

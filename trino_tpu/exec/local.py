@@ -540,6 +540,12 @@ class LocalExecutor:
         else:
             caps = stage.plan_capacities(chain, page.capacity)
         while True:
+            if page.ordered_on is not None and self._jit_cache.get(
+                _unordered_key(caps_key)
+            ):
+                # this chain's input broke its declared order (in an
+                # earlier statement, or in the run just made): by sort
+                page = dc_replace(page, ordered_on=None)
             env, mask, flags, n_live_dev, out_layout = self._dispatch_chain(
                 chain, page, caps
             )
@@ -571,20 +577,8 @@ class LocalExecutor:
             # never re-sync
             with telemetry.child_span("host_sync", site="chain_flags"):
                 vals, n_live = jax.device_get((flags, n_live_dev))
-            if vals:
-                overflowed = [i for i, v in vals.items() if v]
-                if overflowed:
-                    for i in overflowed:
-                        cap, mx = caps[i]
-                        if cap >= mx:
-                            raise RuntimeError(
-                                "aggregation table overflow at max capacity"
-                            )
-                        caps[i][0] = min(cap * 8, mx)
-                    self._jit_cache[caps_key] = {
-                        i: list(v) for i, v in caps.items()
-                    }
-                    continue
+            if vals and self.note_chain_flags(vals, caps_key, caps):
+                continue
             return self._finalize_chain(
                 chain, env, mask, int(n_live), out_layout
             )
@@ -751,6 +745,7 @@ class LocalExecutor:
             [cols[o] for o in canon.in_map],
             page.mask,
             known_rows=page.known_rows, packed=page.packed,
+            ordered_on=canon.in_map.get(page.ordered_on),
         )
         return canon.chain, view, canon.out_map
 
@@ -765,6 +760,8 @@ class LocalExecutor:
             tuple(self._node_key(n) for n in chain),
             tuple((i, c[0]) for i, c in sorted(caps.items())),
             self._layout_sig(page),
+            # an Aggregate over this key groups by runs: another program
+            page.ordered_on,
         )
         hit = self._jit_cache.get(key)
         was_miss = hit is None
@@ -791,6 +788,7 @@ class LocalExecutor:
                         for n, c in zip(page.names, page.columns)
                         if c.array_pool is not None
                     },
+                    ordered_on=page.ordered_on,
                 )
                 fn, out_layout = stage.build_chain(chain, in_layout, caps)
 
@@ -1055,9 +1053,19 @@ class LocalExecutor:
         columns = [
             cache[ckey(s, c)] for s, c in node.assignments.items()
         ]
+        # the whole table in the connector's order, live rows a prefix:
+        # the one page that carries the declared sort order
+        sorted_col = connector.sorted_by(node.schema, node.table)
         return Page(
             names, columns, cache[""],
             known_rows=cache["#rows"], packed=True,
+            ordered_on=next(
+                (
+                    s for s, c in node.assignments.items()
+                    if c == sorted_col and s not in hashed_syms
+                ),
+                None,
+            ),
         )
 
     def _scan_pruned(self, node: P.TableScan, connector) -> Page:
@@ -1344,15 +1352,24 @@ class LocalExecutor:
             out.pending_flags = pend
         return out
 
-    def note_deferred_overflow(self, pending) -> bool:
-        """Check a deferred final-chain overflow flag set (already
-        fetched to host). Returns True when a capacity was bumped and
-        the query must re-run."""
-        vals, caps_key, caps = pending
-        overflowed = [i for i, v in vals.items() if v]
-        if not overflowed:
+    def note_chain_flags(self, vals, caps_key, caps) -> bool:
+        """Act on one chain run's flags, already on the host (read in
+        the chain's own sync, or — a statement's last chain, deferred —
+        with its result): a tripped overflow flag grows that
+        Aggregate's table, a tripped order check
+        (``stage.unordered_flag``) bars the chain from grouping by
+        runs. Both are remembered per chain shape. True: the rows of
+        this run are not the answer, run the chain (the query) again."""
+        tripped = [k for k, v in vals.items() if v]
+        if not tripped:
             return False
-        for i in overflowed:
+        if any(k < 0 for k in tripped):
+            # a run's rows under a broken order mean nothing, its
+            # overflow flag included: rerun by sort, table as it was
+            self._jit_cache[_unordered_key(caps_key)] = True
+            telemetry.STREAMED_GROUPBY_FALLBACKS.inc()
+            return True
+        for i in tripped:
             cap, mx = caps[i]
             if cap >= mx:
                 raise RuntimeError(
@@ -2829,6 +2846,12 @@ class LocalExecutor:
                     )
                 )
         return Page(names, cols, live)
+
+
+def _unordered_key(caps_key: tuple) -> tuple:
+    """Where the executor remembers, beside a chain shape's learned
+    capacities, that its input broke the connector's declared order."""
+    return ("unordered",) + caps_key[1:]
 
 
 def _splittable(agg: P.Aggregate) -> bool:

@@ -40,7 +40,10 @@ from trino_tpu.expr.compiler import ColumnLayout, compile_expr
 from trino_tpu.page import StringDictionary, content_hash64, pad_capacity
 from trino_tpu.plan import nodes as P
 
-__all__ = ["FUSABLE", "ChainLayout", "plan_capacities", "build_chain"]
+__all__ = [
+    "FUSABLE", "ChainLayout", "plan_capacities", "build_chain",
+    "unordered_flag",
+]
 
 #: node types that fuse into one program (single-source, static shapes).
 #: Exchange is a stage boundary (collective / gather), never fused.
@@ -60,16 +63,30 @@ class ChainLayout:
     #: ARRAY-column pools by symbol (page.ArrayPool)
     arrays: dict = field(default_factory=dict)
     #: on ``build_chain``'s output layout: chain position -> "direct" |
-    #: "sorted", the path of each grouped Aggregate of the program
-    #: (filled when the program is traced; kept beside the cached
-    #: program so that a warm dispatch reports it too)
+    #: "streamed" | "sorted", the path of each grouped Aggregate of the
+    #: program (filled when the program is traced; kept beside the
+    #: cached program so that a warm dispatch reports it too)
     groupbys: dict = field(default_factory=dict)
+    #: the symbol the page's live rows ascend on (``Page.ordered_on``),
+    #: while that still holds inside the chain: a Project that passes
+    #: the column through renames it, every other step drops it
+    ordered_on: str | None = None
 
     def expr_layout(self) -> ColumnLayout:
         return ColumnLayout(
             types=dict(self.types), dictionaries=dict(self.dicts),
             array_pools=dict(self.arrays),
         )
+
+
+def unordered_flag(pos: int) -> int:
+    """Key, in a chain's flags, of the order check of the streamed
+    Aggregate at chain position ``pos`` (True: the declared order does
+    not hold, group by sort instead). Overflow flags are keyed by the
+    position itself, and the keys of one pytree dict must sort
+    together, so this one is its complement: negative keys are order
+    checks."""
+    return ~pos
 
 
 def _norm_opt(data, valid):
@@ -196,6 +213,11 @@ def build_chain(chain: list[P.PlanNode], layout: ChainLayout, caps: dict[int, li
             steps.append((scope, _limit_step(nd)))
         else:
             raise NotImplementedError(type(nd).__name__)
+        if layout.ordered_on is not None and not isinstance(nd, P.Project):
+            # a Sort moves rows, a Filter or a Limit leaves dead rows
+            # inside runs: those group by sort (an Aggregate's and a
+            # Project's output layouts are built anew, with what holds)
+            layout = dc_replace(layout, ordered_on=None)
 
     def fn(env, mask):
         flags = {}
@@ -247,6 +269,15 @@ def _project_step(nd: P.Project, layout: ChainLayout):
             if compiled[s].pool is not None
             or (isinstance(e, _Ref) and layout.arrays.get(e.name) is not None)
         },
+        # rows stay where they are; the ordered column, if it is passed
+        # through as it is, goes on under its new name
+        ordered_on=next(
+            (
+                s for s, e in nd.assignments.items()
+                if isinstance(e, _Ref) and e.name == layout.ordered_on
+            ),
+            None,
+        ),
     )
 
     def step(env, mask, flags):
@@ -331,6 +362,9 @@ def _aggregate_step(
     dense_aggs = all(
         dense_reducible(call.name, call.distinct) for _s, call, *_ in agg_meta
     )
+    # the input's live rows ascend on the one group key (a connector's
+    # declared order, carried by the page): its runs are the groups
+    in_key_order = group_keys == [layout.ordered_on]
 
     def step(env, mask, flags):
         if is_global:
@@ -360,17 +394,27 @@ def _aggregate_step(
             norm = [_norm_opt(d, v) for d, v in shifted]
             widths = tuple(width_list)
             null_flags = tuple(fl for _, fl in norm)
-            # a key domain of a few bits is addressed, not sorted: the
-            # choice reads only what is static under jit (key widths,
-            # nullability, aggregate kinds), so it is part of the program
-            direct = dense_aggs and (
-                K.slot_key_bits(widths, null_flags) <= K.SLOT_KEY_BITS
+            # a key domain of a few bits is addressed, rows already in
+            # key order are grouped where they lie, anything else is
+            # sorted: the choice reads only what is static under jit
+            # (key widths, nullability, aggregate kinds, the page's
+            # declared order), so it is part of the program
+            key_bits = K.slot_key_bits(widths, null_flags)
+            group_args = (
+                tuple(b for b, _ in norm), null_flags, mask, capacity, widths,
             )
-            groupbys[pos] = "direct" if direct else "sorted"
-            info = (K.slot_group if direct else K.sort_group)(
-                tuple(b for b, _ in norm), null_flags,
-                mask, capacity, widths=widths,
-            )
+            if dense_aggs and key_bits <= K.SLOT_KEY_BITS:
+                groupbys[pos] = "direct"
+                info = K.slot_group(*group_args)
+            elif in_key_order and key_bits <= 64:  # packs into one word
+                groupbys[pos] = "streamed"
+                # declared, then verified: the caller reruns the chain
+                # by sort when the flag comes back set
+                info, unordered = K.run_group(*group_args)
+                flags = {**flags, unordered_flag(pos): unordered}
+            else:
+                groupbys[pos] = "sorted"
+                info = K.sort_group(*group_args)
             flags = {**flags, pos: info.num_groups > capacity}
             env2 = {}
             occupied = (
@@ -446,7 +490,7 @@ def _aggregate_step(
                 contrib = _dedupe(list(shifted), d_arg, contrib, in_cap,
                                   widths + (dwidth,))
             prepared.append((sym, call, arg, contrib))
-        if isinstance(info, K.GroupInfo):
+        if isinstance(info, K.GroupInfo) and info.perm is not None:
             _presort_shared(prepared, info, share)
         for sym, call, arg, contrib in prepared:
             data, valid = compute_aggregate(

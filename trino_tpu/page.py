@@ -559,6 +559,21 @@ class Page:
     known_rows: int | None = None
     #: True when live rows occupy positions [0, known_rows) exactly
     packed: bool = False
+    #: the symbol whose packed key words the live rows ascend on, as the
+    #: connector declared (``Connector.sorted_by``) — None: no order
+    #: known. Opt-in and lost by default: only the whole-table resident
+    #: scan (``LocalExecutor._TableScan``) sets it, and whatever builds
+    #: another page from this one (a join, a sort, an exchange, a union,
+    #: a compaction, a slice) builds it without. A run cut at a page
+    #: border would be two partial groups, so the pages that hold a
+    #: row range only — a split (``_scan_split``), a domain-pruned scan,
+    #: a chunk of ``_run_chain_chunked``, a batch of a streamed scan
+    #: (``exec/stream_scan.py``, ``exec/spill.py``), a mesh shard
+    #: (``ShardedPage`` has no such field) — never carry it. A grouped
+    #: Aggregate over exactly this key, reached through Projects only,
+    #: then groups by the runs in place (``kernels.run_group``), which
+    #: checks the order on the device.
+    ordered_on: str | None = None
 
     def __post_init__(self):
         assert len(self.names) == len(self.columns)

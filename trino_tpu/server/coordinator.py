@@ -785,7 +785,7 @@ class Coordinator:
         "runner_wait_ms", "parse_ms", "plan_ms", "execute_ms",
         "build_trace_ms", "host_sync_ms", "host_syncs", "dispatches",
         "upload_ms", "to_rows_ms", "respond_ms", "rows_out_ms",
-        "direct_groupbys", "sorted_groupbys",
+        "direct_groupbys", "sorted_groupbys", "streamed_groupbys",
     )
 
     def _seal(self, q: QueryState) -> None:

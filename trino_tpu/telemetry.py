@@ -349,7 +349,8 @@ def span_totals(root: Span) -> Dict[str, float]:
     spans of each name, a name being the span's first word with ``-``
     as ``_`` (``stage s0`` is ``stage``, ``spool-read`` ``spool_read``),
     and ``host_syncs`` / ``dispatches`` counting those;
-    ``direct_groupbys`` / ``sorted_groupbys`` count the grouped
+    ``direct_groupbys`` / ``streamed_groupbys`` / ``sorted_groupbys``
+    count the grouped
     aggregates of the dispatched programs by the path each took (the
     ``dispatch`` span's ``groupbys``). The root is left out: its time
     is the statement's ``elapsed_ms``."""
@@ -663,6 +664,9 @@ JIT_CACHE_HITS = REGISTRY.counter(
     "trino_jit_cache_hits_total", "Executor jit-cache hits, by cache")
 JIT_CACHE_MISSES = REGISTRY.counter(
     "trino_jit_cache_misses_total", "Executor jit-cache misses, by cache")
+STREAMED_GROUPBY_FALLBACKS = REGISTRY.counter(
+    "trino_streamed_groupby_fallbacks_total",
+    "Chains rerun by sort after a declared row order failed its device check")
 LISTENER_FAILURES = REGISTRY.counter(
     "trino_event_listener_failures_total", "EventListener callbacks that raised")
 WORKER_TASKS = REGISTRY.counter(
