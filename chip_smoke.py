@@ -46,13 +46,15 @@ import importlib.util
 import json
 import os
 import shutil
-import signal
-import socket
 import subprocess
 import sys
 import threading
 import time
-import urllib.request
+
+# the supervisor (children and their logs, ports, the Prometheus
+# parser) is the benchmark's: one copy, used read-only here
+from benchmarks import supervisor
+from benchmarks.supervisor import Child, free_port, http_json, load_client
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 #: what the driver allows the whole script, compilation included
@@ -78,17 +80,6 @@ def log(msg: str) -> None:
     print(f"[smoke {elapsed():7.1f}s] {msg}", file=sys.stderr, flush=True)
 
 
-def load_client():
-    """The real client (trino_tpu/server/client.py is pure stdlib),
-    loaded by path so that this process imports neither trino_tpu nor
-    jax."""
-    path = os.path.join(HERE, "trino_tpu", "server", "client.py")
-    spec = importlib.util.spec_from_file_location("_smoke_client", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def load_queries() -> dict:
     path = os.path.join(
         HERE, "trino_tpu", "connectors", "tpch", "queries.py"
@@ -100,117 +91,30 @@ def load_queries() -> dict:
 
 
 def child_env(platform: str | None, data_cache: str) -> dict:
-    """A child's environment: no JAX_PLATFORMS at all unless this child
-    is explicitly a host-only role."""
-    env = os.environ.copy()
-    env.pop("JAX_PLATFORMS", None)
-    env.pop("XLA_FLAGS", None)
-    if platform is not None:
-        env["JAX_PLATFORMS"] = platform
+    """The supervisor's child environment (no JAX_PLATFORMS at all
+    unless this child is explicitly a host-only role) plus the one
+    variable that is the smoke's own: where the oracle child left the
+    generated data."""
+    env = supervisor.child_env(platform)
     env["TRINO_TPU_DATA_CACHE"] = data_cache
-    env["PYTHONPATH"] = HERE + os.pathsep + env.get("PYTHONPATH", "")
-    env["PYTHONUNBUFFERED"] = "1"
     return env
-
-
-class Child:
-    """A server child: stdout/stderr drained to a log file by a thread
-    (a full pipe must never block a server), ``ready`` line awaited."""
-
-    def __init__(self, name: str, argv: list[str], env: dict, logdir: str):
-        self.name = name
-        self.log_path = os.path.join(logdir, f"{name}.log")
-        self.proc = subprocess.Popen(
-            argv, env=env, cwd=HERE, stdout=subprocess.PIPE,
-            stderr=subprocess.STDOUT, text=True,
-        )
-        self.lines: list[str] = []
-        self._cv = threading.Condition()
-        self._t = threading.Thread(target=self._drain, daemon=True)
-        self._t.start()
-
-    def _drain(self):
-        with open(self.log_path, "w") as fh:
-            for line in self.proc.stdout:
-                fh.write(line)
-                fh.flush()
-                with self._cv:
-                    self.lines.append(line.rstrip("\n"))
-                    self._cv.notify_all()
-        with self._cv:
-            self._cv.notify_all()
-
-    def wait_line(self, prefix: str, timeout_s: float) -> str:
-        deadline = time.monotonic() + timeout_s
-        seen = 0
-        with self._cv:
-            while True:
-                for line in self.lines[seen:]:
-                    if line.startswith(prefix):
-                        return line
-                seen = len(self.lines)
-                if self.proc.poll() is not None and not self._t.is_alive():
-                    raise RuntimeError(
-                        f"{self.name} exited rc={self.proc.returncode} "
-                        f"before '{prefix}': {self.tail()}"
-                    )
-                left = deadline - time.monotonic()
-                if left <= 0:
-                    raise TimeoutError(
-                        f"{self.name}: no '{prefix}' in {timeout_s:.0f}s: "
-                        f"{self.tail()}"
-                    )
-                self._cv.wait(min(left, 1.0))
-
-    def tail(self, n: int = 12) -> str:
-        return " | ".join(
-            x[:300] for x in self.lines[-n:] if "cpu_aot_loader" not in x
-        )
-
-    def stop(self, timeout_s: float = 30.0) -> int | None:
-        """SIGTERM, wait, SIGKILL; returns the exit code (None: had to
-        be killed)."""
-        if self.proc.poll() is None:
-            self.proc.send_signal(signal.SIGTERM)
-            try:
-                self.proc.wait(timeout=timeout_s)
-            except subprocess.TimeoutExpired:
-                self.proc.kill()
-                self.proc.wait(timeout=10)
-                return None
-        return self.proc.returncode
-
-
-def http_json(url: str, timeout: float = 30.0) -> dict:
-    with urllib.request.urlopen(url, timeout=timeout) as resp:
-        return json.loads(resp.read())
 
 
 def metrics(uri: str) -> dict:
     """The compile-related series of /v1/metrics, summed over labels."""
-    with urllib.request.urlopen(f"{uri}/v1/metrics", timeout=30) as resp:
-        text = resp.read().decode()
+    series = supervisor.prometheus(supervisor.http_text(f"{uri}/v1/metrics"))
     want = {
         "trino_xla_compile_total": "compiles",
         "trino_xla_compile_seconds_total": "compile_s",
         "trino_persistent_cache_hits_total": "persistent_hits",
         "trino_persistent_cache_degraded": "degraded",
     }
-    out = {v: 0.0 for v in want.values()}
-    found = set()
-    for line in text.splitlines():
-        if not line or line.startswith("#"):
-            continue
-        name = line.split("{", 1)[0].split(" ", 1)[0]
-        if name in want:
-            out[want[name]] += float(line.rsplit(" ", 1)[1])
-            found.add(name)
     for name in ("trino_xla_compile_total",
                  "trino_persistent_cache_hits_total",
                  "trino_persistent_cache_degraded"):
-        if name not in found:
+        if name not in series:
             raise RuntimeError(f"{uri}/v1/metrics carries no {name}")
-    return out
+    return {key: series.get(name, 0.0) for name, key in want.items()}
 
 
 def delta(after: dict, before: dict) -> dict:
@@ -221,12 +125,6 @@ def delta(after: dict, before: dict) -> dict:
             after["persistent_hits"] - before["persistent_hits"]
         ),
     }
-
-
-def free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
 
 
 def device_check(info: dict, failures: list, who: str, want_count: int = 1):

@@ -593,3 +593,55 @@ def test_serving_layer_shares_result_cache_across_queries(workers, tmp_path):
     snap = s.result_cache.snapshot()
     assert snap["entries"] == 1
     assert snap["hits"] >= 1
+
+
+def test_serving_cached_rows_equal_uncached_twin(workers, tmp_path):
+    """The cache CI lane's round: one repeat-heavy schedule over three
+    TPC-H templates through the serving layer with both tiers on, every
+    answer (hit or miss) held to the rows of a twin with both tiers
+    off — exactly, no tolerance — and the repeats really were hits."""
+    import random
+
+    from trino_tpu.connectors.tpch.queries import QUERIES
+    from trino_tpu.testing import chaos as chaos_mod
+    from trino_tpu.testing.golden import assert_rows_match
+
+    mix = [QUERIES["q01"], QUERIES["q03"], QUERIES["q06"]]
+    # zipf-ish weights 1/rank, fixed seed; every template at least once
+    schedule = random.Random(11).choices(
+        range(len(mix)), weights=[1.0, 0.5, 1 / 3], k=12
+    )
+    schedule[:len(mix)] = range(len(mix))
+
+    def serving(cache_on: bool):
+        s = chaos_mod.make_serving(workers, str(tmp_path))
+        s.session.properties["result_cache_enabled"] = cache_on
+        s.session.properties["device_cache_enabled"] = cache_on
+        return s
+
+    def hit(res) -> bool:
+        return bool(((res.cache_stats or {}).get("result") or {}).get("hit"))
+
+    twin = serving(False)
+    try:
+        uncached = [twin.execute(sql) for sql in mix]
+    finally:
+        twin.stop()
+    assert not any(hit(r) for r in uncached)
+
+    hits0 = telemetry.RESULT_CACHE_HITS.value()
+    hits = []
+    cached = serving(True)
+    try:
+        for idx in schedule:
+            res = cached.execute(mix[idx])
+            assert_rows_match(
+                res.rows, uncached[idx].rows,
+                ordered=uncached[idx].ordered, abs_tol=0.0,
+            )
+            hits.append(hit(res))
+    finally:
+        cached.stop()
+    # first sight of a template misses, every repeat hits
+    assert hits == [False] * len(mix) + [True] * (len(schedule) - len(mix))
+    assert telemetry.RESULT_CACHE_HITS.value() - hits0 == sum(hits)

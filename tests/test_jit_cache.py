@@ -9,7 +9,10 @@ in-memory-only compilation WITHOUT failing the task, and degraded mode
 must be visible in ``/v1/metrics``.
 """
 
+import json
 import os
+import subprocess
+import sys
 import time
 import urllib.request
 
@@ -203,3 +206,64 @@ def test_worker_wedge_degrades_without_failing_the_task(tmp_path_factory):
         )
     finally:
         chaos.stop_workers(procs)
+
+
+# ---------------------------------------------------------------------------
+# a fresh process against a warm persistent cache
+# ---------------------------------------------------------------------------
+
+_PROBE = """
+import json, sys
+from trino_tpu import telemetry
+from trino_tpu.connectors.tpch.queries import QUERIES
+from trino_tpu.engine import QueryRunner
+
+telemetry.install_jax_compile_hook()
+runner = QueryRunner.tpch("tiny")
+report = {}
+for q in sys.argv[1:]:
+    c0 = telemetry.compile_snapshot()
+    rows = runner.execute(QUERIES[q]).rows
+    c1 = telemetry.compile_snapshot()
+    report[q] = {
+        "compiles": int(c1["compiles"] - c0["compiles"]),
+        "persistent_hits": int(
+            c1["persistent_hits"] - c0["persistent_hits"]
+        ),
+        "rows": json.dumps(rows, default=str),
+    }
+print("PROBE " + json.dumps(report))
+"""
+
+
+def _probe(cache_dir, qids) -> dict:
+    env = os.environ.copy()
+    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
+    env.pop("XLA_FLAGS", None)
+    env["PYTHONPATH"] = os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    )) + os.pathsep + env.get("PYTHONPATH", "")
+    proc = subprocess.run(
+        [sys.executable, "-c", _PROBE, *qids],
+        env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    line = [x for x in proc.stdout.splitlines() if x.startswith("PROBE ")]
+    return json.loads(line[-1][len("PROBE "):])
+
+
+def test_fresh_process_compiles_at_most_one_program_a_query(tmp_path):
+    """What a restart pays (the warm-cache bar of the compile-tax work):
+    a second process, against the persistent cache the first one
+    filled, deserializes its programs — at most one real compile a
+    query — and answers the same rows."""
+    qids = ("q01", "q03")
+    cold = _probe(tmp_path, qids)
+    assert any(cold[q]["compiles"] > 1 for q in qids), cold
+    assert any(tmp_path.iterdir()), "the first process cached nothing"
+    warm = _probe(tmp_path, qids)
+    for q in qids:
+        assert warm[q]["compiles"] <= 1, (q, warm[q])
+        assert warm[q]["persistent_hits"] >= 1, (q, warm[q])
+        assert warm[q]["rows"] == cold[q]["rows"], q

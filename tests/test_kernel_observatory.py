@@ -1,12 +1,11 @@
-"""Kernel observatory: compiled-program catalog, HLO-scope device-time
-attribution, and the kernel_report regression gate.
+"""Kernel observatory: compiled-program catalog and HLO-scope
+device-time attribution.
 
 The device tier's observability stack (the layer below PR 7's operator
 roofline): every canonical-bucket compile registers a catalog entry
 (XLA cost model + memory_analysis HBM footprint + the HLO
 instruction→named-scope map), ``jax.profiler`` captures attribute
-device time to named plan operators INSIDE a fused program, and
-``tools/kernel_report.py`` diffs two catalog snapshots per bucket.
+device time to named plan operators INSIDE a fused program.
 """
 
 import json
@@ -18,11 +17,6 @@ import time
 import urllib.request
 
 import pytest
-
-# tools/ is a plain directory off the repo root, not an installed pkg
-sys.path.insert(
-    0, os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
-)
 
 from trino_tpu import program_catalog
 from trino_tpu.connectors.tpch.connector import TpchConnector
@@ -310,113 +304,6 @@ def test_diagnostics_bundle_snapshots_programs(runner):
     assert isinstance(bundle["programs"], list)
     assert bundle["programs"], "catalog snapshot missing from bundle"
     assert "program_id" in bundle["programs"][0]
-
-
-# ---------------------------------------------------------------------------
-# kernel_report verdicts
-# ---------------------------------------------------------------------------
-
-
-def _entry(pid, label, flops, temp, compile_s):
-    return {
-        "program_id": pid, "label": label, "source": "local",
-        "hits": 3, "flops": flops, "temp_bytes": temp,
-        "compile_s": compile_s,
-    }
-
-
-def _write(tmp_path, name, entries):
-    p = tmp_path / name
-    p.write_text(json.dumps({"programs": entries}))
-    return str(p)
-
-
-def test_kernel_report_clean_and_regressed(tmp_path):
-    from tools import kernel_report
-
-    base = [
-        _entry("aaa", "Filter→Aggregate", 1000.0, 4096, 0.2),
-        _entry("bbb", "Filter", 50.0, 0, 0.05),
-    ]
-    baseline = _write(tmp_path, "base.json", base)
-    clean = _write(tmp_path, "clean.json", [
-        _entry("aaa", "Filter→Aggregate", 1000.0, 4100, 0.25),
-        _entry("bbb", "Filter", 50.0, 0, 0.04),
-    ])
-    assert kernel_report.main(
-        [clean, "--baseline", baseline]
-    ) == 0
-    # flops regression past the band -> nonzero exit
-    regressed = _write(tmp_path, "regressed.json", [
-        _entry("aaa", "Filter→Aggregate", 2000.0, 4096, 0.2),
-        _entry("bbb", "Filter", 50.0, 0, 0.05),
-    ])
-    assert kernel_report.main(
-        [regressed, "--baseline", baseline]
-    ) == 1
-    # temp-HBM regression alone also fails
-    hbm = _write(tmp_path, "hbm.json", [
-        _entry("aaa", "Filter→Aggregate", 1000.0, 9999, 0.2),
-        _entry("bbb", "Filter", 50.0, 0, 0.05),
-    ])
-    assert kernel_report.main([hbm, "--baseline", baseline]) == 1
-
-
-def test_kernel_report_new_gone_buckets_skip(tmp_path):
-    from tools import kernel_report
-
-    baseline = _write(tmp_path, "base.json", [
-        _entry("aaa", "Filter", 100.0, 0, 0.1),
-        _entry("old", "Sort", 900.0, 128, 0.3),
-    ])
-    fresh = _write(tmp_path, "fresh.json", [
-        _entry("aaa", "Filter", 100.0, 0, 0.1),
-        _entry("new", "TopN", 5000.0, 65536, 2.0),
-    ])
-    # drifted buckets never fail the gate
-    assert kernel_report.main([fresh, "--baseline", baseline]) == 0
-
-
-def test_kernel_report_label_fallback_join(tmp_path):
-    from tools import kernel_report
-
-    baseline = _write(tmp_path, "base.json", [
-        _entry("id-old", "Filter→Sort", 100.0, 256, 0.1),
-    ])
-    # same unique label, different program_id (key drifted): still
-    # joined, and the regression still caught
-    fresh = _write(tmp_path, "fresh.json", [
-        _entry("id-new", "Filter→Sort", 100.0, 9999, 0.1),
-    ])
-    assert kernel_report.main([fresh, "--baseline", baseline]) == 1
-
-
-def test_kernel_report_unusable_input(tmp_path):
-    from tools import kernel_report
-
-    bad = tmp_path / "bad.json"
-    bad.write_text('{"neither": "shape"}')
-    good = _write(tmp_path, "good.json", [
-        _entry("aaa", "Filter", 1.0, 0, 0.1)
-    ])
-    assert kernel_report.main(
-        [str(bad), "--baseline", good]
-    ) == 2
-    assert kernel_report.main(
-        [good, "--baseline", str(bad)]
-    ) == 2
-
-
-def test_committed_baseline_loads_and_is_clean_vs_itself():
-    here = os.path.dirname(__file__)
-    from tools import kernel_report
-
-    path = os.path.join(
-        here, "..", "tools", "kernel_baseline.json"
-    )
-    entries = kernel_report.load_snapshot(path)
-    assert entries and all("program_id" in e for e in entries)
-    assert kernel_report.main([path, "--baseline", path]) == 0
 
 
 # ---------------------------------------------------------------------------

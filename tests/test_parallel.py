@@ -11,10 +11,13 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from trino_tpu.exec import kernels as K
+from trino_tpu import types as T
+from trino_tpu.connectors.base import TableSchema
+from trino_tpu.connectors.memory import MemoryConnector
+from trino_tpu.engine import QueryRunner
+from trino_tpu.metadata import Metadata, Session
 from trino_tpu.parallel.core import WORKER_AXIS, make_mesh
 from trino_tpu.parallel.exchange import partition_exchange
-from trino_tpu.parallel.groupby import distributed_group_sums
 
 import jax
 
@@ -26,6 +29,20 @@ def mesh():
     return make_mesh(8)
 
 
+def _mesh_runner(mesh, columns, arrays):
+    """A mesh QueryRunner over one memory table ``t``: the path a
+    distributed GROUP BY really takes (exec/mesh.py: PARTIAL aggregate
+    per shard -> hash exchange on the key -> FINAL on the owner)."""
+    md = Metadata()
+    md.register_catalog("memory", MemoryConnector())
+    conn = md.connector("memory")
+    conn.create_table("default", "t", TableSchema("t", columns))
+    conn.insert("default", "t", arrays)
+    return QueryRunner(
+        md, Session(catalog="memory", schema="default"), mesh=mesh
+    )
+
+
 def test_distributed_group_sums(mesh):
     rng = np.random.default_rng(0)
     n = 1024
@@ -34,20 +51,21 @@ def test_distributed_group_sums(mesh):
     live = np.ones(n, dtype=bool)
     live[::13] = False
 
-    kb, kn = K.normalize_key(jnp.asarray(keys), None)
-    key, null, sums, counts, slot_live, overflow = distributed_group_sums(
-        mesh, WORKER_AXIS, kb, kn, jnp.asarray(live), [jnp.asarray(vals)],
-        local_capacity=128, final_capacity=64, bucket_capacity=64,
+    r = _mesh_runner(
+        mesh,
+        [("k", T.BIGINT), ("v", T.BIGINT), ("live", T.BOOLEAN)],
+        {"k": keys, "v": vals, "live": live},
     )
-    assert not overflow
+    rows = r.execute(
+        "select k, sum(v), count(*) from t where live group by k"
+    ).rows
+    # the aggregation crossed the mesh: one hash exchange on the key
+    assert r.executor.exchange_stats["exchanges"] == 1
 
     got = {}
-    k_h, s_h, c_h, l_h = map(np.asarray, (key, sums[0], counts, slot_live))
-    for i in range(len(l_h)):
-        if l_h[i]:
-            k = int(k_h[i])
-            assert k not in got, f"key {k} finalized on two devices"
-            got[k] = (int(s_h[i]), int(c_h[i]))
+    for k, s, c in rows:
+        assert k not in got, f"key {k} finalized on two devices"
+        got[int(k)] = (int(s), int(c))
 
     want_s = collections.Counter()
     want_c = collections.Counter()
@@ -64,18 +82,18 @@ def test_distributed_group_sums_with_nulls(mesh):
     keys = rng.integers(0, 5, n).astype(np.int64)
     valid = rng.random(n) > 0.2  # NULL keys group together
     vals = np.ones(n, dtype=np.int64)
-    live = np.ones(n, dtype=bool)
 
-    kb, kn = K.normalize_key(jnp.asarray(keys), jnp.asarray(valid))
-    key, null, sums, counts, slot_live, overflow = distributed_group_sums(
-        mesh, WORKER_AXIS, kb, kn, jnp.asarray(live), [jnp.asarray(vals)],
-        local_capacity=64, final_capacity=64, bucket_capacity=64,
+    r = _mesh_runner(
+        mesh, [("k", T.BIGINT), ("v", T.BIGINT)],
+        {"k": (keys, valid), "v": vals},
     )
-    assert not overflow
-    n_h, c_h, l_h = map(np.asarray, (null, counts, slot_live))
-    null_groups = [int(c_h[i]) for i in range(len(l_h)) if l_h[i] and n_h[i]]
+    rows = r.execute("select k, sum(v), count(*) from t group by k").rows
+    assert r.executor.exchange_stats["exchanges"] == 1
+    null_groups = [int(c) for k, s, c in rows if k is None]
     assert len(null_groups) == 1
     assert null_groups[0] == int((~valid).sum())
+    want = collections.Counter(int(k) for k in keys[valid])
+    assert {int(k): int(s) for k, s, c in rows if k is not None} == want
 
 
 def test_partition_exchange_overflow_detected(mesh):

@@ -379,6 +379,56 @@ def test_local_injected_compile_delay_detected(live_sentry,
     assert len(sen.anomalies()) == 1
 
 
+def test_injected_delay_flags_only_the_faulted_plan_among_three(
+    tmp_path, monkeypatch
+):
+    """The sentry CI lane's round: three TPC-H plan shapes warm their
+    own baselines in one durable history store, a healthy twin pass
+    flags nothing, and a seeded compile delay on Q3 alone yields
+    exactly one verdict, attributed to xla_compile and naming the
+    faulted statement. (Thresholds are wide so that a loaded machine's
+    jitter on a warmed statement cannot flag: 400 ms against a 1.2 s
+    injected delay.)"""
+    from trino_tpu.connectors.tpch.queries import QUERIES
+
+    monkeypatch.setenv("TRINO_TPU_COMPILE_DELAY_S", "1.2")
+    prev_h, prev_s = history.active(), sentry.active()
+    store = history.QueryHistory(root=str(tmp_path))
+    sen = sentry.Sentry(store, min_samples=3, min_delta_ms=400.0)
+    history.set_active(store)
+    sentry.set_active(sen)
+    try:
+        runner = QueryRunner.tpch("tiny")
+        qids = ("q01", "q03", "q06")
+        for _ in range(sen.min_samples + 1):
+            for q in qids:
+                runner.execute(QUERIES[q])
+        assert sen.baseline_count() == len(qids)
+        for q in qids:  # the healthy twin: zero false positives
+            runner.execute(QUERIES[q])
+        assert sen.anomalies() == []
+        inj = fault.FaultInjector(seed=0)
+        inj.arm_nth("compile-delay", 1)
+        fault.activate(inj)
+        try:
+            runner.execute(QUERIES["q03"])
+        finally:
+            fault.deactivate()
+        verdict, = sen.anomalies()
+        assert verdict.driver == "xla_compile", verdict.message
+        flagged = store.entries()[-1]
+        assert flagged["query_id"] == verdict.query_id
+        assert flagged["plan_digest"] == verdict.plan_digest
+        # the other two plans' baselines took no sample from it
+        assert sen.baseline_count() == len(qids)
+        for q in ("q01", "q06"):
+            runner.execute(QUERIES[q])
+        assert len(sen.anomalies()) == 1
+    finally:
+        history.set_active(prev_h)
+        sentry.set_active(prev_s)
+
+
 def test_explain_analyze_baseline_footer(live_sentry):
     _store, sen = live_sentry
     runner = QueryRunner.tpch("tiny")

@@ -135,11 +135,33 @@ def test_poll_threads_stay_o_workers(serving, oracle):
     n_workers = len(serving.workers)
     assert serving.dispatcher.poll_thread_count() == n_workers
 
-    counts = []
+    def poll_threads():
+        return {
+            t for t in threading.enumerate()
+            if t.name.startswith("dispatch-poll-")
+        }
+
+    # reactors of dispatchers that earlier tests of this process
+    # stopped may still be leaving their last RPC: they can only
+    # exit, so they are not this dispatcher's and are not counted
+    own = set(serving.dispatcher._threads.values())
+    stale = poll_threads() - own
+    # every statement has to reach the workers and stay there long
+    # enough to be seen together: no answers from the result cache,
+    # and each task holds its worker for a while
+    serving.session.properties["result_cache_enabled"] = False
+    serving.session.properties["fleet_task_delay_ms"] = 200
+    # the serving default admits 2x the worker count at once
+    max_running = 2 * n_workers
+    errors = []
 
     def client(cid):
-        serving.execute(MIX[1])
+        try:
+            serving.execute(MIX[1])
+        except Exception as e:
+            errors.append(f"client {cid}: {type(e).__name__}: {e}")
 
+    counts = []
     for n_queries in (2, 8):
         threads = [
             threading.Thread(target=client, args=(c,))
@@ -147,19 +169,25 @@ def test_poll_threads_stay_o_workers(serving, oracle):
         ]
         for t in threads:
             t.start()
-        # sample while the queries are genuinely concurrent
-        time.sleep(0.5)
+        # sample when the statements ARE concurrent: wait for that,
+        # not for a fixed time; the deadline only bounds a hang
+        want = min(n_queries, max_running)
+        deadline = time.monotonic() + 120
+        running = len(serving.running_queries())
+        while running < want and time.monotonic() < deadline:
+            time.sleep(0.005)
+            running = len(serving.running_queries())
         counts.append((
             n_queries,
+            running,
             serving.dispatcher.poll_thread_count(),
-            sum(
-                1 for t in threading.enumerate()
-                if t.name.startswith("dispatch-poll-")
-            ),
+            len(poll_threads() - stale),
         ))
         for t in threads:
             t.join()
-    for n_queries, tracked, live in counts:
+    assert not errors, errors
+    for n_queries, running, tracked, live in counts:
+        assert running >= min(n_queries, max_running), (n_queries, running)
         assert tracked == n_workers, (n_queries, tracked)
         assert live == n_workers, (n_queries, live)
 

@@ -278,6 +278,75 @@ def test_over_budget_table_streams_under_cap(tmp_path, oracle):
         off.execute(sql)
 
 
+# ---- a table several times the HBM budget (the storage CI lane's asserts) ---
+
+N_EVENTS = 600_000
+#: tight enough that the scan MUST stream (scanned bytes are ~14 MB)
+EVENTS_BUDGET = 8 << 20
+
+
+@pytest.fixture(scope="module")
+def events(tmp_path_factory):
+    """600 k rows in 100 k-row groups, partitioned four ways, ``k``
+    sorted so that every row group's footer range is narrow; a runner
+    held to an 8 MiB HBM budget; and the columns, for a numpy answer."""
+    root = str(tmp_path_factory.mktemp("events"))
+    rng = np.random.default_rng(7)
+    k = np.arange(N_EVENTS, dtype=np.int64)
+    v = rng.integers(0, 1000, N_EVENTS, dtype=np.int64)
+    p = (k * 13) % 4
+    write_parquet_table(
+        root, "default", "events",
+        TableSchema(
+            "events", [("k", T.BIGINT), ("v", T.BIGINT), ("p", T.BIGINT)]
+        ),
+        {"k": k, "v": v, "p": p},
+        row_group_size=100_000, partition_by=["p"],
+    )
+    runner = QueryRunner.parquet(root)
+    runner.session.properties["hbm_budget_bytes"] = EVENTS_BUDGET
+    return runner, k, v, p
+
+
+def _by_partition(v, p, keep):
+    return [
+        (int(g), int((keep & (p == g)).sum()), int(v[keep & (p == g)].sum()))
+        for g in range(4) if (keep & (p == g)).any()
+    ]
+
+
+def test_streamed_scan_peak_stays_under_hbm_budget(events):
+    runner, k, v, p = events
+    rows = runner.execute(
+        "select p, count(*), sum(v) from events group by p order by p"
+    ).rows
+    assert [tuple(r) for r in rows] == _by_partition(
+        v, p, np.ones(N_EVENTS, dtype=bool)
+    )
+    entry = runner.executor.scan_log[-1]
+    assert entry["streamed"] is True and entry["batches"] > 1
+    peak = int(runner.executor.memory_pool.peak_bytes)
+    assert 0 < peak <= EVENTS_BUDGET, peak
+
+
+def test_selective_scan_prunes_rowgroups_of_a_large_table(events):
+    runner, k, v, p = events
+    lo, hi = int(N_EVENTS * 0.50), int(N_EVENTS * 0.55)
+    rows = runner.execute(
+        "select p, count(*), sum(v) from events "
+        f"where k >= {lo} and k < {hi} group by p order by p"
+    ).rows
+    assert [tuple(r) for r in rows] == _by_partition(
+        v, p, (k >= lo) & (k < hi)
+    )
+    entry = runner.executor.scan_log[-1]
+    # 5 % of a sorted key sits in the first row group of each
+    # partition file: the second goes by its footer range, before a
+    # page is decoded
+    assert entry["rowgroups_total"] == 8, entry
+    assert entry["rowgroups_pruned"] == 4, entry
+
+
 # ---- chaos: split-granular read retry --------------------------------------
 
 

@@ -111,7 +111,8 @@ class QueryResult:
         return info
 
     def profile_json(self, indent: int | None = None) -> str:
-        """The profile artifact bench.py --profile-dir writes."""
+        """The query-info tree (with the time breakdown) as one JSON
+        document."""
         import json
 
         info = dict(self.query_info or {})

@@ -382,8 +382,8 @@ class ProgramCatalog:
         self, include_hlo: bool = False, resolve: bool = True,
     ) -> list[dict]:
         """JSON-safe entry list (most-used first) — the payload of
-        ``GET /v1/programs``, ``system.runtime.programs``, diagnostics
-        bundles, and ``tools/kernel_report.py`` snapshots."""
+        ``GET /v1/programs``, ``system.runtime.programs`` and
+        diagnostics bundles."""
         with self._lock:
             entries = list(self._entries.values())
         if resolve:

@@ -1,8 +1,7 @@
 """Performance sentry: per-plan baselines + live attributed anomalies.
 
-The online counterpart of ``tools/bench_gate.py``: the gate catches
-regressions offline against hand-committed BENCH baselines, the sentry
-catches them in serving traffic against each plan shape's OWN history.
+It catches regressions in serving traffic, each plan shape against
+its OWN history.
 On every completed statement it:
 
 1. folds the query's wall clock into a rolling robust baseline keyed

@@ -1,7 +1,7 @@
 """Seeded chaos soak: drive a real multi-process fleet through every
 fault-injection site and assert oracle-exact results.
 
-The harness behind ``tests/test_chaos.py`` and ``bench.py --chaos``.
+The harness behind ``tests/test_chaos.py``.
 One *scenario* = one query executed with one armed
 :class:`trino_tpu.fault.FaultInjector`; the soak runs a fixed scenario
 list per retry policy (TASK recovers everything at the task tier;
