@@ -32,6 +32,7 @@ FLAT_FIELDS = (
     "build_trace_ms", "host_sync_ms", "host_syncs", "dispatches",
     "upload_ms", "to_rows_ms", "respond_ms", "rows_out_ms",
     "direct_groupbys", "sorted_groupbys", "streamed_groupbys",
+    "compactions", "compact_gather_ops",
 )
 PROGRAM = re.compile(
     r"^(chain_[A-Za-z_]+|join_count|join_bounds|join_expand|semi_join"
@@ -207,6 +208,37 @@ def test_query_rows_count_grouped_aggregates_by_their_path(
         row = row_of(coord, qid)
         assert (row["direct_groupbys"], row["sorted_groupbys"],
                 row["streamed_groupbys"]) == (direct, by_sort, streamed), row
+
+
+@pytest.mark.parametrize("q,compacts", [
+    ("q01", False), ("q03", True), ("q06", False), ("q18", True),
+])
+def test_query_rows_count_compactions_and_their_gather_operands(
+        coord, q, compacts):
+    """A compaction reads its page at the sorted positions in stacked
+    gathers (``kernels.gather_rows``, ISSUE 36): the row says how many
+    compaction programs the statement dispatched and how many gather
+    operands they hold together — fewer than the columns they moved;
+    a warm dispatch reports the same."""
+    for _ in range(2):
+        qid, _ = serve(coord, QUERIES[q])
+        row = row_of(coord, qid)
+        spans = [
+            sp for sp, _ in walk(get(coord, f"/v1/query/{qid}")["spans"])
+            if sp["name"] == "dispatch"
+            and sp["attrs"]["program"] == "compact"
+        ]
+        assert row["compactions"] == len(spans)
+        assert row["compact_gather_ops"] == sum(
+            sp["attrs"]["gather_ops"] for sp in spans)
+        for sp in spans:
+            assert sp["attrs"]["rows_out"] <= sp["attrs"]["rows_in"]
+        if not compacts:
+            assert (row["compactions"], row["compact_gather_ops"]) == (0, 0)
+            continue
+        assert row["compactions"] >= 1
+        assert row["compactions"] <= row["compact_gather_ops"] < sum(
+            sp["attrs"]["columns"] for sp in spans)
 
 
 def test_protocol_stats_carry_queued_and_planning_time(coord):

@@ -84,22 +84,41 @@ def test_device_is_a_v5e(topo):
 
 
 def test_compaction_at_lineitem_sf1(one_chip, no_compile_cache):
-    """exec/local.py _compact: live rows to the front, five columns."""
-    limit = 2_097_152
+    """``LocalExecutor._compact``'s body (``kernels.compact_rows``) on
+    Q3's filtered ``lineitem`` page at SF1 — ``l_orderkey``,
+    ``l_extendedprice``, ``l_discount`` (int64), ``l_shipdate`` (int32),
+    6,291,456 rows into 4,194,304 (ISSUE 36): one single-operand
+    unstable sort, the page read in ``gather_plan``'s gathers, no
+    gather of a ``pred`` (a validity lane or the mask), and no more
+    memory than the per-column body's output plus the stacked operand
+    and its result."""
+    n, limit = LINEITEM_SF1, 4_194_304
+    cols = [jnp.int64, jnp.int64, jnp.int64, jnp.int32]
 
-    def compact(mask, a, b, c, d, e):
-        perm = K.compact_perm(mask)[:limit]
-        return [x[perm] for x in (a, b, c, d, e)], mask[perm]
+    def compact(mask, *data):
+        env = {str(i): (d, None) for i, d in enumerate(data)}
+        return K.compact_rows(env, mask, limit)
 
-    n = LINEITEM_SF1
     lowered, compiled = _compile(
-        compact, one_chip, ((n,), jnp.bool_),
-        ((n,), jnp.int64), ((n,), jnp.int64), ((n,), jnp.int64),
-        ((n,), jnp.int32), ((n,), jnp.int32),
+        compact, one_chip, ((n,), jnp.bool_), *(((n,), dt) for dt in cols)
     )
     assert _sorts(lowered) == [(1, False)]
+    words, gathers = K.gather_plan([(dt, (), False) for dt in cols])
+    assert (words, gathers) == (7, -(-7 // K.GATHER_STACK_WORDS))
+    results = re.findall(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = (\S+) gather\(",
+        compiled.as_text(), re.M,
+    )
+    assert len(results) == gathers, results
+    assert not any(r.startswith("pred") for r in results), results
     ma = compiled.memory_analysis()
-    assert ma.temp_size_in_bytes + ma.output_size_in_bytes < 16 << 30
+    page_out = limit * (3 * 8 + 4 + 1)
+    padded = -(-words // 8) * 8 * 4  # the tile's sublanes hold 8 words
+    assert ma.output_size_in_bytes <= page_out + (1 << 20)
+    assert (
+        ma.temp_size_in_bytes + ma.output_size_in_bytes
+        <= page_out + (n + limit) * padded + (16 << 20)
+    )
 
 
 def test_group_and_sum_at_lineitem_sf1(one_chip, no_compile_cache):

@@ -15,6 +15,14 @@ ranked as the sort path ranks them (``kernels.searchsorted``) instead
 of compacted, and the pieces of both on their own — the table of
 PERF.md, PR 31.
 
+``--shape q3compact``: the compaction of Q3's filtered ``lineitem``
+page (``LocalExecutor._compact``: three int64 columns and one int32,
+6,291,456 rows of which some 3.24M live, 4,194,304 positions out): the
+per-column body the executor had until PR 36 against
+``kernels.gather_rows`` at stacks of 8, 4 and 2 words, bit for bit,
+and the gathers both are made of, each alone — the table of PERF.md,
+PR 36, which set ``kernels.GATHER_STACK_WORDS``.
+
 Run it on the chip: ``chiprun -- python tools/groupby_crossover.py``.
 On a CPU it checks that the paths agree and prints host times, which
 are no device metric.
@@ -37,6 +45,7 @@ import numpy as np
 
 from trino_tpu import types as T
 from trino_tpu.exec import kernels as K
+from trino_tpu.exec import shapes
 from trino_tpu.exec.aggregates import compute_aggregate
 from trino_tpu.exec.stage import _presort_shared
 
@@ -210,6 +219,128 @@ def q18_main(a) -> int:
     return 0 if ok else 1
 
 
+Q3_LIVE_SHARE = 0.54  # l_shipdate > date '1995-03-15'
+
+
+def q3_inputs(rows: int, seed: int):
+    """Q3's page after its Filter: ``l_orderkey``, ``l_extendedprice``,
+    ``l_discount`` (int64), ``l_shipdate`` (int32), and a mask that
+    keeps rows at random among the table's 6.0M, none of the padding."""
+    rng = np.random.default_rng(seed)
+    env = {
+        str(i): (jnp.asarray(
+            rng.integers(-(1 << 62), 1 << 62, rows, dtype=np.int64)), None)
+        for i in range(3)
+    }
+    env["3"] = (jnp.asarray(
+        rng.integers(8000, 10600, rows, dtype=np.int32)), None)
+    mask = (rng.random(rows) < Q3_LIVE_SHARE) & (
+        np.arange(rows) < rows * 6_000_145 // 6_291_456)
+    return env, jnp.asarray(mask)
+
+
+def q3_bodies(limit: int):
+    """name -> compaction body: the per-column one ``_compact`` held
+    until PR 36, and ``kernels.compact_rows`` (``gather_rows``, the live
+    mask made from the count) at each stack width."""
+
+    def per_column(env, mask):
+        perm = K.compact_perm(mask)[:limit]
+        return {
+            s: (d[perm], None if v is None else v[perm])
+            for s, (d, v) in env.items()
+        }, mask[perm]
+
+    kept = K.GATHER_STACK_WORDS
+
+    def stacked(width):
+        def body(env, mask):
+            K.GATHER_STACK_WORDS = width  # read while tracing
+            try:
+                return K.compact_rows(env, mask, limit)
+            finally:
+                K.GATHER_STACK_WORDS = kept
+
+        return body
+
+    return {"per_column": per_column,
+            **{f"gather_rows_w{w}": stacked(w) for w in (8, 4, 2)}}
+
+
+def q3_pieces(env, mask, limit: int):
+    """name -> (fn, args): the compaction's primitives alone, at the
+    compaction's own permutation."""
+    perm = jax.jit(lambda m: K.compact_perm(m)[:limit])(mask)
+    i64, i32 = env["0"][0], env["3"][0]
+    u32 = [
+        jax.lax.bitcast_convert_type(env[str(i)][0], jnp.uint32)[:, j]
+        for i in range(3) for j in range(2)
+    ] + [i32.astype(jnp.uint32)] * 2
+    pieces = {
+        "compact_uint32_sort (both bodies)": (K.compact_perm, (mask,)),
+        "gather_mask_pred (per-column body: mask[perm])":
+            (lambda m, p: m[p], (mask, perm)),
+        "live_mask_from_count (gather_rows body: arange < count)":
+            (lambda m: jnp.arange(limit, dtype=jnp.int32) < K.count_true(m),
+             (mask,)),
+        "gather_int64": (lambda x, p: x[p], (i64, perm)),
+        "gather_int32": (lambda x, p: x[p], (i32, perm)),
+    }
+    for w in (1, 2, 4, 7, 8):
+        pieces[f"gather_stack_{w}_uint32_words"] = (
+            lambda p, *cols: jnp.stack(cols, axis=1)[p], (perm, *u32[:w]))
+    for w in (4, 8):  # the same words laid [W, rows]: the compiler's choice?
+        pieces[f"gather_stack_{w}_uint32_words_rows_minor"] = (
+            lambda p, *cols: jnp.stack(cols, axis=0)[:, p], (perm, *u32[:w]))
+    return pieces
+
+
+def same_pages(a, b) -> bool:
+    """Two compacted pages, bit for bit: every column, every validity
+    lane, the mask."""
+    (env_a, mask_a), (env_b, mask_b) = a, b
+    if not np.array_equal(mask_a, mask_b):
+        return False
+    for name, (d, v) in env_a.items():
+        d2, v2 = env_b[name]
+        if d.dtype != d2.dtype or not np.array_equal(
+            np.asarray(d).view(np.uint8), np.asarray(d2).view(np.uint8)
+        ):
+            return False
+        if (v is None) != (v2 is None) or (
+            v is not None and not np.array_equal(v, v2)
+        ):
+            return False
+    return True
+
+
+def q3compact_main(a) -> int:
+    dev = jax.devices()[0]
+    env, mask = q3_inputs(a.rows, a.seed)
+    live = int(mask.sum())
+    limit = shapes.bucket(live)  # as ``_compact`` sizes its output
+    rec = {"platform": dev.platform, "device_kind": dev.device_kind,
+           "shape": "q3compact", "rows": a.rows, "live": live,
+           "limit": limit, "runs": [], "pieces": []}
+    results = {}
+    for name, body in q3_bodies(limit).items():
+        results[name], t = timed(body, (env, mask), a.reps)
+        rec["runs"].append({"body": name, **t})
+        print(rec["runs"][-1], flush=True)
+    ref = results["per_column"]
+    ok = ref is not None
+    for run in rec["runs"][1:]:
+        got = results[run["body"]]
+        run["agrees"] = None not in (ref, got) and same_pages(got, ref)
+        ok &= run["agrees"]
+    for name, (fn, fargs) in q3_pieces(env, mask, limit).items():
+        _out, t = timed(fn, fargs, a.reps)
+        rec["pieces"].append({"piece": name, **t})
+        print(rec["pieces"][-1], flush=True)
+    write(rec, a.out)
+    return 0 if ok else 1
+
+
 def same(a, b) -> bool:
     """Bit for bit on the occupied prefix (values, validity, owners),
     and no order fault on either side."""
@@ -233,7 +364,8 @@ def same(a, b) -> bool:
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--shape", choices=("q1", "q18"), default="q1")
+    ap.add_argument("--shape", choices=("q1", "q18", "q3compact"),
+                    default="q1")
     ap.add_argument("--capacity", type=int, default=Q18_CAPACITY,
                     help="group-table capacity of --shape q18")
     ap.add_argument("--rows", type=int, default=6_291_456)
@@ -248,6 +380,10 @@ def main() -> int:
         if a.out == ap.get_default("out"):
             a.out = "chiprun_out/groupby_crossover_q18.json"
         return q18_main(a)
+    if a.shape == "q3compact":
+        if a.out == ap.get_default("out"):
+            a.out = "chiprun_out/groupby_crossover_q3compact.json"
+        return q3compact_main(a)
     dev = jax.devices()[0]
     rec = {"platform": dev.platform, "device_kind": dev.device_kind,
            "rows": a.rows, "capacity": CAPACITY, "runs": []}

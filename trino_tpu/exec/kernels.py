@@ -28,6 +28,7 @@ the only host syncs are capacity decisions at operator boundaries.
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import NamedTuple
 
@@ -46,6 +47,11 @@ __all__ = [
     "hash_columns",
     "packed_argsort",
     "compact_perm",
+    "GATHER_STACK_WORDS",
+    "GATHER_WIDE_WORDS",
+    "gather_plan",
+    "gather_rows",
+    "compact_rows",
     "cumsum",
     "floor_div",
     "searchsorted",
@@ -164,6 +170,132 @@ def compact_perm(mask: jnp.ndarray) -> jnp.ndarray:
     """Permutation gathering live rows to the front, in row order (dead
     rows after them, in row order)."""
     return packed_argsort(None, 0, last=~mask)
+
+
+# ---- row gathers -------------------------------------------------------------
+#
+# What a gather costs on the chip is its index walk, not the width of
+# what an index fetches, and a ``pred`` gather costs more than an int32
+# one. So a page is read at one index vector in as few gathers as the
+# table below allows: every column viewed as 32-bit words, the words of
+# all columns side by side in one ``[rows, W]`` uint32 operand, the
+# validity lanes as bits of one more word. Words are moved, never
+# converted: the result is ``d[idx]``, ``v[idx]`` bit for bit.
+
+#: most 32-bit words one gather operand holds; a wider page is read in
+#: several stacks of at most this many. On a v5e, 4,194,304 positions
+#: out of 6,291,456 rows: a stack of 1 / 2 / 4 / 7 / 8 words takes
+#: 36.9 / 26.3 / 26.4 / 86.6 / 86.5 ms (an int64 column alone 67.0, a
+#: ``pred`` 41.4), and the whole compaction of Q3's seven words 60.3 ms
+#: at 4 against 92.7 at 8, 91.4 at 2 and 329.0 a column
+#: (tools/groupby_crossover.py --shape q3compact; PERF.md, PR 36): up
+#: to four words ride one index walk, two stacks of four beat one of
+#: eight.
+GATHER_STACK_WORDS = 4
+
+#: a column whose row is wider than this many words (an HLL or sketch
+#: state, an array pool's wide lanes) is gathered alone, as it is:
+#: stacking it would copy megabytes to save one index walk
+GATHER_WIDE_WORDS = 8
+
+
+def _col_words(dtype, lanes) -> int:
+    """32-bit words one row of a ``[rows, *lanes]`` column holds."""
+    return math.prod(lanes) * max(1, np.dtype(dtype).itemsize // 4)
+
+
+def gather_plan(sig) -> tuple[int, int]:
+    """(words stacked, gathers) of a page whose columns are ``(dtype,
+    lanes, nullable)``: what ``gather_rows`` builds, from the layout
+    alone."""
+    stacked = alone = nullable = 0
+    for dtype, lanes, has_valid in sig:
+        w = _col_words(dtype, lanes)
+        if w > GATHER_WIDE_WORDS:
+            alone += 1
+        else:
+            stacked += w
+        nullable += bool(has_valid)
+    stacked += -(-nullable // 32)
+    return stacked, alone + -(-stacked // GATHER_STACK_WORDS)
+
+
+def _to_words(d: jnp.ndarray) -> jnp.ndarray:
+    """``[rows, *lanes]`` of any fixed-width dtype as ``[rows, W]``
+    uint32 words, exactly."""
+    if d.dtype == jnp.bool_:
+        w = d.astype(jnp.uint32)
+    elif d.dtype.itemsize >= 4:
+        w = jax.lax.bitcast_convert_type(d, jnp.uint32)
+    else:
+        narrow = jnp.uint8 if d.dtype.itemsize == 1 else jnp.uint16
+        w = jax.lax.bitcast_convert_type(d, narrow).astype(jnp.uint32)
+    return w.reshape(d.shape[0], _col_words(d.dtype, d.shape[1:]))
+
+
+def _from_words(w: jnp.ndarray, dtype, lanes) -> jnp.ndarray:
+    """``_to_words`` undone: ``[n, W]`` words as ``[n, *lanes]``."""
+    dtype = np.dtype(dtype)
+    n = w.shape[0]
+    if dtype == np.bool_:
+        return (w != 0).reshape((n, *lanes))
+    if dtype.itemsize == 8:
+        return jax.lax.bitcast_convert_type(w.reshape((n, *lanes, 2)), dtype)
+    if dtype.itemsize == 4:
+        return jax.lax.bitcast_convert_type(w.reshape((n, *lanes)), dtype)
+    narrow = jnp.uint8 if dtype.itemsize == 1 else jnp.uint16
+    return jax.lax.bitcast_convert_type(
+        w.astype(narrow).reshape((n, *lanes)), dtype
+    )
+
+
+def gather_rows(env: dict, idx: jnp.ndarray) -> dict:
+    """``{name: (d[idx], v[idx])}`` of a page's ``{name: (data,
+    valid)}`` in ``gather_plan``'s number of gathers."""
+    width = {n: _col_words(d.dtype, d.shape[1:]) for n, (d, _) in env.items()}
+    parts = [
+        _to_words(d) for n, (d, _) in env.items()
+        if width[n] <= GATHER_WIDE_WORDS
+    ]
+    valid_at = sum(p.shape[1] for p in parts)
+    valids = [v for _, v in env.values() if v is not None]
+    for i in range(0, len(valids), 32):
+        word = jnp.zeros(valids[0].shape, jnp.uint32)
+        for bit, v in enumerate(valids[i:i + 32]):
+            word = word | (v.astype(jnp.uint32) << jnp.uint32(bit))
+        parts.append(word[:, None])
+    if parts:
+        words = jnp.concatenate(parts, axis=1)
+        words = jnp.concatenate([
+            words[:, i:i + GATHER_STACK_WORDS][idx]
+            for i in range(0, words.shape[1], GATHER_STACK_WORDS)
+        ], axis=1)
+    out, at, nth = {}, 0, 0
+    for n, (d, v) in env.items():
+        if width[n] > GATHER_WIDE_WORDS:
+            data = d[idx]
+        else:
+            data = _from_words(
+                words[:, at:at + width[n]], d.dtype, d.shape[1:])
+            at += width[n]
+        valid = None
+        if v is not None:
+            word = words[:, valid_at + nth // 32]
+            valid = (word >> jnp.uint32(nth % 32)) & jnp.uint32(1) != 0
+            nth += 1
+        out[n] = (data, valid)
+    return out
+
+
+def compact_rows(env: dict, mask: jnp.ndarray, limit: int):
+    """The page's live rows first, in row order, in ``limit`` rows:
+    ``(env2, mask2)``. One packed sort gives the positions (measured on
+    one v5e chip at 6.29M rows, PR 22: 92 ms with its gathers, against
+    380 ms for cumsum+searchsorted and 438 ms for cumsum+scatter
+    compactions), ``gather_rows`` reads the page there, and the live
+    rows being a prefix, the new mask is their count — no gather."""
+    env2 = gather_rows(env, compact_perm(mask)[:limit])
+    return env2, jnp.arange(limit, dtype=jnp.int32) < count_true(mask)
 
 
 def _rank_key(x: jnp.ndarray) -> tuple[jnp.ndarray, int]:

@@ -1403,22 +1403,10 @@ class LocalExecutor:
             keys = list(page.names)
         key = ("compact", sig, limit)
         fn = self._jit_cache.get(key)
-        with _dispatching("compact", fn is None):
+        with _dispatching("compact", fn is None) as dispatch:
             if fn is None:
                 def compact_fn(env, mask):
-                    # live rows to the front by one packed sort (measured
-                    # on one v5e chip at 6.29M rows, PR 22: 92 ms, against
-                    # 380 ms for cumsum+searchsorted and 438 ms for
-                    # cumsum+scatter compactions)
-                    perm = K.compact_perm(mask)[:limit]
-                    env2 = {
-                        s: (
-                            d[perm],
-                            None if v is None else v[perm],
-                        )
-                        for s, (d, v) in env.items()
-                    }
-                    return env2, mask[perm]
+                    return K.compact_rows(env, mask, limit)
 
                 fn = _named_jit(compact_fn, "compact")
                 self._jit_cache[key] = fn
@@ -1426,6 +1414,14 @@ class LocalExecutor:
                 k: (c.data, c.valid) for k, c in zip(keys, page.columns)
             }
             env2, mask2 = fn(env_in, page.mask)
+            dispatch.note(
+                rows_in=page.capacity, rows_out=limit,
+                columns=len(page.columns),
+                gather_ops=K.gather_plan(
+                    (c.data.dtype, c.data.shape[1:], c.valid is not None)
+                    for c in page.columns
+                )[1],
+            )
         cols = [
             Column(c.type, *env2[k], c.dictionary, c.hash_pool, c.array_pool)
             for k, c in zip(keys, page.columns)

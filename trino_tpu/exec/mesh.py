@@ -982,28 +982,23 @@ class MeshExecutor(LocalExecutor):
         prog_c = self._mesh_jit_cache.get(key_c)
         if prog_c is None:
             def fc(kp, *ls):
-                perm = K.compact_perm(kp)[:new_cap]
-                return [a[perm] for a in ls], kp[perm]
+                env, _ = _env_from_leaves(list(ls), p_meta)
+                return K.compact_rows(env, kp, new_cap)
 
             prog_c = jax.jit(
                 jax.shard_map(
                     fc, mesh=self.mesh,
                     in_specs=(PS(axis),) * (len(p_leaves) + 1),
-                    out_specs=([PS(axis)] * len(p_leaves), PS(axis)),
+                    out_specs=(PS(axis), PS(axis)),
                     check_vma=False,
                 )
             )
             self._mesh_jit_cache[key_c] = prog_c
-        out, new_mask = prog_c(keep, *p_leaves)
-        cols, i = [], 0
-        for (name, has_valid), c in zip(p_meta, probe.columns):
-            data = out[i]
-            i += 1
-            valid = None
-            if has_valid:
-                valid = out[i]
-                i += 1
-            cols.append(Column(c.type, data, valid, c.dictionary, c.hash_pool))
+        env, new_mask = prog_c(keep, *p_leaves)
+        cols = [
+            Column(c.type, *env[n], c.dictionary, c.hash_pool)
+            for n, c in zip(probe.names, probe.columns)
+        ]
         return ShardedPage(list(probe.names), cols, new_mask, probe.n_shards)
 
     # ---- skew-split join (SkewedPartitionRebalancer analog,

@@ -786,6 +786,7 @@ class Coordinator:
         "build_trace_ms", "host_sync_ms", "host_syncs", "dispatches",
         "upload_ms", "to_rows_ms", "respond_ms", "rows_out_ms",
         "direct_groupbys", "sorted_groupbys", "streamed_groupbys",
+        "compactions", "compact_gather_ops",
     )
 
     def _seal(self, q: QueryState) -> None:

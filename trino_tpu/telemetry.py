@@ -352,8 +352,10 @@ def span_totals(root: Span) -> Dict[str, float]:
     ``direct_groupbys`` / ``streamed_groupbys`` / ``sorted_groupbys``
     count the grouped
     aggregates of the dispatched programs by the path each took (the
-    ``dispatch`` span's ``groupbys``). The root is left out: its time
-    is the statement's ``elapsed_ms``."""
+    ``dispatch`` span's ``groupbys``); ``compactions`` counts the
+    compaction programs and ``compact_gather_ops`` the gather operands
+    they were built with (the ``dispatch`` span's ``gather_ops``). The
+    root is left out: its time is the statement's ``elapsed_ms``."""
     out: Dict[str, float] = {}
     for sp in root.walk():
         if sp is root:
@@ -364,6 +366,10 @@ def span_totals(root: Span) -> Dict[str, float]:
             out[_COUNTED[key]] = out.get(_COUNTED[key], 0) + 1
         for path in sp.attrs.get("groupbys", ()):
             out[path + "_groupbys"] = out.get(path + "_groupbys", 0) + 1
+        if sp.attrs.get("program") == "compact":
+            out["compactions"] = out.get("compactions", 0) + 1
+            out["compact_gather_ops"] = out.get(
+                "compact_gather_ops", 0) + sp.attrs.get("gather_ops", 0)
     return out
 
 
