@@ -1,0 +1,236 @@
+"""The mesh executor behind the served coordinator (ISSUE 39): the
+benchmark's four templates, every tuple of their closed sets, through a
+coordinator over a 4-device mesh at ``tiny`` — in this process and as
+``python -m trino_tpu.server.coordinator --mesh 4`` — with rows equal to
+the benchmark's own reference's (sqlite3 over the same generated
+columns, by the templates' comparison kinds); the six ``mesh_*`` fields
+on the rows of ``GET /v1/query``; the mesh programs' names; ``--mesh``'s
+start-up check; the ``sf5`` schema."""
+
+from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+import urllib.request
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+import datagen  # noqa: E402
+import loadgen  # noqa: E402
+import reference  # noqa: E402
+import run as harness  # noqa: E402
+import traffic  # noqa: E402
+
+from trino_tpu.connectors.tpch.connector import TpchConnector  # noqa: E402
+from trino_tpu.connectors.tpch.generator import SCHEMA_SF  # noqa: E402
+from trino_tpu.engine import QueryRunner  # noqa: E402
+from trino_tpu.parallel.core import make_mesh  # noqa: E402
+from trino_tpu.server import client as client_mod  # noqa: E402
+from trino_tpu.server.coordinator import Coordinator  # noqa: E402
+
+MIX = traffic.load_mix("power")
+STATEMENTS = traffic.all_statements(MIX)
+IDS = [st.template + "-" + "_".join(st.params.values()) for st in STATEMENTS]
+MESH_FIELDS = (
+    "mesh_exchanges", "mesh_exchange_ms", "mesh_exchange_live_bytes",
+    "mesh_exchange_buffer_bytes", "mesh_gather_ms", "mesh_upload_ms",
+)
+CONFIG = harness.load_json(
+    os.path.join(BENCH, "configs", "tpch_sf5_mesh4.json"))
+
+
+@pytest.fixture(scope="module")
+def ref_conn(tmp_path_factory):
+    """The benchmark's reference at ``tiny``: the configuration's
+    columns in sqlite, as ``datagen.py`` loads them for a run."""
+    db = str(tmp_path_factory.mktemp("mesh_ref") / "ref.db")
+    datagen.build_db("tiny", CONFIG["reference_tables"], db, {})
+    conn = reference.connect(db)
+    reference.create_indexes(conn, CONFIG["reference_indexes"])
+    yield conn
+    conn.close()
+
+
+@pytest.fixture(scope="module")
+def mesh_coord():
+    runner = QueryRunner.tpch("tiny", mesh=make_mesh(4))
+    c = Coordinator(runner=runner, port=0).start()
+    yield c
+    c.stop()
+
+
+@pytest.fixture(scope="module")
+def local_coord():
+    c = Coordinator(runner=QueryRunner.tpch("tiny"), port=0).start()
+    yield c
+    c.stop()
+
+
+@pytest.fixture(scope="module")
+def served(mesh_coord):
+    """Every statement text of the mix through the in-process mesh
+    coordinator, once: ``{key: (rows, row of GET /v1/query)}``."""
+    out = {}
+    for st in STATEMENTS:
+        out[st.key] = serve(mesh_coord.uri, st.sql)
+    return out
+
+
+def serve(uri: str, sql: str):
+    # the benchmark's client: the real one, keeping the last response
+    client = loadgen.timed_client(client_mod, uri, 600.0)
+    _, rows = client.execute(sql)
+    with urllib.request.urlopen(uri + "/v1/query", timeout=30) as r:
+        listed = {q["query_id"]: q for q in json.loads(r.read())}
+    return rows, listed[client.last["id"]]
+
+
+def assert_equal_to_reference(st, rows, conn):
+    tpl = MIX["templates"][st.template]
+    expected = reference.expected_rows(
+        conn, reference.render(tpl.ref_text, st.params))
+    r = reference.compare_statement(
+        tpl.compare["columns"], tpl.compare["ordered"], rows, expected)
+    assert r["exact_mismatches"] == 0, r["detail"]
+    assert r["decimal_gap_ulp"] <= harness.LIMITS["decimal_gap_ulp"], r
+    assert r["avg_gap_ulp"] <= harness.LIMITS["avg_gap_ulp"], r
+
+
+@pytest.mark.parametrize("st", STATEMENTS, ids=IDS)
+def test_mesh_coordinator_rows_equal_the_reference(st, served, ref_conn):
+    rows, _ = served[st.key]
+    assert_equal_to_reference(st, rows, ref_conn)
+
+
+@pytest.mark.parametrize("st", STATEMENTS, ids=IDS)
+def test_mesh_fields_on_the_statement_row(st, served):
+    _, row = served[st.key]
+    for field in MESH_FIELDS:
+        assert isinstance(row.get(field), (int, float)), (field, row)
+    assert row["mesh_gather_ms"] > 0  # every plan ends in Exchange(single)
+    if st.template in ("q03", "q18"):
+        assert row["mesh_exchanges"] >= 1
+        assert row["mesh_exchange_ms"] > 0
+        assert (0 < row["mesh_exchange_live_bytes"]
+                <= row["mesh_exchange_buffer_bytes"])
+    if st.template == "q06":
+        assert row["mesh_exchanges"] == 0
+
+
+@pytest.mark.parametrize(
+    "st", [s for s in STATEMENTS if s.template in ("q03", "q18")],
+    ids=["q03", "q18"])
+def test_no_mesh_fields_move_without_a_mesh(st, local_coord):
+    _, row = serve(local_coord.uri, st.sql)
+    assert [row[f] for f in MESH_FIELDS] == [0] * len(MESH_FIELDS)
+
+
+def test_every_mesh_program_is_named(served, mesh_coord):
+    """What the mesh executor compiled for the mix, read off its jit
+    cache: XLA's module (and the device trace) is ``jit_<name>``."""
+    names = set()
+    for hit in mesh_coord.runner.executor._mesh_jit_cache.values():
+        prog = hit[0] if isinstance(hit, tuple) else hit
+        names.add(prog.__name__)
+    assert names and all(n.startswith("mesh_") for n in names), names
+    assert {"mesh_exchange", "mesh_exchange_dest", "mesh_join_count",
+            "mesh_join_expand", "mesh_semi_join"} <= names
+    assert any(n.startswith("mesh_chain_") for n in names)
+
+
+def test_mesh_exchange_span_carries_what_moved(served, mesh_coord):
+    st = next(s for s in STATEMENTS if s.template == "q18")
+    qid = served[st.key][1]["query_id"]
+    with urllib.request.urlopen(
+            mesh_coord.uri + "/v1/query/" + qid, timeout=30) as r:
+        tree = json.loads(r.read())["spans"]
+
+    def walk(sp):
+        yield sp
+        for ch in sp.get("children", ()):
+            yield from walk(ch)
+
+    exchanges = [sp for sp in walk(tree) if sp["name"] == "mesh-exchange"]
+    assert exchanges
+    for sp in exchanges:
+        attrs = sp["attrs"]
+        assert attrs["edge"].startswith("mesh-")
+        assert attrs["live_bytes"] <= attrs["buffer_bytes"]
+        assert attrs["live_rows"] >= 0 and attrs["escalations"] >= 0
+        # the wait for the flag and the count is a host_sync inside it
+        inner = [c["name"] for c in sp["children"]]
+        assert "dispatch" in inner and "host_sync" in inner
+
+
+# ---- the entry point: python -m trino_tpu.server.coordinator --mesh N ----
+
+
+def _spawn(*args, stderr=subprocess.PIPE):
+    env = dict(os.environ, PYTHONPATH=ROOT, PYTHONUNBUFFERED="1")
+    return subprocess.Popen(
+        [sys.executable, "-m", "trino_tpu.server.coordinator", *args],
+        cwd=ROOT, env=env, stdout=subprocess.PIPE, stderr=stderr, text=True)
+
+
+@pytest.fixture(scope="module")
+def cli_uri(served, tmp_path_factory):
+    # after ``served``: the child reads this process's programs from the
+    # persistent compile cache instead of compiling them again
+    port = harness.supervisor.free_port()
+    log = tmp_path_factory.mktemp("mesh_cli") / "coordinator.err"
+    with open(log, "w") as err:  # a file: a full pipe would block it
+        proc = _spawn("--schema", "tiny", "--port", str(port), "--mesh", "4",
+                      stderr=err)
+    try:
+        for line in proc.stdout:
+            if line.startswith("coordinator ready on port"):
+                break
+        else:
+            pytest.fail("no ready line: " + log.read_text()[-2000:])
+        yield f"http://127.0.0.1:{port}"
+    finally:
+        proc.kill()
+        proc.wait(timeout=30)
+
+
+@pytest.mark.parametrize("st", STATEMENTS, ids=IDS)
+def test_mesh_flag_serves_the_templates(st, cli_uri, ref_conn):
+    rows, row = serve(cli_uri, st.sql)
+    assert_equal_to_reference(st, rows, ref_conn)
+    assert row["mesh_gather_ms"] > 0
+
+
+def test_mesh_flag_reports_its_devices(cli_uri):
+    with urllib.request.urlopen(cli_uri + "/v1/info", timeout=30) as r:
+        info = json.loads(r.read())
+    assert info["device_count"] >= 4
+
+
+def test_mesh_larger_than_the_host_fails_before_ready():
+    proc = _spawn("--schema", "tiny", "--port", "0", "--mesh", "16")
+    out, err = proc.communicate(timeout=120)
+    assert proc.returncode not in (0, None)
+    assert "coordinator ready" not in out
+    assert "--mesh 16" in err and "have 8" in err
+
+
+# ---- the sf5 schema ------------------------------------------------------
+
+
+@pytest.mark.parametrize("table,rows", [
+    ("customer", 750_000), ("orders", 7_500_000), ("lineitem", 30_006_807)])
+def test_sf5_row_counts(table, rows):
+    """By formula (lineitem from the per-order counts): no column of
+    the table is generated."""
+    conn = TpchConnector()
+    assert SCHEMA_SF["sf5"] == 5.0 and "sf5" in conn.list_schemas()
+    assert conn.row_count("sf5", table) == rows
+    assert CONFIG["tables"][table]["rows"] == rows
+    assert all(c == "__counts__" for _, c in conn.data("sf5")._cache)

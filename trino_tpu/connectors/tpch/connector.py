@@ -1,4 +1,4 @@
-"""TPC-H connector: schemas tiny/sf1/sf10/sf100 of generated tables."""
+"""TPC-H connector: schemas tiny/sf1/sf5/sf10/sf100 of generated tables."""
 
 from __future__ import annotations
 

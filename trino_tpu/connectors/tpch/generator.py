@@ -81,7 +81,9 @@ SCHEMAS: dict[str, TableSchema] = {
 }
 
 #: named schema -> scale factor, mirroring the reference's tpch schemas
-SCHEMA_SF = {"tiny": 0.01, "sf1": 1.0, "sf10": 10.0, "sf100": 100.0}
+SCHEMA_SF = {
+    "tiny": 0.01, "sf1": 1.0, "sf5": 5.0, "sf10": 10.0, "sf100": 100.0,
+}
 
 
 def _seed(sf: float, table: str, stream: str) -> list[int]:

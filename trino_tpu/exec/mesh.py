@@ -38,7 +38,13 @@ from trino_tpu.exec import kernels as K
 from trino_tpu.exec import shapes as shape_policy
 from trino_tpu.exec import stage
 from trino_tpu.exec.failure import FailureInjector, InjectedFailure
-from trino_tpu.exec.local import LocalExecutor, _rename_out
+from trino_tpu.exec.local import (
+    LocalExecutor,
+    _chain_program_name,
+    _dispatching,
+    _named_jit,
+    _rename_out,
+)
 from trino_tpu.expr.compiler import compile_expr, ColumnLayout
 from trino_tpu.metadata import Metadata, Session
 from trino_tpu.page import Column, Page, pad_capacity, unify_dictionaries
@@ -77,19 +83,20 @@ class ShardedPage:
         return self.columns[self.names.index(name)]
 
 
-def _exchange_key_pairs(cols):
-    """(bits, valid) pairs for exchange-key hashing: hash-coded varchar
-    contributes its hash lane only (the id lane is row identity and
-    would split equal strings across destinations); two-limb decimals
-    contribute both limbs."""
+def _exchange_key_pairs(keys):
+    """(bits, valid) pairs for exchange-key hashing from ``(data,
+    valid, hashed)`` triples: hash-coded varchar contributes its hash
+    lane only (the id lane is row identity and would split equal
+    strings across destinations); two-limb decimals contribute both
+    limbs."""
     pairs = []
-    for c in cols:
-        if c.hash_pool is not None:
-            pairs.append((c.data[:, 0], c.valid))
+    for data, valid, hashed in keys:
+        if hashed:
+            pairs.append((data[:, 0], valid))
             continue
-        parts = K.limb_parts(c.data)
+        parts = K.limb_parts(data)
         pairs.extend(
-            (p, c.valid if i == 0 else None)
+            (p, valid if i == 0 else None)
             for i, p in enumerate(parts)
         )
     return pairs
@@ -193,6 +200,28 @@ class MeshExecutor(LocalExecutor):
         #: per-query exchange telemetry (EXPLAIN ANALYZE + tests):
         #: all_to_all count and device bytes moved through them
         self.exchange_stats = {"exchanges": 0, "bytes": 0}
+
+    def _shard_jit(self, fn, name: str, in_specs, out_specs):
+        """One SPMD program over the mesh, named as the local executor
+        names its own (``_named_jit``): the device trace and the program
+        catalog read ``jit_mesh_<name>``."""
+        return _named_jit(
+            jax.shard_map(
+                fn, mesh=self.mesh, in_specs=in_specs,
+                out_specs=out_specs, check_vma=False,
+            ),
+            "mesh_" + name,
+        )
+
+    def _run(self, prog, miss: bool, *args, tag: str | None = None):
+        """``prog(*args)`` under a ``dispatch`` span that carries the
+        program's name (with a ``build_trace`` child on a jit-cache
+        miss, as the local executor's), through ``_attempt`` where the
+        site is a retry unit."""
+        with _dispatching(prog.__name__, miss):
+            if tag is None:
+                return prog(*args)
+            return self._attempt(tag, lambda: prog(*args))
 
     def _attempt(self, tag: str, call):
         """Run one stage-shard program with injected-failure retry.
@@ -318,50 +347,54 @@ class MeshExecutor(LocalExecutor):
             if ckey(sym, c) not in cache
         ]
         if missing or "" not in cache:
-            connector = self.metadata.connector(node.catalog)
-            cols = connector.scan(
-                node.schema, node.table, [c for _, c in missing]
-            )
-            if missing:
-                first = cols[missing[0][1]]
-                n = len(first[0] if isinstance(first, tuple) else first)
-            else:
-                n = connector.row_count(node.schema, node.table)
-            per, cap = self._shard_layout(n)
-            if "" not in cache:
-                cache[""] = self._shard_split(
-                    np.ones(n, dtype=np.bool_), n, per, cap
+            with telemetry.child_span(
+                "mesh-scan-upload", table=node.table,
+                columns=len(missing),
+            ):
+                connector = self.metadata.connector(node.catalog)
+                cols = connector.scan(
+                    node.schema, node.table, [c for _, c in missing]
                 )
-            for sym, cname in missing:
-                v = cols[cname]
-                valid = None
-                if isinstance(v, tuple):
-                    v, valid = v
-                if sym in hashed_syms:
-                    from trino_tpu.exec.local import _hash_varchar_column
-
-                    # global pool + global row ids: the id lane stays
-                    # meaningful on every shard (pools are host-side)
-                    col = _hash_varchar_column(
-                        node.outputs[sym], np.asarray(v, dtype=object),
-                        valid, max(n, 1),
-                    )
+                if missing:
+                    first = cols[missing[0][1]]
+                    n = len(first[0] if isinstance(first, tuple) else first)
                 else:
-                    col = Column.from_numpy(
-                        node.outputs[sym], v, valid=valid,
-                        capacity=max(n, 1),
+                    n = connector.row_count(node.schema, node.table)
+                per, cap = self._shard_layout(n)
+                if "" not in cache:
+                    cache[""] = self._shard_split(
+                        np.ones(n, dtype=np.bool_), n, per, cap
                     )
-                cache[ckey(sym, cname)] = Column(
-                    col.type,
-                    self._shard_split(
-                        np.asarray(col.data[:n]), n, per, cap
-                    ),
-                    None if col.valid is None else self._shard_split(
-                        np.asarray(col.valid[:n]), n, per, cap
-                    ),
-                    col.dictionary,
-                    col.hash_pool,
-                )
+                for sym, cname in missing:
+                    v = cols[cname]
+                    valid = None
+                    if isinstance(v, tuple):
+                        v, valid = v
+                    if sym in hashed_syms:
+                        from trino_tpu.exec.local import _hash_varchar_column
+
+                        # global pool + global row ids: the id lane stays
+                        # meaningful on every shard (pools are host-side)
+                        col = _hash_varchar_column(
+                            node.outputs[sym], np.asarray(v, dtype=object),
+                            valid, max(n, 1),
+                        )
+                    else:
+                        col = Column.from_numpy(
+                            node.outputs[sym], v, valid=valid,
+                            capacity=max(n, 1),
+                        )
+                    cache[ckey(sym, cname)] = Column(
+                        col.type,
+                        self._shard_split(
+                            np.asarray(col.data)[:n], n, per, cap
+                        ),
+                        None if col.valid is None else self._shard_split(
+                            np.asarray(col.valid)[:n], n, per, cap
+                        ),
+                        col.dictionary,
+                        col.hash_pool,
+                    )
         names = list(node.assignments)
         columns = [
             cache[ckey(s, c)] for s, c in node.assignments.items()
@@ -370,7 +403,18 @@ class MeshExecutor(LocalExecutor):
 
     def gather(self, sp: ShardedPage) -> Page:
         """ShardedPage -> compacted single-device Page (the reference's
-        root-stage output buffer drain)."""
+        root-stage output buffer drain). The wait for the programs that
+        make the page is a ``host_sync``; the transfers and the host's
+        compaction are the ``mesh-gather`` span."""
+        with telemetry.child_span("host_sync", site="mesh_gather"):
+            jax.block_until_ready(_page_leaves(sp)[0])
+        with telemetry.child_span("mesh-gather") as span:
+            page = self._gather(sp)
+            if span is not None:
+                span.attrs["rows"] = page.known_rows
+            return page
+
+    def _gather(self, sp: ShardedPage) -> Page:
         mask = np.asarray(sp.mask)
         idx = np.nonzero(mask)[0]
         cap = pad_capacity(len(idx))
@@ -394,6 +438,12 @@ class MeshExecutor(LocalExecutor):
 
     def scatter(self, page: Page) -> ShardedPage:
         """Split a local Page's live rows contiguously over the mesh."""
+        with telemetry.child_span("host_sync", site="mesh_scatter"):
+            jax.block_until_ready(_page_leaves(page)[0])
+        with telemetry.child_span("mesh-scatter"):
+            return self._scatter(page)
+
+    def _scatter(self, page: Page) -> ShardedPage:
         idx = np.nonzero(np.asarray(page.mask))[0]
         n = len(idx)
         per, cap = self._shard_layout(n)
@@ -514,14 +564,9 @@ class MeshExecutor(LocalExecutor):
                     PS(axis),
                     jax.tree.map(lambda _: PS(), out_shape[2]),
                 )
-                prog = jax.jit(
-                    jax.shard_map(
-                        flat_fn,
-                        mesh=self.mesh,
-                        in_specs=(PS(axis),) * len(leaves),
-                        out_specs=out_specs,
-                        check_vma=False,
-                    )
+                prog = self._shard_jit(
+                    flat_fn, _chain_program_name(chain),
+                    (PS(axis),) * len(leaves), out_specs,
                 )
                 hit = (prog, out_layout, meta)
                 self._mesh_jit_cache[key] = hit
@@ -544,15 +589,16 @@ class MeshExecutor(LocalExecutor):
                 t_compile = None
             prog, out_layout, meta = hit
             leaves, _ = _page_leaves(sp)
-            env, mask, flags = self._attempt(
-                "chain", lambda: prog(*leaves)
+            env, mask, flags = self._run(
+                prog, t_compile is not None, *leaves, tag="chain"
             )
             if t_compile is not None:
                 program_catalog.CATALOG.note_compile_seconds(
                     key, time.perf_counter() - t_compile
                 )
             if flags:
-                vals = jax.device_get(flags)
+                with telemetry.child_span("host_sync", site="mesh_chain_flags"):
+                    vals = jax.device_get(flags)
                 overflowed = [i for i, v in vals.items() if v]
                 if overflowed:
                     for i in overflowed:
@@ -584,12 +630,36 @@ class MeshExecutor(LocalExecutor):
     def hash_exchange(
         self, sp: ShardedPage, key_symbols: list[str]
     ) -> ShardedPage:
-        cols = [sp.column(k) for k in key_symbols]
-        h = K.hash_columns(_exchange_key_pairs(cols))
-        dest = (h % jnp.uint64(self.n_shards)).astype(jnp.int32)
         return self.exchange_by_dest(
-            sp, dest, edge=f"mesh-hash({', '.join(key_symbols)})"
+            sp, self._hash_dest(sp, key_symbols),
+            edge=f"mesh-hash({', '.join(key_symbols)})",
         )
+
+    def _hash_dest(self, sp: ShardedPage, key_symbols: list[str]):
+        """The shard every row's key hashes to, as one program."""
+        cols = [sp.column(k) for k in key_symbols]
+        hashed = tuple(c.hash_pool is not None for c in cols)
+        key = (
+            "mesh-dest", hashed,
+            tuple(
+                (c.data.dtype.str, c.data.shape, c.valid is not None)
+                for c in cols
+            ),
+        )
+        prog = self._mesh_jit_cache.get(key)
+        miss = prog is None
+        if miss:
+            n = self.n_shards
+
+            def fd(*pairs):
+                h = K.hash_columns(_exchange_key_pairs(
+                    [(d, v, hd) for (d, v), hd in zip(pairs, hashed)]
+                ))
+                return (h % jnp.uint64(n)).astype(jnp.int32)
+
+            prog = _named_jit(fd, "mesh_exchange_dest")
+            self._mesh_jit_cache[key] = prog
+        return self._run(prog, miss, *[(c.data, c.valid) for c in cols])
 
     def range_exchange(
         self, sp: ShardedPage, sort_keys
@@ -611,11 +681,16 @@ class MeshExecutor(LocalExecutor):
         nulls_first = (
             k.nulls_first if k.nulls_first is not None else not k.ascending
         )
+        # splitters from a strided sample (the runtime analog of the
+        # reference's DeterminePartitionCount + writer rebalancing:
+        # quantiles of the observed key distribution)
+        stride = max(int(sp.mask.shape[0]) // 4096, 1)
         key = ("mesh-range-bits", self._sharded_sig(sp), k.symbol,
                k.ascending, nulls_first)
         prog = self._mesh_jit_cache.get(key)
-        if prog is None:
-            def fb(data, valid):
+        miss = prog is None
+        if miss:
+            def fb(data, valid, mask):
                 d = data[:, 0] if data.ndim == 2 else data
                 bits = K.order_bits(d)
                 if not k.ascending:
@@ -626,29 +701,36 @@ class MeshExecutor(LocalExecutor):
                         else jnp.uint64(0xFFFFFFFFFFFFFFFF)
                     )
                     bits = jnp.where(valid, bits, sentinel)
-                return bits
+                return bits, bits[::stride], mask[::stride]
 
-            prog = jax.jit(fb, static_argnames=())
+            prog = _named_jit(fb, "mesh_range_bits")
             self._mesh_jit_cache[key] = prog
-        bits = prog(col.data, col.valid)
-        # splitters from a strided sample (the runtime analog of the
-        # reference's DeterminePartitionCount + writer rebalancing:
-        # quantiles of the observed key distribution)
-        stride = max(int(bits.shape[0]) // 4096, 1)
-        sample = np.asarray(bits[::stride])
-        live = np.asarray(sp.mask[::stride])
+        bits, sample_dev, live_dev = self._run(
+            prog, miss, col.data, col.valid, sp.mask
+        )
+        with telemetry.child_span("host_sync", site="mesh_range_sample"):
+            sample, live = jax.device_get((sample_dev, live_dev))
         sample = sample[live]
         if len(sample) == 0:
-            dest = jnp.zeros(bits.shape, dtype=jnp.int32)
+            # every row dead: any destination conserves them
+            qs = np.zeros(self.n_shards - 1, dtype=np.uint64)
         else:
             qs = np.quantile(
                 np.sort(sample),
                 [i / self.n_shards for i in range(1, self.n_shards)],
                 method="nearest",
             ).astype(np.uint64)
-            dest = jnp.searchsorted(
-                jnp.asarray(qs), bits, side="right"
-            ).astype(jnp.int32)
+        prog_d = self._mesh_jit_cache.get("range-dest")
+        miss = prog_d is None
+        if miss:
+            def fd(splitters, bits_):
+                return jnp.searchsorted(
+                    splitters, bits_, side="right"
+                ).astype(jnp.int32)
+
+            prog_d = _named_jit(fd, "mesh_range_dest")
+            self._mesh_jit_cache["range-dest"] = prog_d
+        dest = self._run(prog_d, miss, qs, bits)
         return self.exchange_by_dest(
             sp, dest, edge=f"mesh-range({k.symbol})"
         )
@@ -661,7 +743,21 @@ class MeshExecutor(LocalExecutor):
         engine's shuffle: one all_to_all over ICI, with bucket-overflow
         retry (the OutputBuffer backpressure analog). ``edge`` names
         the exchange for the ``check_exchange_coverage`` debug
-        assertion (live rows must be conserved across the shuffle)."""
+        assertion (live rows must be conserved across the shuffle).
+
+        The whole of it is one ``mesh-exchange`` span: ``live_rows`` is
+        the count the program returns beside its overflow flag (read
+        in the same transfer), ``live_bytes`` those rows at the width
+        of one row of every leaf (what had to move), ``buffer_bytes``
+        the padded buffers the all_to_all is given (what
+        ``exchange_stats`` counts), ``escalations`` the bucket
+        retries."""
+        with telemetry.child_span("mesh-exchange", edge=edge) as span:
+            return self._exchange_by_dest(sp, dest, edge, span)
+
+    def _exchange_by_dest(
+        self, sp: ShardedPage, dest: jnp.ndarray, edge: str, span
+    ) -> ShardedPage:
         shard_cap = sp.shard_capacity
         n = self.n_shards
         bucket_cap = shape_policy.exchange_bucket(shard_cap, n)
@@ -672,6 +768,10 @@ class MeshExecutor(LocalExecutor):
         )
         self.exchange_stats["bytes"] += moved
         telemetry.EXCHANGE_BYTES.inc(moved)
+        row_bytes = sum(
+            int(np.prod(l.shape[1:])) * l.dtype.itemsize for l in leaves
+        )
+        escalations = 0
         while True:
             key = (
                 "mesh-exchange",
@@ -679,7 +779,8 @@ class MeshExecutor(LocalExecutor):
                 bucket_cap,
             )
             prog = self._mesh_jit_cache.get(key)
-            if prog is None:
+            miss = prog is None
+            if miss:
                 axis = self.axis
 
                 def fn(dest_, *ls):
@@ -689,33 +790,36 @@ class MeshExecutor(LocalExecutor):
                         dest_, live, payload, n, bucket_cap, axis
                     )
                     ovf = jax.lax.pmax(ovf.astype(jnp.int32), axis)
-                    out = [recv[str(i)] for i in range(len(ls) - 1)]
-                    return out, rlive, ovf
-
-                prog = jax.jit(
-                    jax.shard_map(
-                        fn,
-                        mesh=self.mesh,
-                        in_specs=(PS(axis),) * (len(leaves) + 1),
-                        out_specs=(
-                            [PS(axis)] * (len(leaves) - 1),
-                            PS(axis),
-                            PS(),
-                        ),
-                        check_vma=False,
+                    n_live = jax.lax.psum(
+                        jnp.sum(live.astype(jnp.int32)), axis
                     )
+                    out = [recv[str(i)] for i in range(len(ls) - 1)]
+                    return out, rlive, jnp.stack([ovf, n_live])
+
+                prog = self._shard_jit(
+                    fn, "exchange",
+                    (PS(axis),) * (len(leaves) + 1),
+                    ([PS(axis)] * (len(leaves) - 1), PS(axis), PS()),
                 )
                 self._mesh_jit_cache[key] = prog
-            out, rlive, ovf = self._attempt(
-                "exchange", lambda: prog(dest, *leaves)
+            out, rlive, stat = self._run(
+                prog, miss, dest, *leaves, tag="exchange"
             )
-            if bool(jax.device_get(ovf)) and bucket_cap < shard_cap:
+            with telemetry.child_span("host_sync", site="mesh_exchange_flag"):
+                ovf, n_live = (int(v) for v in jax.device_get(stat))
+            if ovf and bucket_cap < shard_cap:
                 self.exchange_escalations += 1
+                escalations += 1
                 bucket_cap = min(bucket_cap * 4, shard_cap)
                 continue
-            if bool(jax.device_get(ovf)):
+            if ovf:
                 raise SkewOverflow(
                     "exchange bucket overflow at max capacity"
+                )
+            if span is not None:
+                span.attrs.update(
+                    live_rows=n_live, live_bytes=n_live * row_bytes,
+                    buffer_bytes=moved, escalations=escalations,
                 )
             cols, i = [], 0
             for (name, has_valid), c in zip(meta, sp.columns):
@@ -756,7 +860,10 @@ class MeshExecutor(LocalExecutor):
                 # row counts for this named edge, folded into
                 # exchange_stats histograms and the
                 # trino_exchange_partition_rows metric family
-                d_host, live_host = jax.device_get((dest, sp.mask))
+                with telemetry.child_span(
+                    "host_sync", site="mesh_partition_counters"
+                ):
+                    d_host, live_host = jax.device_get((dest, sp.mask))
                 counts = np.bincount(
                     np.asarray(d_host).ravel()[
                         np.asarray(live_host).ravel().astype(bool)
@@ -780,10 +887,13 @@ class MeshExecutor(LocalExecutor):
                 # short result
                 from trino_tpu.plan.validate import ExchangeCoverageError
 
-                n_in, n_out = jax.device_get((
-                    jnp.sum(sp.mask.astype(jnp.int32)),
-                    jnp.sum(rlive.astype(jnp.int32)),
-                ))
+                with telemetry.child_span(
+                    "host_sync", site="mesh_exchange_coverage"
+                ):
+                    n_in, n_out = jax.device_get((
+                        jnp.sum(sp.mask.astype(jnp.int32)),
+                        jnp.sum(rlive.astype(jnp.int32)),
+                    ))
                 if int(n_in) != int(n_out):
                     raise ExchangeCoverageError(
                         edge, int(n_in), int(n_out),
@@ -935,7 +1045,8 @@ class MeshExecutor(LocalExecutor):
             self._join_sig(build, replicated),
         )
         prog_b = self._mesh_jit_cache.get(key_b)
-        if prog_b is None:
+        miss = prog_b is None
+        if miss:
             def fk(*ls):
                 (_, p_mask, _, _, pk, bk, probe_live, build_live,
                  _, _) = prelude(ls)
@@ -950,21 +1061,19 @@ class MeshExecutor(LocalExecutor):
                 n_keep = jnp.sum(keep.astype(jnp.int32)).reshape(1)
                 return keep, n_in, n_keep
 
-            prog_b = jax.jit(
-                jax.shard_map(
-                    fk, mesh=self.mesh,
-                    in_specs=(PS(axis),) * n_p + (
-                        (PS(),) if replicated else (PS(axis),)
-                    ) * len(b_leaves),
-                    out_specs=(PS(axis), PS(axis), PS(axis)),
-                    check_vma=False,
-                )
+            prog_b = self._shard_jit(
+                fk, "dynamic_filter",
+                (PS(axis),) * n_p + (
+                    (PS(),) if replicated else (PS(axis),)
+                ) * len(b_leaves),
+                (PS(axis), PS(axis), PS(axis)),
             )
             self._mesh_jit_cache[key_b] = prog_b
-        keep, n_in_dev, n_keep_dev = self._attempt(
-            "dynamic-filter", lambda: prog_b(*leaves)
+        keep, n_in_dev, n_keep_dev = self._run(
+            prog_b, miss, *leaves, tag="dynamic-filter"
         )
-        n_in, n_keep = jax.device_get((n_in_dev, n_keep_dev))
+        with telemetry.child_span("host_sync", site="mesh_dynamic_filter"):
+            n_in, n_keep = jax.device_get((n_in_dev, n_keep_dev))
         in_rows, kept = int(n_in.sum()), int(n_keep.sum())
         self.df_log.append(
             {"rows_in": in_rows, "rows_kept": kept, "pairs": list(criteria)}
@@ -980,21 +1089,18 @@ class MeshExecutor(LocalExecutor):
             )
         key_c = ("mesh-dfC", self._sharded_sig(probe), new_cap)
         prog_c = self._mesh_jit_cache.get(key_c)
-        if prog_c is None:
+        miss = prog_c is None
+        if miss:
             def fc(kp, *ls):
                 env, _ = _env_from_leaves(list(ls), p_meta)
                 return K.compact_rows(env, kp, new_cap)
 
-            prog_c = jax.jit(
-                jax.shard_map(
-                    fc, mesh=self.mesh,
-                    in_specs=(PS(axis),) * (len(p_leaves) + 1),
-                    out_specs=(PS(axis), PS(axis)),
-                    check_vma=False,
-                )
+            prog_c = self._shard_jit(
+                fc, "compact",
+                (PS(axis),) * (len(p_leaves) + 1), (PS(axis), PS(axis)),
             )
             self._mesh_jit_cache[key_c] = prog_c
-        env, new_mask = prog_c(keep, *p_leaves)
+        env, new_mask = self._run(prog_c, miss, keep, *p_leaves)
         cols = [
             Column(c.type, *env[n], c.dictionary, c.hash_pool)
             for n, c in zip(probe.names, probe.columns)
@@ -1046,11 +1152,10 @@ class MeshExecutor(LocalExecutor):
 
     def _dest_counts(self, sp: ShardedPage, key_syms: list[str]):
         """(dest per row, global per-destination row counts)."""
-        cols = [sp.column(k) for k in key_syms]
-        h = K.hash_columns(_exchange_key_pairs(cols))
-        dest = (h % jnp.uint64(self.n_shards)).astype(jnp.int32)
+        dest = self._hash_dest(sp, key_syms)
         prog = self._mesh_jit_cache.get("dest-hist")
-        if prog is None:
+        miss = prog is None
+        if miss:
             n = self.n_shards
 
             def hist(d, m):
@@ -1058,10 +1163,11 @@ class MeshExecutor(LocalExecutor):
                     jnp.where(m, 1, 0), d, num_segments=n
                 )
 
-            prog = jax.jit(hist)
+            prog = _named_jit(hist, "mesh_dest_hist")
             self._mesh_jit_cache["dest-hist"] = prog
-        counts = prog(dest, sp.mask)
-        return dest, np.asarray(jax.device_get(counts))
+        counts = self._run(prog, miss, dest, sp.mask)
+        with telemetry.child_span("host_sync", site="mesh_dest_counts"):
+            return dest, np.asarray(jax.device_get(counts))
 
     def _skew_join(
         self, node: P.Join, left: ShardedPage, right: ShardedPage,
@@ -1145,7 +1251,8 @@ class MeshExecutor(LocalExecutor):
         axis = self.axis
         key = ("mesh-groupid", self._sharded_sig(src), tuple(sets))
         prog = self._mesh_jit_cache.get(key)
-        if prog is None:
+        miss = prog is None
+        if miss:
             names = list(src.names)
 
             def fg(*ls):
@@ -1176,16 +1283,12 @@ class MeshExecutor(LocalExecutor):
             n_out = sum(
                 2 if (nm in keyed or hv) else 1 for nm, hv in meta
             ) + 2
-            prog = jax.jit(
-                jax.shard_map(
-                    fg, mesh=self.mesh,
-                    in_specs=(PS(axis),) * len(leaves),
-                    out_specs=[PS(axis)] * n_out,
-                    check_vma=False,
-                )
+            prog = self._shard_jit(
+                fg, "group_id",
+                (PS(axis),) * len(leaves), [PS(axis)] * n_out,
             )
             self._mesh_jit_cache[key] = prog
-        out = prog(*leaves)
+        out = self._run(prog, miss, *leaves)
         cols, i = [], 0
         names = []
         for (name, has_valid), c in zip(meta, src.columns):
@@ -1212,7 +1315,8 @@ class MeshExecutor(LocalExecutor):
             "mesh-concat", self._sharded_sig(a), self._sharded_sig(b),
         )
         prog = self._mesh_jit_cache.get(key)
-        if prog is None:
+        miss = prog is None
+        if miss:
             n_a = len(a_leaves)
 
             def fc(*ls):
@@ -1221,16 +1325,13 @@ class MeshExecutor(LocalExecutor):
                     jnp.concatenate([x, y]) for x, y in zip(xs, ys)
                 ]
 
-            prog = jax.jit(
-                jax.shard_map(
-                    fc, mesh=self.mesh,
-                    in_specs=(PS(axis),) * (len(a_leaves) + len(b_leaves)),
-                    out_specs=[PS(axis)] * len(a_leaves),
-                    check_vma=False,
-                )
+            prog = self._shard_jit(
+                fc, "concat",
+                (PS(axis),) * (len(a_leaves) + len(b_leaves)),
+                [PS(axis)] * len(a_leaves),
             )
             self._mesh_jit_cache[key] = prog
-        out = prog(*a_leaves, *b_leaves)
+        out = self._run(prog, miss, *a_leaves, *b_leaves)
         cols, i = [], 0
         for (name, has_valid), c in zip(meta, a.columns):
             data = out[i]
@@ -1247,7 +1348,8 @@ class MeshExecutor(LocalExecutor):
         """Phase A of a distributed join: per-shard match totals, one
         host sync, padded output capacity (the build-side barrier)."""
         prog = self._mesh_jit_cache.get(key)
-        if prog is None:
+        miss = prog is None
+        if miss:
             axis = self.axis
 
             def fa(*ls):
@@ -1257,16 +1359,11 @@ class MeshExecutor(LocalExecutor):
                 _, _, cnt = K.join_ranges(bk, build_live, pk, probe_live)
                 return jnp.sum(cnt).reshape(1)
 
-            prog = jax.jit(
-                jax.shard_map(
-                    fa, mesh=self.mesh, in_specs=in_specs,
-                    out_specs=PS(axis), check_vma=False,
-                )
-            )
+            prog = self._shard_jit(fa, "join_count", in_specs, PS(axis))
             self._mesh_jit_cache[key] = prog
-        totals = jax.device_get(
-            self._attempt("join-count", lambda: prog(*leaves))
-        )
+        totals_dev = self._run(prog, miss, *leaves, tag="join-count")
+        with telemetry.child_span("host_sync", site="mesh_join_total"):
+            totals = jax.device_get(totals_dev)
         return pad_capacity(int(max(totals.max(), 1)))
 
     def _join_sig(self, page, replicated: bool) -> tuple:
@@ -1368,7 +1465,8 @@ class MeshExecutor(LocalExecutor):
             self._join_sig(probe, False), self._join_sig(build, replicated),
         )
         prog_b = self._mesh_jit_cache.get(key_b)
-        if prog_b is None:
+        miss = prog_b is None
+        if miss:
             def fb(*ls):
                 (p_env, p_mask, b_env, b_mask,
                  pk, bk, probe_live, build_live, p_bits, b_bits) = (
@@ -1455,16 +1553,13 @@ class MeshExecutor(LocalExecutor):
                 return outs, jnp.concatenate(mask_sections)
 
             n_out = sum(2 if hv else 1 for _, _, hv in out_meta)
-            prog_b = jax.jit(
-                jax.shard_map(
-                    fb, mesh=self.mesh, in_specs=in_specs,
-                    out_specs=([PS(axis)] * n_out, PS(axis)),
-                    check_vma=False,
-                )
+            prog_b = self._shard_jit(
+                fb, "join_expand", in_specs,
+                ([PS(axis)] * n_out, PS(axis)),
             )
             self._mesh_jit_cache[key_b] = prog_b
-        outs, mask = self._attempt(
-            "join-expand", lambda: prog_b(*p_leaves, *b_leaves)
+        outs, mask = self._run(
+            prog_b, miss, *p_leaves, *b_leaves, tag="join-expand"
         )
         cols, i = [], 0
         for s, from_probe, has_valid in out_meta:
@@ -1491,18 +1586,18 @@ class MeshExecutor(LocalExecutor):
         # phase A: max live probe rows on any shard
         key_a = ("mesh-crossA", self._join_sig(probe, False))
         prog_a = self._mesh_jit_cache.get(key_a)
-        if prog_a is None:
+        miss = prog_a is None
+        if miss:
             def fa(mask):
                 return jnp.sum(mask.astype(jnp.int32)).reshape(1)
 
-            prog_a = jax.jit(
-                jax.shard_map(
-                    fa, mesh=self.mesh, in_specs=(PS(axis),),
-                    out_specs=PS(axis), check_vma=False,
-                )
+            prog_a = self._shard_jit(
+                fa, "cross_count", (PS(axis),), PS(axis)
             )
             self._mesh_jit_cache[key_a] = prog_a
-        lmax = int(jax.device_get(prog_a(probe.mask)).max())
+        live_dev = self._run(prog_a, miss, probe.mask)
+        with telemetry.child_span("host_sync", site="mesh_cross_count"):
+            lmax = int(jax.device_get(live_dev).max())
         out_cap = pad_capacity(max(lmax * nb, 1))
         p_cols = {n: c for n, c in zip(probe.names, probe.columns)}
         b_cols = {n: c for n, c in zip(build.names, build.columns)}
@@ -1516,7 +1611,8 @@ class MeshExecutor(LocalExecutor):
             self._join_sig(probe, False), self._join_sig(build, True),
         )
         prog_b = self._mesh_jit_cache.get(key_b)
-        if prog_b is None:
+        miss = prog_b is None
+        if miss:
             def fb(*ls):
                 p_env, p_mask = _env_from_leaves(list(ls[:n_p]), p_meta)
                 b_env, b_mask = _env_from_leaves(list(ls[n_p:]), b_meta)
@@ -1542,16 +1638,13 @@ class MeshExecutor(LocalExecutor):
 
             n_out = sum(2 if hv else 1 for _, _, hv in out_meta)
             in_specs = (PS(axis),) * n_p + (PS(),) * len(b_leaves)
-            prog_b = jax.jit(
-                jax.shard_map(
-                    fb, mesh=self.mesh, in_specs=in_specs,
-                    out_specs=([PS(axis)] * n_out, PS(axis)),
-                    check_vma=False,
-                )
+            prog_b = self._shard_jit(
+                fb, "cross_join", in_specs,
+                ([PS(axis)] * n_out, PS(axis)),
             )
             self._mesh_jit_cache[key_b] = prog_b
-        outs, mask = self._attempt(
-            "join-expand", lambda: prog_b(*p_leaves, *b_leaves)
+        outs, mask = self._run(
+            prog_b, miss, *p_leaves, *b_leaves, tag="join-expand"
         )
         cols, i = [], 0
         for s, from_probe, has_valid in out_meta:
@@ -1632,7 +1725,8 @@ class MeshExecutor(LocalExecutor):
             self._join_sig(sp, False), self._join_sig(filt, True),
         )
         prog_b = self._mesh_jit_cache.get(key_b)
-        if prog_b is None:
+        miss = prog_b is None
+        if miss:
             def fb(*ls):
                 (p_env, p_mask, b_env, b_mask,
                  pk, bk, probe_live, build_live, p_bits, b_bits) = (
@@ -1672,15 +1766,10 @@ class MeshExecutor(LocalExecutor):
                     matched = cnt > 0
                 return matched
 
-            prog_b = jax.jit(
-                jax.shard_map(
-                    fb, mesh=self.mesh, in_specs=in_specs,
-                    out_specs=PS(axis), check_vma=False,
-                )
-            )
+            prog_b = self._shard_jit(fb, "semi_join", in_specs, PS(axis))
             self._mesh_jit_cache[key_b] = prog_b
-        matched = self._attempt(
-            "semi-join", lambda: prog_b(*p_leaves, *b_leaves)
+        matched = self._run(
+            prog_b, miss, *p_leaves, *b_leaves, tag="semi-join"
         )
         from trino_tpu import types as T
 

@@ -787,6 +787,8 @@ class Coordinator:
         "upload_ms", "to_rows_ms", "respond_ms", "rows_out_ms",
         "direct_groupbys", "sorted_groupbys", "streamed_groupbys",
         "compactions", "compact_gather_ops",
+        "mesh_exchanges", "mesh_exchange_ms", "mesh_exchange_live_bytes",
+        "mesh_exchange_buffer_bytes", "mesh_gather_ms", "mesh_upload_ms",
     )
 
     def _seal(self, q: QueryState) -> None:
@@ -1064,11 +1066,20 @@ def main():
     ap.add_argument("--schema", default="tiny")
     ap.add_argument("--n-partitions", type=int, default=4)
     ap.add_argument(
+        "--mesh", type=int, default=None, metavar="N",
+        help="embedded runner only: one process over a mesh of N "
+             "devices, tables range-sharded in scan order over the "
+             "mesh axis (default: the single-device executor)",
+    )
+    ap.add_argument(
         "--session", action="append", default=[], metavar="K=V",
         help="session property override (repeatable)",
     )
     args = ap.parse_args()
     journal = None
+    if args.mesh is not None and (args.workers or args.mesh < 1):
+        ap.error("--mesh N starts the embedded runner over N >= 1 devices; "
+                 "it takes no --workers")
     if args.workers:
         from trino_tpu.connectors.tpch.connector import TpchConnector
         from trino_tpu.journal import QueryJournal
@@ -1098,6 +1109,14 @@ def main():
             md, session, spool_root=spool_root,
             n_partitions=args.n_partitions, journal=journal,
         )
+    elif args.mesh is not None:
+        from trino_tpu.parallel.core import make_mesh
+
+        try:
+            mesh = make_mesh(args.mesh)
+        except ValueError as e:  # "need N devices, have M"
+            sys.exit(f"--mesh {args.mesh}: {e}")
+        runner = QueryRunner.tpch(args.schema, mesh=mesh)
     else:
         runner = QueryRunner.tpch(args.schema)
     coord = Coordinator(runner, port=args.port, journal=journal)

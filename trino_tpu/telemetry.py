@@ -354,8 +354,13 @@ def span_totals(root: Span) -> Dict[str, float]:
     aggregates of the dispatched programs by the path each took (the
     ``dispatch`` span's ``groupbys``); ``compactions`` counts the
     compaction programs and ``compact_gather_ops`` the gather operands
-    they were built with (the ``dispatch`` span's ``gather_ops``). The
-    root is left out: its time is the statement's ``elapsed_ms``."""
+    they were built with (the ``dispatch`` span's ``gather_ops``);
+    ``mesh_exchanges`` counts the mesh executor's ``mesh-exchange``
+    spans, ``mesh_exchange_live_bytes`` / ``mesh_exchange_buffer_bytes``
+    sum their attributes of those names, and ``mesh_upload_ms`` is the
+    time of its two host-to-mesh layings-out (``mesh-scan-upload``,
+    ``mesh-scatter``). The root is left out: its time is the
+    statement's ``elapsed_ms``."""
     out: Dict[str, float] = {}
     for sp in root.walk():
         if sp is root:
@@ -370,6 +375,14 @@ def span_totals(root: Span) -> Dict[str, float]:
             out["compactions"] = out.get("compactions", 0) + 1
             out["compact_gather_ops"] = out.get(
                 "compact_gather_ops", 0) + sp.attrs.get("gather_ops", 0)
+        if key == "mesh_exchange":
+            out["mesh_exchanges"] = out.get("mesh_exchanges", 0) + 1
+            for attr in ("live_bytes", "buffer_bytes"):
+                field = "mesh_exchange_" + attr
+                out[field] = out.get(field, 0) + sp.attrs.get(attr, 0)
+        elif key in ("mesh_scan_upload", "mesh_scatter"):
+            out["mesh_upload_ms"] = (
+                out.get("mesh_upload_ms", 0.0) + sp.duration_ms)
     return out
 
 
