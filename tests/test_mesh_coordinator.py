@@ -41,6 +41,7 @@ IDS = [st.template + "-" + "_".join(st.params.values()) for st in STATEMENTS]
 MESH_FIELDS = (
     "mesh_exchanges", "mesh_exchange_ms", "mesh_exchange_live_bytes",
     "mesh_exchange_buffer_bytes", "mesh_gather_ms", "mesh_upload_ms",
+    "mesh_exchanges_in_place",
 )
 CONFIG = harness.load_json(
     os.path.join(BENCH, "configs", "tpch_sf5_mesh4.json"))
@@ -122,6 +123,10 @@ def test_mesh_fields_on_the_statement_row(st, served):
                 <= row["mesh_exchange_buffer_bytes"])
     if st.template == "q06":
         assert row["mesh_exchanges"] == 0
+    # Q18's inner group-by is on the key lineitem's shards are ranged
+    # and ordered on (ISSUE 40): that exchange, and no other of the mix
+    assert row["mesh_exchanges_in_place"] == (st.template == "q18")
+    assert row["streamed_groupbys"] == (2 if st.template == "q18" else 0)
 
 
 @pytest.mark.parametrize(
@@ -140,8 +145,8 @@ def test_every_mesh_program_is_named(served, mesh_coord):
         prog = hit[0] if isinstance(hit, tuple) else hit
         names.add(prog.__name__)
     assert names and all(n.startswith("mesh_") for n in names), names
-    assert {"mesh_exchange", "mesh_exchange_dest", "mesh_join_count",
-            "mesh_join_expand", "mesh_semi_join"} <= names
+    assert {"mesh_exchange", "mesh_exchange_dest", "mesh_exchange_in_place",
+            "mesh_join_count", "mesh_join_expand", "mesh_semi_join"} <= names
     assert any(n.startswith("mesh_chain_") for n in names)
 
 

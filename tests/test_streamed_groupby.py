@@ -546,9 +546,10 @@ def test_a_sort_or_a_limit_under_the_aggregate_drops_it():
             100 if isinstance(pre, P.Limit) else 400)
 
 
-def test_a_mesh_shard_does_not(by_order_rows):
+def test_a_mesh_shard_does_too(by_order_rows):
     """The mesh executor's sharded chains (virtual CPU mesh): a shard
-    is a row range of the table, and ``ShardedPage`` has no order."""
+    is a row range of the table in scan order, and since ISSUE 40 the
+    ``ShardedPage`` says so (``tests/test_mesh_ordered_groupby.py``)."""
     from trino_tpu.exec.mesh import make_mesh
 
     r = QueryRunner.tpch("tiny", mesh=make_mesh(4))
@@ -559,8 +560,8 @@ def test_a_mesh_shard_does_not(by_order_rows):
         path for key, hit in r.executor._mesh_jit_cache.items()
         if key[0] == "mesh-chain" for path in hit[1].groupbys.values()
     ]
-    assert paths and set(paths) == {"sorted"}
-    assert "streamed" not in _groupbys(res)
+    assert paths and set(paths) == {"streamed"}
+    assert set(_groupbys(res)) == {"streamed"}
 
 
 def test_q18_tiny_streams_its_inner_group_by(tiny):

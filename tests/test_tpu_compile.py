@@ -238,3 +238,51 @@ def test_q6_scan_filter_sum_at_lineitem_sf1(one_chip, no_compile_cache):
         ((n,), jnp.int64), ((n,), jnp.int64), ((n,), jnp.bool_),
     )
     assert compiled.memory_analysis().argument_size_in_bytes > n * 8 * 3
+
+
+def test_seam_exchange_on_four_chips_at_q18_sf5(topo, no_compile_cache):
+    """The mesh exchange satisfied in place (``parallel.exchange.
+    seam_exchange``, ISSUE 40) over Q18's partial-aggregate page at SF5
+    on the described 2x2 slice — a key, a two-lane partial state with
+    its validity lanes, 4,194,304 slots a shard: the compiled program
+    holds the neighbour permutes and the exchange of the shards'
+    scalars, no sort, gather, scatter or all-to-all of any size —
+    contiguous copies only — and next to nothing beside its arguments
+    and results."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
+
+    from trino_tpu.parallel.exchange import seam_exchange
+
+    n, cap, bucket = 4, 4_194_304, 128
+    mesh = Mesh(np.asarray(topo.devices[:n]), ("workers",))
+    rows = NamedSharding(mesh, PS("workers"))
+    dtypes = [jnp.int64, jnp.int64, jnp.bool_, jnp.int64, jnp.bool_, jnp.bool_]
+
+    def body(*ls):
+        return seam_exchange(
+            ls[0].astype(jnp.uint64), ls[-1], list(ls[:-1]), n, bucket,
+            "workers",
+        )
+
+    prog = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(PS("workers"),) * len(dtypes),
+        out_specs=([PS("workers")] * (len(dtypes) - 1), PS("workers"), PS()),
+        check_vma=False,
+    ))
+    lowered = prog.lower(*(
+        jax.ShapeDtypeStruct((n * cap,), dt, sharding=rows) for dt in dtypes
+    ))
+    assert not re.search(
+        r"stablehlo\.(sort|gather|scatter|all_to_all)\b", lowered.as_text())
+    compiled = lowered.compile()
+    txt = compiled.as_text()
+    # the neighbour hand-over, and the shards' scalars (which XLA:TPU
+    # gathers by an all-reduce)
+    assert "collective-permute-start" in txt
+    assert "all-gather" in txt or "all-reduce" in txt
+    assert not re.search(r" (sort|gather|scatter|all-to-all)\(", txt)
+    ma = compiled.memory_analysis()
+    page = cap * (8 + 8 + 1 + 8 + 1 + 1)
+    assert ma.argument_size_in_bytes >= page
+    assert ma.temp_size_in_bytes < page // 8

@@ -14,8 +14,11 @@ SortingProperty, which its ``tpch`` connector reports for ``orders``
 and ``lineitem``). A GROUP BY over exactly that column of the whole
 resident table then needs no sort: the runs are the groups
 (``exec/kernels.py:run_group``, the StreamingAggregationOperator
-analog). The declaration is checked on the device, and a table that
-breaks it is grouped by sort — slower, never wrong.
+analog), on one device and shard by shard on a mesh, where the hash
+exchange between the partial and the final step is then satisfied in
+place (``exec/mesh.py:exchange_in_place``). The declaration is checked
+on the device, and a table that breaks it is grouped by sort — slower,
+never wrong.
 """
 
 from __future__ import annotations
@@ -214,7 +217,12 @@ class Connector:
         ascending order (of a whole-table scan; equal values adjacent),
         or None — the default — when no order is promised. A sort
         order, not just clustering: monotonicity is what the engine can
-        check as it reads."""
+        check as it reads. A mesh scan relies on it across shards as
+        well as inside one: it lays the rows over the devices as
+        consecutive ranges of the scan's order, so shard i's keys
+        precede shard i+1's and only a key whose run a boundary cuts is
+        on two (``exec/mesh.py:ShardedPage.ordered_on``; checked on
+        the devices there too, a broken promise costs one rerun)."""
         return None
 
     def table_stats(self, schema: str, table: str) -> TableStats:

@@ -1055,17 +1055,10 @@ class LocalExecutor:
         ]
         # the whole table in the connector's order, live rows a prefix:
         # the one page that carries the declared sort order
-        sorted_col = connector.sorted_by(node.schema, node.table)
         return Page(
             names, columns, cache[""],
             known_rows=cache["#rows"], packed=True,
-            ordered_on=next(
-                (
-                    s for s, c in node.assignments.items()
-                    if c == sorted_col and s not in hashed_syms
-                ),
-                None,
-            ),
+            ordered_on=_declared_order(node, connector),
         )
 
     def _scan_pruned(self, node: P.TableScan, connector) -> Page:
@@ -2844,6 +2837,21 @@ class LocalExecutor:
         return Page(names, cols, live)
 
 
+def _declared_order(node: P.TableScan, connector) -> str | None:
+    """The symbol a whole-table scan assigns to the column the
+    connector declares its rows ascend on (``Connector.sorted_by``);
+    not a hash-coded varchar, whose lanes are not the value's order."""
+    sorted_col = connector.sorted_by(node.schema, node.table)
+    hashed = node.hash_varchar or ()
+    return next(
+        (
+            s for s, c in node.assignments.items()
+            if c == sorted_col and s not in hashed
+        ),
+        None,
+    )
+
+
 def _unordered_key(caps_key: tuple) -> tuple:
     """Where the executor remembers, beside a chain shape's learned
     capacities, that its input broke the connector's declared order."""
@@ -2887,6 +2895,7 @@ def _rename_out(out_layout, env: dict, out_map: dict):
         capacity=out_layout.capacity,
         pools={m[n]: p for n, p in out_layout.pools.items() if n in m},
         arrays={m[n]: a for n, a in out_layout.arrays.items() if n in m},
+        ordered_on=m.get(out_layout.ordered_on),
     )
     env2 = {m[n]: env[n] for n in out_layout.names}
     return layout, env2

@@ -13,7 +13,13 @@ HTTP exchange -> ExchangeOperator, SURVEY.md §3.4) — rebuilt SPMD:
   (each shard runs the same fused pipeline on its rows);
 - the hash ``Exchange`` is one ``lax.all_to_all`` on ICI
   (parallel.exchange.partition_exchange) inside the same SPMD program
-  style — the whole shuffle is a collective, not a protocol;
+  style — the whole shuffle is a collective, not a protocol; over a
+  page already ranged and ordered on the exchange's one key
+  (``ShardedPage.ordered_on``) it is satisfied where the rows lie
+  (``exchange_in_place``: AddExchanges.visitAggregation places no
+  exchange under an aggregation whose child is partitioned on the
+  grouping keys; here the plan keeps its node and the executor finds
+  it has nothing to move but the runs a shard boundary cuts);
 - joins co-partition or broadcast their build side and run shard-local
   sort-probe joins; data-dependent output capacities are resolved with
   one host sync (count phase, then expand phase) — mirroring the
@@ -26,7 +32,7 @@ HTTP exchange -> ExchangeOperator, SURVEY.md §3.4) — rebuilt SPMD:
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, replace as dc_replace
 
 import jax
 import jax.numpy as jnp
@@ -41,15 +47,17 @@ from trino_tpu.exec.failure import FailureInjector, InjectedFailure
 from trino_tpu.exec.local import (
     LocalExecutor,
     _chain_program_name,
+    _declared_order,
     _dispatching,
     _named_jit,
     _rename_out,
+    _unordered_key,
 )
 from trino_tpu.expr.compiler import compile_expr, ColumnLayout
 from trino_tpu.metadata import Metadata, Session
 from trino_tpu.page import Column, Page, pad_capacity, unify_dictionaries
 from trino_tpu.parallel.core import WORKER_AXIS, make_mesh
-from trino_tpu.parallel.exchange import partition_exchange
+from trino_tpu.parallel.exchange import partition_exchange, seam_exchange
 from trino_tpu.plan import nodes as P
 
 __all__ = ["MeshExecutor", "ShardedPage", "SkewOverflow"]
@@ -67,13 +75,32 @@ class ShardedPage:
     """Columnar batch sharded along the mesh's worker axis.
 
     Column data has global shape [n_shards * shard_capacity] with a
-    NamedSharding over the axis; row order carries no meaning across
-    shards (a bag of rows, like the reference's distributed Pages)."""
+    NamedSharding over the axis. As a rule it is a bag of rows, like
+    the reference's distributed Pages: where a row lies says nothing.
+
+    ``ordered_on`` names the one exception, the twin of
+    ``Page.ordered_on`` across shards: inside every shard the live
+    rows are a prefix and ascend on that column (by its normalized key
+    bits, the order ``kernels.run_group`` checks), and shard i's rows
+    precede shard i+1's in that order, so a key's rows lie on one
+    shard or at the seam of adjacent ones. *Declared* by the resident
+    whole-table scan alone (``_scan_dist``: ``Connector.sorted_by``
+    over rows range-sharded in scan order). *Kept* by a chain whose
+    steps keep it (``stage.build_chain``: a Project that passes the
+    column through, an Aggregate grouped on it alone) and by
+    ``exchange_in_place``; every other producer — ``scatter``, a split
+    scan, a remote source, a join, a concatenation, a GroupId, the
+    general exchange — builds its page without it. *Verified* where it
+    is used: the streamed group-by checks each shard's order on the
+    device (a chain reruns by sort and drops the property),
+    ``exchange_in_place`` checks the order inside and across shards
+    (and falls back to the hash exchange)."""
 
     names: list[str]
     columns: list[Column]
     mask: jnp.ndarray
     n_shards: int
+    ordered_on: str | None = None
 
     @property
     def shard_capacity(self) -> int:
@@ -125,6 +152,22 @@ def _env_from_leaves(leaves, meta):
             i += 1
         env[name] = (data, valid)
     return env, leaves[i]
+
+
+def _columns_from_leaves(leaves, meta, like: list[Column]) -> list[Column]:
+    """``_page_leaves`` undone for a page's columns: the program's data
+    and validity lanes (in ``meta``'s order) as Columns that keep the
+    type, dictionary and pool of ``like``'s."""
+    cols, i = [], 0
+    for (_name, has_valid), c in zip(meta, like):
+        data = leaves[i]
+        i += 1
+        valid = None
+        if has_valid:
+            valid = leaves[i]
+            i += 1
+        cols.append(Column(c.type, data, valid, c.dictionary, c.hash_pool))
+    return cols
 
 
 def _make_prelude(criteria, p_meta, b_meta, n_p, verify, kinds=None):
@@ -213,12 +256,16 @@ class MeshExecutor(LocalExecutor):
             "mesh_" + name,
         )
 
-    def _run(self, prog, miss: bool, *args, tag: str | None = None):
+    def _run(
+        self, prog, miss: bool, *args, tag: str | None = None, **note
+    ):
         """``prog(*args)`` under a ``dispatch`` span that carries the
         program's name (with a ``build_trace`` child on a jit-cache
-        miss, as the local executor's), through ``_attempt`` where the
-        site is a retry unit."""
-        with _dispatching(prog.__name__, miss):
+        miss, as the local executor's) and ``note``'s attributes,
+        through ``_attempt`` where the site is a retry unit."""
+        with _dispatching(prog.__name__, miss) as dispatch:
+            if note:
+                dispatch.note(**note)
             if tag is None:
                 return prog(*args)
             return self._attempt(tag, lambda: prog(*args))
@@ -292,6 +339,9 @@ class MeshExecutor(LocalExecutor):
         if isinstance(node, P.Exchange):
             if node.partitioning == "hash":
                 sp = self.execute_dist(node.source)
+                if list(node.hash_symbols) == [sp.ordered_on]:
+                    # ranged and ordered on the exchange's key already
+                    return self.exchange_in_place(sp)
                 return self.hash_exchange(sp, node.hash_symbols)
             if node.partitioning == "range":
                 sp = self.execute_dist(node.source)
@@ -399,7 +449,14 @@ class MeshExecutor(LocalExecutor):
         columns = [
             cache[ckey(s, c)] for s, c in node.assignments.items()
         ]
-        return ShardedPage(names, columns, cache[""], self.n_shards)
+        # the whole table in the connector's order, cut into consecutive
+        # ranges: the one sharded page that carries the declared order
+        return ShardedPage(
+            names, columns, cache[""], self.n_shards,
+            ordered_on=_declared_order(
+                node, self.metadata.connector(node.catalog)
+            ),
+        )
 
     def gather(self, sp: ShardedPage) -> Page:
         """ShardedPage -> compacted single-device Page (the reference's
@@ -493,9 +550,15 @@ class MeshExecutor(LocalExecutor):
     ) -> ShardedPage:
         """Chain runner per shard: same fused-pipeline compiler as the
         local executor, wrapped in shard_map so every shard executes the
-        one program on its rows (overflow flags pmax-reduced)."""
+        one program on its rows (overflow flags and the streamed
+        group-by's order check pmax-reduced). A page that arrives
+        ordered on a column (``ShardedPage.ordered_on``) says so to the
+        chain builder, and the output carries what the chain kept."""
         shard_cap = sp.shard_capacity
-        caps = stage.plan_capacities(chain, shard_cap, n_shards=self.n_shards)
+        caps = stage.plan_capacities(
+            chain, shard_cap, n_shards=self.n_shards,
+            ordered_on=sp.ordered_on,
+        )
         axis = self.axis
         out_map = None
         if shape_policy.enabled(self.session):
@@ -509,14 +572,25 @@ class MeshExecutor(LocalExecutor):
                     list(canon.in_map.values()),
                     [by_name[o] for o in canon.in_map],
                     sp.mask, self.n_shards,
+                    ordered_on=canon.in_map.get(sp.ordered_on),
                 )
                 chain, out_map = canon.chain, canon.out_map
+        chain_key = tuple(self._node_key(n) for n in chain)
+        # where ``note_chain_flags`` remembers, as for a local chain,
+        # that this one's input broke its declared order
+        caps_key = ("caps", chain_key, self._sharded_sig(sp))
         while True:
+            if sp.ordered_on is not None and dict.get(
+                self._jit_cache, _unordered_key(caps_key)  # no program
+            ):
+                sp = dc_replace(sp, ordered_on=None)
             key = (
                 "mesh-chain",
-                tuple(self._node_key(n) for n in chain),
+                chain_key,
                 tuple((i, c[0]) for i, c in sorted(caps.items())),
                 self._sharded_sig(sp),
+                # an Aggregate over this key groups by runs: another program
+                sp.ordered_on,
             )
             hit = self._mesh_jit_cache.get(key)
             if hit is None:
@@ -533,6 +607,7 @@ class MeshExecutor(LocalExecutor):
                         for n, c in zip(sp.names, sp.columns)
                         if c.hash_pool is not None
                     },
+                    ordered_on=sp.ordered_on,
                 )
                 fn, out_layout = stage.build_chain(chain, in_layout, caps)
                 leaves, meta = _page_leaves(sp)
@@ -589,8 +664,15 @@ class MeshExecutor(LocalExecutor):
                 t_compile = None
             prog, out_layout, meta = hit
             leaves, _ = _page_leaves(sp)
+            # each grouped Aggregate's path, in chain order, as the
+            # local runner notes it (telemetry.span_totals counts them)
+            note = {}
+            if out_layout.groupbys:
+                note["groupbys"] = [
+                    path for _pos, path in sorted(out_layout.groupbys.items())
+                ]
             env, mask, flags = self._run(
-                prog, t_compile is not None, *leaves, tag="chain"
+                prog, t_compile is not None, *leaves, tag="chain", **note
             )
             if t_compile is not None:
                 program_catalog.CATALOG.note_compile_seconds(
@@ -599,15 +681,10 @@ class MeshExecutor(LocalExecutor):
             if flags:
                 with telemetry.child_span("host_sync", site="mesh_chain_flags"):
                     vals = jax.device_get(flags)
-                overflowed = [i for i, v in vals.items() if v]
-                if overflowed:
-                    for i in overflowed:
-                        cap, mx = caps[i]
-                        if cap >= mx:
-                            raise RuntimeError(
-                                "aggregation table overflow at max capacity"
-                            )
-                        caps[i][0] = min(cap * 8, mx)
+                # an overflow grows that table, a tripped order check
+                # (some shard's rows are not the runs they were declared
+                # to be) bars the chain from grouping by runs
+                if self.note_chain_flags(vals, caps_key, caps):
                     continue
             if out_map is not None:
                 out_layout, env = _rename_out(out_layout, env, out_map)
@@ -622,7 +699,8 @@ class MeshExecutor(LocalExecutor):
                 for s in out_layout.names
             ]
             return ShardedPage(
-                list(out_layout.names), cols, mask, self.n_shards
+                list(out_layout.names), cols, mask, self.n_shards,
+                ordered_on=out_layout.ordered_on,
             )
 
     # ---- hash exchange ---------------------------------------------------
@@ -660,6 +738,107 @@ class MeshExecutor(LocalExecutor):
             prog = _named_jit(fd, "mesh_exchange_dest")
             self._mesh_jit_cache[key] = prog
         return self._run(prog, miss, *[(c.data, c.valid) for c in cols])
+
+    #: most rows of a shard's leading run that ``exchange_in_place``
+    #: hands to the shard before it (one order's lines, one PARTIAL
+    #: row a key: a seam cuts a run of a few rows); a longer run takes
+    #: the hash exchange
+    IN_PLACE_BUCKET = 128
+
+    def exchange_in_place(self, sp: ShardedPage) -> ShardedPage:
+        """The hash exchange on the key the page is ranged and ordered
+        on (``sp.ordered_on``), satisfied where the rows lie. The
+        exchange's contract — afterwards every key's live rows are on
+        one shard and every live row is conserved — holds already but
+        for a key whose run a shard boundary cuts: a shard whose
+        leading run continues its predecessor's last key hands that
+        run over (one neighbour ``ppermute`` of a bucket of
+        ``IN_PLACE_BUCKET`` rows a leaf), the receiver appends it to
+        its live prefix, the sender shifts its rows left by what it
+        sent. Contiguous copies only; each shard's live rows stay an
+        ascending prefix, so the page keeps ``ordered_on``.
+
+        The order is a declaration, so the program checks what it
+        relies on — every shard's live rows an ascending prefix, no
+        shard starting below the rows before it, no key on more than
+        two adjacent shards, the run within the bucket, room behind
+        the receiver's rows — and returns one flag with its counts;
+        where that is set (or the key is nullable or wider than one
+        lane) the rows go through ``hash_exchange`` as they came. One
+        ``mesh-exchange`` span either way: it is the one exchange the
+        plan asked for (``in_place`` says which way it went)."""
+        k = sp.ordered_on
+        col = sp.column(k)
+        if col.valid is not None or col.data.ndim != 1:
+            return self.hash_exchange(sp, [k])
+        edge = f"mesh-hash({k})"
+        with telemetry.child_span("mesh-exchange", edge=edge) as span:
+            out = self._exchange_in_place(sp, edge, span)
+            if out is None:
+                out = self._exchange_by_dest(
+                    sp, self._hash_dest(sp, [k]), edge, span
+                )
+            return out
+
+    def _exchange_in_place(
+        self, sp: ShardedPage, edge: str, span
+    ) -> ShardedPage | None:
+        n, cap = self.n_shards, sp.shard_capacity
+        bucket = min(self.IN_PLACE_BUCKET, cap)
+        leaves, meta = _page_leaves(sp)
+        key_at = next(
+            i for i, l in enumerate(leaves)
+            if l is sp.column(sp.ordered_on).data
+        )
+        key = (
+            "mesh-exchange-in-place",
+            tuple((l.dtype.str, l.shape) for l in leaves), key_at, bucket,
+        )
+        prog = self._mesh_jit_cache.get(key)
+        miss = prog is None
+        if miss:
+            axis = self.axis
+
+            def fn(*ls):
+                return seam_exchange(
+                    K.normalize_key(ls[key_at], None)[0], ls[-1],
+                    list(ls[:-1]), n, bucket, axis,
+                )
+
+            prog = self._shard_jit(
+                fn, "exchange_in_place",
+                (PS(axis),) * len(leaves),
+                ([PS(axis)] * (len(leaves) - 1), PS(axis), PS()),
+            )
+            self._mesh_jit_cache[key] = prog
+        out, new_live, stat = self._run(
+            prog, miss, *leaves, tag="exchange"
+        )
+        with telemetry.child_span("host_sync", site="mesh_exchange_flag"):
+            stat = np.asarray(jax.device_get(stat))
+        if stat[0]:
+            return None
+        moved = int(stat[1])
+        row_bytes = sum(
+            int(np.prod(l.shape[1:])) * l.dtype.itemsize for l in leaves
+        )
+        sent_bytes = n * bucket * (row_bytes - sp.mask.dtype.itemsize)
+        self.exchange_stats["exchanges"] += 1
+        self.exchange_stats["bytes"] += sent_bytes
+        telemetry.EXCHANGE_BYTES.inc(sent_bytes)
+        if span is not None:
+            span.attrs.update(
+                live_rows=moved, live_bytes=moved * row_bytes,
+                buffer_bytes=sent_bytes, escalations=0, in_place=True,
+            )
+        self._observe_exchange(
+            edge, sp.mask, new_live, lambda: stat[2:],
+            f"{n}-shard in-place exchange, bucket={bucket}",
+        )
+        return ShardedPage(
+            list(sp.names), _columns_from_leaves(out, meta, sp.columns),
+            new_live, n, ordered_on=sp.ordered_on,
+        )
 
     def range_exchange(
         self, sp: ShardedPage, sort_keys
@@ -821,88 +1000,91 @@ class MeshExecutor(LocalExecutor):
                     live_rows=n_live, live_bytes=n_live * row_bytes,
                     buffer_bytes=moved, escalations=escalations,
                 )
-            cols, i = [], 0
-            for (name, has_valid), c in zip(meta, sp.columns):
-                data = out[i]
-                i += 1
-                valid = None
-                if has_valid:
-                    valid = out[i]
-                    i += 1
-                cols.append(Column(c.type, data, valid, c.dictionary, c.hash_pool))
-            from trino_tpu import session_properties as SP
-
-            count_now = bool(
-                SP.get(self.session, "exchange_partition_counters")
-            )
-            if not count_now:
-                # sampled mode: count every Nth all_to_all instead of
-                # every one. The host sync the counters force costs the
-                # whole dispatch pipeline, so exact counting taxes
-                # every exchange; 1/N sampling keeps skew observability
-                # on by default at 1/N of that tax. Tradeoff: absolute
-                # rows under-report by ~N (the metric is a sample, not
-                # a census) but max/mean and cv are preserved in
-                # expectation — hot-partition DETECTION survives
-                # sampling, exact conservation accounting does not.
-                n_sample = int(
-                    SP.get(
-                        self.session, "exchange_partition_counter_sample"
-                    )
-                )
-                if n_sample > 0:
-                    seq = getattr(self, "_exchange_count_seq", 0)
-                    self._exchange_count_seq = seq + 1
-                    count_now = (seq % n_sample) == 0
-            if count_now:
-                # skew observability (forces a host sync, so gated the
-                # same way as the coverage check): per-destination live
-                # row counts for this named edge, folded into
-                # exchange_stats histograms and the
-                # trino_exchange_partition_rows metric family
+            cols = _columns_from_leaves(out, meta, sp.columns)
+            def dest_counts():
                 with telemetry.child_span(
                     "host_sync", site="mesh_partition_counters"
                 ):
                     d_host, live_host = jax.device_get((dest, sp.mask))
-                counts = np.bincount(
+                return np.bincount(
                     np.asarray(d_host).ravel()[
                         np.asarray(live_host).ravel().astype(bool)
                     ],
                     minlength=n,
                 )
-                hist = self.exchange_stats.setdefault(
-                    "partition_rows", {}
-                ).setdefault(edge, {})
-                for p, c in enumerate(counts):
-                    if c:
-                        hist[p] = hist.get(p, 0) + int(c)
-                        telemetry.EXCHANGE_PARTITION_ROWS.inc(
-                            int(c), edge=edge, partition=str(p)
-                        )
-            if SP.get(self.session, "check_exchange_coverage"):
-                # debug assertion (forces a host sync): an all_to_all
-                # must conserve live rows — any loss here is exactly
-                # the mesh×fleet wrong-results class, attributed to
-                # this named edge instead of surfacing as a silently
-                # short result
-                from trino_tpu.plan.validate import ExchangeCoverageError
 
-                with telemetry.child_span(
-                    "host_sync", site="mesh_exchange_coverage"
-                ):
-                    n_in, n_out = jax.device_get((
-                        jnp.sum(sp.mask.astype(jnp.int32)),
-                        jnp.sum(rlive.astype(jnp.int32)),
-                    ))
-                if int(n_in) != int(n_out):
-                    raise ExchangeCoverageError(
-                        edge, int(n_in), int(n_out),
-                        detail=(
-                            f"{self.n_shards}-shard all_to_all, "
-                            f"bucket_cap={bucket_cap}"
-                        ),
-                    )
+            self._observe_exchange(
+                edge, sp.mask, rlive, dest_counts,
+                f"{self.n_shards}-shard all_to_all, bucket_cap={bucket_cap}",
+            )
             return ShardedPage(list(sp.names), cols, rlive, self.n_shards)
+
+    def _observe_exchange(
+        self, edge: str, live_in, live_out, dest_counts, detail: str
+    ) -> None:
+        """What every exchange owes its observers once its rows are
+        placed: the per-destination row counters (``dest_counts()``
+        gives them, asked only when this exchange is counted) and the
+        ``check_exchange_coverage`` assertion over the live masks
+        before and after."""
+        from trino_tpu import session_properties as SP
+
+        count_now = bool(
+            SP.get(self.session, "exchange_partition_counters")
+        )
+        if not count_now:
+            # sampled mode: count every Nth all_to_all instead of
+            # every one. The host sync the counters force costs the
+            # whole dispatch pipeline, so exact counting taxes
+            # every exchange; 1/N sampling keeps skew observability
+            # on by default at 1/N of that tax. Tradeoff: absolute
+            # rows under-report by ~N (the metric is a sample, not
+            # a census) but max/mean and cv are preserved in
+            # expectation — hot-partition DETECTION survives
+            # sampling, exact conservation accounting does not.
+            n_sample = int(
+                SP.get(
+                    self.session, "exchange_partition_counter_sample"
+                )
+            )
+            if n_sample > 0:
+                seq = getattr(self, "_exchange_count_seq", 0)
+                self._exchange_count_seq = seq + 1
+                count_now = (seq % n_sample) == 0
+        if count_now:
+            # skew observability (forces a host sync, so gated the
+            # same way as the coverage check): per-destination live
+            # row counts for this named edge, folded into
+            # exchange_stats histograms and the
+            # trino_exchange_partition_rows metric family
+            hist = self.exchange_stats.setdefault(
+                "partition_rows", {}
+            ).setdefault(edge, {})
+            for p, c in enumerate(dest_counts()):
+                if c:
+                    hist[p] = hist.get(p, 0) + int(c)
+                    telemetry.EXCHANGE_PARTITION_ROWS.inc(
+                        int(c), edge=edge, partition=str(p)
+                    )
+        if SP.get(self.session, "check_exchange_coverage"):
+            # debug assertion (forces a host sync): an exchange
+            # must conserve live rows — any loss here is exactly
+            # the mesh×fleet wrong-results class, attributed to
+            # this named edge instead of surfacing as a silently
+            # short result
+            from trino_tpu.plan.validate import ExchangeCoverageError
+
+            with telemetry.child_span(
+                "host_sync", site="mesh_exchange_coverage"
+            ):
+                n_in, n_out = jax.device_get((
+                    jnp.sum(live_in.astype(jnp.int32)),
+                    jnp.sum(live_out.astype(jnp.int32)),
+                ))
+            if int(n_in) != int(n_out):
+                raise ExchangeCoverageError(
+                    edge, int(n_in), int(n_out), detail=detail
+                )
 
     # ---- distributed joins ----------------------------------------------
 
@@ -1332,17 +1514,8 @@ class MeshExecutor(LocalExecutor):
             )
             self._mesh_jit_cache[key] = prog
         out = self._run(prog, miss, *a_leaves, *b_leaves)
-        cols, i = [], 0
-        for (name, has_valid), c in zip(meta, a.columns):
-            data = out[i]
-            i += 1
-            valid = None
-            if has_valid:
-                valid = out[i]
-                i += 1
-            cols.append(Column(c.type, data, valid, c.dictionary, c.hash_pool))
-        mask = out[i]
-        return ShardedPage(list(a.names), cols, mask, a.n_shards)
+        cols = _columns_from_leaves(out, meta, a.columns)
+        return ShardedPage(list(a.names), cols, out[-1], a.n_shards)
 
     def _match_count_capacity(self, key, prelude, in_specs, leaves) -> int:
         """Phase A of a distributed join: per-shard match totals, one

@@ -139,7 +139,8 @@ def _bcast(data, valid, capacity):
 
 
 def plan_capacities(
-    chain: list[P.PlanNode], in_capacity: int, n_shards: int = 1
+    chain: list[P.PlanNode], in_capacity: int, n_shards: int = 1,
+    ordered_on: str | None = None,
 ) -> dict[int, list[int]]:
     """Initial [capacity, max_capacity] per Aggregate position.
 
@@ -150,12 +151,16 @@ def plan_capacities(
     steps in a sharded chain see only their hash partition of the key
     space, so the estimate divides by the shard count (×1.5 margin for
     partition imbalance); PARTIAL steps may see every key on every
-    shard. Estimates being wrong is safe: the overflow flag still
-    triggers the retry-larger loop."""
+    shard — unless the sharded page is ranged and ordered on the one
+    group key (``ordered_on``: ``ShardedPage.ordered_on``), when a
+    shard holds its range of the keys and no more. Estimates being
+    wrong is safe: the overflow flag still triggers the retry-larger
+    loop."""
     caps: dict[int, list[int]] = {}
     cap = in_capacity
     for i, nd in enumerate(chain):
         if isinstance(nd, P.Aggregate):
+            ranged = list(nd.group_keys) == [ordered_on]
             if not nd.group_keys:
                 caps[i] = [1, 1]
                 cap = 8
@@ -165,7 +170,9 @@ def plan_capacities(
                 max_cap = pad_capacity(max(2 * cap, 8))
                 if nd.est_groups is not None:
                     est = nd.est_groups
-                    if n_shards > 1 and nd.step in ("FINAL", "SINGLE"):
+                    if n_shards > 1 and (
+                        ranged or nd.step in ("FINAL", "SINGLE")
+                    ):
                         est = est / n_shards * 1.5
                     start = shapes.table_bucket(est, max_cap)
                 else:
@@ -177,10 +184,16 @@ def plan_capacities(
                     )
                 caps[i] = [start, max_cap]
                 cap = start
-        elif isinstance(nd, P.TopN):
-            from trino_tpu.exec import shapes
+            # what build_chain's layouts rule, as far as capacities care
+            ordered_on = ordered_on if ranged else None
+        elif isinstance(nd, P.Project):
+            ordered_on = _passed_through(nd, ordered_on)
+        else:
+            ordered_on = None
+            if isinstance(nd, P.TopN):
+                from trino_tpu.exec import shapes
 
-            cap = shapes.bucket(min(nd.count, cap), site="topn")
+                cap = shapes.bucket(min(nd.count, cap), site="topn")
     return caps
 
 
@@ -213,7 +226,9 @@ def build_chain(chain: list[P.PlanNode], layout: ChainLayout, caps: dict[int, li
             steps.append((scope, _limit_step(nd)))
         else:
             raise NotImplementedError(type(nd).__name__)
-        if layout.ordered_on is not None and not isinstance(nd, P.Project):
+        if layout.ordered_on is not None and not isinstance(
+            nd, (P.Project, P.Aggregate)
+        ):
             # a Sort moves rows, a Filter or a Limit leaves dead rows
             # inside runs: those group by sort (an Aggregate's and a
             # Project's output layouts are built anew, with what holds)
@@ -227,6 +242,18 @@ def build_chain(chain: list[P.PlanNode], layout: ChainLayout, caps: dict[int, li
         return env, mask, flags
 
     return fn, dc_replace(layout, groupbys=groupbys)
+
+
+def _passed_through(nd: P.Project, name: str | None) -> str | None:
+    """The symbol under which a Project hands column ``name`` on as it
+    is, or None."""
+    return next(
+        (
+            s for s, e in nd.assignments.items()
+            if isinstance(e, InputRef) and e.name == name
+        ),
+        None,
+    )
 
 
 def _filter_step(nd: P.Filter, layout: ChainLayout):
@@ -271,13 +298,7 @@ def _project_step(nd: P.Project, layout: ChainLayout):
         },
         # rows stay where they are; the ordered column, if it is passed
         # through as it is, goes on under its new name
-        ordered_on=next(
-            (
-                s for s, e in nd.assignments.items()
-                if isinstance(e, _Ref) and e.name == layout.ordered_on
-            ),
-            None,
-        ),
+        ordered_on=_passed_through(nd, layout.ordered_on),
     )
 
     def step(env, mask, flags):
@@ -304,6 +325,9 @@ def _aggregate_step(
         )
         agg_meta.append((sym, call, arg_c, filter_c))
     group_keys = list(nd.group_keys)
+    # the input's live rows ascend on the one group key (a connector's
+    # declared order, carried by the page): its runs are the groups
+    in_key_order = group_keys == [layout.ordered_on]
     in_cap = layout.capacity
     out_cap = 8 if is_global else capacity
 
@@ -356,15 +380,15 @@ def _aggregate_step(
             s: layout.pools[s]
             for s in group_keys if layout.pools.get(s) is not None
         },
+        # every grouping path emits its groups in key order, live slots
+        # a prefix: input ordered on the one key, output ordered on it
+        ordered_on=group_keys[0] if in_key_order else None,
     )
 
     key_ranges = nd.key_ranges or {}
     dense_aggs = all(
         dense_reducible(call.name, call.distinct) for _s, call, *_ in agg_meta
     )
-    # the input's live rows ascend on the one group key (a connector's
-    # declared order, carried by the page): its runs are the groups
-    in_key_order = group_keys == [layout.ordered_on]
 
     def step(env, mask, flags):
         if is_global:

@@ -789,6 +789,7 @@ class Coordinator:
         "compactions", "compact_gather_ops",
         "mesh_exchanges", "mesh_exchange_ms", "mesh_exchange_live_bytes",
         "mesh_exchange_buffer_bytes", "mesh_gather_ms", "mesh_upload_ms",
+        "mesh_exchanges_in_place",
     )
 
     def _seal(self, q: QueryState) -> None:

@@ -356,7 +356,9 @@ def span_totals(root: Span) -> Dict[str, float]:
     compaction programs and ``compact_gather_ops`` the gather operands
     they were built with (the ``dispatch`` span's ``gather_ops``);
     ``mesh_exchanges`` counts the mesh executor's ``mesh-exchange``
-    spans, ``mesh_exchange_live_bytes`` / ``mesh_exchange_buffer_bytes``
+    spans and ``mesh_exchanges_in_place`` those among them that were
+    satisfied where the rows lay (the span's ``in_place``),
+    ``mesh_exchange_live_bytes`` / ``mesh_exchange_buffer_bytes``
     sum their attributes of those names, and ``mesh_upload_ms`` is the
     time of its two host-to-mesh layings-out (``mesh-scan-upload``,
     ``mesh-scatter``). The root is left out: its time is the
@@ -377,6 +379,9 @@ def span_totals(root: Span) -> Dict[str, float]:
                 "compact_gather_ops", 0) + sp.attrs.get("gather_ops", 0)
         if key == "mesh_exchange":
             out["mesh_exchanges"] = out.get("mesh_exchanges", 0) + 1
+            if sp.attrs.get("in_place"):
+                out["mesh_exchanges_in_place"] = out.get(
+                    "mesh_exchanges_in_place", 0) + 1
             for attr in ("live_bytes", "buffer_bytes"):
                 field = "mesh_exchange_" + attr
                 out[field] = out.get(field, 0) + sp.attrs.get(attr, 0)
