@@ -23,7 +23,7 @@ import numpy as np
 from trino_tpu import fault, memory, program_catalog, telemetry
 from trino_tpu import types as T
 from trino_tpu.exec import kernels as K
-from trino_tpu.exec import shapes, stage
+from trino_tpu.exec import scan_cache, shapes, stage
 from trino_tpu.exec.aggregates import compute_aggregate
 from trino_tpu.expr.compiler import ColumnLayout, compile_expr
 from trino_tpu.expr.ir import AggCall, Call, Cast, InputRef, RowExpression
@@ -272,8 +272,6 @@ class LocalExecutor:
         selectivities, group-by capacities) are dropped with it: they
         were observed against the pre-write data and would otherwise
         persist stale forever."""
-        from trino_tpu.exec import scan_cache
-
         try:
             connector = self.metadata.connector(catalog)
         except KeyError:
@@ -1005,8 +1003,6 @@ class LocalExecutor:
         else:
             # process-wide shared pages: concurrent queries (and other
             # executors over the same connector) reuse one resident copy
-            from trino_tpu.exec import scan_cache
-
             cache = scan_cache.SHARED.table(
                 connector, node.schema, node.table
             )
@@ -1023,7 +1019,7 @@ class LocalExecutor:
         if missing or "" not in cache:
             # columns not resident yet: the connector's read and the
             # host->device copy
-            with telemetry.child_span("upload", table=node.table):
+            with telemetry.child_span("upload", table=node.table) as span:
                 connector = self.metadata.connector(node.catalog)
                 cols = connector.scan(
                     node.schema, node.table, [c for _, c in missing]
@@ -1039,16 +1035,23 @@ class LocalExecutor:
                 else:
                     n = connector.row_count(node.schema, node.table)
                 cap = shapes.bucket(n, site="scan")
+                uploaded = 0  # device bytes this span stores
                 if "" not in cache:
                     mask = np.zeros(cap, dtype=np.bool_)
                     mask[:n] = True
                     cache[""] = jnp.asarray(mask)
+                    uploaded += mask.nbytes
                 for sym, cname in missing:
-                    cache[ckey(sym, cname)] = _scan_column(
+                    col = cache[ckey(sym, cname)] = _scan_column(
                         node.outputs[sym], cols[cname], cap,
                         hashed=sym in hashed_syms,
                     )
+                    uploaded += scan_cache.column_nbytes(col)
                 cache["#rows"] = n
+                if span is not None:
+                    span.attrs["bytes"] = int(uploaded)
+            if connector.cacheable:
+                scan_cache.SHARED.publish()
         names = list(node.assignments)
         columns = [
             cache[ckey(s, c)] for s, c in node.assignments.items()

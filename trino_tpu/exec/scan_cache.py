@@ -43,6 +43,15 @@ def _fingerprint(connector) -> tuple[str, str]:
     return connector_fingerprint(connector)
 
 
+def column_nbytes(col) -> int:
+    """Device bytes of one cached Column: its data and validity lanes."""
+    nbytes = getattr(getattr(col, "data", None), "nbytes", 0) or 0
+    valid = getattr(col, "valid", None)
+    if valid is not None:
+        nbytes += getattr(valid, "nbytes", 0) or 0
+    return nbytes
+
+
 class ScanPageCache:
     """connector fingerprint -> (schema, table) -> per-table page dict.
 
@@ -100,6 +109,7 @@ class ScanPageCache:
         with self._lock:
             self._watched.discard(ident)
             self._by_ident.pop(ident, None)
+        self.publish()
 
     def invalidate(self, connector, schema: str, table: str) -> None:
         """Drop one table's pages (after DML through any executor)."""
@@ -108,6 +118,7 @@ class ScanPageCache:
             ent = self._by_ident.get(ident)
             if ent is not None:
                 ent[1].pop((schema, table), None)
+        self.publish()
 
     def resident_tables(self, connector) -> list[tuple[str, str]]:
         """(schema, table) pairs currently device-resident for one
@@ -119,34 +130,54 @@ class ScanPageCache:
                 return []
             return [k for k, v in ent[1].items() if "" in v]
 
+    def describe(self) -> list[dict]:
+        """One object a resident whole-table page: schema, table, live
+        rows, padded capacity, resident columns and their device bytes
+        (data and validity lanes, and the live mask)."""
+        out = []
+        with self._lock:
+            for _content, tables in self._by_ident.values():
+                for (schema, table), cache in tables.items():
+                    if "" not in cache:
+                        continue
+                    mask = cache[""]
+                    columns = [
+                        v for k, v in cache.items() if k not in ("", "#rows")
+                    ]
+                    out.append({
+                        "schema": schema, "table": table,
+                        "rows": int(cache.get("#rows", 0)),
+                        "capacity": int(getattr(mask, "size", 0)),
+                        "columns": len(columns),
+                        "bytes": int(
+                            (getattr(mask, "nbytes", 0) or 0)
+                            + sum(column_nbytes(v) for v in columns)
+                        ),
+                    })
+        return out
+
     def snapshot(self) -> dict:
         """entries/bytes across every resident table page dict
         (system.runtime.caches feed)."""
-        entries = 0
-        nbytes = 0
-        with self._lock:
-            for _content, tables in self._by_ident.values():
-                for cache in tables.values():
-                    if "" not in cache:
-                        continue
-                    entries += 1
-                    for k, v in cache.items():
-                        if k == "#rows":
-                            continue
-                        if k == "":
-                            nbytes += getattr(v, "nbytes", 0) or 0
-                        else:
-                            nbytes += getattr(
-                                getattr(v, "data", None), "nbytes", 0
-                            ) or 0
-                            valid = getattr(v, "valid", None)
-                            if valid is not None:
-                                nbytes += getattr(valid, "nbytes", 0) or 0
-        return {"entries": entries, "bytes": nbytes}
+        tables = self.describe()
+        return {"entries": len(tables),
+                "bytes": sum(t["bytes"] for t in tables)}
+
+    def publish(self) -> None:
+        """Set the residency gauges from what is held now. Called where
+        a table's page is stored (the scan that uploaded columns) or
+        dropped; nothing polls."""
+        from trino_tpu import telemetry
+
+        with self._lock:  # two stores publish in the order they count
+            snap = self.snapshot()
+            telemetry.SCAN_CACHE_RESIDENT_BYTES.set(snap["bytes"])
+            telemetry.SCAN_CACHE_RESIDENT_TABLES.set(snap["entries"])
 
     def clear(self) -> None:
         with self._lock:
             self._by_ident.clear()
+        self.publish()
 
 
 class SplitBatchCache:

@@ -34,6 +34,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from trino_tpu import profiler, session_properties as sp, telemetry
 from trino_tpu.engine import QueryResult, QueryRunner
+from trino_tpu.exec import scan_cache
 from trino_tpu.tracker import QueryTracker
 
 __all__ = ["Coordinator"]
@@ -281,6 +282,7 @@ class Coordinator:
                         "coordinator": True,
                         "starting": False,
                         **profiler.device_info(),
+                        "resident_tables": scan_cache.SHARED.describe(),
                     })
                     return
                 if self.path == "/v1/queries":

@@ -27,6 +27,7 @@ from trino_tpu import (
     fault, membership as membership_mod, profiler, telemetry,
 )
 from trino_tpu.engine import QueryRunner
+from trino_tpu.exec import scan_cache
 from trino_tpu.plan.serde import plan_from_json
 
 __all__ = ["WorkerServer"]
@@ -402,6 +403,7 @@ class WorkerServer:
                     mesh = worker.runner.mesh
                     self._send(200, {
                         **profiler.device_info(),
+                        "resident_tables": scan_cache.SHARED.describe(),
                         "state": worker.lifecycle_state(),
                         "activeTasks": worker._active_tasks,
                         "mesh": mesh is not None,

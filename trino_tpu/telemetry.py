@@ -737,6 +737,12 @@ SCAN_CACHE_HITS = REGISTRY.counter(
 SCAN_CACHE_MISSES = REGISTRY.counter(
     "trino_scan_cache_misses_total",
     "Table-scan page materializations that had to hit the connector")
+SCAN_CACHE_RESIDENT_BYTES = REGISTRY.gauge(
+    "trino_scan_cache_resident_bytes",
+    "Device bytes of the whole-table pages the shared scan-page cache holds (data, validity, live masks)")
+SCAN_CACHE_RESIDENT_TABLES = REGISTRY.gauge(
+    "trino_scan_cache_resident_tables",
+    "Tables with a whole-table page resident in the shared scan-page cache")
 RESULT_CACHE_HITS = REGISTRY.counter(
     "trino_result_cache_hits_total",
     "Statements served byte-identical from a semantic result cache")
