@@ -99,3 +99,37 @@ def test_local_df_multi_key(runner, oracle):
         "and l2.l_orderkey < 300"
     )
     check(runner, oracle, sql)
+
+
+def test_mesh_df_over_a_small_build_counts_and_keeps_the_same_rows(
+        monkeypatch, oracle):
+    """A dynamic filter whose (replicated) build side is a handful of
+    rows ranks each probe shard by compare-and-count (ISSUE 42): its
+    ``dispatch`` span says so, and rows, kept count and answer are what
+    the search by sort gives."""
+    from trino_tpu.exec import kernels as K
+    from trino_tpu.parallel.core import make_mesh
+
+    monkeypatch.setattr(LocalExecutor, "DF_MIN_PROBE", 1024)
+    sql = (
+        "select count(*), sum(l_quantity) from lineitem, orders "
+        "where l_orderkey = o_orderkey and o_orderkey < 500"
+    )
+    seen, small = {}, K.JOIN_SMALL_BUILD
+    for search, limit in (("count", small), ("sort", -1)):
+        monkeypatch.setattr(K, "JOIN_SMALL_BUILD", limit)
+        K.join_ranges.clear_cache()  # the constant is read while tracing
+        try:
+            runner = QueryRunner.tpch("tiny", mesh=make_mesh(4))
+            result = check(runner, oracle, sql)
+        finally:
+            K.join_ranges.clear_cache()
+        (df,) = [
+            sp.attrs for sp in result.trace.root.walk()
+            if sp.attrs.get("program") == "mesh_dynamic_filter"
+        ]
+        assert df["join_search"] == search
+        assert df["build_rows"] <= small  # the few orders, replicated
+        seen[search] = (result.rows, runner.executor.df_log[-1])
+    assert seen["count"] == seen["sort"]
+    assert seen["count"][1]["rows_kept"] < 0.3 * seen["count"][1]["rows_in"]

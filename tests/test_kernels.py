@@ -6,6 +6,7 @@ kernels are driven directly with synthetic arrays and checked against
 straightforward numpy computations.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -243,3 +244,76 @@ def test_join_ranges_brute_force():
         want = int(((bk == pk[i]) & bl).sum()) if pl[i] else 0
         assert cnt[i] == want
         assert (bk[order[lo[i]:lo[i] + cnt[i]]] == pk[i]).all()
+
+
+# ---- join_ranges: the two searches, bit for bit (PR 42) -------------------
+
+_TOP = np.uint64(0xFFFFFFFFFFFFFFFF)
+
+
+def _join_ranges_ref(bk, bl, pk, pl):
+    """``join_ranges`` in numpy: the build argsorted by (dead, key, row),
+    its dead tail pinned to the top word, each probe's left and right
+    edge clamped to the live prefix."""
+    order = np.lexsort((bk, ~bl))
+    n_live = int(bl.sum())
+    sk = np.where(np.arange(len(bk)) < n_live, bk[order], _TOP)
+    lo = np.minimum(np.searchsorted(sk, pk, "left"), n_live)
+    hi = np.minimum(np.searchsorted(sk, pk, "right"), n_live)
+    return (order.astype(np.int32), lo.astype(np.int32),
+            np.where(pl, hi - lo, 0).astype(np.int32))
+
+
+def _join_case(case: str, nb: int, rng):
+    n_p = 20_000 if case == "large_probe" else 700  # > 16,384: _merge_rank
+    bk = rng.integers(0, 3 * nb + 3, nb).astype(np.uint64)
+    pk = rng.integers(0, 3 * nb + 6, n_p).astype(np.uint64)
+    bl, pl = np.ones(nb, bool), np.ones(n_p, bool)
+    if case == "duplicate_build_keys":
+        bk = rng.integers(0, max(nb // 8, 2), nb).astype(np.uint64)
+        pk = rng.integers(0, max(nb // 8, 2) + 2, n_p).astype(np.uint64)
+    elif case == "dead_build_rows":
+        bl = rng.random(nb) < 0.6
+        bk[~bl] = rng.choice(pk, int((~bl).sum()))  # dead keys that "match"
+    elif case == "dead_probe_rows":
+        pl = rng.random(n_p) < 0.5
+    elif case == "no_live_build_row":
+        bl[:] = False
+    elif case == "keys_0_and_top":
+        bk[: nb // 2] = rng.choice([np.uint64(0), _TOP], nb // 2)
+        bl = rng.random(nb) < 0.8  # live and dead rows hold the top word
+        pk[:40] = rng.choice([np.uint64(0), _TOP], 40)
+    else:
+        bl, pl = rng.random(nb) < 0.9, rng.random(n_p) < 0.9
+    return bk, bl, pk, pl
+
+
+@pytest.mark.parametrize("cap", [
+    pytest.param(lambda limit: 1, id="1"),
+    pytest.param(lambda limit: 64, id="64"),
+    pytest.param(lambda limit: limit, id="JOIN_SMALL_BUILD"),
+    pytest.param(lambda limit: limit + 1, id="JOIN_SMALL_BUILD+1"),
+    pytest.param(lambda limit: 2 * limit, id="2xJOIN_SMALL_BUILD"),
+])
+@pytest.mark.parametrize("case", [
+    "duplicate_build_keys", "dead_build_rows", "dead_probe_rows",
+    "no_live_build_row", "keys_0_and_top", "large_probe",
+])
+def test_join_ranges_two_searches_agree_with_numpy(case, cap, monkeypatch):
+    nb = cap(K.JOIN_SMALL_BUILD)
+    rng = np.random.default_rng(nb * 7 + len(case))
+    bk, bl, pk, pl = _join_case(case, nb, rng)
+    args = tuple(jnp.asarray(x) for x in (bk, bl, pk, pl))
+    want = _join_ranges_ref(bk, bl, pk, pl)
+    got = {"chosen": tuple(map(_np, K.join_ranges(*args)))}
+    # the choice reads the build's capacity, and it shows in the program
+    scatters = "scatter" in str(jax.make_jaxpr(K.join_ranges)(*args))
+    assert K.join_search(nb) == (
+        "count" if nb <= K.JOIN_SMALL_BUILD else "sort")
+    assert scatters == (K.join_search(nb) == "sort" and len(pk) > 16384)
+    for search, limit in (("count", 1 << 30), ("sort", -1)):
+        monkeypatch.setattr(K, "JOIN_SMALL_BUILD", limit)
+        got[search] = tuple(map(_np, jax.jit(K.join_ranges.__wrapped__)(*args)))
+    for name, (order, lo, cnt) in got.items():
+        for what, a, b in zip(("order", "lo", "cnt"), (order, lo, cnt), want):
+            assert a.dtype == b.dtype and np.array_equal(a, b), (name, what)

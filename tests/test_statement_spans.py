@@ -33,6 +33,7 @@ FLAT_FIELDS = (
     "upload_ms", "to_rows_ms", "respond_ms", "rows_out_ms",
     "direct_groupbys", "sorted_groupbys", "streamed_groupbys",
     "compactions", "compact_gather_ops",
+    "small_build_joins", "sorted_joins",
 )
 PROGRAM = re.compile(
     r"^(chain_[A-Za-z_]+|join_count|join_bounds|join_expand|semi_join"
@@ -239,6 +240,51 @@ def test_query_rows_count_compactions_and_their_gather_operands(
         assert row["compactions"] >= 1
         assert row["compactions"] <= row["compact_gather_ops"] < sum(
             sp["attrs"]["columns"] for sp in spans)
+
+
+#: a join built over the whole of ``orders`` (16,384 rows at ``tiny``)
+BIG_BUILD_JOIN = ("select count(*) from lineitem, orders"
+                  " where l_orderkey = o_orderkey")
+
+
+@pytest.mark.parametrize("sql,small,by_sort", [
+    (QUERIES["q01"], 0, 0), (QUERIES["q06"], 0, 0),
+    # Q18: its semi join and its ``lineitem`` join are built over the
+    # few orders that pass the HAVING; its ``customer`` join over
+    # ``customer``, which at ``tiny`` is 1,536 rows and counts too (at
+    # SF1 it is 196,608 and sorts: the row reads 2 and 1 there)
+    (QUERIES["q18"], 3, 0), (BIG_BUILD_JOIN, 0, 1),
+])
+def test_query_rows_count_joins_by_their_search(coord, sql, small, by_sort):
+    """A join whose build side is a handful of rows ranks its probe by
+    compare-and-count, any other by sort (``kernels.join_search``,
+    ISSUE 42): the ``dispatch`` span of a program that holds a
+    ``join_ranges`` says which, the row sums them, and
+    ``trino_joins_total`` moves by the same counts; a warm dispatch
+    reports the same."""
+    from trino_tpu.exec import kernels as K
+
+    for _ in range(2):
+        before = {s: telemetry.JOINS.value(search=s) for s in ("count", "sort")}
+        qid, _ = serve(coord, sql)
+        row = row_of(coord, qid)
+        assert (row["small_build_joins"], row["sorted_joins"]) == (
+            small, by_sort), row
+        for search, n in (("count", small), ("sort", by_sort)):
+            assert telemetry.JOINS.value(search=search) - before[search] == n
+        noted = [
+            sp["attrs"] for sp, _ in walk(get(coord, f"/v1/query/{qid}")["spans"])
+            if sp["name"] == "dispatch" and "join_search" in sp["attrs"]
+        ]
+        assert len(noted) == small + by_sort
+        for attrs in noted:
+            assert attrs["program"] in ("join_count", "semi_join")
+            assert attrs["join_search"] == K.join_search(attrs["build_rows"])
+            assert (attrs["join_search"] == "count") == (
+                attrs["build_rows"] <= K.JOIN_SMALL_BUILD)
+    text = urllib.request.urlopen(coord.uri + "/v1/metrics").read().decode()
+    assert 'trino_joins_total{search="sort"}' in text or not by_sort
+    assert 'trino_joins_total{search="count"}' in text or not small
 
 
 def test_protocol_stats_carry_queued_and_planning_time(coord):

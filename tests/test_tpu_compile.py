@@ -222,6 +222,24 @@ def test_search_by_merged_sort(one_chip, no_compile_cache):
     assert _sorts(lowered) == [(1, False)]
 
 
+def test_join_ranges_by_count_at_lineitem_sf5(one_chip, no_compile_cache):
+    """Q18's ``lineitem`` join at SF5 (ISSUE 42): a 33,554,432-row probe
+    against a build of 512 rows ranks by compare-and-count — the only
+    sorts are the build's two, the probe is neither sorted nor
+    scattered, and the ``[build, probe]`` compare is fused into its
+    reduction: the program's temporaries stay a few words a probe row,
+    nowhere near the 17 G cells of the product."""
+    n, b = 33_554_432, 512
+    assert K.join_search(b) == "count"
+    lowered, compiled = _compile(
+        K.join_ranges.__wrapped__, one_chip, ((b,), jnp.uint64),
+        ((b,), jnp.bool_), ((n,), jnp.uint64), ((n,), jnp.bool_),
+    )
+    assert _sorts(lowered) == [(1, False)] * 2
+    assert "stablehlo.scatter" not in lowered.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes <= n * 16
+
+
 def test_q6_scan_filter_sum_at_lineitem_sf1(one_chip, no_compile_cache):
     """Q6's whole chain: compare, multiply, one masked global sum."""
 

@@ -23,6 +23,16 @@ per-column body the executor had until PR 36 against
 and the gathers both are made of, each alone — the table of PERF.md,
 PR 36, which set ``kernels.GATHER_STACK_WORDS``.
 
+``--shape joinrank``: ``kernels.join_ranges`` for a build side of a
+few rows against a probe of millions (Q18's ``lineitem`` join and semi
+join: probe capacities 6,291,456 at SF1 and 33,554,432 at SF5, builds
+of 64 to 16,384 rows): the search by sort (``searchsorted`` ->
+``_merge_rank``) against compare-and-count (``_count_ranges``), bit
+for bit, then the pieces of the sort path each alone, the count
+kernel's variants, and ``jnp.searchsorted(method="scan")`` over the
+small haystack — the table of PERF.md, PR 42, which set
+``kernels.JOIN_SMALL_BUILD``.
+
 Run it on the chip: ``chiprun -- python tools/groupby_crossover.py``.
 On a CPU it checks that the paths agree and prints host times, which
 are no device metric.
@@ -341,6 +351,148 @@ def q3compact_main(a) -> int:
     return 0 if ok else 1
 
 
+JOINRANK_PROBES = (6_291_456, 33_554_432)  # lineitem's capacity, SF1 / SF5
+JOINRANK_BUILDS = (64, 256, 1024, 4096, 16384)
+
+
+def joinrank_inputs(probe: int, build: int, seed: int):
+    """Q18's ``lineitem`` join in shape: order keys of 1-7 lines each
+    as the probe (live rows a prefix), and as the build a sample of
+    those keys, three quarters of the capacity live, a few twice."""
+    rng = np.random.default_rng(seed)
+    n_live = probe * 6_001_215 // 6_291_456
+    pk = np.sort(rng.integers(1, n_live // 4 * 32, n_live, dtype=np.int64))
+    pk = np.concatenate([pk, np.zeros(probe - n_live, np.int64)])
+    bk = rng.choice(pk[:n_live], build)
+    bk[: build // 16] = bk[build // 16: 2 * (build // 16)]
+    return (jnp.asarray(bk.astype(np.uint64)),
+            jnp.asarray(rng.random(build) < 0.75),
+            jnp.asarray(pk.astype(np.uint64)),
+            jnp.asarray(np.arange(probe) < n_live))
+
+
+def _join_ranges_by(search: str):
+    """``join_ranges``' body traced with the search forced."""
+    kept = K.JOIN_SMALL_BUILD
+
+    def body(bk, bl, pk, pl):
+        K.JOIN_SMALL_BUILD = (1 << 30) if search == "count" else -1
+        try:
+            return K.join_ranges.__wrapped__(bk, bl, pk, pl)
+        finally:
+            K.JOIN_SMALL_BUILD = kept
+
+    return body
+
+
+def _count_two_sums(sk, pk):
+    """The count kernel as two reductions of the 64-bit compares."""
+    lo = jnp.sum(sk[:, None] < pk[None, :], axis=0, dtype=jnp.int32)
+    return lo, lo + jnp.sum(
+        sk[:, None] == pk[None, :], axis=0, dtype=jnp.int32)
+
+
+def _count_low_words(sk, pk):
+    """What a key known to fit 32 bits would cost: the low words alone
+    (right only where every high word is equal)."""
+    sl, pl = sk.astype(jnp.uint32), pk.astype(jnp.uint32)
+    return K._packed_counts(
+        sl[:, None] < pl[None, :], sl[:, None] == pl[None, :])
+
+
+def joinrank_pieces(bk, bl, pk, pl):
+    """name -> (fn, args): what the sort path's ``join_ranges`` is made
+    of at this shape, each alone and on the data the step before it
+    leaves, then the count kernel's variants and the binary scan."""
+    n, m = pk.shape[0], bk.shape[0]
+    sk = jnp.sort(jnp.where(bl, bk, jnp.uint64(0xFFFFFFFFFFFFFFFF)))
+    both = jnp.concatenate([pk, sk])
+    low = both & jnp.uint64(0xFFFFFFFF)
+    p1 = jax.jit(lambda w: K.packed_argsort(w, 32))(low)
+    high = jax.jit(lambda k, p: (k >> jnp.uint64(32))[p])(both, p1)
+    p2 = jax.jit(lambda w: K.packed_argsort(w, 32))(high)
+    perm = jax.jit(lambda a, b: a[b])(p1, p2)
+    is_hay = perm >= n
+    dest = jnp.where(is_hay, n, perm)
+    lo = jax.jit(lambda a, v: K.searchsorted(a, v))(sk, pk)
+    at = jnp.clip(lo, 0, m - 1)
+    run_end = jnp.arange(1, m + 1, dtype=jnp.int32)
+    return {
+        "build_argsort (packed_argsort of the build, 64 bits)":
+            (lambda k, l: K.packed_argsort(k, 64, last=~l), (bk, bl)),
+        "merge_rank whole (searchsorted of the probe in the build)":
+            (lambda a, v: K.searchsorted(a, v), (sk, pk)),
+        "merge_rank: uint64 sort of the low words with the row index":
+            (lambda w: K.packed_argsort(w, 32), (low,)),
+        "merge_rank: uint64 gather of the high words into that order":
+            (lambda k, p: (k >> jnp.uint64(32))[p], (both, p1)),
+        "merge_rank: uint64 sort of the high words with the row index":
+            (lambda w: K.packed_argsort(w, 32), (high,)),
+        "merge_rank: int32 gather composing the two permutations":
+            (lambda a, b: a[b], (p1, p2)),
+        "merge_rank: int32 cumsum of the haystack flags":
+            (lambda f: jnp.cumsum(f.astype(jnp.int32)), (is_hay,)),
+        "merge_rank: int32 scatter back to probe order":
+            (lambda d, a: jnp.zeros((n,), jnp.int32).at[d].set(
+                a, mode="drop"), (dest, perm)),
+        "at gather: sorted_key[at] (uint64 out of the build)":
+            (lambda t, a: t[a], (sk, at)),
+        "at gather: run_end[at] (int32 out of the build)":
+            (lambda t, a: t[a], (run_end, at)),
+        "count kernel (kept): 32-bit halves, one packed int32 sum":
+            (K._count_ranges, (sk, pk)),
+        "count kernel: two sums of 64-bit compares":
+            (_count_two_sums, (sk, pk)),
+        "count kernel: low words alone (a 32-bit key)":
+            (_count_low_words, (sk, pk)),
+        "binary scan: jnp.searchsorted(method='scan') left and right":
+            (lambda a, v: (jnp.searchsorted(a, v, method="scan"),
+                           jnp.searchsorted(a, v, side="right",
+                                            method="scan")), (sk, pk)),
+    }
+
+
+def joinrank_main(a) -> int:
+    dev = jax.devices()[0]
+    rec = {"platform": dev.platform, "device_kind": dev.device_kind,
+           "shape": "joinrank", "runs": [], "pieces": []}
+    ok = True
+    probes = [int(x) for x in a.probes.split(",")]
+    builds = [int(x) for x in a.builds.split(",")]
+    sorted_at = {int(x) for x in a.sorted_builds.split(",") if x}
+    for probe in probes:
+        for build in builds:
+            args = joinrank_inputs(probe, build, a.seed + build)
+            got, t = timed(_join_ranges_by("count"), args, a.reps)
+            run = {"probe": probe, "build": build, "search": "count", **t}
+            if got is not None:
+                run["matches"] = int(jnp.sum(got[2]))
+            rec["runs"].append(run)
+            print(run, flush=True)
+            if build in sorted_at:
+                ref, t = timed(_join_ranges_by("sort"), args, a.reps)
+                agree = None not in (got, ref) and all(
+                    np.array_equal(x, y) for x, y in zip(got, ref))
+                ok &= agree
+                rec["runs"].append({"probe": probe, "build": build,
+                                    "search": "sort", "agrees": agree, **t})
+                print(rec["runs"][-1], flush=True)
+                del ref
+            del got
+            whole = build == builds[0]
+            for name, (fn, fargs) in joinrank_pieces(*args).items():
+                # the sort path's pieces do not depend on the build's
+                # size: once a probe; the count kernel and the scan do
+                if not whole and not name.startswith(("count", "binary")):
+                    continue
+                _out, t = timed(fn, fargs, a.reps)
+                rec["pieces"].append(
+                    {"probe": probe, "build": build, "piece": name, **t})
+                print(rec["pieces"][-1], flush=True)
+            write(rec, a.out)  # a later shape may exhaust the call
+    return 0 if ok else 1
+
+
 def same(a, b) -> bool:
     """Bit for bit on the occupied prefix (values, validity, owners),
     and no order fault on either side."""
@@ -364,8 +516,15 @@ def same(a, b) -> bool:
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--shape", choices=("q1", "q18", "q3compact"),
+    ap.add_argument("--shape", choices=("q1", "q18", "q3compact", "joinrank"),
                     default="q1")
+    ap.add_argument("--probes", default=",".join(map(str, JOINRANK_PROBES)),
+                    help="probe capacities of --shape joinrank")
+    ap.add_argument("--builds", default=",".join(map(str, JOINRANK_BUILDS)),
+                    help="build capacities of --shape joinrank")
+    ap.add_argument("--sorted-builds", default="64,1024,16384",
+                    help="build capacities at which --shape joinrank "
+                         "times the sort path too")
     ap.add_argument("--capacity", type=int, default=Q18_CAPACITY,
                     help="group-table capacity of --shape q18")
     ap.add_argument("--rows", type=int, default=6_291_456)
@@ -384,6 +543,10 @@ def main() -> int:
         if a.out == ap.get_default("out"):
             a.out = "chiprun_out/groupby_crossover_q3compact.json"
         return q3compact_main(a)
+    if a.shape == "joinrank":
+        if a.out == ap.get_default("out"):
+            a.out = "chiprun_out/groupby_crossover_joinrank.json"
+        return joinrank_main(a)
     dev = jax.devices()[0]
     rec = {"platform": dev.platform, "device_kind": dev.device_kind,
            "rows": a.rows, "capacity": CAPACITY, "runs": []}

@@ -66,6 +66,8 @@ __all__ = [
     "slot_reduce",
     "assign_groups",
     "sort_perm",
+    "JOIN_SMALL_BUILD",
+    "join_search",
     "join_ranges",
     "expand_matches",
     "range_any",
@@ -343,7 +345,12 @@ def searchsorted(a: jnp.ndarray, v: jnp.ndarray, side: str = "left") -> jnp.ndar
     0.22 s but compiled 74 s (two stable argsorts + two scatters per
     call). Few queries take 'scan'; many take one merged single-operand
     sort (``_merge_rank``). Shapes are static under jit, so the choice
-    is made at trace time."""
+    is made at trace time — from the QUERY count alone: the haystack's
+    size is not looked at here. The one caller whose haystack can be a
+    handful of rows under millions of queries, ``join_ranges``, decides
+    before it calls (``join_search``: a build of at most
+    ``JOIN_SMALL_BUILD`` rows is ranked by compare-and-count and never
+    reaches this function)."""
     if v.size <= 16384:
         return jnp.searchsorted(a, v, side=side, method="scan")
     return _merge_rank(a, v.ravel(), side).reshape(v.shape)
@@ -1022,6 +1029,67 @@ def _float_sort_bits(data: jnp.ndarray) -> jnp.ndarray:
 
 # ---- equi-join -------------------------------------------------------------
 
+#: largest build capacity whose probe is ranked by compare-and-count
+#: (``_count_ranges``) instead of by sorting probe and build together
+#: (``searchsorted`` -> ``_merge_rank``). The count is linear in probe
+#: x build — on a v5e 2.9-3.0 ps a pair: 2.3 / 6.0 / 19 / 73 / 287 ms
+#: at builds of 64 / 256 / 1,024 / 4,096 / 16,384 rows under a
+#: 6,291,456-row probe, 10 / 31 / 101 / 392 / 1,558 ms under 33,554,432
+#: — where the sort path costs 158 ms (a 64-row build) to 287-309 ms
+#: (1,024 and up: its two ``[at]`` gathers turn into index walks) at
+#: 6.29 M and 1,806 to 2,689-2,814 ms at 33.5 M (its permutation
+#: gathers, not its sorts, grow 17-fold), so the two meet near 17,000
+#: rows at the smaller probe and near 30,000 at the larger
+#: (tools/groupby_crossover.py --shape joinrank; PERF.md, PR 42). 4,096
+#: keeps compare-and-count 4.0 and 6.9 times cheaper there, for probes
+#: and chips that table did not measure; the crossing moves up with
+#: the probe, so the build's capacity alone decides.
+JOIN_SMALL_BUILD = 4096
+
+
+def join_search(n_build: int) -> str:
+    """Which search ``join_ranges`` is built with for a build side of
+    ``n_build`` rows (its static capacity): ``"count"`` or ``"sort"``."""
+    return "count" if n_build <= JOIN_SMALL_BUILD else "sort"
+
+
+def _halves(x: jnp.ndarray):
+    return (x >> jnp.uint64(32)).astype(jnp.uint32), x.astype(jnp.uint32)
+
+
+def _packed_counts(less: jnp.ndarray, equal: jnp.ndarray):
+    """``(lo, hi)`` from the ``[build rows, probe rows]`` compares of a
+    sorted build with each probe key: a build key being less than or
+    equal to a probe but never both, one int32 sum over the build
+    carries both counts (the equal ones above bit 16), and the select
+    is fused into it — the product is never materialized (as
+    ``_per_slot``)."""
+    if less.shape[0] >= 1 << 15:
+        raise ValueError("_packed_counts: a count needs its 16 bits")
+    both = jnp.sum(
+        jnp.where(less, jnp.int32(1),
+                  jnp.where(equal, jnp.int32(1 << 16), jnp.int32(0))),
+        axis=0, dtype=jnp.int32,
+    )
+    lo = both & jnp.int32(0xFFFF)
+    return lo, lo + (both >> jnp.int32(16))
+
+
+def _count_ranges(sorted_key: jnp.ndarray, probe_key: jnp.ndarray):
+    """``(lo, hi)`` of every probe key in a sorted build of a few rows
+    by compare-and-count: ``lo[i] = #{j : sorted_key[j] < probe_key[i]}``
+    — which is ``searchsorted(sorted_key, probe_key, "left")`` — and
+    ``hi[i] = lo[i] + #{j : sorted_key[j] == probe_key[i]}``, the keys
+    compared as 32-bit halves (the lanes the chip has)."""
+    sh, sl = _halves(sorted_key)
+    ph, pl = _halves(probe_key)
+    high_eq = sh[:, None] == ph[None, :]
+    return _packed_counts(
+        (sh[:, None] < ph[None, :]) | (high_eq & (sl[:, None] < pl[None, :])),
+        high_eq & (sl[:, None] == pl[None, :]),
+    )
+
+
 @jax.jit
 def join_ranges(
     build_key: jnp.ndarray,
@@ -1039,6 +1107,13 @@ def join_ranges(
     Returns (order, lo, cnt): ``order`` sorts the build side by key
     (dead rows last), ``lo[i]``/``cnt[i]`` give each probe row's match
     range inside the sorted build side.
+
+    How the probe is ranked in the sorted build is chosen from the
+    build's static capacity (``join_search``): at most
+    ``JOIN_SMALL_BUILD`` rows, by compare-and-count — linear in the
+    probe, no sort, gather, prefix sum or scatter of probe size; above
+    it, by ``searchsorted``. Both give the same three arrays bit for
+    bit.
     """
     # sort build: dead rows pushed past every live key
     n_build = build_key.shape[0]
@@ -1051,20 +1126,24 @@ def join_ranges(
     sorted_key = jnp.where(
         pos < n_build_live, build_key[order], jnp.uint64(0xFFFFFFFFFFFFFFFF)
     )
-    lo = searchsorted(sorted_key, probe_key, side="left")
-    # the right edge without a second search: each build position knows
-    # where its run of equal keys ends (suffix-min over the run-last
-    # positions), and a probe that found its key at ``lo`` takes it
-    last_of_run = jnp.concatenate(
-        [sorted_key[1:] != sorted_key[:-1], jnp.ones((1,), jnp.bool_)]
-    ) if n_build else jnp.zeros((0,), jnp.bool_)
-    run_end = jax.lax.cummin(
-        jnp.where(last_of_run, pos + 1, n_build).astype(jnp.int32),
-        reverse=True,
-    )
-    at = jnp.clip(lo, 0, max(n_build - 1, 0))
-    found = (lo < n_build) & (sorted_key[at] == probe_key)
-    hi = jnp.where(found, run_end[at], lo)
+    if join_search(n_build) == "count":
+        lo, hi = _count_ranges(sorted_key, probe_key)
+    else:
+        lo = searchsorted(sorted_key, probe_key, side="left")
+        # the right edge without a second search: each build position
+        # knows where its run of equal keys ends (suffix-min over the
+        # run-last positions), and a probe that found its key at ``lo``
+        # takes it
+        last_of_run = jnp.concatenate(
+            [sorted_key[1:] != sorted_key[:-1], jnp.ones((1,), jnp.bool_)]
+        )
+        run_end = jax.lax.cummin(
+            jnp.where(last_of_run, pos + 1, n_build).astype(jnp.int32),
+            reverse=True,
+        )
+        at = jnp.clip(lo, 0, n_build - 1)
+        found = (lo < n_build) & (sorted_key[at] == probe_key)
+        hi = jnp.where(found, run_end[at], lo)
     lo = jnp.minimum(lo, n_build_live)
     hi = jnp.minimum(hi, n_build_live)
     cnt = jnp.where(probe_live, hi - lo, 0)

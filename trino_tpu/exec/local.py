@@ -147,6 +147,15 @@ class _dispatching:
         if self._outer.span is not None:
             self._outer.span.attrs.update(attrs)
 
+    def note_join(self, n_build: int):
+        """The program holds a ``kernels.join_ranges`` over a build
+        side of ``n_build`` rows: note which search that was built with
+        (``join_search``: ``count`` / ``sort``; ``build_rows``) and
+        count it."""
+        search = K.join_search(n_build)
+        self.note(join_search=search, build_rows=n_build)
+        telemetry.JOINS.inc(search=search)
+
     def __exit__(self, *exc):
         if self._inner is not None:
             self._inner.__exit__(*exc)
@@ -1945,7 +1954,8 @@ class LocalExecutor:
             self._layout_sig(probe), self._layout_sig(build),
         )
         fn = self._jit_cache.get(key)
-        with _dispatching("join_count", fn is None):
+        with _dispatching("join_count", fn is None) as dispatch:
+            dispatch.note_join(build.capacity)
             if fn is None:
                 crit = list(criteria)
                 kinds = self._join_key_kinds(probe, build, crit)
@@ -2716,7 +2726,8 @@ class LocalExecutor:
                 self._layout_sig(source), self._layout_sig(filt),
             )
             fn = self._jit_cache.get(key)
-            with _dispatching("semi_join", fn is None):
+            with _dispatching("semi_join", fn is None) as dispatch:
+                dispatch.note_join(filt.capacity)
                 if fn is None:
                     crit = list(node.keys)
                     kinds = self._join_key_kinds(source, filt, crit)

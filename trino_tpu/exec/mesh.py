@@ -257,15 +257,20 @@ class MeshExecutor(LocalExecutor):
         )
 
     def _run(
-        self, prog, miss: bool, *args, tag: str | None = None, **note
+        self, prog, miss: bool, *args, tag: str | None = None,
+        join_build: int | None = None, **note
     ):
         """``prog(*args)`` under a ``dispatch`` span that carries the
         program's name (with a ``build_trace`` child on a jit-cache
-        miss, as the local executor's) and ``note``'s attributes,
-        through ``_attempt`` where the site is a retry unit."""
+        miss, as the local executor's), ``note``'s attributes and,
+        where the program holds a ``kernels.join_ranges``, the search
+        it was built with for a build of ``join_build`` rows, through
+        ``_attempt`` where the site is a retry unit."""
         with _dispatching(prog.__name__, miss) as dispatch:
             if note:
                 dispatch.note(**note)
+            if join_build is not None:
+                dispatch.note_join(join_build)
             if tag is None:
                 return prog(*args)
             return self._attempt(tag, lambda: prog(*args))
@@ -1252,7 +1257,11 @@ class MeshExecutor(LocalExecutor):
             )
             self._mesh_jit_cache[key_b] = prog_b
         keep, n_in_dev, n_keep_dev = self._run(
-            prog_b, miss, *leaves, tag="dynamic-filter"
+            prog_b, miss, *leaves, tag="dynamic-filter",
+            join_build=(
+                build.capacity if replicated
+                else build.shard_capacity * build.n_shards  # all-gathered
+            ),
         )
         with telemetry.child_span("host_sync", site="mesh_dynamic_filter"):
             n_in, n_keep = jax.device_get((n_in_dev, n_keep_dev))
@@ -1517,7 +1526,9 @@ class MeshExecutor(LocalExecutor):
         cols = _columns_from_leaves(out, meta, a.columns)
         return ShardedPage(list(a.names), cols, out[-1], a.n_shards)
 
-    def _match_count_capacity(self, key, prelude, in_specs, leaves) -> int:
+    def _match_count_capacity(
+        self, key, prelude, in_specs, leaves, b_cap: int
+    ) -> int:
         """Phase A of a distributed join: per-shard match totals, one
         host sync, padded output capacity (the build-side barrier)."""
         prog = self._mesh_jit_cache.get(key)
@@ -1534,7 +1545,9 @@ class MeshExecutor(LocalExecutor):
 
             prog = self._shard_jit(fa, "join_count", in_specs, PS(axis))
             self._mesh_jit_cache[key] = prog
-        totals_dev = self._run(prog, miss, *leaves, tag="join-count")
+        totals_dev = self._run(
+            prog, miss, *leaves, tag="join-count", join_build=b_cap
+        )
         with telemetry.child_span("host_sync", site="mesh_join_total"):
             totals = jax.device_get(totals_dev)
         return pad_capacity(int(max(totals.max(), 1)))
@@ -1585,7 +1598,7 @@ class MeshExecutor(LocalExecutor):
             self._join_sig(probe, False), self._join_sig(build, replicated),
         )
         out_cap = self._match_count_capacity(
-            key_a, prelude, in_specs, p_leaves + b_leaves
+            key_a, prelude, in_specs, p_leaves + b_leaves, b_cap
         )
 
         # reserve the per-device join working set (probe shard + build
@@ -1732,7 +1745,8 @@ class MeshExecutor(LocalExecutor):
             )
             self._mesh_jit_cache[key_b] = prog_b
         outs, mask = self._run(
-            prog_b, miss, *p_leaves, *b_leaves, tag="join-expand"
+            prog_b, miss, *p_leaves, *b_leaves, tag="join-expand",
+            join_build=b_cap,
         )
         cols, i = [], 0
         for s, from_probe, has_valid in out_meta:
@@ -1890,7 +1904,7 @@ class MeshExecutor(LocalExecutor):
                 self._join_sig(sp, False), self._join_sig(filt, True),
             )
             out_cap = self._match_count_capacity(
-                key_a, prelude, in_specs, p_leaves + b_leaves
+                key_a, prelude, in_specs, p_leaves + b_leaves, filt.capacity
             )
 
         key_b = (
@@ -1942,7 +1956,8 @@ class MeshExecutor(LocalExecutor):
             prog_b = self._shard_jit(fb, "semi_join", in_specs, PS(axis))
             self._mesh_jit_cache[key_b] = prog_b
         matched = self._run(
-            prog_b, miss, *p_leaves, *b_leaves, tag="semi-join"
+            prog_b, miss, *p_leaves, *b_leaves, tag="semi-join",
+            join_build=filt.capacity,
         )
         from trino_tpu import types as T
 

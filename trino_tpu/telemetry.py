@@ -344,6 +344,10 @@ class child_span:
 _COUNTED = {"host_sync": "host_syncs", "dispatch": "dispatches"}
 
 
+#: row field counting the programs of each ``join_search``
+_JOIN_SEARCH_FIELDS = {"count": "small_build_joins", "sort": "sorted_joins"}
+
+
 def span_totals(root: Span) -> Dict[str, float]:
     """A sealed tree as flat numbers: ``<name>_ms`` summed over the
     spans of each name, a name being the span's first word with ``-``
@@ -355,6 +359,9 @@ def span_totals(root: Span) -> Dict[str, float]:
     ``dispatch`` span's ``groupbys``); ``compactions`` counts the
     compaction programs and ``compact_gather_ops`` the gather operands
     they were built with (the ``dispatch`` span's ``gather_ops``);
+    ``small_build_joins`` / ``sorted_joins`` count the programs that
+    hold a ``kernels.join_ranges`` by the search it was built with
+    (the ``dispatch`` span's ``join_search``: ``count`` / ``sort``);
     ``mesh_exchanges`` counts the mesh executor's ``mesh-exchange``
     spans and ``mesh_exchanges_in_place`` those among them that were
     satisfied where the rows lay (the span's ``in_place``),
@@ -373,6 +380,10 @@ def span_totals(root: Span) -> Dict[str, float]:
             out[_COUNTED[key]] = out.get(_COUNTED[key], 0) + 1
         for path in sp.attrs.get("groupbys", ()):
             out[path + "_groupbys"] = out.get(path + "_groupbys", 0) + 1
+        search = sp.attrs.get("join_search")
+        if search is not None:
+            field = _JOIN_SEARCH_FIELDS[search]
+            out[field] = out.get(field, 0) + 1
         if sp.attrs.get("program") == "compact":
             out["compactions"] = out.get("compactions", 0) + 1
             out["compact_gather_ops"] = out.get(
@@ -691,6 +702,10 @@ JIT_CACHE_MISSES = REGISTRY.counter(
 STREAMED_GROUPBY_FALLBACKS = REGISTRY.counter(
     "trino_streamed_groupby_fallbacks_total",
     "Chains rerun by sort after a declared row order failed its device check")
+JOINS = REGISTRY.counter(
+    "trino_joins_total",
+    "Dispatched programs holding a kernels.join_ranges, by the search it was "
+    "built with: count (a small build) or sort")
 LISTENER_FAILURES = REGISTRY.counter(
     "trino_event_listener_failures_total", "EventListener callbacks that raised")
 WORKER_TASKS = REGISTRY.counter(
