@@ -98,6 +98,64 @@ ENTRY %main {
     assert "add.2" not in scopes  # no opN: component in its op_name
 
 
+def test_scope_map_reads_a_one_operator_programs_scope():
+    """``op:<NodeType>`` (``local._named_jit``) beside the chains'
+    ``op<i>:<NodeType>``; kernel and site scopes are not operators."""
+    hlo = """
+HloModule jit_join_count, is_scheduled=true
+ENTRY %main {
+  %gather.3 = s32[8]{0} gather(a, b), metadata={op_name="jit(join_count)/op:Join/jit(join_ranges)/k:join_ranges/s:key_at/gather"}
+  %sort.1 = u64[8]{0} sort(c), metadata={op_name="jit(join_count)/k:packed_argsort/sort"}
+  %mul.2 = s64[8]{0} multiply(a, a), metadata={op_name="jit(mesh_chain_Aggregate_Project)/op0:Aggregate/shard_map/op1:Project/mul"}
+}
+"""
+    # the innermost operator wins, as a chip's trace is read
+    assert program_catalog.scope_map_from_hlo(hlo) == {
+        "gather.3": "op:Join", "mul.2": "op1:Project"}
+    assert program_catalog._MODULE_RE.match(hlo).group(1) == "jit_join_count"
+
+
+def test_scope_maps_join_by_module_not_across_programs():
+    """Two programs hold an instruction of one name under different
+    operators: joined by the module the event names, neither takes the
+    other's scope (the union over all programs did)."""
+    cat = program_catalog.ProgramCatalog(max_entries=4)
+
+    class _Compiled:
+        def __init__(self, module, scope):
+            self.text = (
+                f"HloModule {module}\nENTRY %main {{\n  %fusion.8 = f32[8]{{0}} "
+                f'fusion(a), kind=kLoop, metadata={{op_name="jit(x)/{scope}/add"}}\n}}\n'
+            )
+
+        def as_text(self):
+            return self.text
+
+    cat.register(("a",), source="local", label="Join",
+                 resolver=lambda: _Compiled("jit_join_count", "op:Join"))
+    cat.register(("b",), source="local", label="Aggregate",
+                 resolver=lambda: _Compiled("jit_chain_Aggregate", "op0:Aggregate"))
+    maps = cat.scope_maps()
+    assert maps == {"jit_join_count": {"fusion.8": "op:Join"},
+                    "jit_chain_Aggregate": {"fusion.8": "op0:Aggregate"}}
+    from trino_tpu import kernel_profile
+
+    events = [
+        {"name": "fusion.8", "dur_us": 3.0, "hlo_op": "fusion.8",
+         "hlo_module": "jit_join_count"},
+        {"name": "fusion.8", "dur_us": 5.0, "hlo_op": "fusion.8",
+         "hlo_module": "jit_chain_Aggregate"},
+        {"name": "fusion.8", "dur_us": 7.0, "hlo_op": "fusion.8",
+         "hlo_module": "jit_somebody_elses"},
+    ]
+    out = kernel_profile.attribute(events, maps)
+    assert out["scopes"] == {"op0:Aggregate": 5.0, "op:Join": 3.0}
+    assert out["operators"] == {"unscoped": 7.0, "Aggregate": 5.0, "Join": 3.0}
+    assert out["unscoped_us"] == out["unattributed_us"] == 7.0
+    assert out["matched_events"] == 2 and out["events"] == 3
+    assert cat.snapshot()[0]["module"] in maps
+
+
 # ---------------------------------------------------------------------------
 # end-to-end: query -> catalog entry -> system table / EXPLAIN VERBOSE
 # ---------------------------------------------------------------------------

@@ -25,8 +25,11 @@ from trino_tpu.server.coordinator import Coordinator
 
 #: the names of a served statement's tree (PERF.md section 3 lists them)
 ALWAYS = {"statement", "queued", "runner_wait", "parse", "plan",
-          "execute", "to_rows", "respond"}
+          "execute", "to_rows", "epilogue", "respond"}
 UNDER_EXECUTE = {"dispatch", "build_trace", "host_sync", "upload"}
+#: one a recorder of the epilogue that costs anything
+UNDER_EPILOGUE = {"operator_stats", "compile_snapshot", "plan_digest",
+                  "time_breakdown", "listeners", "slow_query"}
 FLAT_FIELDS = (
     "runner_wait_ms", "parse_ms", "plan_ms", "execute_ms",
     "build_trace_ms", "host_sync_ms", "host_syncs", "dispatches",
@@ -97,7 +100,7 @@ def test_served_statement_yields_one_tree_of_documented_names(coord):
     tree = get(coord, f"/v1/query/{qid}")["spans"]
     spans = list(walk(tree))
     names = {sp["name"] for sp, _ in spans}
-    assert ALWAYS <= names <= ALWAYS | UNDER_EXECUTE, names
+    assert ALWAYS <= names <= ALWAYS | UNDER_EXECUTE | UNDER_EPILOGUE, names
     assert tree["name"] == "statement" and tree["parent_id"] is None
     ids = {sp["span_id"] for sp, _ in spans}
     assert len(ids) == len(spans)
@@ -108,13 +111,36 @@ def test_served_statement_yields_one_tree_of_documented_names(coord):
                 sp["parent_id"] in ids
     top = [sp["name"] for sp in tree["children"]]
     assert top == ["queued", "runner_wait", "parse", "plan", "execute",
-                   "to_rows", "respond"], top
+                   "to_rows", "epilogue", "respond"], top
     under = {sp["name"] for sp, par in spans
              if par is not None and par["name"] == "execute"}
     assert under <= UNDER_EXECUTE and "dispatch" in under
     for sp, par in spans:
         if sp["name"] == "build_trace":
             assert par["name"] == "dispatch"
+
+
+def test_epilogue_is_on_the_tree_and_on_the_row(coord):
+    """What the runner still does under its lock after ``to_rows`` is a
+    span of its own (``device.idle_in_epilogue_share`` reads it), one
+    child a recorder, and ``_seal`` puts its total on the row."""
+    qid, _ = serve(coord, QUERIES["q06"])
+    row = row_of(coord, qid)
+    tree = get(coord, f"/v1/query/{qid}")["spans"]
+    (epilogue,) = [sp for sp in tree["children"] if sp["name"] == "epilogue"]
+    kids = [sp["name"] for sp in epilogue["children"]]
+    assert set(kids) <= UNDER_EPILOGUE and "time_breakdown" in kids
+    assert row["epilogue_ms"] == pytest.approx(
+        epilogue["duration_ms"], abs=0.01)
+    assert sum(sp["duration_ms"] for sp in epilogue["children"]) <= (
+        epilogue["duration_ms"] + 0.5)
+    # it starts where to_rows ends: nothing between them is unspanned
+    (to_rows,) = [sp for sp in tree["children"] if sp["name"] == "to_rows"]
+    assert epilogue["start_ms"] - end_ms(to_rows) < 5.0
+    # the flight recorder's window is the statement's execution still
+    res = QueryRunner.tpch("tiny").execute("select count(*) from nation")
+    assert "epilogue" in {sp.name for sp in res.trace.root.children}
+    assert res.time_breakdown is not None
 
 
 def test_children_lie_inside_their_parents_and_add_up(coord):

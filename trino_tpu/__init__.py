@@ -65,8 +65,13 @@ def _cpu_fingerprint() -> str:
 #: bust the cache) — which means changes that only manifest in HLO
 #: metadata are invisible to it. Bump this when such metadata becomes
 #: load-bearing: g2 = operator ``jax.named_scope`` labels, which
-#: kernel-profile attribution reads back out of cached executables' HLO.
-_CACHE_GEN = "g2"
+#: kernel-profile attribution reads back out of cached executables' HLO;
+#: g3 = the whole scope grammar (``op:``, ``k:``, ``s:``; exec/kernels.py),
+#: which a device trace hands back as ``tf_op`` — an executable out of
+#: an older cache would be traced under the scopes it was compiled with.
+#: A cache named by JAX_COMPILATION_CACHE_DIR has no such namespace:
+#: whoever sets the variable points it at a fresh directory.
+_CACHE_GEN = "g3"
 
 if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
     jax.config.update(

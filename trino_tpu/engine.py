@@ -18,6 +18,7 @@ from __future__ import annotations
 import threading
 import time
 import uuid
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 
 from trino_tpu import telemetry
@@ -121,6 +122,20 @@ class QueryResult:
         return json.dumps(
             info, indent=indent, default=str, sort_keys=True,
         )
+
+
+@contextmanager
+def _epilogue_span(root):
+    """``epilogue``: what ``QueryRunner.execute`` still does after
+    ``to_rows`` while it holds the runner's lock and the next statement
+    waits — the registry's and the recorders' book-keeping, one child
+    span a recorder — as a child of the statement's ``root``."""
+    telemetry.set_active_span(root)
+    try:
+        with telemetry.child_span("epilogue") as sp:
+            yield sp
+    finally:
+        telemetry.set_active_span(None)
 
 
 class QueryRunner:
@@ -400,221 +415,230 @@ class QueryRunner:
             error = f"{type(e).__name__}: {e}"
             raise
         finally:
-            self.executor.cancel_event = None
-            self.executor.deadline = None
-            self.executor.memory_ctx = prev_ctx
-            self.executor.profiler = prev_prof
-            self.executor.cache_stats = prev_cstats
-            self._cache_stats = prev_self_cstats
-            if result is not None and result.cache_stats is None and (
-                cstats.result_hit is not None
-                or cstats.device_hits
-                or cstats.device_misses
-            ):
-                result.cache_stats = cstats.as_dict()
-            self._tracer = prev_tracer
+            # the statement's own spans: the flight recorder's window
+            # (the epilogue below is not the statement's execution)
             mine = root.children[n_before:]
-            plan_ms = sum(
-                sp.duration_ms for top in mine for sp in top.walk()
-                if sp.name == "plan"
-            )
-            elapsed_ms = (time.perf_counter() - t0) * 1e3
-            state = "FAILED" if error else "FINISHED"
-            telemetry.QUERIES_TOTAL.inc(state=state)
-            node_id = self.executor.memory_pool.node_id
-            # timings-only seal for the live registry; the lazy
-            # QueryResult.query_info resolver is the path that pays
-            # for XLA cost analysis
-            op_stats = prof.finish(None)
-            for _row in op_stats:
-                telemetry.OPERATOR_SELF_TIME.observe(
-                    _row.get("self_ms", 0.0) / 1e3,
-                    operator=_row.get("node_type", "?"),
-                )
-            tracker.QUERY_INFO.finish(
-                query_id, state=state,
-                rows=len(result.rows) if result else None,
-                error=error,
-                peak_memory_bytes=qctx.peak_bytes,
-                operator_stats=op_stats,
-            )
-            if result is not None:
-                _ex, _prof, _qid = self.executor, prof, query_id
-                result._query_info_resolver = (
-                    lambda: _local_query_info(_ex, _prof, _qid)
-                )
-            comp1 = telemetry.compile_snapshot()
-            compiles_delta = int(
-                comp1.get("compiles", 0) - comp0.get("compiles", 0)
-            )
-            compile_ms_delta = max(
-                (
-                    comp1.get("compile_seconds", 0.0)
-                    - comp0.get("compile_seconds", 0.0)
-                ) * 1e3,
-                0.0,
-            )
-            plan_digest = None
-            fingerprint = None
-            if result is not None and result.plan is not None:
-                from trino_tpu import history as history_mod
-                from trino_tpu import journal as journal_mod
-
-                try:
-                    plan_digest = journal_mod.plan_digest(result.plan)
-                except Exception:
-                    plan_digest = None
-                fingerprint = history_mod.session_fingerprint(
-                    self.session
-                )
-            if result is not None:
-                # a tree handed in is sealed by who opened it
-                result.trace = (
-                    tracer.finish() if own_tracer
-                    else telemetry.Trace(root)
-                )
-                result.planning_ms = plan_ms
-                result.execution_ms = max(elapsed_ms - plan_ms, 0.0)
-                from trino_tpu import telemetry_analysis
-
-                result.time_breakdown = (
-                    telemetry_analysis.compute_time_breakdown(
-                        telemetry.Trace(telemetry.Span(
-                            name=root.name, kind=root.kind,
-                            start_ms=start_ms, duration_ms=elapsed_ms,
-                            children=mine, _open=False,
-                        )),
-                        elapsed_ms, op_stats=op_stats,
-                        compile_ms=compile_ms_delta,
-                    )
-                )
-                if (
-                    result.time_breakdown
-                    and result.names == ["Query Plan"]
-                    and result.stage_stats
+            with _epilogue_span(root):
+                self.executor.cancel_event = None
+                self.executor.deadline = None
+                self.executor.memory_ctx = prev_ctx
+                self.executor.profiler = prev_prof
+                self.executor.cache_stats = prev_cstats
+                self._cache_stats = prev_self_cstats
+                if result is not None and result.cache_stats is None and (
+                    cstats.result_hit is not None
+                    or cstats.device_hits
+                    or cstats.device_misses
                 ):
-                    # local EXPLAIN ANALYZE (stage_stats filled by
-                    # _explain; plain EXPLAIN has none yet): the
-                    # breakdown footer rides the rendered plan
-                    result.rows.extend(
-                        (line,)
-                        for line in telemetry_analysis
-                        .format_breakdown(result.time_breakdown)
-                    )
-                    # sentry baseline footer — judged against
-                    # history that does NOT yet include this run
-                    # (completion fires below)
-                    from trino_tpu import sentry as sentry_mod
-
-                    _bf = sentry_mod.baseline_footer(
-                        plan_digest, fingerprint or "",
-                        elapsed_ms, result.time_breakdown,
-                    )
-                    if _bf:
-                        result.rows.append((_bf,))
-                if not result.stage_stats:
-                    # local execution is one pseudo-stage; the fleet
-                    # runner fills real per-stage aggregates instead
-                    result.stage_stats = [{
-                        "stage_id": "local",
-                        "tasks": 1,
-                        "rows_in": 0,
-                        "rows_out": len(result.rows),
-                        "bytes_out": 0,
-                        "elapsed_ms": elapsed_ms,
-                        "retries": 0,
-                        "peak_memory_bytes": qctx.peak_bytes,
-                        "admission_wait_ms": 0.0,
-                    }]
-                if not result.task_stats:
-                    # mirror the (possibly _explain-provided)
-                    # stage aggregate so system.runtime.tasks and
-                    # stage_stats always report the same numbers
-                    st = result.stage_stats[0]
-                    result.task_stats = [{
-                        "query_id": query_id,
-                        "stage_id": st["stage_id"],
-                        "task_id": f"{st['stage_id']}.0",
-                        "attempt": 0,
-                        "state": state,
-                        "worker": node_id,
-                        "elapsed_ms": st["elapsed_ms"],
-                        "rows_in": st["rows_in"],
-                        "rows_out": st["rows_out"],
-                        "bytes_out": st["bytes_out"],
-                        "peak_memory_bytes": st[
-                            "peak_memory_bytes"
-                        ],
-                    }]
-            listeners = getattr(self.metadata, "event_listeners", ())
-            if listeners:
-                from trino_tpu.events import (
-                    QueryCompletedEvent,
-                    fire_query_completed,
+                    result.cache_stats = cstats.as_dict()
+                self._tracer = prev_tracer
+                plan_ms = sum(
+                    sp.duration_ms for top in mine for sp in top.walk()
+                    if sp.name == "plan"
                 )
+                elapsed_ms = (time.perf_counter() - t0) * 1e3
+                state = "FAILED" if error else "FINISHED"
+                telemetry.QUERIES_TOTAL.inc(state=state)
+                node_id = self.executor.memory_pool.node_id
+                # timings-only seal for the live registry; the lazy
+                # QueryResult.query_info resolver is the path that pays
+                # for XLA cost analysis
+                with telemetry.child_span("operator_stats"):
+                    op_stats = prof.finish(None)
+                    for _row in op_stats:
+                        telemetry.OPERATOR_SELF_TIME.observe(
+                            _row.get("self_ms", 0.0) / 1e3,
+                            operator=_row.get("node_type", "?"),
+                        )
+                    tracker.QUERY_INFO.finish(
+                        query_id, state=state,
+                        rows=len(result.rows) if result else None,
+                        error=error,
+                        peak_memory_bytes=qctx.peak_bytes,
+                        operator_stats=op_stats,
+                    )
+                if result is not None:
+                    _ex, _prof, _qid = self.executor, prof, query_id
+                    result._query_info_resolver = (
+                        lambda: _local_query_info(_ex, _prof, _qid)
+                    )
+                with telemetry.child_span("compile_snapshot"):
+                    comp1 = telemetry.compile_snapshot()
+                    compiles_delta = int(
+                        comp1.get("compiles", 0) - comp0.get("compiles", 0)
+                    )
+                    compile_ms_delta = max(
+                        (
+                            comp1.get("compile_seconds", 0.0)
+                            - comp0.get("compile_seconds", 0.0)
+                        ) * 1e3,
+                        0.0,
+                    )
+                plan_digest = None
+                fingerprint = None
+                with telemetry.child_span("plan_digest"):
+                    if result is not None and result.plan is not None:
+                        from trino_tpu import history as history_mod
+                        from trino_tpu import journal as journal_mod
 
-                fire_query_completed(listeners, QueryCompletedEvent(
-                    query_id=query_id,
-                    user=self.session.user,
-                    sql=sql,
-                    state=state,
-                    elapsed_ms=elapsed_ms,
-                    rows=len(result.rows) if result else 0,
-                    error=error,
-                    peak_memory_bytes=qctx.peak_bytes,
-                    peak_memory_per_node=(
-                        (node_id, qctx.peak_bytes),
-                    ) if qctx.peak_bytes else (),
-                    planning_ms=plan_ms,
-                    execution_ms=max(elapsed_ms - plan_ms, 0.0),
-                    cpu_ms=max(elapsed_ms - plan_ms, 0.0),
-                    query_retries=(
-                        result.query_retries if result else 0
-                    ),
-                    tasks_retried=(
-                        result.tasks_retried if result else 0
-                    ),
-                    tasks_speculated=(
-                        result.tasks_speculated if result else 0
-                    ),
-                    speculation_wins=(
-                        result.speculation_wins if result else 0
-                    ),
-                    workers_readmitted=(
-                        result.workers_readmitted if result else 0
-                    ),
-                    plan_digest=plan_digest,
-                    session_fingerprint=fingerprint,
-                    cache_hit_tier=(
-                        "result"
-                        if result is not None
-                        and result.cache_stats
-                        and (
-                            result.cache_stats.get("result") or {}
-                        ).get("hit")
-                        else None
-                    ),
-                    compiles=compiles_delta,
-                    time_breakdown=(
-                        result.time_breakdown if result else None
-                    ),
-                    trace=result.trace if result else None,
-                    task_stats=tuple(
-                        result.task_stats if result else ()
-                    ),
-                ))
-            from trino_tpu.events import maybe_log_slow_query
+                        try:
+                            plan_digest = journal_mod.plan_digest(result.plan)
+                        except Exception:
+                            plan_digest = None
+                        fingerprint = history_mod.session_fingerprint(
+                            self.session
+                        )
+                if result is not None:
+                    # a tree handed in is sealed by who opened it
+                    result.trace = (
+                        tracer.finish() if own_tracer
+                        else telemetry.Trace(root)
+                    )
+                    result.planning_ms = plan_ms
+                    result.execution_ms = max(elapsed_ms - plan_ms, 0.0)
+                    with telemetry.child_span("time_breakdown"):
+                        from trino_tpu import telemetry_analysis
 
-            maybe_log_slow_query(
-                listeners, self.session, query_id, sql,
-                elapsed_ms, op_stats, state=state,
-                time_breakdown=(
-                    result.time_breakdown if result else None
-                ),
-                kernel_profile=(
-                    result.kernel_profile if result else None
-                ),
-            )
+                        result.time_breakdown = (
+                            telemetry_analysis.compute_time_breakdown(
+                                telemetry.Trace(telemetry.Span(
+                                    name=root.name, kind=root.kind,
+                                    start_ms=start_ms, duration_ms=elapsed_ms,
+                                    children=mine, _open=False,
+                                )),
+                                elapsed_ms, op_stats=op_stats,
+                                compile_ms=compile_ms_delta,
+                            )
+                        )
+                    if (
+                        result.time_breakdown
+                        and result.names == ["Query Plan"]
+                        and result.stage_stats
+                    ):
+                        # local EXPLAIN ANALYZE (stage_stats filled by
+                        # _explain; plain EXPLAIN has none yet): the
+                        # breakdown footer rides the rendered plan
+                        result.rows.extend(
+                            (line,)
+                            for line in telemetry_analysis
+                            .format_breakdown(result.time_breakdown)
+                        )
+                        # sentry baseline footer — judged against
+                        # history that does NOT yet include this run
+                        # (completion fires below)
+                        from trino_tpu import sentry as sentry_mod
+
+                        _bf = sentry_mod.baseline_footer(
+                            plan_digest, fingerprint or "",
+                            elapsed_ms, result.time_breakdown,
+                        )
+                        if _bf:
+                            result.rows.append((_bf,))
+                    if not result.stage_stats:
+                        # local execution is one pseudo-stage; the fleet
+                        # runner fills real per-stage aggregates instead
+                        result.stage_stats = [{
+                            "stage_id": "local",
+                            "tasks": 1,
+                            "rows_in": 0,
+                            "rows_out": len(result.rows),
+                            "bytes_out": 0,
+                            "elapsed_ms": elapsed_ms,
+                            "retries": 0,
+                            "peak_memory_bytes": qctx.peak_bytes,
+                            "admission_wait_ms": 0.0,
+                        }]
+                    if not result.task_stats:
+                        # mirror the (possibly _explain-provided)
+                        # stage aggregate so system.runtime.tasks and
+                        # stage_stats always report the same numbers
+                        st = result.stage_stats[0]
+                        result.task_stats = [{
+                            "query_id": query_id,
+                            "stage_id": st["stage_id"],
+                            "task_id": f"{st['stage_id']}.0",
+                            "attempt": 0,
+                            "state": state,
+                            "worker": node_id,
+                            "elapsed_ms": st["elapsed_ms"],
+                            "rows_in": st["rows_in"],
+                            "rows_out": st["rows_out"],
+                            "bytes_out": st["bytes_out"],
+                            "peak_memory_bytes": st[
+                                "peak_memory_bytes"
+                            ],
+                        }]
+                listeners = getattr(self.metadata, "event_listeners", ())
+                with telemetry.child_span("listeners"):
+                    if listeners:
+                        from trino_tpu.events import (
+                            QueryCompletedEvent,
+                            fire_query_completed,
+                        )
+
+                        fire_query_completed(listeners, QueryCompletedEvent(
+                            query_id=query_id,
+                            user=self.session.user,
+                            sql=sql,
+                            state=state,
+                            elapsed_ms=elapsed_ms,
+                            rows=len(result.rows) if result else 0,
+                            error=error,
+                            peak_memory_bytes=qctx.peak_bytes,
+                            peak_memory_per_node=(
+                                (node_id, qctx.peak_bytes),
+                            ) if qctx.peak_bytes else (),
+                            planning_ms=plan_ms,
+                            execution_ms=max(elapsed_ms - plan_ms, 0.0),
+                            cpu_ms=max(elapsed_ms - plan_ms, 0.0),
+                            query_retries=(
+                                result.query_retries if result else 0
+                            ),
+                            tasks_retried=(
+                                result.tasks_retried if result else 0
+                            ),
+                            tasks_speculated=(
+                                result.tasks_speculated if result else 0
+                            ),
+                            speculation_wins=(
+                                result.speculation_wins if result else 0
+                            ),
+                            workers_readmitted=(
+                                result.workers_readmitted if result else 0
+                            ),
+                            plan_digest=plan_digest,
+                            session_fingerprint=fingerprint,
+                            cache_hit_tier=(
+                                "result"
+                                if result is not None
+                                and result.cache_stats
+                                and (
+                                    result.cache_stats.get("result") or {}
+                                ).get("hit")
+                                else None
+                            ),
+                            compiles=compiles_delta,
+                            time_breakdown=(
+                                result.time_breakdown if result else None
+                            ),
+                            trace=result.trace if result else None,
+                            task_stats=tuple(
+                                result.task_stats if result else ()
+                            ),
+                        ))
+                with telemetry.child_span("slow_query"):
+                    from trino_tpu.events import maybe_log_slow_query
+
+                    maybe_log_slow_query(
+                        listeners, self.session, query_id, sql,
+                        elapsed_ms, op_stats, state=state,
+                        time_breakdown=(
+                            result.time_breakdown if result else None
+                        ),
+                        kernel_profile=(
+                            result.kernel_profile if result else None
+                        ),
+                    )
 
     def _execute(self, sql: str) -> QueryResult:
         from trino_tpu import session_properties
@@ -1301,6 +1325,13 @@ class QueryRunner:
                         "  (unattributed): "
                         f"{summary['unattributed_us'] / 1e3:.3f} ms"
                     )
+                # a chip's trace names kernels and primitives too
+                for axis in ("kernels", "primitives"):
+                    if summary.get(axis):
+                        lines.append(f"  by {axis[:-1]}: " + ", ".join(
+                            f"{k} {us / 1e3:.3f} ms"
+                            for k, us in list(summary[axis].items())[:8]
+                        ))
             else:
                 lines.append("  <no attributable device events captured>")
             for key in dispatched:

@@ -175,10 +175,12 @@ class _Reducer:
             )
         xs = self._sorted(data)
         masked = jnp.where(self.contrib_s, xs, jnp.int64(0))
-        hi = K.seg_sum_ranges(masked >> jnp.int64(32), self.info, zero)
-        lo = K.seg_sum_ranges(
-            masked & jnp.int64(0xFFFFFFFF), self.info, zero
-        )
+        with K.site("limb_hi"):
+            hi = K.seg_sum_ranges(masked >> jnp.int64(32), self.info, zero)
+        with K.site("limb_lo"):
+            lo = K.seg_sum_ranges(
+                masked & jnp.int64(0xFFFFFFFF), self.info, zero
+            )
         return _limb_norm(hi, lo)
 
     def count(self):
@@ -220,6 +222,7 @@ class _Reducer:
         masked = jnp.where(self.contrib_s, self._sorted(data), fill)
         return K.seg_minmax_scan(masked, self.info, fill, is_min)
 
+    @K.kernel
     def first_value(self, data):
         """Value of the first contributing row per group."""
         n = data.shape[0]
@@ -244,6 +247,7 @@ class _Reducer:
         return self.info.group
 
 
+@K.kernel
 def compute_aggregate(
     name: str,
     out_type: T.DataType,
@@ -529,6 +533,7 @@ def dev_hash64(data: jnp.ndarray) -> jnp.ndarray:
     return v
 
 
+@K.kernel
 def _hll_registers(h, contrib, m, info, capacity):
     """HLL register arrays from 64-bit hash lanes, scatter-free: rows
     sort by (group, bucket, -rho) and each register reads the first
@@ -572,6 +577,7 @@ def _hll_estimate(reg: jnp.ndarray) -> jnp.ndarray:
     return jnp.round(est).astype(jnp.int64)
 
 
+@K.kernel
 def _hll_merge(states, contrib, info, capacity):
     """Element-wise max of member rows' register arrays (the FINAL
     combine; register max is the HLL merge). Inputs are partial-state
@@ -584,6 +590,7 @@ def _hll_merge(states, contrib, info, capacity):
     return out.at[gid].max(live)
 
 
+@K.kernel
 def _quant_sorted_perm(vd, eff, info):
     """Permutation ordering rows (group asc, contributing-first,
     value asc) — shared by the exact percentile and the summary
@@ -596,6 +603,7 @@ def _quant_sorted_perm(vd, eff, info):
     return p
 
 
+@K.kernel
 def _quant_summary(vd, eff, info, capacity, k, red):
     """Mergeable quantile summary: k evenly-spaced order statistics
     per group + a count lane, as float64[cap, k+1] (the qdigest-state
@@ -624,6 +632,7 @@ def _quant_summary(vd, eff, info, capacity, k, red):
     return state, cnt > 0
 
 
+@K.kernel
 def _quant_merge(states, q, contrib, valid, info, capacity, out_type):
     """FINAL combine of quantile summaries: member rows' points merge
     as a weighted quantile (each point carries weight count/k). Sort-

@@ -29,7 +29,7 @@ the only host syncs are capacity decisions at operator boundaries.
 from __future__ import annotations
 
 import math
-from functools import partial
+from functools import partial, wraps
 from typing import NamedTuple
 
 import jax
@@ -43,7 +43,47 @@ from trino_tpu import telemetry
 # the first jit call anywhere in the process
 telemetry.install_jax_compile_hook()
 
+# ---- scopes: what a device trace can say of a program's inside --------------
+#
+# ``jax.named_scope`` writes one component into the ``op_name`` of every
+# HLO instruction traced under it, which the device trace hands back on
+# each ``XLA Ops`` event's metadata (``tf_op``): nothing is added to a
+# program but the name. One grammar, static strings only (a name holds
+# node types, kernel names and site names, never a literal, a capacity
+# or a hash):
+#
+#   op<i>:<NodeType>  a chain's position        (stage.build_chain)
+#   op:<NodeType>     a program that is one operator (local._named_jit)
+#   k:<kernel>        an entry point of this module or of aggregates.py
+#   s:<site>          one sort, gather or scatter inside a kernel
+#
+# ``benchmarks/readers/trace_scopes.py`` and ``kernel_profile.attribute``
+# read them: the operator of an instruction is its ``op`` component's
+# node type, its kernel the innermost ``k:``, its site the innermost
+# ``s:``.
+
+
+def kernel(fn):
+    """``fn`` under the scope ``k:<fn's name>`` (below ``jax.jit``, where
+    the kernel is one)."""
+    scope = "k:" + fn.__name__.lstrip("_")
+
+    @wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(scope):
+            return fn(*args, **kwargs)
+
+    return scoped
+
+
+def site(name: str):
+    """``with site("compose"):`` — the scope ``s:compose``."""
+    return jax.named_scope("s:" + name)
+
+
 __all__ = [
+    "kernel",
+    "site",
     "hash_columns",
     "packed_argsort",
     "compact_perm",
@@ -51,6 +91,8 @@ __all__ = [
     "GATHER_WIDE_WORDS",
     "gather_plan",
     "gather_rows",
+    "rows_at",
+    "keys_match",
     "compact_rows",
     "cumsum",
     "floor_div",
@@ -134,6 +176,7 @@ def _idx_bits(n: int) -> int:
     return max(1, (max(n, 1) - 1).bit_length())
 
 
+@kernel
 def packed_argsort(
     key: jnp.ndarray | None, key_bits: int, last: jnp.ndarray | None = None
 ) -> jnp.ndarray:
@@ -160,14 +203,18 @@ def packed_argsort(
     if n >= 1 << 31:
         raise ValueError(f"packed_argsort: {n} rows need a wider index")
     key = key.astype(jnp.uint64)
-    p1 = packed_argsort(key & jnp.uint64(0xFFFFFFFF), 32)
-    p2 = packed_argsort(
-        (key >> jnp.uint64(32))[p1], 32,
-        None if last is None else last[p1],
-    )
-    return p1[p2]
+    with site("sort_low"):
+        p1 = packed_argsort(key & jnp.uint64(0xFFFFFFFF), 32)
+    with site("gather_high"):
+        high = (key >> jnp.uint64(32))[p1]
+        last1 = None if last is None else last[p1]
+    with site("sort_high"):
+        p2 = packed_argsort(high, 32, last1)
+    with site("compose"):
+        return p1[p2]
 
 
+@kernel
 def compact_perm(mask: jnp.ndarray) -> jnp.ndarray:
     """Permutation gathering live rows to the front, in row order (dead
     rows after them, in row order)."""
@@ -251,6 +298,7 @@ def _from_words(w: jnp.ndarray, dtype, lanes) -> jnp.ndarray:
     )
 
 
+@kernel
 def gather_rows(env: dict, idx: jnp.ndarray) -> dict:
     """``{name: (d[idx], v[idx])}`` of a page's ``{name: (data,
     valid)}`` in ``gather_plan``'s number of gathers."""
@@ -268,14 +316,16 @@ def gather_rows(env: dict, idx: jnp.ndarray) -> dict:
         parts.append(word[:, None])
     if parts:
         words = jnp.concatenate(parts, axis=1)
-        words = jnp.concatenate([
-            words[:, i:i + GATHER_STACK_WORDS][idx]
-            for i in range(0, words.shape[1], GATHER_STACK_WORDS)
-        ], axis=1)
+        with site("stacked_walk"):
+            words = jnp.concatenate([
+                words[:, i:i + GATHER_STACK_WORDS][idx]
+                for i in range(0, words.shape[1], GATHER_STACK_WORDS)
+            ], axis=1)
     out, at, nth = {}, 0, 0
     for n, (d, v) in env.items():
         if width[n] > GATHER_WIDE_WORDS:
-            data = d[idx]
+            with site("lone_walk"):
+                data = d[idx]
         else:
             data = _from_words(
                 words[:, at:at + width[n]], d.dtype, d.shape[1:])
@@ -289,6 +339,21 @@ def gather_rows(env: dict, idx: jnp.ndarray) -> dict:
     return out
 
 
+@kernel
+def rows_at(data: jnp.ndarray, valid: jnp.ndarray | None, idx: jnp.ndarray):
+    """One column read at ``idx``, as it is: ``(data[idx], valid[idx])``
+    (no valid lane: None)."""
+    return data[idx], None if valid is None else valid[idx]
+
+
+@kernel
+def keys_match(pb, bb, probe_idx, build_idx) -> jnp.ndarray:
+    """Do an expanded pair's key bits agree (the re-verification of a
+    hash-combined join key): ``pb[probe_idx] == bb[build_idx]``."""
+    return pb[probe_idx] == bb[build_idx]
+
+
+@kernel
 def compact_rows(env: dict, mask: jnp.ndarray, limit: int):
     """The page's live rows first, in row order, in ``limit`` rows:
     ``(env2, mask2)``. One packed sort gives the positions (measured on
@@ -315,6 +380,7 @@ def _rank_key(x: jnp.ndarray) -> tuple[jnp.ndarray, int]:
     return x.astype(jnp.uint64), 32
 
 
+@kernel
 def _merge_rank(a: jnp.ndarray, v: jnp.ndarray, side: str) -> jnp.ndarray:
     """searchsorted by one merged sort: rank every query among the
     haystack by sorting them TOGETHER (ties: queries first for 'left',
@@ -333,10 +399,15 @@ def _merge_rank(a: jnp.ndarray, v: jnp.ndarray, side: str) -> jnp.ndarray:
         perm = packed_argsort(jnp.concatenate([ka, kv]), bits)
         is_hay = perm < m
         dest = jnp.where(is_hay, q, perm - m)
-    ahead = jnp.cumsum(is_hay.astype(jnp.int32)) - is_hay.astype(jnp.int32)
-    return jnp.zeros((q,), jnp.int32).at[dest].set(ahead, mode="drop")
+    with site("hay_prefix"):
+        ahead = (
+            jnp.cumsum(is_hay.astype(jnp.int32)) - is_hay.astype(jnp.int32)
+        )
+    with site("scatter_back"):
+        return jnp.zeros((q,), jnp.int32).at[dest].set(ahead, mode="drop")
 
 
+@kernel
 def searchsorted(a: jnp.ndarray, v: jnp.ndarray, side: str = "left") -> jnp.ndarray:
     """searchsorted with the method chosen by measurement (one v5e
     chip, 6,291,456 uint64 queries into as many keys, PR 22): the
@@ -381,6 +452,7 @@ def floor_div(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(neg, -(uq + (ur != 0).astype(jnp.int64)), uq)
 
 
+@kernel
 def cumsum(x: jnp.ndarray) -> jnp.ndarray:
     """1-D inclusive prefix sum. On TPU ``jnp.cumsum`` lowers to a
     log2(n)-level associative scan over the whole column (int64 at
@@ -417,6 +489,7 @@ def normalize_key(data: jnp.ndarray, valid: jnp.ndarray | None):
     return jnp.where(valid, bits, jnp.uint64(0)), ~valid
 
 
+@kernel
 def hash_columns(cols: list[tuple[jnp.ndarray, jnp.ndarray | None]]) -> jnp.ndarray:
     """Combined 64-bit hash of key columns (nulls hash to a salt)."""
     h = jnp.zeros(cols[0][0].shape, dtype=jnp.uint64)
@@ -462,16 +535,19 @@ class GroupInfo(NamedTuple):
     owner: jnp.ndarray
     num_groups: jnp.ndarray
 
+    @kernel
     def in_order(self, x: jnp.ndarray) -> jnp.ndarray:
         """A row-ordered column in group-sorted order."""
         return x if self.perm is None else x[self.perm]
 
+    @kernel
     def rows_at(self, pos: jnp.ndarray) -> jnp.ndarray:
         """Original row index of sorted positions ``pos``."""
         return pos if self.perm is None else self.perm[pos]
 
 
 @partial(jax.jit, static_argnames=("capacity", "widths"))
+@kernel
 def sort_group(
     norm_bits: tuple[jnp.ndarray, ...],
     null_flags: tuple[jnp.ndarray, ...],
@@ -654,6 +730,7 @@ def assign_groups(
 # is a declaration, so it is checked on the way.
 
 
+@kernel
 def run_group(
     norm_bits: tuple[jnp.ndarray, ...],
     null_flags: tuple[jnp.ndarray, ...],
@@ -680,7 +757,8 @@ def run_group(
     first = jnp.arange(n, dtype=jnp.int32) == 0
     unordered = jnp.any(live & ~first & (~live_prev | (word < prev)))
     boundary = live & (first | (word != prev))
-    gid1 = cumsum(boundary.astype(jnp.int32))  # 1-based within live
+    with site("boundary_prefix"):
+        gid1 = cumsum(boundary.astype(jnp.int32))  # 1-based within live
     num_groups = gid1[-1] if n else jnp.int32(0)
     gid = jnp.minimum(jnp.where(live, gid1 - 1, capacity), capacity)
     n_live = jnp.sum(live.astype(jnp.int32))
@@ -688,7 +766,8 @@ def run_group(
     used = sids < num_groups
     # the g-th boundary row is where group g starts: boundary rows to
     # the front, in row order, by one packed uint32 sort
-    at_boundary = compact_perm(boundary)[:capacity]
+    with site("boundary_rows"):
+        at_boundary = compact_perm(boundary)[:capacity]
     if capacity > n:
         at_boundary = jnp.concatenate(
             [at_boundary, jnp.full((capacity - n,), n, jnp.int32)]
@@ -761,6 +840,7 @@ def _per_slot(slot, n_slots: int, vals, identity, op: str):
     )
 
 
+@kernel
 def slot_group(norm_bits, null_flags, live, capacity: int, widths) -> SlotInfo:
     """Grouping for keys of ``slot_key_bits(...) <= SLOT_KEY_BITS``:
     same arguments and the same groups, ids and owners as
@@ -790,6 +870,7 @@ def slot_group(norm_bits, null_flags, live, capacity: int, widths) -> SlotInfo:
     return SlotInfo(slot, rank, order, owner, num_groups)
 
 
+@kernel
 def slot_reduce(vals, contrib, info: SlotInfo, identity, op: str = "sum"):
     """Sum / min / max (``op``) of the contributing rows' ``vals`` per
     group, as [capacity] in dense id order; ``identity`` where a group
@@ -812,6 +893,7 @@ def _range_gather(cs: jnp.ndarray, idx: jnp.ndarray, zero):
     return jnp.where(idx > 0, cs[at], zero)
 
 
+@kernel
 def seg_sum_ranges(vals_sorted, info: GroupInfo, zero=None):
     """Per-group sums of an already group-sorted, contribution-masked
     value column — scatter-free.
@@ -848,6 +930,7 @@ def seg_sum_ranges(vals_sorted, info: GroupInfo, zero=None):
     return jnp.where(info.ends > info.starts, hi - lo, zero)
 
 
+@kernel
 def seg_minmax_scan(vals_sorted, info: GroupInfo, fill, is_min: bool):
     """Per-group min/max via a segmented associative scan over the
     group-sorted values (positions outside the group reset the run)."""
@@ -878,6 +961,7 @@ def order_bits(data: jnp.ndarray) -> jnp.ndarray:
     )
 
 
+@kernel
 def seg_arg_extreme(
     key_sorted: jnp.ndarray,
     contrib_sorted: jnp.ndarray,
@@ -917,6 +1001,7 @@ def seg_arg_extreme(
     return info.rows_at(jnp.clip(bp, 0, max(n - 1, 0)))
 
 
+@kernel
 def seg_first_index(contrib_sorted, info: GroupInfo):
     """Original row index of the first contributing row per group
     (== n when the group has none)."""
@@ -942,6 +1027,7 @@ def count_true(mask: jnp.ndarray) -> jnp.ndarray:
 # ---- join-side match marks (scatter-free) ----------------------------------
 
 
+@kernel
 def range_any(cnt: jnp.ndarray, out_live: jnp.ndarray) -> jnp.ndarray:
     """Per-probe 'any live expanded output in my range' — the
     scatter-free form of segment-any over the (sorted) probe_idx that
@@ -955,6 +1041,7 @@ def range_any(cnt: jnp.ndarray, out_live: jnp.ndarray) -> jnp.ndarray:
     return (hi - lo) > 0
 
 
+@kernel
 def scatter_any(idx: jnp.ndarray, flags: jnp.ndarray, capacity: int) -> jnp.ndarray:
     """``any(flags[idx == b])`` per b in [0, capacity) for arbitrary
     (unsorted) idx — sort + membership probe instead of a scatter."""
@@ -968,6 +1055,7 @@ def scatter_any(idx: jnp.ndarray, flags: jnp.ndarray, capacity: int) -> jnp.ndar
 
 # ---- sorting ---------------------------------------------------------------
 
+@kernel
 def sort_perm(
     keys: list[tuple[jnp.ndarray, jnp.ndarray | None, bool, bool]],
     live: jnp.ndarray,
@@ -1075,6 +1163,7 @@ def _packed_counts(less: jnp.ndarray, equal: jnp.ndarray):
     return lo, lo + (both >> jnp.int32(16))
 
 
+@kernel
 def _count_ranges(sorted_key: jnp.ndarray, probe_key: jnp.ndarray):
     """``(lo, hi)`` of every probe key in a sorted build of a few rows
     by compare-and-count: ``lo[i] = #{j : sorted_key[j] < probe_key[i]}``
@@ -1091,6 +1180,7 @@ def _count_ranges(sorted_key: jnp.ndarray, probe_key: jnp.ndarray):
 
 
 @jax.jit
+@kernel
 def join_ranges(
     build_key: jnp.ndarray,
     build_live: jnp.ndarray,
@@ -1123,9 +1213,11 @@ def join_ranges(
     # is globally sorted (binary-search precondition), then clamp the
     # ranges to the live prefix
     pos = jnp.arange(n_build)
-    sorted_key = jnp.where(
-        pos < n_build_live, build_key[order], jnp.uint64(0xFFFFFFFFFFFFFFFF)
-    )
+    with site("build_in_order"):
+        sorted_key = jnp.where(
+            pos < n_build_live, build_key[order],
+            jnp.uint64(0xFFFFFFFFFFFFFFFF),
+        )
     if join_search(n_build) == "count":
         lo, hi = _count_ranges(sorted_key, probe_key)
     else:
@@ -1142,8 +1234,10 @@ def join_ranges(
             reverse=True,
         )
         at = jnp.clip(lo, 0, n_build - 1)
-        found = (lo < n_build) & (sorted_key[at] == probe_key)
-        hi = jnp.where(found, run_end[at], lo)
+        with site("key_at"):
+            found = (lo < n_build) & (sorted_key[at] == probe_key)
+        with site("run_end_at"):
+            hi = jnp.where(found, run_end[at], lo)
     lo = jnp.minimum(lo, n_build_live)
     hi = jnp.minimum(hi, n_build_live)
     cnt = jnp.where(probe_live, hi - lo, 0)
@@ -1151,6 +1245,7 @@ def join_ranges(
 
 
 @partial(jax.jit, static_argnames=("out_capacity",))
+@kernel
 def expand_matches(
     order: jnp.ndarray,
     lo: jnp.ndarray,

@@ -106,12 +106,53 @@ def _maybe_compile_delay() -> None:
             sp.finish()
 
 
-def _named_jit(fn, name: str):
+#: the plan operator a program that is one operator serves, by the
+#: program's name (a mesh twin's, ``mesh_<name>``, as the local one's):
+#: the ``op:<NodeType>`` scope every instruction of it is traced under
+#: (``kernels.py`` has the grammar). A chain opens an ``op<i>:`` scope a
+#: position itself (``stage.build_chain``) and is not listed.
+PROGRAM_OPERATOR = {
+    "compact": "Compact",
+    "join_count": "Join",
+    "join_bounds": "Join",
+    "join_expand": "Join",
+    "concat": "Join",  # the two halves of a skew-split join
+    "semi_join": "SemiJoin",
+    "cross_count": "CrossJoin",
+    "cross_join": "CrossJoin",
+    "dynamic_filter": "DynamicFilter",
+    "unnest": "Unnest",
+    "window": "Window",
+    "group_id": "GroupId",
+    "exchange": "Exchange",
+    "exchange_in_place": "Exchange",
+    "exchange_dest": "Exchange",
+    "range_bits": "Exchange",
+    "range_dest": "Exchange",
+    "dest_hist": "Exchange",
+}
+
+
+def _named_jit(fn, name: str, scope: str | None = None):
     """``jax.jit`` of a program named by what it does: XLA's module, and
     with it the ``XLA Modules`` line of a device trace, reads
     ``jit_<name>`` (the fingerprint XLA appends tells two instances of
     one name apart). A name holds node types and phases, never a
-    literal, a capacity or a hash."""
+    literal, a capacity or a hash. A program that is not a chain is
+    traced under its operator's scope (``PROGRAM_OPERATOR``; a name
+    that is not listed there is an error, so no program is left without
+    one); a chain under ``scope`` where it has work outside its
+    positions' own scopes (the mesh's way into the shards)."""
+    local_name = name.removeprefix("mesh_")
+    if not local_name.startswith("chain_"):
+        scope = "op:" + PROGRAM_OPERATOR[local_name]
+    if scope is not None:
+        body = fn
+
+        def fn(*args):
+            with jax.named_scope(scope):
+                return body(*args)
+
     fn.__name__ = fn.__qualname__ = name
     return jax.jit(fn)
 
@@ -799,9 +840,13 @@ class LocalExecutor:
                 )
                 fn, out_layout = stage.build_chain(chain, in_layout, caps)
 
+                tail = stage.op_scope(len(chain) - 1, chain[-1])
+
                 def counted(env, mask, _fn=fn):
                     env2, mask2, flags = _fn(env, mask)
-                    return env2, mask2, flags, K.count_true(mask2)
+                    # the live count is the last operator's
+                    with jax.named_scope(tail):
+                        return env2, mask2, flags, K.count_true(mask2)
 
                 hit = (_named_jit(counted, program), out_layout)
                 self._jit_cache[key] = hit
@@ -1741,10 +1786,7 @@ class LocalExecutor:
                         (lnames, lenv, li), (rnames, renv, ri)
                     ):
                         for nm in names:
-                            d, v = env[nm]
-                            env2[nm] = (
-                                d[idx], None if v is None else v[idx]
-                            )
+                            env2[nm] = K.rows_at(*env[nm], idx)
                     return env2, out_live
 
                 fn = _named_jit(fx, "cross_join")
@@ -2178,12 +2220,14 @@ class LocalExecutor:
                 for pd, bd in pairs:
                     pb, _ = K.normalize_key(pd, None)
                     bb, _ = K.normalize_key(bd, None)
-                    out_live = out_live & (pb[probe_idx] == bb[build_idx])
+                    out_live = out_live & K.keys_match(
+                        pb, bb, probe_idx, build_idx
+                    )
             inner = {}
             for sym, from_probe, _t, _d, _hp, _ap in out_meta:
                 d, v = (penv if from_probe else benv)[sym]
                 idx = probe_idx if from_probe else build_idx
-                inner[sym] = (d[idx], None if v is None else v[idx])
+                inner[sym] = K.rows_at(d, v, idx)
             if filter_c is not None:
                 fenv = _gather_pair_env(
                     penv, benv, probe_names, fsyms,
@@ -2257,7 +2301,9 @@ class LocalExecutor:
             for pd, bd in pairs:
                 pb, _ = K.normalize_key(pd, None)
                 bb, _ = K.normalize_key(bd, None)
-                out_live = out_live & (pb[probe_idx] == bb[build_idx])
+                out_live = out_live & K.keys_match(
+                    pb, bb, probe_idx, build_idx
+                )
             if filter_c is not None:
                 fenv = _gather_pair_env(
                     penv, benv, probe_names, fsyms, probe_idx, build_idx
@@ -2402,9 +2448,7 @@ class LocalExecutor:
                     idx = jnp.arange(out_cap, dtype=jnp.int32) // k
                     env2 = {}
                     for s, (d, v) in env.items():
-                        env2[s] = (
-                            d[idx], None if v is None else v[idx]
-                        )
+                        env2[s] = K.rows_at(d, v, idx)
                     for sym, prods in zip(node.element_symbols, producers):
                         t = node.outputs[sym]
                         cols = []
@@ -2979,7 +3023,7 @@ def _gather_pair_env(penv, benv, probe_names, syms, probe_idx, build_idx, base=N
             from_probe = sym in probe_names
             d, v = (penv if from_probe else benv)[sym]
             idx = probe_idx if from_probe else build_idx
-            fenv[sym] = (d[idx], None if v is None else v[idx])
+            fenv[sym] = K.rows_at(d, v, idx)
     return fenv
 
 

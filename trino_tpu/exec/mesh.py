@@ -244,16 +244,19 @@ class MeshExecutor(LocalExecutor):
         #: all_to_all count and device bytes moved through them
         self.exchange_stats = {"exchanges": 0, "bytes": 0}
 
-    def _shard_jit(self, fn, name: str, in_specs, out_specs):
+    def _shard_jit(self, fn, name: str, in_specs, out_specs, entry=None):
         """One SPMD program over the mesh, named as the local executor
         names its own (``_named_jit``): the device trace and the program
-        catalog read ``jit_mesh_<name>``."""
+        catalog read ``jit_mesh_<name>``. ``entry`` is the scope a
+        chain's way into and out of the shards is charged to (its first
+        operator's; a program that is one operator lies under that
+        operator's scope whole)."""
         return _named_jit(
             jax.shard_map(
                 fn, mesh=self.mesh, in_specs=in_specs,
                 out_specs=out_specs, check_vma=False,
             ),
-            "mesh_" + name,
+            "mesh_" + name, scope=entry,
         )
 
     def _run(
@@ -617,13 +620,18 @@ class MeshExecutor(LocalExecutor):
                 fn, out_layout = stage.build_chain(chain, in_layout, caps)
                 leaves, meta = _page_leaves(sp)
 
+                tail = stage.op_scope(len(chain) - 1, chain[-1])
+
                 def flat_fn(*ls, _fn=fn, _meta=meta):
                     env, mask = _env_from_leaves(list(ls), _meta)
                     env2, mask2, flags = _fn(env, mask)
-                    flags = {
-                        k: jax.lax.pmax(v.astype(jnp.int32), axis)
-                        for k, v in flags.items()
-                    }
+                    # the flags' reduction over shards is the last
+                    # operator's
+                    with jax.named_scope(tail):
+                        flags = {
+                            k: jax.lax.pmax(v.astype(jnp.int32), axis)
+                            for k, v in flags.items()
+                        }
                     return env2, mask2, flags
 
                 def flat_fn_shape(*ls, _fn=fn, _meta=meta):
@@ -647,6 +655,7 @@ class MeshExecutor(LocalExecutor):
                 prog = self._shard_jit(
                     flat_fn, _chain_program_name(chain),
                     (PS(axis),) * len(leaves), out_specs,
+                    entry=stage.op_scope(0, chain[0]),
                 )
                 hit = (prog, out_layout, meta)
                 self._mesh_jit_cache[key] = hit
@@ -907,12 +916,12 @@ class MeshExecutor(LocalExecutor):
         prog_d = self._mesh_jit_cache.get("range-dest")
         miss = prog_d is None
         if miss:
-            def fd(splitters, bits_):
+            def range_dest(splitters, bits_):
                 return jnp.searchsorted(
                     splitters, bits_, side="right"
                 ).astype(jnp.int32)
 
-            prog_d = _named_jit(fd, "mesh_range_dest")
+            prog_d = _named_jit(K.kernel(range_dest), "mesh_range_dest")
             self._mesh_jit_cache["range-dest"] = prog_d
         dest = self._run(prog_d, miss, qs, bits)
         return self.exchange_by_dest(
@@ -1349,12 +1358,12 @@ class MeshExecutor(LocalExecutor):
         if miss:
             n = self.n_shards
 
-            def hist(d, m):
+            def dest_hist(d, m):
                 return jax.ops.segment_sum(
                     jnp.where(m, 1, 0), d, num_segments=n
                 )
 
-            prog = _named_jit(hist, "mesh_dest_hist")
+            prog = _named_jit(K.kernel(dest_hist), "mesh_dest_hist")
             self._mesh_jit_cache["dest-hist"] = prog
         counts = self._run(prog, miss, dest, sp.mask)
         with telemetry.child_span("host_sync", site="mesh_dest_counts"):
@@ -1666,8 +1675,8 @@ class MeshExecutor(LocalExecutor):
                 )
                 if verify:
                     for pb, bb in zip(p_bits, b_bits):
-                        out_live = out_live & (
-                            pb[probe_idx] == bb[build_idx]
+                        out_live = out_live & K.keys_match(
+                            pb, bb, probe_idx, build_idx
                         )
                 inner = {}
                 for s, from_probe, _ in out_meta:
@@ -1675,10 +1684,7 @@ class MeshExecutor(LocalExecutor):
                         (p_env, probe_idx) if from_probe
                         else (b_env, build_idx)
                     )
-                    d, v = env[s]
-                    inner[s] = (
-                        d[idx], None if v is None else v[idx]
-                    )
+                    inner[s] = K.rows_at(*env[s], idx)
                 if filter_c is not None:
                     fd, fv = filter_c.fn(inner)
                     out_live = out_live & (
@@ -1814,12 +1820,12 @@ class MeshExecutor(LocalExecutor):
                 outs = []
                 for s, from_probe, has_valid in out_meta:
                     env, idx = (p_env, li) if from_probe else (b_env, ri)
-                    d, v = env[s]
-                    outs.append(d[idx])
+                    d, v = K.rows_at(*env[s], idx)
+                    outs.append(d)
                     if has_valid:
                         outs.append(
                             jnp.ones(out_cap, dtype=jnp.bool_)
-                            if v is None else v[idx]
+                            if v is None else v
                         )
                 return outs, out_live
 
@@ -1927,23 +1933,15 @@ class MeshExecutor(LocalExecutor):
                         order, lo, cnt, out_cap
                     )
                     for pb, bb in zip(p_bits, b_bits):
-                        out_live = out_live & (
-                            pb[probe_idx] == bb[build_idx]
+                        out_live = out_live & K.keys_match(
+                            pb, bb, probe_idx, build_idx
                         )
                     if filter_c is not None:
                         pair = {}
                         for s in p_env:
-                            d, v = p_env[s]
-                            pair[s] = (
-                                d[probe_idx],
-                                None if v is None else v[probe_idx],
-                            )
+                            pair[s] = K.rows_at(*p_env[s], probe_idx)
                         for s in b_env:
-                            d, v = b_env[s]
-                            pair[s] = (
-                                d[build_idx],
-                                None if v is None else v[build_idx],
-                            )
+                            pair[s] = K.rows_at(*b_env[s], build_idx)
                         fd, fv = filter_c.fn(pair)
                         out_live = out_live & (
                             fd if fv is None else (fd & fv)

@@ -197,6 +197,11 @@ def plan_capacities(
     return caps
 
 
+def op_scope(i: int, nd: P.PlanNode) -> str:
+    """The scope of a chain's position ``i``: ``op<i>:<NodeType>``."""
+    return f"op{i}:{type(nd).__name__}"
+
+
 def build_chain(chain: list[P.PlanNode], layout: ChainLayout, caps: dict[int, list[int]]):
     """Build (fn, out_layout): ``fn(env, mask) -> (env', mask', flags)``
     is pure and jittable; ``flags`` maps chain position -> overflow
@@ -208,7 +213,7 @@ def build_chain(chain: list[P.PlanNode], layout: ChainLayout, caps: dict[int, li
         # per-instruction HLO op_name metadata (fusions included), so
         # a captured device profile can attribute time to this plan
         # operator INSIDE the fused program (kernel observatory)
-        scope = f"op{i}:{type(nd).__name__}"
+        scope = op_scope(i, nd)
         if isinstance(nd, P.Filter):
             steps.append((scope, _filter_step(nd, layout)))
         elif isinstance(nd, P.Project):
@@ -440,17 +445,13 @@ def _aggregate_step(
                 groupbys[pos] = "sorted"
                 info = K.sort_group(*group_args)
             flags = {**flags, pos: info.num_groups > capacity}
-            env2 = {}
             occupied = (
                 jnp.arange(capacity, dtype=jnp.int32) < info.num_groups
             )
-            own = jnp.clip(info.owner, 0, in_cap - 1)
-            for s in group_keys:
-                data, valid = env[s]
-                env2[s] = (
-                    data[own],
-                    None if valid is None else (valid[own] & occupied),
-                )
+            env2 = _keys_at_owner(
+                {s: env[s] for s in group_keys},
+                jnp.clip(info.owner, 0, in_cap - 1), occupied,
+            )
             out_mask = occupied
         cap_seg = 1 if is_global else capacity
         share = {"#mask": mask}  # per-step cache of sorted cols/counts
@@ -530,6 +531,16 @@ def _aggregate_step(
     return step, out_layout
 
 
+@K.kernel
+def _keys_at_owner(keys: dict, own, occupied) -> dict:
+    """Each group's key columns, read at the group's first row."""
+    return {
+        s: (data[own], None if valid is None else (valid[own] & occupied))
+        for s, (data, valid) in keys.items()
+    }
+
+
+@K.kernel
 def _presort_shared(prepared, info, share):
     """Gather every column the step's aggregates need into group-sorted
     order in as few device gathers as possible: same-dtype columns are
@@ -587,6 +598,7 @@ def _presort_shared(prepared, info, share):
                 share[("sorted", id(x))] = (x, stacked[:, i])
 
 
+@K.kernel
 def _dedupe(key_cols, arg, live, page_capacity, widths=None):
     """DISTINCT: keep one representative row per (group keys, value).
 
@@ -639,10 +651,8 @@ def _sort_step(nd, layout: ChainLayout):
         perm = K.sort_perm(sort_keys, mask)
         if limit is not None:
             perm = perm[:limit]
-        env2 = {}
-        for s, (data, valid) in env.items():
-            env2[s] = (data[perm], None if valid is None else valid[perm])
-        mask2 = mask[perm]
+        env2 = {s: K.rows_at(d, v, perm) for s, (d, v) in env.items()}
+        mask2, _ = K.rows_at(mask, None, perm)
         if count is not None:
             mask2 = mask2 & (jnp.arange(mask2.shape[0]) < count)
         return env2, mask2, flags
@@ -650,9 +660,15 @@ def _sort_step(nd, layout: ChainLayout):
     return step, out_layout
 
 
+@K.kernel
+def _live_rank(mask):
+    """1-based rank of each live row among the live rows."""
+    return jnp.cumsum(mask.astype(jnp.int64))
+
+
 def _limit_step(nd: P.Limit):
     def step(env, mask, flags):
-        rank = jnp.cumsum(mask.astype(jnp.int64))
+        rank = _live_rank(mask)
         keep = mask & (rank > nd.offset)
         if nd.count >= 0:
             keep = keep & (rank <= nd.offset + nd.count)

@@ -57,7 +57,8 @@ def build_window_program(node: P.Window, layout_types, layout_dicts, capacity):
             d = layout_dicts.get(call.args[0].name)
         out_meta.append((sym, call.type, d))
 
-    def fn(env, mask):
+    @K.kernel
+    def window_rows(env, mask):
         n = mask.shape[0]
         # 1. order-by sort first, stable partition grouping on top
         if order_keys:
@@ -163,9 +164,10 @@ def build_window_program(node: P.Window, layout_types, layout_dicts, capacity):
             env2[sym] = (data, valid)
         return env2
 
-    return fn, out_meta
+    return window_rows, out_meta
 
 
+@K.kernel
 def _eval_call(
     call, env, mask, perm, info, pos, live_s,
     pstart, pend, peer_start, peer_end, peer_b, row_number, n,
@@ -371,6 +373,7 @@ def _const_arg(ref) -> int:
     raise NotImplementedError("window offset must be a literal")
 
 
+@K.kernel
 def _sorted_arg(env, ref, perm):
     if ref is None:
         return None, None
@@ -409,6 +412,7 @@ def _bound_pos(bound, pos, pstart, pend, peer_start, peer_end, mode, is_lo):
     return pos + off if is_lo else pos + off + 1
 
 
+@K.kernel
 def _seg_searchsorted(w, target, lo0, hi0, left, n):
     """Per-row binary search of ``target[i]`` within the row's own
     sorted segment ``w[lo0[i]:hi0[i])``. ``left`` gives the first
@@ -426,6 +430,7 @@ def _seg_searchsorted(w, target, lo0, hi0, left, n):
     return lo.astype(lo0.dtype)
 
 
+@K.kernel
 def _range_sum(vals, lo, hi, n, gid=None):
     """Per-row sum of vals over sorted positions [lo, hi).
 
@@ -461,6 +466,7 @@ def _range_sum(vals, lo, hi, n, gid=None):
     return jnp.where(hi > lo, top - bot, zero)
 
 
+@K.kernel
 def _range_minmax(data, contrib, lo, hi, pos, pstart, info, is_min, n):
     """Running min/max for prefix frames (lo == partition start):
     a segmented scan; the value at hi-1 is the frame's reduction.
@@ -483,6 +489,7 @@ def _range_minmax(data, contrib, lo, hi, pos, pstart, info, is_min, n):
     return jnp.where(hi > lo, scan[at], fill)
 
 
+@K.kernel
 def _range_minmax_limbs(data, contrib, lo, hi, info, is_min, n):
     """Running min/max over a two-limb decimal column: numeric order is
     lexicographic (hi signed, lo canonical non-negative), so the

@@ -26,6 +26,7 @@ import re
 from dataclasses import dataclass, field
 from typing import Callable
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -368,12 +369,19 @@ class _Compiler:
                 # (float64 approximation; exactness lives in the limb
                 # aggregates, not in mixed arithmetic)
                 scale = 10.0 ** s_t.scale
-                return wrap(
-                    lambda x: (
-                        x[..., 0].astype(jnp.float64) * 4294967296.0
-                        + x[..., 1].astype(jnp.float64)
-                    ).astype(dtype) / scale
-                )
+
+                def limbs_to_double(x):
+                    # a site of its own in a device trace (the scope
+                    # grammar of exec/kernels.py): float64 is emulated
+                    # on the chip, and a HAVING over a decimal(38) sum
+                    # pays this a group
+                    with jax.named_scope("s:limbs_to_double"):
+                        return (
+                            x[..., 0].astype(jnp.float64) * 4294967296.0
+                            + x[..., 1].astype(jnp.float64)
+                        ).astype(dtype) / scale
+
+                return wrap(limbs_to_double)
             if isinstance(s_t, T.DecimalType):
                 scale = 10.0 ** s_t.scale
                 return wrap(lambda x: x.astype(dtype) / scale)

@@ -23,9 +23,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from trino_tpu.exec.kernels import kernel
+
 __all__ = ["partition_exchange", "seam_exchange"]
 
 
+@kernel
 def partition_exchange(
     dest: jnp.ndarray,
     live: jnp.ndarray,
@@ -89,6 +92,7 @@ def partition_exchange(
     return out, recv_live, overflowed
 
 
+@kernel
 def seam_exchange(
     word: jnp.ndarray,
     live: jnp.ndarray,

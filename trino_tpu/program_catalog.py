@@ -55,8 +55,12 @@ DEFAULT_MAX_ENTRIES = 256
 #: retained HLO text per entry; full modules for big chains run to MBs
 HLO_TEXT_LIMIT = 120_000
 
-#: one ``jax.named_scope`` component as stage.build_chain writes them
-_SCOPE_RE = re.compile(r"op\d+:[A-Za-z_]\w*")
+#: an operator's ``jax.named_scope`` component: ``op<i>:<NodeType>`` as
+#: stage.build_chain writes a chain's positions, ``op:<NodeType>`` as
+#: local._named_jit writes a program that is one operator
+_SCOPE_RE = re.compile(r"op\d*:[A-Za-z_]\w*")
+#: the module's name at the head of its HLO text
+_MODULE_RE = re.compile(r"\s*HloModule\s+([\w.\-]+)")
 #: one HLO instruction definition carrying op_name metadata
 _INSTR_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?([A-Za-z_][\w.\-]*)\s*=.*?"
@@ -74,9 +78,11 @@ def scope_map_from_hlo(hlo_text: str) -> dict[str, str]:
     them back onto plan operators."""
     out: dict[str, str] = {}
     for instr, op_name in _INSTR_RE.findall(hlo_text or ""):
-        m = _SCOPE_RE.search(op_name)
-        if m is not None:
-            out[instr] = m.group(0)
+        # the innermost operator wins (a mesh chain's way into the
+        # shards lies under its first position's scope)
+        found = _SCOPE_RE.findall(op_name)
+        if found:
+            out[instr] = found[-1]
     return out
 
 
@@ -124,6 +130,8 @@ class ProgramEntry:
     hlo_hash: Optional[str] = None
     hlo_lines: int = 0
     hlo_text: Optional[str] = field(default=None, repr=False)
+    #: XLA's module name (``jit_<program>``), as a trace names it
+    module: Optional[str] = None
     #: HLO instruction name -> named-scope label (profile attribution)
     scopes: dict = field(default_factory=dict, repr=False)
 
@@ -147,6 +155,7 @@ class ProgramEntry:
             "alias_bytes": self.alias_bytes,
             "hlo_hash": self.hlo_hash,
             "hlo_lines": self.hlo_lines,
+            "module": self.module,
             "scope_count": len(self.scopes),
         }
         if include_hlo:
@@ -314,6 +323,8 @@ class ProgramCatalog:
             entry.hlo_hash = hashlib.sha1(text.encode()).hexdigest()[:16]
             entry.hlo_lines = text.count("\n") + 1
             entry.scopes = scope_map_from_hlo(text)
+            m = _MODULE_RE.match(text)
+            entry.module = m.group(1) if m is not None else None
             entry.hlo_text = text[:HLO_TEXT_LIMIT]
 
     # ---- reads --------------------------------------------------------
@@ -363,19 +374,22 @@ class ProgramCatalog:
             "alias_bytes": entry.alias_bytes,
         }
 
-    def scope_union(self, resolve: bool = True) -> dict[str, str]:
-        """Union of every entry's instruction→scope map, for joining a
-        captured device profile back onto plan operators. Instruction
-        names collide across modules only for same-shaped programs
-        whose colliding instructions carry the same scope label, so a
-        union is the right join table."""
+    def scope_maps(self, resolve: bool = True) -> dict[str, dict[str, str]]:
+        """``{module name: {instruction: scope}}``, for joining a
+        captured profile whose events name their HLO module (a CPU
+        trace's; a chip's carries the scope on the event itself) back
+        onto plan operators. Two programs of one name (other shapes of
+        one chain) share a module name and their maps are merged: where
+        an instruction name collides there, both programs are the same
+        operators in the same order."""
         with self._lock:
             entries = list(self._entries.values())
-        out: dict[str, str] = {}
+        out: dict[str, dict[str, str]] = {}
         for e in entries:
             if resolve:
                 self._resolve(e)
-            out.update(e.scopes)
+            if e.module is not None:
+                out.setdefault(e.module, {}).update(e.scopes)
         return out
 
     def snapshot(
