@@ -317,3 +317,175 @@ def test_join_ranges_two_searches_agree_with_numpy(case, cap, monkeypatch):
     for name, (order, lo, cnt) in got.items():
         for what, a, b in zip(("order", "lo", "cnt"), (order, lo, cnt), want):
             assert a.dtype == b.dtype and np.array_equal(a, b), (name, what)
+
+
+# ---- reads at one index vector ride one walk of words (ISSUE 44) ----------
+
+
+@pytest.mark.parametrize("case", [
+    "probe_above_every_build_key", "probe_below_every_build_key",
+    "probe_is_the_top_word", "duplicate_build_keys", "dead_build_tail",
+    "empty_live_build", "dead_probe_rows",
+])
+@pytest.mark.parametrize("n_probe", [700, 20_000], ids=["scan", "merge_rank"])
+def test_join_ranges_sort_branch_reads_key_and_run_end_in_one_walk(
+        case, n_probe, monkeypatch):
+    """The sort branch's ``[at]`` reads — the build key and the end of
+    its run — are one gather of ``[probe, 3]`` uint32 words, the key
+    compared as halves: ``lo`` and ``cnt`` are numpy's ``searchsorted``
+    left and right, clamped to the live build."""
+    monkeypatch.setattr(K, "JOIN_SMALL_BUILD", -1)  # every build sorts
+    rng = np.random.default_rng(len(case) + n_probe)
+    nb = 300
+    bk = (rng.integers(1000, 2000, nb) << 33).astype(np.uint64)  # both halves
+    bk += rng.integers(0, 3, nb).astype(np.uint64)
+    pk = rng.choice(bk, n_probe)
+    pk[::3] += np.uint64(1)  # a near miss in the low half
+    pk[1::7] ^= np.uint64(1 << 40)  # and one in the high half
+    bl, pl = np.ones(nb, bool), np.ones(n_probe, bool)
+    if case == "probe_above_every_build_key":
+        pk[:50] = bk.max() + np.uint64(5)  # lo == n_build: ``at`` is clipped
+    elif case == "probe_below_every_build_key":
+        pk[:50] = np.uint64(7)
+    elif case == "probe_is_the_top_word":
+        pk[:50] = _TOP
+        bk[:3] = _TOP  # live
+        bl[-40:] = False  # and a dead tail pinned to the same word
+    elif case == "duplicate_build_keys":
+        bk = rng.choice(bk[:20], nb)
+        pk = rng.choice(bk, n_probe)
+    elif case == "dead_build_tail":
+        bl = rng.random(nb) < 0.5
+        pk[:200] = rng.choice(bk[~bl], 200)  # keys only dead rows hold
+    elif case == "empty_live_build":
+        bl[:] = False
+    elif case == "dead_probe_rows":
+        pl = rng.random(n_probe) < 0.5
+    args = tuple(jnp.asarray(x) for x in (bk, bl, pk, pl))
+    fn = jax.jit(K.join_ranges.__wrapped__)
+    got = tuple(map(_np, fn(*args)))
+    for what, a, b in zip(("order", "lo", "cnt"), got,
+                          _join_ranges_ref(bk, bl, pk, pl)):
+        assert a.dtype == b.dtype and np.array_equal(a, b), what
+    probe_sized = [
+        e.outvars[0].aval
+        for e in jax.make_jaxpr(K.join_ranges.__wrapped__)(*args).eqns
+        if e.primitive.name == "gather"
+        and e.outvars[0].aval.shape[:1] == (n_probe,)
+    ]
+    assert [(a.shape, a.dtype) for a in probe_sized] == [
+        ((n_probe, 3), jnp.uint32)]
+
+
+def _start_walk_case(case: str, rng):
+    """(gid_sorted, starts, ends, owner, n_live, n) of contiguous runs."""
+    n, cap = 96, 48  # runs of 1-5 rows: at most 71 groups, some 24
+    if case == "capacity_above_rows":
+        cap = 160
+    n_live = {"all_live": n, "no_live_row": 0}.get(case, 71)
+    lens = []
+    while sum(lens) < n_live:
+        lens.append(int(rng.integers(1, 6)))
+    if lens:
+        lens[-1] -= sum(lens) - n_live
+    if case == "overflow":
+        lens = [1] * n_live  # 71 groups in 48 slots
+    starts_all = np.cumsum([0] + lens[:-1]).astype(np.int32)
+    g = len(lens)
+    used = min(g, cap)
+    starts = np.full(cap, n_live, np.int32)
+    starts[:used] = starts_all[:used]
+    ends = np.concatenate([starts[1:], [n_live]]).astype(np.int32)
+    owner = np.full(cap, n, np.int32)
+    owner[:used] = starts_all[:used]
+    gid = np.minimum(np.repeat(np.arange(g), lens), cap)
+    gid = np.concatenate([gid, np.full(n - n_live, cap)]).astype(np.int32)
+    return gid, starts, ends, owner, n_live, g
+
+
+@pytest.mark.parametrize("case", [
+    "dead_tail", "all_live", "capacity_above_rows", "no_live_row",
+    "overflow",
+])
+def test_start_walk_sums_and_keys_at_one_vector(case):
+    """``kernels.start_walk`` over rows grouped in place: sums of int64
+    and int32 columns as numpy's per-run sums (wrapping like int64),
+    the keys — a nullable int64, a bool, an int16 — as ``data[owner]``
+    bit for bit, in ``gather_plan``'s gathers and none of them
+    64-bit."""
+    rng = np.random.default_rng(len(case))
+    gid, starts, ends, owner, n_live, g = _start_walk_case(case, rng)
+    n, cap = len(gid), len(starts)
+    info = K.GroupInfo(
+        None, jnp.asarray(gid), jnp.asarray(gid), jnp.asarray(starts),
+        jnp.asarray(ends), jnp.asarray(owner), jnp.int32(g))
+    live = np.arange(n) < n_live
+    sums = [
+        np.where(live, rng.integers(-(1 << 62), 1 << 62, n), 0),
+        np.where(live, rng.integers(0, 1 << 32, n), 0),
+        np.where(live, rng.integers(-9, 9, n), 0).astype(np.int32),
+    ]
+    keys = {
+        "k": (rng.integers(-(1 << 62), 1 << 62, n), rng.random(n) < 0.7),
+        "b": (rng.random(n) < 0.5, None),
+        "h": (rng.integers(-300, 300, n).astype(np.int16), None),
+    }
+    dev = lambda x: None if x is None else jnp.asarray(x)  # noqa: E731
+
+    def walk(sums, keys):
+        return K.start_walk(info, sums, keys)
+
+    args = ([dev(s) for s in sums],
+            {s: (dev(d), dev(v)) for s, (d, v) in keys.items()})
+    got_sums, got_keys = jax.jit(walk)(*args)
+    for vals, got in zip(sums, got_sums):
+        want = np.zeros(cap, vals.dtype)
+        with np.errstate(over="ignore"):
+            for slot in range(cap):
+                if ends[slot] > starts[slot]:
+                    want[slot] = vals[starts[slot]:ends[slot]].sum(
+                        dtype=vals.dtype)
+        assert got.dtype == want.dtype and np.array_equal(_np(got), want)
+    at = np.clip(owner, 0, n - 1)
+    for s, (d, v) in keys.items():
+        gd, gv = got_keys[s]
+        assert gd.dtype == d.dtype and np.array_equal(_np(gd), d[at]), s
+        assert (gv is None) == (v is None)
+        if v is not None:
+            assert np.array_equal(_np(gv), v[at]), s
+    sized = [
+        e.outvars[0].aval for e in jax.make_jaxpr(walk)(*args).eqns
+        if e.primitive.name == "gather"
+        and e.outvars[0].aval.shape[:1] == (cap,)
+    ]
+    # 2 + 2 + 1 words of sums, 2 + 1 + 1 of keys, one of validity bits
+    assert len(sized) == K.gather_plan(
+        [(s.dtype, (), False) for s in sums]
+        + [(d.dtype, (), v is not None) for d, v in keys.values()]
+    )[1] == 3
+    assert all(a.dtype == jnp.uint32 for a in sized), sized
+
+
+def test_seg_sum_ranges_is_a_walk_of_one_column():
+    """The kernel's lone entry point: an integer column is a
+    ``start_walk`` of itself — a word view, not a 64-bit gather — and a
+    float column keeps its segmented scan."""
+    rng = np.random.default_rng(5)
+    gid, starts, ends, owner, n_live, g = _start_walk_case("dead_tail", rng)
+    info = K.GroupInfo(
+        None, jnp.asarray(gid), jnp.asarray(gid), jnp.asarray(starts),
+        jnp.asarray(ends), jnp.asarray(owner), jnp.int32(g))
+    vals = np.where(np.arange(len(gid)) < n_live,
+                    rng.integers(-(1 << 40), 1 << 40, len(gid)), 0)
+    want = np.array([vals[a:b].sum() for a, b in zip(starts, ends)])
+    got = K.seg_sum_ranges(jnp.asarray(vals), info)
+    assert np.array_equal(_np(got), want)
+    fgot = K.seg_sum_ranges(jnp.asarray(vals.astype(np.float64)), info)
+    np.testing.assert_allclose(_np(fgot), want.astype(np.float64))
+    jaxpr = jax.make_jaxpr(lambda v: K.seg_sum_ranges(v, info))(
+        jnp.asarray(vals))
+    out = [e.outvars[0].aval for e in jaxpr.eqns
+           if e.primitive.name == "gather"
+           and e.outvars[0].aval.shape[:1] == (len(starts),)]
+    assert [(a.shape, a.dtype) for a in out] == [
+        ((len(starts), 2), jnp.uint32)]

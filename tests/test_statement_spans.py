@@ -35,7 +35,7 @@ FLAT_FIELDS = (
     "build_trace_ms", "host_sync_ms", "host_syncs", "dispatches",
     "upload_ms", "to_rows_ms", "respond_ms", "rows_out_ms",
     "direct_groupbys", "sorted_groupbys", "streamed_groupbys",
-    "compactions", "compact_gather_ops",
+    "groupby_start_walks", "compactions", "compact_gather_ops",
     "small_build_joins", "sorted_joins",
 )
 PROGRAM = re.compile(
@@ -235,6 +235,37 @@ def test_query_rows_count_grouped_aggregates_by_their_path(
         row = row_of(coord, qid)
         assert (row["direct_groupbys"], row["sorted_groupbys"],
                 row["streamed_groupbys"]) == (direct, by_sort, streamed), row
+
+
+@pytest.mark.parametrize("q", FOUR)
+def test_query_rows_count_the_walks_at_their_groups_first_rows(coord, q):
+    """A grouped aggregate reads its keys and its integer sums at its
+    groups' first rows in stacked walks of 32-bit words (ISSUE 44): the
+    ``dispatch`` span of a chain says in how many ``[capacity]``-sized
+    gathers (``kernels.gather_plan`` over the columns read there), the
+    row sums them; a warm dispatch reports the same. Q18's inner step
+    reads two int64 limb sums and its int64 key: six words, two
+    gathers, where three lone int64 gathers stood."""
+    from trino_tpu.exec import kernels as K
+
+    for _ in range(2):
+        qid, _ = serve(coord, QUERIES[q])
+        row = row_of(coord, qid)
+        chains = [
+            sp["attrs"] for sp, _ in
+            walk(get(coord, f"/v1/query/{qid}")["spans"])
+            if sp["name"] == "dispatch" and "groupbys" in sp["attrs"]
+        ]
+        assert row["groupby_start_walks"] == sum(
+            a["start_walks"] for a in chains)
+        if q == "q06":  # an ungrouped aggregate reads at no group's row
+            assert row["groupby_start_walks"] == 0
+            continue
+        assert all(a["start_walks"] >= len(a["groupbys"]) for a in chains)
+        if q == "q18":
+            (inner,) = [a for a in chains if a["groupbys"] == ["streamed"]]
+            i64 = (jax.numpy.int64, (), False)
+            assert inner["start_walks"] == K.gather_plan([i64] * 3)[1] == 2
 
 
 @pytest.mark.parametrize("q,compacts", [

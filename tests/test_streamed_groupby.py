@@ -11,6 +11,7 @@ no ``ordered_on``. Both run the one lowering the executors use
 
 from dataclasses import replace as dc_replace
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -567,3 +568,254 @@ def test_a_mesh_shard_does_too(by_order_rows):
 def test_q18_tiny_streams_its_inner_group_by(tiny):
     res = tiny.execute(QUERIES["q18"])
     assert _groupbys(res) == ["streamed", "sorted"]
+
+
+# ---- one walk at the groups' first rows (ISSUE 44) ---------------------------
+#
+# The step reads every integer sum of its aggregates, and in place its
+# key, at one index vector (``kernels.start_walk``). Streamed and sorted
+# now share that code, so the reference here is neither: exact integer
+# arithmetic over the rows of each group, in Python.
+
+
+def _wrap64(x: int) -> int:
+    return (x + (1 << 63)) % (1 << 64) - (1 << 63)
+
+
+def _limb_pair(x: int) -> list[int]:
+    return [x >> 32, x & 0xFFFFFFFF]
+
+
+def _avg_half_away(s: int, c: int) -> int:
+    q = (2 * abs(s) + c) // (2 * c)
+    return q if s >= 0 else -q
+
+
+def _walk_columns(rng):
+    """The columns of ``_columns`` plus the two BIGINT limb states a
+    distributed decimal sum's FINAL step re-aggregates (one validity
+    lane for both, as the PARTIAL step emits them)."""
+    cols = _columns(rng)
+    state_valid = rng.random(N) < 0.85
+    cols["hi_state"] = (
+        T.BIGINT, rng.integers(-(1 << 31), 1 << 31, N, dtype=np.int64),
+        state_valid)
+    cols["lo_state"] = (
+        T.BIGINT, rng.integers(0, 1 << 40, N, dtype=np.int64), state_valid)
+    return cols
+
+
+def _walk_aggregates(cols):
+    r = lambda name: InputRef(cols[name][0], name)  # noqa: E731
+    return {
+        "sum_limbs": AggCall("sum", (r("dec"),), DEC38),
+        "sum_states": AggCall("sum", (r("wide"),), DEC38),
+        "sum_final": AggCall(
+            "decimal_sum_final", (r("hi_state"), r("lo_state")), DEC38),
+        "sum_bigint": AggCall("sum", (r("big"),), T.BIGINT),
+        "count_filter": AggCall("count_all", (), T.BIGINT, filter=r("flag")),
+        "count_nullable": AggCall("count", (r("decn"),), T.BIGINT),
+        "avg": AggCall("avg", (r("decn"),), DEC),
+    }
+
+
+def _exact(cols, rows):
+    """name -> (value, is NULL) of ``_walk_aggregates`` over ``rows``."""
+    col = lambda c: [  # noqa: E731
+        (cols[c][1][i], cols[c][2] is None or bool(cols[c][2][i]))
+        for i in rows
+    ]
+    dec = [int(d) for d, _ in col("dec")]
+    wide = [(int(d[0]) << 32) + int(d[1]) for d, ok in col("wide") if ok]
+    hi = [int(d) for d, ok in col("hi_state") if ok]
+    lo = [int(d) for d, ok in col("lo_state") if ok]
+    decn = [int(d) for d, ok in col("decn") if ok]
+    return {
+        "sum_limbs": (_limb_pair(sum(dec)), False),
+        "sum_states": (_limb_pair(sum(wide)), not wide),
+        "sum_final": (_limb_pair((sum(hi) << 32) + sum(lo)), not hi),
+        "sum_bigint": (_wrap64(sum(int(d) for d, _ in col("big"))), False),
+        "count_filter": (sum(bool(d) and ok for d, ok in col("flag")), False),
+        "count_nullable": (len(decn), False),
+        "avg": (_avg_half_away(sum(decn), max(len(decn), 1)), not decn),
+    }
+
+
+def _check_exact(got, g, cols, groups, names=None):
+    """``got``'s first ``g`` slots against ``groups`` (key -> rows, in
+    output order)."""
+    assert g == len(groups)
+    for slot, (key, rows) in enumerate(groups.items()):
+        if key is None:
+            assert not got["k"][1][slot]
+        else:
+            assert got["k"][0][slot] == key
+            assert got["k"][1] is None or got["k"][1][slot]
+        for s, (want, null) in _exact(cols, rows).items():
+            if names is not None and s not in names:
+                continue
+            d, v = got[s]
+            assert (v is None and not null) or v[slot] == (not null), (s, key)
+            if not null:
+                assert np.asarray(d[slot]).tolist() == want, (s, key, slot)
+
+
+def _groups_of(keys, n_live):
+    groups: dict = {}
+    for i in range(n_live):
+        groups.setdefault(keys[i], []).append(i)
+    return groups
+
+
+@pytest.mark.parametrize("ordered", [True, False], ids=["streamed", "sorted"])
+@pytest.mark.parametrize("shape", [
+    "dead_tail", "all_live", "capacity_above_rows", "no_live_row",
+    "one_row_runs", "nullable_key",
+])
+def test_start_walk_against_exact_arithmetic(shape, ordered):
+    """Seven integer-summing aggregates in one step (nine sums and four
+    or five counts, each an int64 prefix sum: seven stacks of words)
+    over runs whose first starts at row 0 and whose last ends at the last live row — at the
+    page's last row where every row is live, the one position an
+    exclusive prefix sum cannot be read at."""
+    rng = np.random.default_rng(44)
+    cols = _walk_columns(rng)
+    n_live = {"all_live": N, "no_live_row": 0}.get(shape, 449)
+    # (a key of one row would have a domain narrow enough to address)
+    key = _run_key(rng, n_live or 400, max_run=1 if shape == "one_row_runs"
+                   else 7)
+    keys = key[1].tolist()
+    if shape == "nullable_key":
+        t, data, _v, (lo, hi) = key
+        first_other = int(np.argmax(data != lo))
+        valid = np.ones(N, bool)
+        valid[first_other:first_other + 5] = False  # word order: 0.., 1, 2..
+        data = data.copy()
+        data[first_other + 5:n_live] += 1
+        key = (t, data, valid, (lo, hi + 1))
+        keys = [int(d) if ok else None for d, ok in zip(data, valid)]
+    cap = 2 * N if shape == "capacity_above_rows" else N
+    mask = np.arange(N) < n_live
+    path, got, g, over, _u = _run_step(
+        key, _walk_aggregates(cols), cols, mask, ordered, cap)
+    assert path == ("streamed" if ordered else "sorted") and not over
+    _check_exact(got, g, cols, _groups_of(keys, n_live))
+
+
+@pytest.mark.parametrize("ordered", [True, False], ids=["streamed", "sorted"])
+def test_start_walk_under_overflow(ordered):
+    """More groups than slots: the flag is set, and every slot but the
+    last still holds its own group's exact sums (the caller retries
+    larger and reads none of them)."""
+    rng = np.random.default_rng(45)
+    cols = _walk_columns(rng)
+    key = _run_key(rng, N, max_run=2)
+    cap = 64
+    path, got, g, over, _u = _run_step(
+        key, _walk_aggregates(cols), cols, np.ones(N, bool), ordered, cap)
+    assert over and g == cap
+    groups = dict(list(_groups_of(key[1].tolist(), N).items())[:cap - 1])
+    got = {s: (d[:cap - 1], None if v is None else v[:cap - 1])
+           for s, (d, v) in got.items()}
+    _check_exact(got, cap - 1, cols, groups)
+
+
+def test_start_walk_two_keys_in_one_word():
+    """Two narrow keys pack into one sort word (the sort path; a page
+    is ordered on one column): sums stack among themselves, the keys
+    are read at ``perm[starts]`` — a second vector — as they are, a
+    column a gather."""
+    rng = np.random.default_rng(46)
+    cols = _walk_columns(rng)
+    cols["k2"] = (T.BIGINT, rng.integers(0, 5, N, dtype=np.int64), None)
+    k1 = rng.integers(10, 200, N, dtype=np.int64)  # 8 + 3 bits: no slots
+    n_live = 470
+    aggs = _walk_aggregates(cols)
+    node = P.Aggregate(
+        outputs={"k": T.BIGINT, "k2": T.BIGINT,
+                 **{s: a.type for s, a in aggs.items()}},
+        source=None, group_keys=["k", "k2"], aggregates=aggs,
+        key_ranges={"k": (10, 199), "k2": (0, 4)},
+    )
+    types = {"k": T.BIGINT, **{c: t for c, (t, *_r) in cols.items()}}
+    layout = stage.ChainLayout(
+        names=list(types), types=types, dicts=dict.fromkeys(types),
+        capacity=N,
+    )
+    fn, out = stage.build_chain([node], layout, {0: [N, N]})
+    env = {"k": (jnp.asarray(k1), None), **{
+        c: (jnp.asarray(d), None if v is None else jnp.asarray(v))
+        for c, (_t, d, v) in cols.items()}}
+    env2, out_mask, flags = fn(env, jnp.asarray(np.arange(N) < n_live))
+    assert out.groupbys == {0: "sorted"} and not bool(flags[0])
+    g = int(np.asarray(out_mask).sum())
+    pairs = sorted(set(zip(k1[:n_live].tolist(),
+                           cols["k2"][1][:n_live].tolist())))
+    assert g == len(pairs)
+    assert list(zip(np.asarray(env2["k"][0])[:g].tolist(),
+                    np.asarray(env2["k2"][0])[:g].tolist())) == pairs
+    by_pair = _groups_of(
+        list(zip(k1.tolist(), cols["k2"][1].tolist())), n_live)
+    for slot, pair in enumerate(pairs):
+        for s, (want, null) in _exact(cols, by_pair[pair]).items():
+            d, v = env2[s]
+            assert (v is None and not null) or bool(v[slot]) == (not null), s
+            if not null:
+                assert np.asarray(d[slot]).tolist() == want, (s, pair)
+    # nine int64 sums and five counts: 28 words, seven stacks; the two
+    # keys a gather each
+    assert out.start_walks == {0: 28 // 4 + 2}
+
+
+def test_the_step_counts_its_start_walks():
+    """``ChainLayout.start_walks``: what ``kernels.gather_plan`` gives
+    for the columns the step reads at its groups' first rows — Q18's
+    inner step two int64 limb sums and its int64 key, six words in two
+    gathers where three int64 gathers stood; the sort path counts its
+    live rows by a prefix sum too (two walks), and its key, at another
+    vector, is a gather of its own, as it is."""
+    from trino_tpu.exec import kernels as K
+
+    rng = np.random.default_rng(47)
+    cols = _walk_columns(rng)
+    aggs = {"s": _walk_aggregates(cols)["sum_limbs"]}
+    key = _run_key(rng, 449)
+    mask = np.arange(N) < 449
+    for ordered, want in ((True, 2), (False, 2 + 1)):
+        kt, kd, kv, krange = key
+        node = P.Aggregate(
+            outputs={"k": kt, "s": DEC38}, source=None, group_keys=["k"],
+            aggregates=aggs, key_ranges={"k": krange},
+        )
+        layout = stage.ChainLayout(
+            names=["k", "dec"], types={"k": kt, "dec": DEC},
+            dicts={"k": None, "dec": None}, capacity=N,
+            ordered_on="k" if ordered else None,
+        )
+        fn, out = stage.build_chain([node], layout, {0: [CAP, CAP]})
+        jaxpr = jax.make_jaxpr(fn)(
+            {"k": (jnp.asarray(kd), None),
+             "dec": (jnp.asarray(cols["dec"][1]), None)}, jnp.asarray(mask))
+        assert out.start_walks == {0: want}
+        sized = [
+            e.outvars[0].aval for e in _equations(jaxpr.jaxpr)
+            if e.primitive.name == "gather"
+            and e.outvars[0].aval.shape[:1] == (CAP,)
+        ]
+        # (the sort path also reads ``perm`` at its starts: int32)
+        wide = [a for a in sized if a.dtype.itemsize == 8]
+        assert [(a.shape, a.dtype) for a in wide] == (
+            [] if ordered else [((CAP,), jnp.int64)]), sized  # its key
+        walks = [a for a in sized if a.dtype == jnp.uint32 and a.ndim == 2]
+        assert len(walks) + len(wide) == want
+        assert all(a.shape[1] <= K.GATHER_STACK_WORDS for a in walks)
+    i64 = (jnp.int64, (), False)
+    assert K.gather_plan([i64] * 3)[1] == 2
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)

@@ -147,8 +147,11 @@ def test_q18_group_by_runs_at_lineitem_sf1(one_chip, no_compile_cache):
     key order, as ``stage.build_chain`` lowers it (``kernels.run_group``,
     ISSUE 31): ONE sort, single-operand and of uint32 words (the
     boundary rows' compaction) — no sort keyed by the group key, no
-    ``_merge_rank`` — and every gather reads ``capacity`` entries, none
-    a row-sized column."""
+    ``_merge_rank`` — and what it reads at the run starts, the two limb
+    sums' prefix sums and the key, is one walk (ISSUE 44): six 32-bit
+    words in two ``[capacity, <= 4]`` uint32 gathers, no gather whose
+    result is 64-bit but the one-entry reads of the two totals, none a
+    row-sized column."""
     from trino_tpu import types as T
     from trino_tpu.exec import stage
     from trino_tpu.expr.ir import AggCall, InputRef
@@ -181,11 +184,52 @@ def test_q18_group_by_runs_at_lineitem_sf1(one_chip, no_compile_cache):
     assert re.findall(
         r"^\s*\}\) : \(tensor<(\w+)>\) -> tensor<\w+>$", txt, re.M
     ) == [f"{n}xui32"]  # the sort's one operand
-    gathered = re.findall(r"stablehlo\.gather.*-> tensor<(\d+)x", txt)
-    # (and the one-entry reads of the prefix sums' totals)
-    assert set(gathered) == {str(capacity), "1"}
+    gathered = re.findall(r"stablehlo\.gather.*-> tensor<([\dx]+)x(\w+)>", txt)
+    assert sorted(gathered) == sorted([
+        ("1", "i64"), ("1", "i64"),  # the prefix sums' totals
+        (f"{capacity}x4", "ui32"), (f"{capacity}x2", "ui32"),
+    ])
+    assert out.start_walks == {0: 2}
+    results = re.findall(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([\d,]*)\]\S* gather\(",
+        compiled.as_text(), re.M,
+    )
+    assert sorted(r for r in results if r[1] != "1") == [
+        ("u32", f"{capacity},2"), ("u32", f"{capacity},4")], results
     ma = compiled.memory_analysis()
-    assert ma.temp_size_in_bytes < 195 << 20  # the sort path's step held more
+    # 164.0 MiB as compiled for a v5e here (PR 44): the two prefix sums
+    # (48 MiB each) and the two stacks of words read at the starts (96
+    # and 48 MiB where the three lone gathers read the columns in
+    # place), less what the compiler overlays; the sort path's step
+    # held more than 195
+    assert ma.temp_size_in_bytes < 180 << 20
+
+
+def test_join_ranges_at_q3_sf1(one_chip, no_compile_cache):
+    """Q3's ``lineitem`` join at SF1 — a probe of 4,194,304 rows ranked
+    in a build of 262,144 by sort (``join_search``) — reads the build
+    key and the end of its run at ``lo`` in ONE gather of ``[probe, 3]``
+    uint32 words (ISSUE 44) where a uint64 and an int32 gather stood,
+    and every sort is still single-operand and unstable."""
+    n, b = 4_194_304, 262_144
+    assert K.join_search(b) == "sort"
+    lowered, compiled = _compile(
+        K.join_ranges.__wrapped__, one_chip, ((b,), jnp.uint64),
+        ((b,), jnp.bool_), ((n,), jnp.uint64), ((n,), jnp.bool_),
+    )
+    sorts = _sorts(lowered)
+    assert sorts and all(s == (1, False) for s in sorts)
+    probe_sized = re.findall(
+        rf"stablehlo\.gather.*-> tensor<({n}x[\dx]*\w+)>", lowered.as_text()
+    )
+    assert probe_sized == [f"{n}x3xui32"]
+    results = re.findall(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([\d,]*)\]\S* gather\(",
+        compiled.as_text(), re.M,
+    )
+    assert ("u32", f"{n},3") in results
+    assert not any(dt in ("u64", "s64") and dims == str(n)
+                   for dt, dims in results), results
 
 
 def test_decimal_average_division(one_chip, no_compile_cache):
@@ -304,3 +348,77 @@ def test_seam_exchange_on_four_chips_at_q18_sf5(topo, no_compile_cache):
     page = cap * (8 + 8 + 1 + 8 + 1 + 1)
     assert ma.argument_size_in_bytes >= page
     assert ma.temp_size_in_bytes < page // 8
+
+
+def test_q3_partial_group_by_compiles_on_four_chips_at_sf5(
+        topo, no_compile_cache):
+    """Q3's PARTIAL group-by as the mesh executor runs it at SF5 — three
+    keys, the two 32-bit halves of a decimal product summed, 49,152 rows
+    and 98,304 slots a shard, by sort — compiles for the described 2x2
+    slice. With the keys read through ``gather_rows``' word view beside
+    the sums' stacked walk (ISSUE 44's first cut) XLA:TPU's
+    ``tpu-reduce-window-rewriter`` died of a SIGSEGV on exactly this
+    program and took the served coordinator with it; under a
+    permutation the keys are read as they are (``stage.
+    _reads_at_first_rows``), and the sums still ride their walks."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
+
+    from trino_tpu import types as T
+    from trino_tpu.exec import stage
+    from trino_tpu.expr.ir import AggCall, Call, InputRef, Literal
+    from trino_tpu.plan import nodes as P
+
+    shards, n, capacity = 4, 49_152, 98_304
+    dec = T.DecimalType(15, 2)
+    product = Call(T.DecimalType(18, 4), "multiply", (
+        InputRef(dec, "price"),
+        Call(T.DecimalType(18, 2), "subtract",
+             (Literal(T.BIGINT, 1), InputRef(dec, "discount"))),
+    ))
+    node = P.Aggregate(
+        outputs={"k": T.BIGINT, "d": T.DATE, "p": T.INTEGER,
+                 "hi": T.BIGINT, "lo": T.BIGINT},
+        source=None, group_keys=["k", "d", "p"],
+        aggregates={"hi": AggCall("sum_hi32", (product,), T.BIGINT),
+                    "lo": AggCall("sum_lo32", (product,), T.BIGINT)},
+        step="PARTIAL", key_ranges={"k": (1, 29_999_976), "p": (0, 0)},
+    )
+    types = {"k": T.BIGINT, "d": T.DATE, "p": T.INTEGER,
+             "price": dec, "discount": dec}
+    layout = stage.ChainLayout(
+        names=list(types), types=types, dicts=dict.fromkeys(types),
+        capacity=n,
+    )
+    fn, out = stage.build_chain([node], layout, {0: [capacity, capacity]})
+
+    def step(*cols):
+        *data, mask = cols
+        return fn({s: (c, None) for s, c in zip(types, data)}, mask)
+
+    dtypes = [jnp.int64, jnp.int32, jnp.int32, jnp.int64, jnp.int64,
+              jnp.bool_]
+    mesh = Mesh(np.asarray(topo.devices[:shards]), ("workers",))
+    rows = NamedSharding(mesh, PS("workers"))
+    out_specs = jax.tree.map(
+        lambda x: PS("workers") if x.ndim else PS(),
+        jax.eval_shape(step, *(jax.ShapeDtypeStruct((n,), d) for d in dtypes)),
+    )
+
+    def body(*cols):
+        env, mask, flags = step(*cols)
+        return env, mask, jax.tree.map(
+            lambda f: jax.lax.pmax(f.astype(jnp.int32), "workers"), flags)
+
+    lowered = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(PS("workers"),) * len(dtypes),
+        out_specs=out_specs, check_vma=False,
+    )).lower(*(
+        jax.ShapeDtypeStruct((shards * n,), d, sharding=rows) for d in dtypes
+    ))
+    walks = re.findall(
+        rf"stablehlo\.gather.*-> tensor<{capacity}x(\d)xui32>",
+        lowered.as_text())
+    assert sorted(walks) == ["2", "4", "4"]  # five int64 sums, no key
+    assert out.groupbys == {0: "sorted"}
+    assert lowered.compile().memory_analysis().temp_size_in_bytes > 0

@@ -33,6 +33,16 @@ kernel's variants, and ``jnp.searchsorted(method="scan")`` over the
 small haystack — the table of PERF.md, PR 42, which set
 ``kernels.JOIN_SMALL_BUILD``.
 
+``--shape startwalk``: what a step reads at one index vector, as lone
+gathers (the bodies the engine had until PR 44) against one walk of
+32-bit words (``kernels.gather_rows``), bit for bit, at SF1's and
+SF5's shapes: Q18's streamed inner step (two int64 limb prefix sums
+and its int64 key at the run starts, 2,097,152 of 6,291,456 and
+8,388,608 of 33,554,432 positions) whole and as its reads alone, and
+``join_ranges``' reads at ``lo`` in Q3's ``lineitem`` join (the build
+key and its run's end: 4,194,304 probes into 262,144 and 16,777,216
+into 1,048,576) — the table of PERF.md, PR 44.
+
 Run it on the chip: ``chiprun -- python tools/groupby_crossover.py``.
 On a CPU it checks that the paths agree and prints host times, which
 are no device metric.
@@ -46,6 +56,7 @@ import os
 import statistics
 import sys
 import time
+from types import SimpleNamespace
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -57,7 +68,7 @@ from trino_tpu import types as T
 from trino_tpu.exec import kernels as K
 from trino_tpu.exec import shapes
 from trino_tpu.exec.aggregates import compute_aggregate
-from trino_tpu.exec.stage import _presort_shared
+from trino_tpu.exec.stage import _reads_at_first_rows
 
 CAPACITY = 1536  # Q1's planned group-table capacity at SF1
 AGGS = [  # (name, output type, column)
@@ -91,17 +102,19 @@ def engine_path(group_fn, bits: int, capacity: int = CAPACITY, aggs=AGGS):
             info, unordered = info
         share = {"#mask": mask}
         prepared = [
-            (None, None, None if c is None else (cols[c], None), mask)
-            for _n, _t, c in aggs
+            (None, SimpleNamespace(name=name, type=typ),
+             None if c is None else (cols[c], None), mask)
+            for name, typ, c in aggs
         ]
-        if isinstance(info, K.GroupInfo) and info.perm is not None:
-            _presort_shared(prepared, info, share)
+        keys_at, _walks = _reads_at_first_rows(
+            {"k": (key, None)}, prepared, info, capacity, share
+        )
         out = [
-            compute_aggregate(name, typ, arg, info, capacity, mask, share=share)
-            for (name, typ, _c), (_s, _k, arg, _m) in zip(aggs, prepared)
+            compute_aggregate(
+                call.name, call.type, arg, info, capacity, mask, share=share)
+            for _s, call, arg, _m in prepared
         ]
-        own = jnp.clip(info.owner, 0, key.shape[0] - 1)
-        return out + [(key[own], None)], info.owner, info.num_groups, unordered
+        return out + [keys_at["k"]], info.owner, info.num_groups, unordered
 
     return prog
 
@@ -192,11 +205,12 @@ def q18_pieces(rows: int, capacity: int):
     }
 
 
-def write(rec: dict, out: str) -> None:
+def write(rec: dict, out: str, echo: bool = True) -> None:
     os.makedirs(os.path.dirname(out) or ".", exist_ok=True)
     with open(out, "w") as fh:
         json.dump(rec, fh, indent=1)
-    print(json.dumps(rec))
+    if echo:
+        print(json.dumps(rec))
 
 
 def q18_main(a) -> int:
@@ -493,6 +507,125 @@ def joinrank_main(a) -> int:
     return 0 if ok else 1
 
 
+#: (rows, capacity) of Q18's inner step, (probe, build) of Q3's
+#: ``lineitem`` join, at SF1 and SF5
+STARTWALK_SHAPES = {
+    "sf1": ((6_291_456, 2_097_152), (4_194_304, 262_144)),
+    "sf5": ((33_554_432, 8_388_608), (16_777_216, 1_048_576)),
+}
+
+
+def _lone_step(key, cols, mask, capacity: int, bits: int):
+    """Q18's streamed step with the reads at the run starts as the
+    engine made them until PR 44: the inclusive prefix sum of each limb
+    at ``starts - 1`` and the key at ``owner``, three lone int64
+    gathers. Same outputs as ``engine_path(K.run_group, ...)``."""
+    kbits, _ = K.normalize_key(key, None)
+    info, unordered = K.run_group(
+        (kbits,), (None,), mask, capacity, widths=(bits,))
+    masked = jnp.where(mask, cols[0], jnp.int64(0))
+
+    def ranges(vals):
+        cs = K.cumsum(vals)
+        lo = K._range_gather(cs, info.starts, jnp.int64(0))
+        total = K._range_gather(cs, info.ends[-1:], jnp.int64(0))
+        hi = jnp.concatenate([lo[1:], total])
+        return jnp.where(info.ends > info.starts, hi - lo, jnp.int64(0))
+
+    s_hi = ranges(masked >> jnp.int64(32))
+    s_lo = ranges(masked & jnp.int64(0xFFFFFFFF))
+    limbs = jnp.stack(
+        [s_hi + (s_lo >> jnp.int64(32)), s_lo & jnp.int64(0xFFFFFFFF)],
+        axis=-1)
+    own = jnp.clip(info.owner, 0, key.shape[0] - 1)
+    nonempty = info.ends > info.starts
+    return ([(limbs, nonempty), (key[own], None)], info.owner,
+            info.num_groups, unordered)
+
+
+def _at_inputs(probe: int, build: int, seed: int):
+    """``join_ranges``' state where it reads at ``lo``: a sorted build
+    of unique keys with a dead tail, the ends of its runs, a probe in
+    key order (``lineitem``'s) of which some nine in ten find a key."""
+    rng = np.random.default_rng(seed)
+    live = build * 7 // 8
+    sk = np.sort(rng.choice(np.arange(1, 8 * build, dtype=np.uint64),
+                            live, replace=False))
+    sk = np.concatenate([sk, np.full(build - live, np.uint64(2**64 - 1))])
+    run_end = np.arange(1, build + 1, dtype=np.int32)
+    pk = np.sort(rng.choice(sk[:live], probe))
+    pk[rng.random(probe) < 0.1] += np.uint64(1)
+    lo = np.searchsorted(sk, pk).astype(np.int32)
+    return tuple(jnp.asarray(x) for x in (sk, run_end, pk, lo))
+
+
+def _at_lone(sorted_key, run_end, probe_key, lo):
+    """The two reads ``join_ranges`` made until PR 44."""
+    n_build = sorted_key.shape[0]
+    at = jnp.clip(lo, 0, n_build - 1)
+    found = (lo < n_build) & (sorted_key[at] == probe_key)
+    return jnp.where(found, run_end[at], lo)
+
+
+def startwalk_main(a) -> int:
+    dev = jax.devices()[0]
+    rec = {"platform": dev.platform, "device_kind": dev.device_kind,
+           "shape": "startwalk", "runs": []}
+    ok = True
+
+    def run(scale, what, body, fn, args, ref=None):
+        nonlocal ok
+        out, t = timed(fn, args, a.reps)
+        row = {"scale": scale, "what": what, "body": body, **t}
+        if ref is not None:
+            row["agrees"] = out is not None and ref(out)
+            ok &= row["agrees"]
+        rec["runs"].append(row)
+        print(row, flush=True)
+        write(rec, a.out, echo=False)  # a later shape may exhaust the call
+        return out
+
+    for scale in a.scales.split(","):
+        (rows, capacity), (probe, build) = STARTWALK_SHAPES[scale]
+        if a.rows_cap:  # a rehearsal's size
+            rows, capacity = a.rows_cap, a.rows_cap // 4
+            probe, build = a.rows_cap // 2, a.rows_cap // 16
+        key, cols, mask = q18_inputs(rows, a.seed)
+        bits = int(key.max()).bit_length()
+        lone = run(scale, "q18_step", "lone_gathers",
+                   lambda k, c, m: _lone_step(k, c, m, capacity, bits),
+                   (key, cols, mask))
+        run(scale, "q18_step", "start_walk",
+            engine_path(K.run_group, bits, capacity, Q18_AGGS),
+            (key, cols, mask),
+            ref=lambda out: lone is not None and same(out, lone))
+        del lone
+        # the reads alone, at the step's own starts
+        info, _ = jax.jit(lambda k, m: K.run_group(
+            (K.normalize_key(k, None)[0],), (None,), m, capacity,
+            widths=(bits,)))(key, mask)
+        at = jnp.clip(info.owner, 0, rows - 1)
+        i64 = [cols[0], cols[0] + 1, key]
+        three = run(scale, "q18_reads", "three_lone_int64",
+                    lambda x, y, z, i: (x[i], y[i], z[i]), (*i64, at))
+        run(scale, "q18_reads", "one_lone_int64", lambda x, i: x[i],
+            (i64[0], at))
+        run(scale, "q18_reads", "gather_rows_6_words",
+            lambda x, y, z, i: tuple(
+                d for d, _ in K.gather_rows(
+                    {0: (x, None), 1: (y, None), 2: (z, None)}, i).values()),
+            (*i64, at),
+            ref=lambda out: three is not None and all(
+                np.array_equal(p, q) for p, q in zip(out, three)))
+        del three, info, at, i64, key, cols, mask
+        args = _at_inputs(probe, build, a.seed)
+        two = run(scale, "q3_at_reads", "two_lone_gathers", _at_lone, args)
+        run(scale, "q3_at_reads", "one_walk_3_words", K._run_end_at, args,
+            ref=lambda out: two is not None and np.array_equal(out, two))
+        del two, args
+    return 0 if ok else 1
+
+
 def same(a, b) -> bool:
     """Bit for bit on the occupied prefix (values, validity, owners),
     and no order fault on either side."""
@@ -516,8 +649,13 @@ def same(a, b) -> bool:
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--shape", choices=("q1", "q18", "q3compact", "joinrank"),
-                    default="q1")
+    ap.add_argument("--shape", choices=("q1", "q18", "q3compact", "joinrank",
+                                        "startwalk"), default="q1")
+    ap.add_argument("--scales", default="sf1,sf5",
+                    help="which of STARTWALK_SHAPES --shape startwalk times")
+    ap.add_argument("--rows-cap", type=int, default=0,
+                    help="--shape startwalk at this many rows instead "
+                         "(a rehearsal)")
     ap.add_argument("--probes", default=",".join(map(str, JOINRANK_PROBES)),
                     help="probe capacities of --shape joinrank")
     ap.add_argument("--builds", default=",".join(map(str, JOINRANK_BUILDS)),
@@ -547,6 +685,10 @@ def main() -> int:
         if a.out == ap.get_default("out"):
             a.out = "chiprun_out/groupby_crossover_joinrank.json"
         return joinrank_main(a)
+    if a.shape == "startwalk":
+        if a.out == ap.get_default("out"):
+            a.out = "chiprun_out/groupby_crossover_startwalk.json"
+        return startwalk_main(a)
     dev = jax.devices()[0]
     rec = {"platform": dev.platform, "device_kind": dev.device_kind,
            "rows": a.rows, "capacity": CAPACITY, "runs": []}

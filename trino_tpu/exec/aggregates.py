@@ -27,6 +27,8 @@ Work shared between the aggregates of one GROUP BY (the gather of a
 column into group order, the per-group row count, contribution masks)
 is deduplicated through a per-step ``share`` cache, the analog of the
 reference's shared GroupByHash + per-aggregate accumulators split.
+The integer sums of a sorted-segment step are all read at the groups'
+first rows, so the step reads them in one walk (``StartReads``).
 
 Distinct aggregates dedupe first: a second ``sort_group`` over
 (group keys + argument) keeps one representative row per distinct
@@ -43,7 +45,9 @@ from trino_tpu import types as T
 from trino_tpu.exec import kernels as K
 from trino_tpu.expr.compiler import _div_round_half_up
 
-__all__ = ["compute_aggregate", "dense_reducible", "VARIANCE_FNS"]
+__all__ = [
+    "compute_aggregate", "dense_reducible", "StartReads", "VARIANCE_FNS",
+]
 
 VARIANCE_FNS = {
     "stddev", "stddev_samp", "stddev_pop", "variance", "var_samp", "var_pop",
@@ -80,6 +84,40 @@ QUANT_GLOBAL_POINTS = 1024
 QUANT_GROUPED_POINTS = 256
 
 
+class StartReads:
+    """The reads of one sorted-segment step at its groups' first rows:
+    every integer sum of its aggregates (``kernels.start_walk``) and,
+    grouped in place, its key columns — one index vector, so one walk.
+
+    An aggregate asks for a sum where it needs it and computes on with
+    the result, so the step's columns are only known once every
+    aggregate has run. The step therefore evaluates its aggregates
+    twice under the one trace: a first time to learn the columns
+    (``sum`` keeps each and answers zeros; the results are dropped and
+    XLA removes what only they used), then, after ``walk``, a second
+    time in which ``sum`` hands out the walk's sums in the order they
+    were asked for — the same code on the same inputs asks in the same
+    order. Kept in the step's ``share`` under ``"#starts"``."""
+
+    def __init__(self, info: K.GroupInfo):
+        self.info = info
+        self.cols: list = []
+        self._sums = None
+
+    def sum(self, vals):
+        if self._sums is None:
+            self.cols.append(vals)
+            return jnp.zeros(self.info.starts.shape, vals.dtype)
+        return next(self._sums)
+
+    def walk(self, keys: dict | None = None):
+        """Read the columns learnt so far, and ``keys`` with them
+        (grouped in place only): the keys at each group's first row."""
+        sums, keys_at = K.start_walk(self.info, self.cols, keys)
+        self._sums = iter(sums)
+        return keys_at
+
+
 class _Reducer:
     """Per-group reductions for one GROUP BY — sorted segments
     (``info`` a ``GroupInfo``) or slots (a ``SlotInfo``) — or one
@@ -109,6 +147,14 @@ class _Reducer:
             self.share[key] = hit
         return hit[1]
 
+    def _range_sum(self, vals):
+        """Per-group sum of a group-sorted, contribution-masked column:
+        an integer one is a column of the step's one walk."""
+        starts = self.share.get("#starts")
+        if starts is None or jnp.issubdtype(vals.dtype, jnp.floating):
+            return K.seg_sum_ranges(vals, self.info)
+        return starts.sum(vals)
+
     def with_valid(self, valid):
         """Reducer whose contribution also requires ``valid`` (cached)."""
         if valid is None:
@@ -137,8 +183,7 @@ class _Reducer:
         if dtype is not None:
             xs = xs.astype(dtype)
         zero = jnp.zeros((), dtype=xs.dtype)
-        masked = jnp.where(self.contrib_s, xs, zero)
-        return K.seg_sum_ranges(masked, self.info, zero)
+        return self._range_sum(jnp.where(self.contrib_s, xs, zero))
 
     def sum_limbs(self, data):
         """Exact (hi, lo) limb sums of an int64 column (or an
@@ -165,23 +210,23 @@ class _Reducer:
                 hi_in = data >> jnp.int64(32)
                 lo_in = data & jnp.int64(0xFFFFFFFF)
             return _limb_norm(self._slot(hi_in, 0), self._slot(lo_in, 0))
-        zero = jnp.int64(0)
-        if hi_in is not None:
-            hs = jnp.where(self.contrib_s, self._sorted(hi_in), zero)
-            ls = jnp.where(self.contrib_s, self._sorted(lo_in), zero)
-            return _limb_norm(
-                K.seg_sum_ranges(hs, self.info, zero),
-                K.seg_sum_ranges(ls, self.info, zero),
-            )
-        xs = self._sorted(data)
-        masked = jnp.where(self.contrib_s, xs, jnp.int64(0))
-        with K.site("limb_hi"):
-            hi = K.seg_sum_ranges(masked >> jnp.int64(32), self.info, zero)
-        with K.site("limb_lo"):
-            lo = K.seg_sum_ranges(
-                masked & jnp.int64(0xFFFFFFFF), self.info, zero
-            )
-        return _limb_norm(hi, lo)
+        # one pair a (column, contribution): the limb partials of a
+        # distributed sum ask twice, and a walk holds each column once
+        key = ("limbs", id(data), id(self.contrib))
+        hit = self.share.get(key)
+        if hit is None or hit[0] is not data or hit[1] is not self.contrib:
+            xs = self._sorted(data)
+            if hi_in is not None:
+                hs, ls = xs[:, 0], xs[:, 1]
+            else:
+                hs, ls = xs >> jnp.int64(32), xs & jnp.int64(0xFFFFFFFF)
+            zero = jnp.int64(0)
+            hs = jnp.where(self.contrib_s, hs, zero)
+            ls = jnp.where(self.contrib_s, ls, zero)
+            hit = (data, self.contrib,
+                   _limb_norm(self._range_sum(hs), self._range_sum(ls)))
+            self.share[key] = hit
+        return hit[2]
 
     def count(self):
         key = ("count", id(self.contrib))
@@ -204,10 +249,7 @@ class _Reducer:
                 # was there: ROADMAP S2.)
                 cnt = (self.info.ends - self.info.starts).astype(jnp.int64)
             else:
-                cnt = K.seg_sum_ranges(
-                    self.contrib_s.astype(jnp.int64), self.info,
-                    jnp.int64(0),
-                )
+                cnt = self._range_sum(self.contrib_s.astype(jnp.int64))
             hit = (self.contrib, cnt)
             self.share[key] = hit
         return hit[1]

@@ -114,6 +114,7 @@ __all__ = [
     "expand_matches",
     "range_any",
     "scatter_any",
+    "start_walk",
     "seg_sum_ranges",
     "seg_minmax_scan",
     "seg_first_index",
@@ -239,7 +240,13 @@ def compact_perm(mask: jnp.ndarray) -> jnp.ndarray:
 #: at 4 against 92.7 at 8, 91.4 at 2 and 329.0 a column
 #: (tools/groupby_crossover.py --shape q3compact; PERF.md, PR 36): up
 #: to four words ride one index walk, two stacks of four beat one of
-#: eight.
+#: eight. Inside a program a lone int64 read of a row-sized column
+#: cost 33-65 ms at 2,097,152 positions of 6.29 M rows and 493-511 ms
+#: at 8,388,608 of 33.5 M (Q18's streamed step, PERF.md, PR 43); its
+#: three such reads as one walk of six words (stacks of 4 + 2) cost
+#: 21.8 ms in the program at the first shape and, timed alone, 24.0
+#: and 265.7 ms where the three lone reads took 100.8 and 1,057.3
+#: (--shape startwalk; PERF.md, PR 44).
 GATHER_STACK_WORDS = 4
 
 #: a column whose row is wider than this many words (an HLL or sketch
@@ -894,40 +901,77 @@ def _range_gather(cs: jnp.ndarray, idx: jnp.ndarray, zero):
 
 
 @kernel
-def seg_sum_ranges(vals_sorted, info: GroupInfo, zero=None):
+def start_walk(info: GroupInfo, sums: list, keys: dict | None = None):
+    """What a grouped step reads at its groups' first rows, in ONE
+    ``gather_rows`` walk: ``(sums', keys')``.
+
+    ``sums`` are integer columns, already group-sorted and
+    contribution-masked; ``sums'[i][g]`` is column i's sum over group
+    g's run — prefix sum + boundary differences, exact and
+    scatter-free. The prefix sum is the *exclusive* one (``cs - vals``,
+    one fused subtract), so a group's lower bound is read AT its start
+    and every column of the step is read at the same index vector.
+    ``ends[g] == starts[g + 1]`` (dense contiguous groups), so the
+    upper bound is the lower one shifted by one slot, and the last
+    live group's is the column's total, a one-entry read.
+
+    ``keys`` (``{name: (data, valid)}``, row order) ride the same walk
+    where the rows are grouped in place (``info.perm`` None: a used
+    slot's ``owner`` IS its ``starts``; an unused slot's reads are
+    masked, so the walk is at ``owner`` and the keys come back as
+    ``data[owner]`` bit for bit). Under a permutation the first rows
+    lie at ``perm[starts]``, another vector: the caller reads them.
+    """
+    n = info.gid_sorted.shape[0]
+    n_live = info.ends[-1:]
+    env, totals = {}, []
+    for i, vals in enumerate(sums):
+        cs = cumsum(vals)
+        env[i] = (cs - vals, None)
+        totals.append(_range_gather(cs, n_live, jnp.zeros((), vals.dtype)))
+    at = info.starts
+    if keys is not None:
+        at = info.owner
+        env.update(keys)
+    read = gather_rows(env, jnp.clip(at, 0, max(n - 1, 0)))
+    out = []
+    for i, total in enumerate(totals):
+        lo = read.pop(i)[0]
+        hi = jnp.where(
+            info.ends >= n_live, total, jnp.concatenate([lo[1:], total])
+        )
+        out.append(jnp.where(
+            info.ends > info.starts, hi - lo, jnp.zeros((), lo.dtype)
+        ))
+    return out, (read if keys is not None else None)
+
+
+@kernel
+def seg_sum_ranges(vals_sorted, info: GroupInfo):
     """Per-group sums of an already group-sorted, contribution-masked
     value column — scatter-free.
 
-    Integers use cumsum + boundary differences (exact). Floats use a
+    Integers are one column of a ``start_walk`` (exact). Floats use a
     segmented associative scan accumulated in float64 so each group's
     rounding error is bounded by its own magnitude, not the whole
     page's running prefix (a cumsum-difference would lose ~ulp(global
     prefix) per group).
     """
     dtype = vals_sorted.dtype
-    if zero is None:
-        zero = jnp.zeros((), dtype=dtype)
-    if jnp.issubdtype(dtype, jnp.floating):
-        acc = vals_sorted.astype(jnp.float64)
+    if not jnp.issubdtype(dtype, jnp.floating):
+        return start_walk(info, [vals_sorted])[0][0]
+    acc = vals_sorted.astype(jnp.float64)
 
-        def op(a, b):
-            ga, va = a
-            gb, vb = b
-            return gb, jnp.where(ga == gb, va + vb, vb)
+    def op(a, b):
+        ga, va = a
+        gb, vb = b
+        return gb, jnp.where(ga == gb, va + vb, vb)
 
-        _, s = jax.lax.associative_scan(op, (info.gid_sorted, acc))
-        n = s.shape[0]
-        at = jnp.clip(info.ends - 1, 0, max(n - 1, 0))
-        out = jnp.where(info.ends > info.starts, s[at], 0.0)
-        return out.astype(dtype)
-    cs = cumsum(vals_sorted)
-    # ends[g] == starts[g+1] (dense contiguous groups), so the hi
-    # prefix is the lo prefix shifted by one — one [capacity] gather
-    # instead of two
-    lo = _range_gather(cs, info.starts, zero)
-    total = _range_gather(cs, info.ends[-1:], zero)
-    hi = jnp.concatenate([lo[1:], total])
-    return jnp.where(info.ends > info.starts, hi - lo, zero)
+    _, s = jax.lax.associative_scan(op, (info.gid_sorted, acc))
+    n = s.shape[0]
+    at = jnp.clip(info.ends - 1, 0, max(n - 1, 0))
+    out = jnp.where(info.ends > info.starts, s[at], 0.0)
+    return out.astype(dtype)
 
 
 @kernel
@@ -1179,6 +1223,21 @@ def _count_ranges(sorted_key: jnp.ndarray, probe_key: jnp.ndarray):
     )
 
 
+def _run_end_at(sorted_key, run_end, probe_key, lo):
+    """``run_end[lo]`` where ``sorted_key[lo] == probe_key``, else
+    ``lo``: the build key and its run's end read at one walk of 32-bit
+    words, the key compared as halves (the lanes the chip has)."""
+    n_build = sorted_key.shape[0]
+    words = jnp.stack(
+        [*_halves(sorted_key), run_end.astype(jnp.uint32)], axis=1
+    )
+    with site("at_walk"):
+        read = words[jnp.clip(lo, 0, n_build - 1)]
+    high, low = _halves(probe_key)
+    found = (lo < n_build) & (read[:, 0] == high) & (read[:, 1] == low)
+    return jnp.where(found, read[:, 2].astype(jnp.int32), lo)
+
+
 @jax.jit
 @kernel
 def join_ranges(
@@ -1233,11 +1292,7 @@ def join_ranges(
             jnp.where(last_of_run, pos + 1, n_build).astype(jnp.int32),
             reverse=True,
         )
-        at = jnp.clip(lo, 0, n_build - 1)
-        with site("key_at"):
-            found = (lo < n_build) & (sorted_key[at] == probe_key)
-        with site("run_end_at"):
-            hi = jnp.where(found, run_end[at], lo)
+        hi = _run_end_at(sorted_key, run_end, probe_key, lo)
     lo = jnp.minimum(lo, n_build_live)
     hi = jnp.minimum(hi, n_build_live)
     cnt = jnp.where(probe_live, hi - lo, 0)

@@ -889,9 +889,13 @@ class LocalExecutor:
             if out_layout.groupbys:
                 # each grouped Aggregate's path, in chain order
                 # (telemetry.span_totals counts them onto the row)
-                dispatch.note(groupbys=[
-                    path for _pos, path in sorted(out_layout.groupbys.items())
-                ])
+                dispatch.note(
+                    groupbys=[
+                        path
+                        for _pos, path in sorted(out_layout.groupbys.items())
+                    ],
+                    start_walks=sum(out_layout.start_walks.values()),
+                )
         if out_map is not None:
             # the cached program speaks canonical names; translate its
             # outputs back for this call (the cached out_layout is

@@ -685,6 +685,7 @@ class MeshExecutor(LocalExecutor):
                 note["groupbys"] = [
                     path for _pos, path in sorted(out_layout.groupbys.items())
                 ]
+                note["start_walks"] = sum(out_layout.start_walks.values())
             env, mask, flags = self._run(
                 prog, t_compile is not None, *leaves, tag="chain", **note
             )

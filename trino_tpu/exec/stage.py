@@ -30,6 +30,7 @@ import numpy as np
 from trino_tpu import types as T
 from trino_tpu.exec import kernels as K
 from trino_tpu.exec.aggregates import (
+    StartReads,
     VARIANCE_FNS,
     compute_aggregate,
     dense_reducible,
@@ -67,6 +68,11 @@ class ChainLayout:
     #: program (filled when the program is traced; kept beside the
     #: cached program so that a warm dispatch reports it too)
     groupbys: dict = field(default_factory=dict)
+    #: beside it: chain position -> the ``[capacity]``-sized device
+    #: gathers that grouped Aggregate reads its keys and its integer
+    #: sums in at the groups' first rows (``kernels.gather_plan``'s
+    #: count of what ``_reads_at_first_rows`` read)
+    start_walks: dict = field(default_factory=dict)
     #: the symbol the page's live rows ascend on (``Page.ordered_on``),
     #: while that still holds inside the chain: a Project that passes
     #: the column through renames it, every other step drops it
@@ -208,6 +214,7 @@ def build_chain(chain: list[P.PlanNode], layout: ChainLayout, caps: dict[int, li
     scalar for each grouped Aggregate."""
     steps = []
     groupbys: dict[int, str] = {}
+    start_walks: dict[int, int] = {}
     for i, nd in enumerate(chain):
         # positional scope label: jax.named_scope stamps it into the
         # per-instruction HLO op_name metadata (fusions included), so
@@ -221,7 +228,7 @@ def build_chain(chain: list[P.PlanNode], layout: ChainLayout, caps: dict[int, li
             steps.append((scope, step))
         elif isinstance(nd, P.Aggregate):
             step, layout = _aggregate_step(
-                nd, layout, caps[i][0], i, groupbys
+                nd, layout, caps[i][0], i, groupbys, start_walks
             )
             steps.append((scope, step))
         elif isinstance(nd, (P.Sort, P.TopN)):
@@ -246,7 +253,7 @@ def build_chain(chain: list[P.PlanNode], layout: ChainLayout, caps: dict[int, li
                 env, mask, flags = step(env, mask, flags)
         return env, mask, flags
 
-    return fn, dc_replace(layout, groupbys=groupbys)
+    return fn, dc_replace(layout, groupbys=groupbys, start_walks=start_walks)
 
 
 def _passed_through(nd: P.Project, name: str | None) -> str | None:
@@ -317,7 +324,7 @@ def _project_step(nd: P.Project, layout: ChainLayout):
 
 def _aggregate_step(
     nd: P.Aggregate, layout: ChainLayout, capacity: int, pos: int,
-    groupbys: dict[int, str],
+    groupbys: dict[int, str], start_walks: dict[int, int],
 ):
     is_global = not nd.group_keys
     expr_layout = layout.expr_layout()
@@ -445,14 +452,9 @@ def _aggregate_step(
                 groupbys[pos] = "sorted"
                 info = K.sort_group(*group_args)
             flags = {**flags, pos: info.num_groups > capacity}
-            occupied = (
+            out_mask = (
                 jnp.arange(capacity, dtype=jnp.int32) < info.num_groups
             )
-            env2 = _keys_at_owner(
-                {s: env[s] for s in group_keys},
-                jnp.clip(info.owner, 0, in_cap - 1), occupied,
-            )
-            out_mask = occupied
         cap_seg = 1 if is_global else capacity
         share = {"#mask": mask}  # per-step cache of sorted cols/counts
         prepared = []
@@ -515,8 +517,16 @@ def _aggregate_step(
                 contrib = _dedupe(list(shifted), d_arg, contrib, in_cap,
                                   widths + (dwidth,))
             prepared.append((sym, call, arg, contrib))
-        if isinstance(info, K.GroupInfo) and info.perm is not None:
-            _presort_shared(prepared, info, share)
+        if not is_global:
+            keys_at, walks = _reads_at_first_rows(
+                {s: env[s] for s in group_keys}, prepared, info, cap_seg,
+                share,
+            )
+            start_walks[pos] = walks
+            env2 = {
+                s: (d, None if v is None else v & out_mask)
+                for s, (d, v) in keys_at.items()
+            }
         for sym, call, arg, contrib in prepared:
             data, valid = compute_aggregate(
                 call.name, call.type, arg, info, cap_seg, contrib,
@@ -531,13 +541,51 @@ def _aggregate_step(
     return step, out_layout
 
 
-@K.kernel
-def _keys_at_owner(keys: dict, own, occupied) -> dict:
-    """Each group's key columns, read at the group's first row."""
-    return {
-        s: (data[own], None if valid is None else (valid[own] & occupied))
-        for s, (data, valid) in keys.items()
-    }
+def _reads_at_first_rows(keys, prepared, info, capacity, share):
+    """What a grouped step reads at each group's first row, before its
+    aggregates are evaluated: ``(keys', gathers)`` — the key columns
+    there, and the ``[capacity]``-sized device gathers the step holds
+    for them and for its integer sums (``kernels.gather_plan``'s
+    count).
+
+    A sorted-segment step (``GroupInfo``) reads its aggregates' integer
+    sums at the same rows: they are learnt by a first evaluation of the
+    aggregates (``aggregates.StartReads``) and left in ``share`` for
+    the caller's. Grouped in place, sums and keys are one walk. Under a
+    permutation the keys lie at ``perm[starts]``, another vector, and
+    are read there as they are, a column a gather: beside the sums'
+    stacked walk a word view of them crashes XLA:TPU's
+    ``tpu-reduce-window-rewriter`` (SIGSEGV while compiling Q3's
+    PARTIAL step for four chips at SF5; ``tests/test_tpu_compile.py``
+    keeps that compile), and such a step's capacity is small."""
+    n = next(iter(keys.values()))[0].shape[0]
+    own = jnp.clip(info.owner, 0, n - 1)
+    if not isinstance(info, K.GroupInfo):
+        return K.gather_rows(keys, own), _gathers(keys)
+    if info.perm is not None:
+        _presort_shared(prepared, info, share)
+    reads = StartReads(info)
+    learning = {**share, "#starts": reads}
+    for _sym, call, arg, contrib in prepared:
+        compute_aggregate(
+            call.name, call.type, arg, info, capacity, contrib,
+            share=learning,
+        )
+    share["#starts"] = reads
+    sums = {i: (c, None) for i, c in enumerate(reads.cols)}
+    if info.perm is None:
+        return reads.walk(keys), _gathers({**sums, **keys})
+    reads.walk()
+    keys_at = {s: K.rows_at(d, v, own) for s, (d, v) in keys.items()}
+    lone = sum(1 + (v is not None) for _, v in keys.values())
+    return keys_at, _gathers(sums) + lone
+
+
+def _gathers(env: dict) -> int:
+    """Device gathers ``kernels.gather_rows`` reads ``env`` in."""
+    return K.gather_plan(
+        (d.dtype, d.shape[1:], v is not None) for d, v in env.values()
+    )[1]
 
 
 @K.kernel

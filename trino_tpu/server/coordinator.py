@@ -788,6 +788,7 @@ class Coordinator:
         "build_trace_ms", "host_sync_ms", "host_syncs", "dispatches",
         "upload_ms", "to_rows_ms", "respond_ms", "rows_out_ms",
         "direct_groupbys", "sorted_groupbys", "streamed_groupbys",
+        "groupby_start_walks",
         "small_build_joins", "sorted_joins",
         "compactions", "compact_gather_ops",
         "mesh_exchanges", "mesh_exchange_ms", "mesh_exchange_live_bytes",

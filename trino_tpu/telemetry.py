@@ -356,7 +356,10 @@ def span_totals(root: Span) -> Dict[str, float]:
     ``direct_groupbys`` / ``streamed_groupbys`` / ``sorted_groupbys``
     count the grouped
     aggregates of the dispatched programs by the path each took (the
-    ``dispatch`` span's ``groupbys``); ``compactions`` counts the
+    ``dispatch`` span's ``groupbys``) and ``groupby_start_walks`` the
+    ``[capacity]``-sized gathers those aggregates read their keys and
+    integer sums in at the groups' first rows (the span's
+    ``start_walks``); ``compactions`` counts the
     compaction programs and ``compact_gather_ops`` the gather operands
     they were built with (the ``dispatch`` span's ``gather_ops``);
     ``small_build_joins`` / ``sorted_joins`` count the programs that
@@ -380,6 +383,9 @@ def span_totals(root: Span) -> Dict[str, float]:
             out[_COUNTED[key]] = out.get(_COUNTED[key], 0) + 1
         for path in sp.attrs.get("groupbys", ()):
             out[path + "_groupbys"] = out.get(path + "_groupbys", 0) + 1
+        if "start_walks" in sp.attrs:
+            out["groupby_start_walks"] = out.get(
+                "groupby_start_walks", 0) + sp.attrs["start_walks"]
         search = sp.attrs.get("join_search")
         if search is not None:
             field = _JOIN_SEARCH_FIELDS[search]
