@@ -184,3 +184,255 @@ def test_a_mesh_coordinator_counts_what_the_shared_cache_holds(coord):
         assert gauges(c.uri) == (0, 0)
     finally:
         c.stop()
+
+
+# ---- a worker's split scan is a row range of the resident table (ISSUE 45) --
+
+from dataclasses import replace as dc_replace  # noqa: E402
+
+import numpy as np  # noqa: E402
+
+from trino_tpu import types as T  # noqa: E402
+from trino_tpu.connectors.base import Split  # noqa: E402
+from trino_tpu.exec import shapes  # noqa: E402
+from trino_tpu.exec.local import LocalExecutor  # noqa: E402
+from trino_tpu.plan import nodes as P  # noqa: E402
+
+#: a varchar of each table read hash-coded as well as dictionary-coded
+HASHED = {"lineitem": "l_comment", "orders": "o_clerk", "customer": "c_name"}
+
+
+def scan_of(runner, table, columns="*"):
+    """The TableScan the planner makes for ``select <columns> from
+    <table>``."""
+    todo = [runner.plan_sql(f"select {columns} from {table}")]
+    while todo:
+        node = todo.pop()
+        if isinstance(node, P.TableScan):
+            return node
+        todo += node.sources
+    raise AssertionError("no scan in the plan")
+
+
+def uploaded_split_page(ex, node):
+    """What the split scan was before: the connector's rows of the
+    range, uploaded."""
+    start, count = node.split
+    connector = ex.metadata.connector(node.catalog)
+    cols = connector.scan(
+        node.schema, node.table, list(node.assignments.values()),
+        split=Split(node.table, start, count))
+    return ex._scanned_page(node, cols, count)
+
+
+def assert_same_page(got, want):
+    assert got.names == want.names
+    assert got.capacity == want.capacity
+    assert (got.known_rows, got.packed) == (want.known_rows, want.packed)
+    assert got.ordered_on is None and want.ordered_on is None
+    np.testing.assert_array_equal(np.asarray(got.mask), np.asarray(want.mask))
+    n = want.known_rows
+    for name, g, w in zip(got.names, got.columns, want.columns):
+        assert g.type == w.type, name
+        assert (g.valid is None) == (w.valid is None), name
+        if w.valid is not None:
+            np.testing.assert_array_equal(
+                np.asarray(g.valid), np.asarray(w.valid), err_msg=name)
+        gd, wd = np.asarray(g.data), np.asarray(w.data)
+        assert gd.dtype == wd.dtype and gd.shape == wd.shape, name
+        # behind the live rows both hold zeros
+        assert not gd[n:].any() and not wd[n:].any(), name
+        if w.dictionary is not None:
+            # the resident page's dictionary is the whole table's: the
+            # same strings, and the same codes where the split holds
+            # every value of the column
+            np.testing.assert_array_equal(
+                g.dictionary.decode(gd[:n]), w.dictionary.decode(wd[:n]),
+                err_msg=name)
+            if len(g.dictionary) == len(w.dictionary):
+                np.testing.assert_array_equal(gd, wd, err_msg=name)
+        elif w.hash_pool is not None:
+            np.testing.assert_array_equal(gd[:, 0], wd[:, 0], err_msg=name)
+            np.testing.assert_array_equal(
+                g.hash_pool.values[gd[:n, 1]], w.hash_pool.values[wd[:n, 1]],
+                err_msg=name)
+        else:
+            assert g.hash_pool is None and g.dictionary is None, name
+            np.testing.assert_array_equal(gd, wd, err_msg=name)
+
+
+@pytest.fixture(scope="module")
+def split_runner():
+    scan_cache.SHARED.clear()
+    return QueryRunner.tpch("tiny")
+
+
+@pytest.mark.parametrize("n_splits", [2, 3])
+@pytest.mark.parametrize("table", TABLES)
+def test_resident_split_page_equals_the_connectors_split_scan(
+        table, n_splits, split_runner):
+    ex = split_runner.executor
+    scan = scan_of(split_runner, table)
+    hashed = [s for s, c in scan.assignments.items() if c == HASHED[table]]
+    connector = ex.metadata.connector("tpch")
+    splits = connector.splits("tiny", table, n_splits)
+    assert len(splits) == n_splits
+    for hash_varchar in (None, hashed):
+        for sp in splits:
+            node = dc_replace(
+                scan, split=(sp.start, sp.count), hash_varchar=hash_varchar)
+            got = ex._scan_split(node)
+            assert got.capacity == shapes.bucket(sp.count)
+            assert_same_page(got, uploaded_split_page(ex, node))
+    # one resident copy of the table served every split of it
+    assert ("tiny", table) in scan_cache.SHARED.resident_tables(connector)
+
+
+@pytest.mark.parametrize("start,count", [
+    (1100, 400),   # the slice's bucket runs past the table's capacity
+    (1400, 200),   # the range runs past the table's last row
+    (1500, 50),    # an empty range
+    (0, 1500),     # the whole table as one split
+], ids=["past-capacity", "past-last-row", "empty", "whole"])
+def test_resident_split_at_the_tables_end(start, count, split_runner):
+    ex = split_runner.executor
+    rows = ex.metadata.connector("tpch").row_count("tiny", "customer")
+    assert (rows, pad_capacity(rows)) == (1500, 1536)
+    node = dc_replace(scan_of(split_runner, "customer"), split=(start, count))
+    got = ex._scan_split(node)
+    assert got.known_rows == max(0, min(count, rows - start))
+    assert_same_page(got, uploaded_split_page(ex, node))
+
+
+def test_a_split_with_no_columns_is_its_live_mask(split_runner):
+    ex = split_runner.executor
+    scan = dc_replace(   # the planner keeps a column; a count needs none
+        scan_of(split_runner, "orders"), outputs={}, assignments={})
+    got = ex._scan_split(dc_replace(scan, split=(5000, 3000)))
+    assert (got.columns, got.known_rows, got.capacity) == ([], 3000, 3072)
+    assert int(np.asarray(got.mask).sum()) == 3000
+
+
+def test_the_whole_table_page_is_what_it_was(split_runner):
+    """The embedded path (``_TableScan`` with no split): the page the
+    shared residency code builds equals the connector's whole-table
+    scan uploaded, column for column, carries the declared order, and
+    is the cache's own arrays (no copy)."""
+    ex = split_runner.executor
+    scan_cache.SHARED.clear()
+    scan = scan_of(split_runner, "orders")
+    page = ex._TableScan(scan)
+    assert page.ordered_on == next(
+        s for s, c in scan.assignments.items() if c == "o_orderkey")
+    connector = ex.metadata.connector("tpch")
+    cols = connector.scan("tiny", "orders", list(scan.assignments.values()))
+    want = ex._scanned_page(scan, cols, None)
+    page.ordered_on = None
+    assert_same_page(page, want)
+    cache = scan_cache.SHARED.table(connector, "tiny", "orders")
+    assert all(c is cache[n] for c, n in zip(
+        page.columns, scan.assignments.values()))
+    assert ex._TableScan(scan).columns[0] is page.columns[0]
+
+
+def spy_on_resident_split(monkeypatch):
+    calls = []
+    real = LocalExecutor._resident_split
+
+    def spy(self, node, *args):
+        calls.append(node.table)
+        return real(self, node, *args)
+
+    monkeypatch.setattr(LocalExecutor, "_resident_split", spy)
+    return calls
+
+
+def test_a_live_view_keeps_the_uploading_split_scan(monkeypatch):
+    from trino_tpu.connectors.system import SystemConnector
+
+    calls = spy_on_resident_split(monkeypatch)
+    r = QueryRunner.tpch("tiny")
+    r.metadata.register_catalog("system", SystemConnector(runner=r))
+    scan = scan_of(r, "system.runtime.caches")
+    assert not r.metadata.connector("system").cacheable
+    page = r.executor._scan_split(dc_replace(scan, split=(0, 2)))
+    assert page.known_rows == 2 and calls == []
+
+
+@pytest.fixture()
+def parquet_runner(tmp_path):
+    pytest.importorskip("pyarrow")
+    from trino_tpu.connectors.base import TableSchema
+    from trino_tpu.connectors.parquet import (
+        ParquetConnector, write_parquet_table)
+    from trino_tpu.metadata import Metadata, Session
+
+    write_parquet_table(
+        str(tmp_path), "default", "f",
+        TableSchema("f", [("k", T.BIGINT), ("v", T.BIGINT)]),
+        {"k": np.arange(1000, dtype=np.int64),
+         "v": np.arange(1000, dtype=np.int64) * 3},
+        row_group_size=100)
+    md = Metadata()
+    md.register_catalog("hive", ParquetConnector(str(tmp_path)))
+    return QueryRunner(md, Session(catalog="hive", schema="default"))
+
+
+def test_a_domain_pruned_parquet_split_keeps_the_uploading_scan(
+        parquet_runner, monkeypatch):
+    calls = spy_on_resident_split(monkeypatch)
+    ex = parquet_runner.executor
+    scan = scan_of(parquet_runner, "f")
+    # k < 250 pushed down: of the split's five row groups, three are read
+    pruned = ex._scan_split(dc_replace(
+        scan, split=(0, 500), domains={"k": (None, 249, False, False)}))
+    assert calls == [] and pruned.known_rows == 300
+    # the same split with nothing to prune is a range of the resident table
+    node = dc_replace(scan, split=(300, 500))
+    got = ex._scan_split(node)
+    assert calls == ["f"]
+    assert_same_page(got, uploaded_split_page(ex, node))
+
+
+def test_a_table_over_the_cap_keeps_task_sized_split_scans(
+        parquet_runner, monkeypatch):
+    """What the code can observe, not a property of its own: a table
+    whose whole-table estimate is over ``query_max_memory_per_node``
+    (as ``enforce_resident_fits`` judges a scan) is not made resident
+    for a split of it that fits."""
+    calls = spy_on_resident_split(monkeypatch)
+    ex = parquet_runner.executor
+    ex.session.properties["query_max_memory_per_node"] = "10kB"
+    ex.session.properties["streaming_scan_enabled"] = False
+    node = dc_replace(scan_of(parquet_runner, "f"), split=(0, 500))
+    page = ex._scan_split(node)      # 500 rows x 16 B fit; 1000 do not
+    assert calls == [] and page.known_rows == 500
+    ex.session.properties["query_max_memory_per_node"] = "1MB"
+    assert ex._scan_split(node).known_rows == 500 and calls == ["f"]
+
+
+def test_an_insert_invalidates_what_split_reads_see():
+    from trino_tpu.connectors.memory import MemoryConnector
+    from trino_tpu.metadata import Metadata, Session
+
+    md = Metadata()
+    md.register_catalog("memory", MemoryConnector())
+    r = QueryRunner(md, Session(catalog="memory", schema="default"))
+    r.execute("create table t (id bigint, name varchar)")
+    r.execute("insert into t values (1, 'a'), (2, 'b'), (3, 'c')")
+    scan = scan_of(r, "t")
+    connector = md.connector("memory")
+
+    def rows_of(page):
+        return [row for row, live in zip(
+            zip(*(c.to_numpy()[0].tolist() for c in page.columns)),
+            np.asarray(page.mask)) if live]
+
+    first = r.executor._scan_split(dc_replace(scan, split=(1, 10)))
+    assert rows_of(first) == [(2, "b"), (3, "c")]
+    assert ("default", "t") in scan_cache.SHARED.resident_tables(connector)
+    r.execute("insert into t values (4, 'd')")
+    # the write dropped the resident columns (invalidate_scan)
+    assert ("default", "t") not in scan_cache.SHARED.resident_tables(connector)
+    second = r.executor._scan_split(dc_replace(scan, split=(1, 10)))
+    assert rows_of(second) == [(2, "b"), (3, "c"), (4, "d")]

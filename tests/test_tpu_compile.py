@@ -422,3 +422,29 @@ def test_q3_partial_group_by_compiles_on_four_chips_at_sf5(
     assert sorted(walks) == ["2", "4", "4"]  # five int64 sums, no key
     assert out.groupbys == {0: "sorted"}
     assert lowered.compile().memory_analysis().temp_size_in_bytes > 0
+
+
+def test_split_slice_at_lineitem_sf1(one_chip, no_compile_cache):
+    """``LocalExecutor._resident_split``'s body (``kernels.slice_rows``)
+    on the second half of Q1's ``lineitem`` columns at SF1 (ISSUE 45):
+    six int64 lanes, a date, a validity lane; rows 3,000,073 onward of
+    6,291,456 into the split's bucket of 3,145,728. A copy of the
+    range: no sort, no gather, no scatter, and no temporary beyond the
+    page it makes."""
+    n, start, rows, cap = LINEITEM_SF1, 3_000_073, 3_000_072, 3_145_728
+    dts = [jnp.int64] * 6 + [jnp.int32]
+
+    def split(valid, *data):
+        arrays = [(d, None) for d in data[:-1]] + [(data[-1], valid)]
+        return K.slice_rows(arrays, start, rows, cap)
+
+    lowered, compiled = _compile(
+        split, one_chip, ((n,), jnp.bool_), *(((n,), dt) for dt in dts)
+    )
+    assert _sorts(lowered) == []
+    hlo = compiled.as_text()
+    assert " gather(" not in hlo and " scatter(" not in hlo
+    ma = compiled.memory_analysis()
+    page_out = cap * (6 * 8 + 4 + 1 + 1)
+    assert ma.output_size_in_bytes <= page_out + (1 << 20)
+    assert ma.temp_size_in_bytes <= (1 << 20)

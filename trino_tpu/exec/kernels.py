@@ -94,6 +94,7 @@ __all__ = [
     "rows_at",
     "keys_match",
     "compact_rows",
+    "slice_rows",
     "cumsum",
     "floor_div",
     "searchsorted",
@@ -370,6 +371,27 @@ def compact_rows(env: dict, mask: jnp.ndarray, limit: int):
     rows being a prefix, the new mask is their count — no gather."""
     env2 = gather_rows(env, compact_perm(mask)[:limit])
     return env2, jnp.arange(limit, dtype=jnp.int32) < count_true(mask)
+
+
+@kernel
+def slice_rows(arrays: list, start: int, n: int, capacity: int):
+    """Rows ``[start, start + n)`` of every ``(data, valid)`` pair as
+    the first rows of ``capacity``, zeros behind them, and the live
+    mask of that prefix: ``(arrays2, mask)``. All three numbers are
+    static, so each column is one slice and one pad — a copy of the
+    range, no gather. (The rows behind the prefix are zeroed because a
+    page uploaded from the host holds zeros there.)"""
+    mask = jnp.arange(capacity, dtype=jnp.int32) < n
+
+    def cut(a):
+        if a is None:
+            return None
+        pad = [(0, capacity - n, 0)] + [(0, 0, 0)] * (a.ndim - 1)
+        return jax.lax.pad(
+            a[start:start + n], jnp.zeros((), a.dtype), pad
+        )
+
+    return [(cut(d), cut(v)) for d, v in arrays], mask
 
 
 def _rank_key(x: jnp.ndarray) -> tuple[jnp.ndarray, int]:

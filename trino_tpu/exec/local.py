@@ -112,6 +112,7 @@ def _maybe_compile_delay() -> None:
 #: (``kernels.py`` has the grammar). A chain opens an ``op<i>:`` scope a
 #: position itself (``stage.build_chain``) and is not listed.
 PROGRAM_OPERATOR = {
+    "scan_split": "TableScan",
     "compact": "Compact",
     "join_count": "Join",
     "join_bounds": "Join",
@@ -1056,6 +1057,23 @@ class LocalExecutor:
             # domain-pruned scans bypass the device cache (the pruned
             # row set is filter-specific, not the table)
             return self._scan_pruned(node, connector)
+        cache, columns = self._resident_columns(node, connector)
+        # the whole table in the connector's order, live rows a prefix:
+        # the one page that carries the declared sort order
+        return Page(
+            list(node.assignments), columns, cache[""],
+            known_rows=cache["#rows"], packed=True,
+            ordered_on=_declared_order(node, connector),
+        )
+
+    def _resident_columns(self, node: P.TableScan, connector):
+        """The whole table's page dict (``scan_cache``'s shape: column
+        key -> device Column, ``""`` the live mask, ``"#rows"``) with
+        every column the scan assigns in it, and those columns in the
+        scan's order. What is not resident yet is read from the
+        connector and copied to the device under an ``upload`` span
+        (its ``bytes``: what the span stored); a whole-table scan and a
+        split scan of a cacheable connector share the one copy."""
         if not connector.cacheable:
             cache = {}  # live views (system tables) re-scan per query
         else:
@@ -1078,7 +1096,6 @@ class LocalExecutor:
             # columns not resident yet: the connector's read and the
             # host->device copy
             with telemetry.child_span("upload", table=node.table) as span:
-                connector = self.metadata.connector(node.catalog)
                 cols = connector.scan(
                     node.schema, node.table, [c for _, c in missing]
                 )
@@ -1110,17 +1127,9 @@ class LocalExecutor:
                     span.attrs["bytes"] = int(uploaded)
             if connector.cacheable:
                 scan_cache.SHARED.publish()
-        names = list(node.assignments)
-        columns = [
+        return cache, [
             cache[ckey(s, c)] for s, c in node.assignments.items()
         ]
-        # the whole table in the connector's order, live rows a prefix:
-        # the one page that carries the declared sort order
-        return Page(
-            names, columns, cache[""],
-            known_rows=cache["#rows"], packed=True,
-            ordered_on=_declared_order(node, connector),
-        )
 
     def _scan_pruned(self, node: P.TableScan, connector) -> Page:
         """Scan with TupleDomain pushdown: the connector prunes storage
@@ -1218,41 +1227,29 @@ class LocalExecutor:
 
     def _scan_split(self, node: P.TableScan) -> Page:
         """Scan one row-range split of a table (fleet-mode source
-        parallelism). With ``device_cache_enabled`` the split page is
-        pinned in the cross-query HBM tier keyed by connector
-        fingerprint + split range + assignments + pushed domains, so a
-        serving worker re-assigned the same split on a repeat statement
-        pays no host->device transfer; otherwise split scans stay
-        uncached (a worker sees a different split per task, and fleet
-        tables are read once per stage wave)."""
+        parallelism). Where the table may stay on the device — a
+        cacheable connector, no pushed-down domain that prunes storage,
+        a table that fits (``stream_scan.table_fits_resident``) — the
+        split is a row range of the resident table
+        (``_resident_split``): a worker holds its tables as the embedded
+        runner does, and a warm task copies nothing from the host. Live
+        views (system tables) and domain-pruned storage splits are read
+        from the connector and uploaded, task by task."""
         from trino_tpu.connectors.base import ColumnDomain, Split
+        from trino_tpu.exec import stream_scan
 
         start, count = node.split
         connector = self.metadata.connector(node.catalog)
-        dkey = tokens = None
-        if self._device_cache_on():
-            from trino_tpu import cache as xcache
-
-            hashed0 = set(node.hash_varchar or [])
-            dkey = xcache.DEVICE.scan_key(
-                connector, node.schema, node.table,
-                tuple(
-                    (s, c, s in hashed0)
-                    for s, c in node.assignments.items()
-                ),
-                domains=node.domains,
-                split=Split(node.table, start, count),
-            )
-            if dkey is not None:
-                hit = xcache.DEVICE.get(dkey, self.cache_stats)
-                if hit is not None:
-                    return hit
-                tokens = self._cache_tokens(
-                    connector, node.schema, node.table
-                )
-        split = Split(node.table, start, count)
+        pruning = bool(node.domains) and getattr(
+            connector, "supports_domains", False
+        )
+        if (
+            connector.cacheable and not pruning
+            and stream_scan.table_fits_resident(self, node)
+        ):
+            return self._resident_split(node, connector, start, count)
         kw = {}
-        if node.domains and getattr(connector, "supports_domains", False):
+        if pruning:
             # pushed-down domains (static filters + coordinator-fed
             # dynamic filters) prune row groups WITHIN this split; the
             # filter above re-applies, so dropped rows stay exact
@@ -1262,14 +1259,47 @@ class LocalExecutor:
         with telemetry.child_span("upload", table=node.table):
             cols = connector.scan(
                 node.schema, node.table, list(node.assignments.values()),
-                split=split, **kw,
+                split=Split(node.table, start, count), **kw,
             )
-            page = self._scanned_page(node, cols, count)
-        if dkey is not None and tokens is not None:
-            from trino_tpu import cache as xcache
+            return self._scanned_page(node, cols, count)
 
-            xcache.DEVICE.put(dkey, page, tokens, pool=self.memory_pool)
-        return page
+    def _resident_split(
+        self, node: P.TableScan, connector, start: int, count: int
+    ) -> Page:
+        """Rows ``[start, start + count)`` of the resident table as a
+        packed page at the split's capacity bucket: one ``scan_split``
+        program slices every column (device work; dictionaries, hash
+        pools and array pools are the whole table's, so the codes are
+        too). Row for row what ``connector.scan(..., split=...)``
+        uploads. Only this path opens a ``split-scan`` span:
+        ``resident_split_scans`` on the statement's row counts them, and
+        ``trino_resident_split_scans_total`` the process's."""
+        with telemetry.child_span("split-scan", table=node.table):
+            cache, columns = self._resident_columns(node, connector)
+            n = max(0, min(count, cache["#rows"] - start))
+            cap = shapes.bucket(count, site="scan-split")
+            sig = tuple(
+                (str(c.data.dtype), c.data.shape[1:], c.valid is not None)
+                for c in columns
+            ) + (cache[""].shape[0],)
+            key = ("scan_split", sig, start, n, cap)
+            fn = self._jit_cache.get(key)
+            with _dispatching("scan_split", fn is None) as dispatch:
+                if fn is None:
+                    def split_fn(arrays):
+                        return K.slice_rows(arrays, start, n, cap)
+
+                    fn = _named_jit(split_fn, "scan_split")
+                    self._jit_cache[key] = fn
+                arrays, mask = fn([(c.data, c.valid) for c in columns])
+                dispatch.note(rows_out=n, columns=len(columns))
+        telemetry.RESIDENT_SPLIT_SCANS.inc(table=node.table)
+        return Page(
+            list(node.assignments),
+            [dc_replace(c, data=d, valid=v)
+             for c, (d, v) in zip(columns, arrays)],
+            mask, known_rows=n, packed=True,
+        )
 
     def _Exchange(self, node: P.Exchange) -> Page:
         # single-device execution: every exchange is the identity (the

@@ -87,6 +87,26 @@ def eligible(ex, node: P.TableScan) -> bool:
     return rows * spill.row_bytes(node.outputs) > budget // 4
 
 
+def _resident_estimate(ex, node: P.TableScan, whole_table: bool):
+    """Bytes a streamable scan that will NOT stream materializes —
+    of its split, or of the ``whole_table`` — where a per-node cap
+    governs it; None where nothing does (no cap, a connector that
+    cannot stream, an unknown row count)."""
+    conn = _connector(ex, node)
+    if conn is None or not getattr(conn, "streamable", False):
+        return None
+    if not ex._per_node_cap():
+        return None
+    if node.split is not None and not whole_table:
+        rows = int(node.split[1])
+    else:
+        try:
+            rows = conn.row_count(node.schema, node.table)
+        except Exception:
+            return None
+    return rows * spill.row_bytes(node.outputs)
+
+
 def enforce_resident_fits(ex, node: P.TableScan) -> None:
     """A streamable scan that will NOT stream must fit the per-node cap
     resident: probe-reserve the materialized page bytes through the
@@ -94,25 +114,25 @@ def enforce_resident_fits(ex, node: P.TableScan) -> None:
     ``ExceededMemoryLimitError`` (naming the cap and the query) instead
     of silently blowing host/device memory. The probe frees
     immediately — the real pages reserve as they materialize."""
-    conn = _connector(ex, node)
-    if conn is None or not getattr(conn, "streamable", False):
-        return
-    cap = ex._per_node_cap()
-    if not cap:
-        return
-    if node.split is not None:
-        rows = int(node.split[1])
-    else:
-        try:
-            rows = conn.row_count(node.schema, node.table)
-        except Exception:
-            return
-    est = rows * spill.row_bytes(node.outputs)
-    if est <= cap:
+    est = _resident_estimate(ex, node, whole_table=False)
+    if est is None or est <= ex._per_node_cap():
         return
     ctx = ex.memory_ctx.child("scan-resident")
     ctx.reserve(est)  # raises ExceededMemoryLimitError over the cap
     ctx.free(est)
+
+
+def table_fits_resident(ex, node: P.TableScan) -> bool:
+    """Whether the WHOLE table of a split scan may stay on the device:
+    ``enforce_resident_fits``' judgement over the table's rows instead
+    of the split's, and under an explicit HBM budget the quarter above
+    which a scan of the table streams (``LocalExecutor._execute_impl``).
+    A table over either keeps its split scans task-sized."""
+    budget = ex.hbm_budget()
+    if budget and spill.scan_bytes(ex.metadata, node) > budget // 4:
+        return False
+    est = _resident_estimate(ex, node, whole_table=True)
+    return est is None or est <= ex._per_node_cap()
 
 
 def _domains_of(node: P.TableScan) -> dict | None:

@@ -794,6 +794,12 @@ class Coordinator:
         "mesh_exchanges", "mesh_exchange_ms", "mesh_exchange_live_bytes",
         "mesh_exchange_buffer_bytes", "mesh_gather_ms", "mesh_upload_ms",
         "mesh_exchanges_in_place",
+        # a fleet statement's: the stage scheduler's spans and the
+        # workers' task subtrees stitched under them (0 where the
+        # runner is embedded, or the statement had no such span)
+        "stage_ms", "rpc_ms", "task_poll_wait_ms", "task_queue_wait_ms",
+        "spool_read_ms", "spool_write_ms", "split_scan_ms",
+        "resident_split_scans",
     )
 
     def _seal(self, q: QueryState) -> None:

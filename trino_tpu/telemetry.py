@@ -341,7 +341,8 @@ class child_span:
 
 
 #: span names whose occurrences are counted beside their time
-_COUNTED = {"host_sync": "host_syncs", "dispatch": "dispatches"}
+_COUNTED = {"host_sync": "host_syncs", "dispatch": "dispatches",
+            "split_scan": "resident_split_scans"}
 
 
 #: row field counting the programs of each ``join_search``
@@ -371,8 +372,11 @@ def span_totals(root: Span) -> Dict[str, float]:
     ``mesh_exchange_live_bytes`` / ``mesh_exchange_buffer_bytes``
     sum their attributes of those names, and ``mesh_upload_ms`` is the
     time of its two host-to-mesh layings-out (``mesh-scan-upload``,
-    ``mesh-scatter``). The root is left out: its time is the
-    statement's ``elapsed_ms``."""
+    ``mesh-scatter``); ``resident_split_scans`` counts the
+    ``split-scan`` spans: the split scans a worker served as a row
+    range of its resident table (one that uploads opens ``upload``
+    alone). The root is left out: its time is the statement's
+    ``elapsed_ms``."""
     out: Dict[str, float] = {}
     for sp in root.walk():
         if sp is root:
@@ -764,6 +768,9 @@ SCAN_CACHE_RESIDENT_BYTES = REGISTRY.gauge(
 SCAN_CACHE_RESIDENT_TABLES = REGISTRY.gauge(
     "trino_scan_cache_resident_tables",
     "Tables with a whole-table page resident in the shared scan-page cache")
+RESIDENT_SPLIT_SCANS = REGISTRY.counter(
+    "trino_resident_split_scans_total",
+    "Split scans served as a row range of the resident whole-table page, by table")
 RESULT_CACHE_HITS = REGISTRY.counter(
     "trino_result_cache_hits_total",
     "Statements served byte-identical from a semantic result cache")
