@@ -51,7 +51,11 @@ ROW_FIELDS = (
     "upload_ms", "spool_read_ms", "spool_write_ms", "rpc_ms",
     "task_poll_wait_ms", "task_queue_wait_ms", "host_sync_ms", "stage_ms",
     "resident_split_scans", "split_scan_ms", "dispatches",
+    "narrow_key_joins",
 )
+#: least joins a statement's tasks rank below 64 bits (ISSUE 46: the
+#: plan's joins; a join that runs in several tasks counts in each)
+NARROW_JOINS = {"q06": 0, "q01": 0, "q03": 2, "q18": 3}
 #: split scans a statement: two a scan of the plan (``n_live = max(2,
 #: workers)`` splits, server/fleet.py); Q18 scans lineitem twice
 SPLIT_SCANS = {"q06": 2, "q01": 2, "q03": 6, "q18": 8}
@@ -157,6 +161,21 @@ def test_the_second_execution_reads_the_resident_table(st, served, fleet):
     # every exchange edge still commits to the spool and is read off it
     assert "spool-write" in names and "spool-read" in names
     assert warm["spool_write_ms"] > 0 and warm["rpc_ms"] > 0
+
+
+@pytest.mark.parametrize("st", STATEMENTS, ids=IDS)
+def test_the_workers_joins_rank_at_the_width_the_coordinator_planned(
+        st, served, fleet):
+    """The fleet coordinator plans and annotates, the worker executes
+    fragments it reads off the wire: a join's exact key range
+    (``Join.key_ranges``) rides ``plan/serde.py``, so the worker's join
+    programs are built at the width the plan proves — cold and warm."""
+    for _, row in served[st.key]:
+        assert row["narrow_key_joins"] >= NARROW_JOINS[st.template], row
+        # every join of the four templates is on one integer key whose
+        # range both inputs prove: none is left at 64 bits
+        joins = row["small_build_joins"] + row["sorted_joins"]
+        assert joins == row["narrow_key_joins"], row
 
 
 def test_the_first_scan_of_a_table_uploads_it_once(served, fleet):

@@ -319,6 +319,98 @@ def test_join_ranges_two_searches_agree_with_numpy(case, cap, monkeypatch):
             assert a.dtype == b.dtype and np.array_equal(a, b), (name, what)
 
 
+# ---- join_ranges at the width the plan proves (ISSUE 46) ------------------
+
+
+def _narrow_join_case(case: str, key_bits: int, nb: int, n_p: int, rng):
+    """Keys whose LIVE values fit ``key_bits`` bits; dead rows hold
+    whatever the case says."""
+    top = (1 << key_bits) - 1
+    span = min(top, 3 * nb) + 1
+    bk = rng.integers(0, span, nb).astype(np.uint64)
+    pk = rng.integers(0, span, n_p).astype(np.uint64)
+    bl, pl = rng.random(nb) < 0.9, rng.random(n_p) < 0.9
+    if case == "duplicate_build_keys":
+        bk = rng.integers(0, min(span, max(nb // 8, 2)), nb).astype(np.uint64)
+    elif case == "absent_probe_keys":
+        bk &= ~np.uint64(1)  # even build keys: every odd probe is absent
+        bk[: nb // 2] = 0
+    elif case == "live_key_is_the_top_of_the_width":
+        bk[::5], pk[::3] = top, top  # live and dead rows alike
+    elif case == "dead_rows_out_of_range":
+        # what a Filter's dead rows and a shifted key's wrap leave: real
+        # values past the width, whose low bits "match" live keys
+        high = np.uint64(1) << np.uint64(min(key_bits, 63))
+        bk[~bl] = (rng.choice(pk[pl], int((~bl).sum())) | high) + (
+            high if key_bits < 63 else np.uint64(0))
+        pk[~pl] = _TOP - rng.integers(0, 9, int((~pl).sum())).astype(np.uint64)
+    elif case == "empty_live_build":
+        bl[:] = False
+    return bk, bl, pk, pl
+
+
+@pytest.mark.parametrize("key_bits", [1, 23, 31, 32, 33, 40, 63, 64])
+@pytest.mark.parametrize("search", ["count", "sort"])
+@pytest.mark.parametrize("case", [
+    "duplicate_build_keys", "absent_probe_keys",
+    "live_key_is_the_top_of_the_width", "dead_rows_out_of_range",
+    "empty_live_build",
+])
+def test_join_ranges_at_a_proven_key_width(case, search, key_bits):
+    """``join_ranges(..., key_bits=k)`` on keys whose live values fit
+    ``k`` bits returns on live rows the three arrays ``key_bits=64``
+    does, bit for bit, under both searches — whatever dead rows hold,
+    with a live key equal to ``2**k - 1`` (the dead tail's sentinel at
+    that width) and with no live build row at all."""
+    nb = 1500 if search == "count" else K.JOIN_SMALL_BUILD + 904
+    assert K.join_search(nb) == search
+    # above 16,384 probes the sort search is the merged rank
+    n_p = 700 if search == "count" else 20_000
+    rng = np.random.default_rng(key_bits * 131 + len(case) + nb)
+    bk, bl, pk, pl = _narrow_join_case(case, key_bits, nb, n_p, rng)
+    args = tuple(jnp.asarray(x) for x in (bk, bl, pk, pl))
+    want = tuple(map(_np, K.join_ranges(*args)))
+    assert all(np.array_equal(a, b) for a, b in zip(
+        want, _join_ranges_ref(bk, bl, pk, pl)))
+    order, lo, cnt = map(_np, K.join_ranges(*args, key_bits=key_bits))
+    n_live = int(bl.sum())
+    assert order.dtype == lo.dtype == cnt.dtype == np.int32
+    assert np.array_equal(order[:n_live], want[0][:n_live])
+    assert sorted(order[n_live:]) == sorted(want[0][n_live:])
+    assert np.array_equal(lo[pl], want[1][pl])
+    assert np.array_equal(cnt, want[2])  # a dead probe counts 0 in both
+
+
+def test_join_ranges_at_q3s_width_sorts_once_a_packed_argsort():
+    """Q3's ``lineitem`` join at SF1 — 4,194,304 probe rows merged with
+    a build of 262,144, keys of 23 bits: key and row index share one
+    word, so the build's sort and the merged rank's are ONE
+    single-operand sort each, and the LSD radix's second sort, the
+    gather between the two and the composed permutation are not in the
+    program; at 64 bits they are."""
+    n, b = 4_194_304, 262_144
+    avals = [
+        jax.ShapeDtypeStruct(shape, dt) for shape, dt in (
+            ((b,), jnp.uint64), ((b,), jnp.bool_),
+            ((n,), jnp.uint64), ((n,), jnp.bool_))
+    ]
+    texts = {
+        bits: K.join_ranges.lower(*avals, key_bits=bits).as_text(
+            debug_info=True)
+        for bits in (23, 64)
+    }
+    sorts = {bits: t.count("stablehlo.sort") for bits, t in texts.items()}
+    assert sorts == {23: 2, 64: 4}
+    for scope in ("s:gather_high", "s:sort_high", "s:compose", "s:sort_low"):
+        assert scope in texts[64]
+        assert scope not in texts[23]
+    # the merged words: 23 bits of key over 23 of index, one uint64
+    assert f"tensor<{n + b}xui64>" in texts[23]
+    # and nothing probe-sized is read as two halves any more
+    assert f"tensor<{n}x2xui32>" in texts[23]
+    assert f"tensor<{n}x3xui32>" in texts[64]
+
+
 # ---- reads at one index vector ride one walk of words (ISSUE 44) ----------
 
 

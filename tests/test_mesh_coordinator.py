@@ -129,6 +129,19 @@ def test_mesh_fields_on_the_statement_row(st, served):
     assert row["streamed_groupbys"] == (2 if st.template == "q18" else 0)
 
 
+@pytest.mark.parametrize("st", STATEMENTS, ids=IDS)
+def test_mesh_joins_rank_at_the_width_the_plan_proves(st, served):
+    """Every mesh program that holds a ``join_ranges`` (join count and
+    expansion, semi join, dynamic filter) takes the key width from the
+    plan node the local executor takes it from (ISSUE 46): each of
+    Q3's and Q18's joins is on one integer key with an exact range, so
+    none is left at 64 bits; Q1 and Q6 run none."""
+    _, row = served[st.key]
+    joins = row["small_build_joins"] + row["sorted_joins"]
+    assert row["narrow_key_joins"] == joins
+    assert (joins > 0) == (st.template in ("q03", "q18"))
+
+
 @pytest.mark.parametrize(
     "st", [s for s in STATEMENTS if s.template in ("q03", "q18")],
     ids=["q03", "q18"])

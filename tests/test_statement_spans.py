@@ -36,7 +36,7 @@ FLAT_FIELDS = (
     "upload_ms", "to_rows_ms", "respond_ms", "rows_out_ms",
     "direct_groupbys", "sorted_groupbys", "streamed_groupbys",
     "groupby_start_walks", "compactions", "compact_gather_ops",
-    "small_build_joins", "sorted_joins",
+    "small_build_joins", "sorted_joins", "narrow_key_joins",
 )
 PROGRAM = re.compile(
     r"^(chain_[A-Za-z_]+|join_count|join_bounds|join_expand|semi_join"
@@ -55,6 +55,16 @@ def coord():
 def get(coord, path):
     with urllib.request.urlopen(coord.uri + path, timeout=30) as r:
         return json.loads(r.read())
+
+
+def joins_counted(search=None, key_bits=None):
+    """``trino_joins_total`` summed over the series of one search, one
+    width, or all."""
+    return sum(
+        telemetry.JOINS.value(search=s, key_bits=str(b))
+        for s in ("count", "sort") if search in (None, s)
+        for b in range(1, 65) if key_bits in (None, b)
+    )
 
 
 def serve(coord, sql):
@@ -322,13 +332,13 @@ def test_query_rows_count_joins_by_their_search(coord, sql, small, by_sort):
     from trino_tpu.exec import kernels as K
 
     for _ in range(2):
-        before = {s: telemetry.JOINS.value(search=s) for s in ("count", "sort")}
+        before = {s: joins_counted(s) for s in ("count", "sort")}
         qid, _ = serve(coord, sql)
         row = row_of(coord, qid)
         assert (row["small_build_joins"], row["sorted_joins"]) == (
             small, by_sort), row
         for search, n in (("count", small), ("sort", by_sort)):
-            assert telemetry.JOINS.value(search=search) - before[search] == n
+            assert joins_counted(search) - before[search] == n
         noted = [
             sp["attrs"] for sp, _ in walk(get(coord, f"/v1/query/{qid}")["spans"])
             if sp["name"] == "dispatch" and "join_search" in sp["attrs"]
@@ -340,8 +350,47 @@ def test_query_rows_count_joins_by_their_search(coord, sql, small, by_sort):
             assert (attrs["join_search"] == "count") == (
                 attrs["build_rows"] <= K.JOIN_SMALL_BUILD)
     text = urllib.request.urlopen(coord.uri + "/v1/metrics").read().decode()
-    assert 'trino_joins_total{search="sort"}' in text or not by_sort
-    assert 'trino_joins_total{search="count"}' in text or not small
+    series = r'trino_joins_total\{key_bits="\d+",search="%s"\}'
+    assert re.search(series % "sort", text) or not by_sort
+    assert re.search(series % "count", text) or not small
+
+
+@pytest.mark.parametrize("sql,narrow,widths", [
+    (QUERIES["q01"], 0, []), (QUERIES["q06"], 0, []),
+    # Q3 at ``tiny``: ``o_custkey`` / ``c_custkey`` lie in [1, 1500]
+    # (11 bits), ``l_orderkey`` / ``o_orderkey`` in [1, 59976] (16)
+    (QUERIES["q03"], 2, [11, 16]),
+    # Q18: the same two joins, and its semi join too — the subquery's
+    # ``l_orderkey`` keeps its exact bounds through the Aggregate (a
+    # group key's stats are its source's) and the HAVING (a filter on
+    # the sum narrows nothing of the key)
+    (QUERIES["q18"], 3, [11, 16, 16]),
+    # two columns are one hashed key: 64 bits, whatever their ranges
+    ("select count(*) from lineitem, partsupp"
+     " where l_partkey = ps_partkey and l_suppkey = ps_suppkey", 0, [64]),
+])
+def test_query_rows_count_joins_ranked_at_their_keys_width(
+        coord, sql, narrow, widths):
+    """A join on one integer key whose exact range the plan proves
+    (``Join.key_ranges``, ISSUE 46) ranks it at ``bit_length(hi - lo)``
+    bits: the ``dispatch`` span of the program that holds the
+    ``join_ranges`` carries ``key_bits``, the row counts those below 64
+    as ``narrow_key_joins``, and ``trino_joins_total`` takes the width
+    as a label; a warm dispatch reports the same."""
+    for _ in range(2):
+        before = {b: joins_counted(key_bits=b) for b in set(widths)}
+        qid, _ = serve(coord, sql)
+        row = row_of(coord, qid)
+        assert row["narrow_key_joins"] == narrow, row
+        noted = sorted(
+            sp["attrs"]["key_bits"]
+            for sp, _ in walk(get(coord, f"/v1/query/{qid}")["spans"])
+            if sp["name"] == "dispatch" and "join_search" in sp["attrs"]
+        )
+        assert noted == widths
+        assert narrow == sum(b < 64 for b in widths)
+        for b in set(widths):
+            assert joins_counted(key_bits=b) - before[b] == widths.count(b)
 
 
 def test_protocol_stats_carry_queued_and_planning_time(coord):

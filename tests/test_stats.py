@@ -290,6 +290,43 @@ def test_outer_join_does_not_narrow_exact_bounds():
     assert got == [(i * 1000, 1) for i in range(20)]
 
 
+def test_inner_join_keeps_the_exact_side_when_the_other_is_an_estimate():
+    """An inner join's key with a guarantee on one side and an estimate
+    on the other — a count(*) output cut by ``in`` to [2, 3], inside
+    the probe's exact [1, 6] — keeps the guarantee alone: intersecting
+    the two and calling the result exact would hand the group-by above
+    (and a join above, through ``Join.key_ranges``) a range that an
+    estimate drew."""
+    md = Metadata()
+    md.register_catalog("memory", MemoryConnector())
+    r = QueryRunner(md, Session(catalog="memory", schema="default"))
+    r.execute("create table t (x bigint)")
+    r.execute("create table u (k bigint)")
+    r.execute("insert into t values (1), (2), (3), (4), (5), (6)")
+    r.execute("insert into u values (7), (7), (9), (9), (9), (4)")
+    sql = (
+        "select t.x, count(*) from t join "
+        "(select k, count(*) c from u group by k) s on t.x = s.c "
+        "where s.c in (2, 3) group by t.x"
+    )
+    plan = r.plan_sql(sql)
+    (join,) = _find(plan, P.Join)
+    ((x, c),) = join.criteria
+    left, right = (estimate(src, md).sym(k) for src, k in
+                   zip(join.sources, (x, c)))
+    assert (left.lo, left.hi, left.exact) == (1, 6, True)
+    assert (right.lo, right.hi, right.exact) == (2, 3, False)
+    joined = estimate(join, md)
+    for k in (x, c):
+        st = joined.sym(k)
+        assert (st.lo, st.hi, st.exact) == (1, 6, True), k
+    # one side is no guarantee: the join itself ranks at 64 bits
+    assert join.key_ranges is None
+    above = [a for a in _find(plan, P.Aggregate) if x in a.group_keys]
+    assert [a.key_ranges for a in above] == [{x: (1, 6)}]
+    assert sorted(r.execute(sql).rows) == [(2, 1), (3, 1)]
+
+
 def test_distinct_agg_dedupes_before_exchange():
     """Distributed DISTINCT aggregation is two-level: a shard-local
     dedupe feeds a (group keys + distinct column) exchange — at most
@@ -312,3 +349,208 @@ def test_distinct_agg_dedupes_before_exchange():
     assert group_ex and isinstance(group_ex[0].source, P.Aggregate)
     assert group_ex[0].source.step == "PARTIAL"
     assert group_ex[0].source.aggregates  # partial count over pairs
+
+
+# ---- a join's exact key range (ISSUE 46) ------------------------------------
+
+def _pair_ranges(node):
+    """``key_ranges`` with the symbols' numeric suffixes cut off."""
+    cut = lambda s: s.rsplit("_", 1)[0]  # noqa: E731
+    return {
+        (cut(l), cut(r)): rng for (l, r), rng in (node.key_ranges or {}).items()
+    }
+
+
+@pytest.mark.parametrize("qid", ["q03", "q18"])
+def test_join_key_ranges_on_tpch_plans(runner, qid):
+    """Every equi criterion of Q3's and Q18's joins has exact integer
+    bounds on BOTH sides at ``tiny``, so each carries one range holding
+    both — Q18's semi join too: the subquery's ``l_orderkey`` keeps its
+    bounds through the Aggregate and the HAVING — and the range rides
+    the wire format the fleet's worker plans from."""
+    from trino_tpu.connectors.tpch.queries import QUERIES
+    from trino_tpu.plan.serde import plan_from_json, plan_to_json
+    import json
+
+    plan = runner.plan_sql(QUERIES[qid])
+    joins = {
+        pair: rng for j in _find(plan, P.Join)
+        for pair, rng in _pair_ranges(j).items()
+    }
+    assert joins == {
+        ("l_orderkey", "o_orderkey"): (1, 59976),
+        ("o_custkey", "c_custkey"): (1, 1500),
+    }
+    semis = _find(plan, P.SemiJoin)
+    assert [_pair_ranges(s) for s in semis] == (
+        [{("o_orderkey", "l_orderkey"): (1, 59976)}] if qid == "q18" else [])
+    back = plan_from_json(json.loads(json.dumps(plan_to_json(plan))))
+    for kind in (P.Join, P.SemiJoin):
+        sent = [n.key_ranges for n in _find(plan, kind)]
+        assert [n.key_ranges for n in _find(back, kind)] == sent
+        for ranges in sent:
+            for pair, (lo, hi) in ranges.items():
+                assert isinstance(pair, tuple) and type(lo) is type(hi) is int
+
+
+def _two_tables(ddl_t, ddl_u, rows_t, rows_u):
+    md = Metadata()
+    md.register_catalog("memory", MemoryConnector())
+    r = QueryRunner(md, Session(catalog="memory", schema="default"))
+    r.execute(f"create table t ({ddl_t})")
+    r.execute(f"create table u ({ddl_u})")
+    r.execute(f"insert into t values {rows_t}")
+    r.execute(f"insert into u values {rows_u}")
+    return r
+
+
+@pytest.mark.parametrize("case,ddl,rows_t,rows_u,on", [
+    ("float_key", "k double, v bigint", "(1.5, 1), (2.5, 2)",
+     "(1.5, 1), (9.5, 2)", "t.k = u.k"),
+    ("varchar_key", "k varchar, v bigint", "('a', 1), ('b', 2)",
+     "('a', 1), ('c', 2)", "t.k = u.k"),
+    ("long_decimal_key", "k decimal(30, 2), v bigint", "(1.50, 1), (2.50, 2)",
+     "(1.50, 1), (9.50, 2)", "t.k = u.k"),
+    # a key that is an expression's value has no exact bounds
+    ("side_without_exact_stats", "k bigint, v bigint", "(1, 1), (2, 2)",
+     "(1, 1), (9, 2)", "t.k = u.v + u.k - u.v"),
+])
+def test_join_key_ranges_absent(case, ddl, rows_t, rows_u, on):
+    """Only exact INTEGER bounds on both sides make a range: a float, a
+    varchar or a two-limb decimal key, or a side whose bounds are not a
+    guarantee, leaves the criterion out — and the join answers as it
+    did, at 64 bits."""
+    r = _two_tables(ddl, ddl, rows_t, rows_u)
+    sql = f"select count(*) from t join u on {on}"
+    for j in _find(r.plan_sql(sql), P.Join):
+        assert j.key_ranges is None, (case, j.key_ranges)
+    assert r.execute(sql).rows == [(1,)]
+
+
+def test_multi_column_join_key_stays_64_bits():
+    """Each criterion of a two-column join may have its range; the
+    combined key is a hash of both, so its width is 64 whatever they
+    are (``LocalExecutor._join_key_width``)."""
+    from trino_tpu.exec.local import LocalExecutor
+
+    r = _two_tables(
+        "a bigint, b bigint", "a bigint, b bigint",
+        "(1, 1), (2, 2), (3, 3)", "(1, 1), (2, 5), (3, 3)",
+    )
+    sql = "select count(*) from t join u on t.a = u.a and t.b = u.b"
+    (join,) = _find(r.plan_sql(sql), P.Join)
+    assert len(join.key_ranges) == 2
+    ex = r.executor
+    left, right = (ex._compact(ex.execute(s)) for s in join.sources)
+    assert LocalExecutor._join_key_width(
+        join.key_ranges, join.criteria, left, right) == (0, 64)
+    one = join.criteria[:1]
+    assert LocalExecutor._join_key_width(
+        join.key_ranges, one, left, right) == (1, 2)
+    assert r.execute(sql).rows == [(2,)]
+
+
+def _sqlite_rows(tables: dict, sql: str):
+    import sqlite3
+
+    conn = sqlite3.connect(":memory:")
+    for name, (cols, rows) in tables.items():
+        conn.execute(f"create table {name} ({', '.join(cols)})")
+        conn.executemany(
+            f"insert into {name} values ({', '.join('?' * len(cols))})", rows
+        )
+    return sorted(conn.execute(sql).fetchall(), key=repr)
+
+
+def _values(rows):
+    return ", ".join(
+        "(" + ", ".join("null" if v is None else str(v) for v in row) + ")"
+        for row in rows
+    )
+
+
+_BASE = 10**15
+
+
+@pytest.mark.parametrize(
+    "kind", ["join", "left join", "right join", "full join"])
+@pytest.mark.parametrize("keys", [
+    "narrow_window_at_1e15", "negative_keys", "null_keys",
+])
+def test_range_ranked_join_is_exact(keys, kind):
+    """A memory-table join whose keys the plan proves a range of —
+    a window of a few values at 10^15, keys below zero, keys that are
+    NULL on both sides — ranks at a handful of bits and answers exactly
+    what sqlite answers, as INNER, LEFT, RIGHT and FULL join (an outer
+    join's unmatched rows are rows of an input, inside the input's
+    range; the RIGHT join runs as the LEFT join of its sides exchanged,
+    under the range the plan gave the pair as written)."""
+    rng = np.random.default_rng(len(keys) * 7 + len(kind))
+    lo = {"narrow_window_at_1e15": _BASE, "negative_keys": -40,
+          "null_keys": 5}[keys]
+    t_rows = [(int(lo + rng.integers(0, 30)), i) for i in range(60)]
+    u_rows = [(int(lo + 10 + rng.integers(0, 35)), 100 + i) for i in range(45)]
+    if keys == "null_keys":
+        t_rows[::7] = [(None, v) for _, v in t_rows[::7]]
+        u_rows[::5] = [(None, v) for _, v in u_rows[::5]]
+    r = _two_tables("k bigint, v bigint", "k bigint, w bigint",
+                    _values(t_rows), _values(u_rows))
+    sql = f"select t.k, t.v, u.k, u.w from t {kind} u on t.k = u.k"
+    (join,) = _find(r.plan_sql(sql), P.Join)
+    (lo_hi,) = join.key_ranges.values()
+    live = [k for k, _ in t_rows + u_rows if k is not None]
+    assert lo_hi == (min(live), max(live))
+    assert (lo_hi[1] - lo_hi[0]).bit_length() <= 6
+    res = r.execute(sql)
+    built = {k[2:4] for k in r.executor._jit_cache if k[0] == "joinA"}
+    assert built == {(lo_hi[0], (lo_hi[1] - lo_hi[0]).bit_length())}
+    want = _sqlite_rows(
+        {"t": (["k", "v"], t_rows), "u": (["k", "w"], u_rows)}, sql)
+    assert sorted(res.rows, key=repr) == want
+
+
+def test_semi_join_ranked_at_its_range_is_exact():
+    rng = np.random.default_rng(46)
+    t_rows = [(int(_BASE + rng.integers(0, 50)), i) for i in range(80)]
+    u_rows = [(int(_BASE + 20 + rng.integers(0, 50)), i) for i in range(30)]
+    r = _two_tables("k bigint, v bigint", "k bigint, w bigint",
+                    _values(t_rows), _values(u_rows))
+    sql = "select k, v from t where k in (select k from u where w < 25)"
+    (semi,) = _find(r.plan_sql(sql), P.SemiJoin)
+    assert semi.key_ranges
+    want = _sqlite_rows(
+        {"t": (["k", "v"], t_rows), "u": (["k", "w"], u_rows)}, sql)
+    assert sorted(r.execute(sql).rows, key=repr) == want
+
+
+def test_insert_that_widens_a_join_key_range_builds_another_program():
+    """The range is part of the join program's cache key: after an
+    INSERT that widens it the next statement plans another range,
+    builds another program and answers exactly — the old program, cut
+    to the old width, is not asked."""
+    t_rows = [(i % 8, i) for i in range(40)]
+    u_rows = [(i % 8, 100 + i) for i in range(16)]
+    r = _two_tables("k bigint, v bigint", "k bigint, w bigint",
+                    _values(t_rows), _values(u_rows))
+    sql = "select t.k, t.v, u.w from t join u on t.k = u.k"
+
+    def check():
+        (join,) = _find(r.plan_sql(sql), P.Join)
+        want = _sqlite_rows(
+            {"t": (["k", "v"], t_rows), "u": (["k", "w"], u_rows)}, sql)
+        assert sorted(r.execute(sql).rows, key=repr) == want
+        return next(iter(join.key_ranges.values()))
+
+    def join_programs():
+        return {k[2:4] for k in r.executor._jit_cache if k[0] == "joinA"}
+
+    assert check() == (0, 7)
+    assert join_programs() == {(0, 3)}
+    # 8 + 2**40 has the low bits of the live key 8 % 8 == 0
+    far = [(8 + 2**40, 1000), (-3, 1001)]
+    t_rows += far
+    u_rows += [(k, 2000 + i) for i, (k, _) in enumerate(far)]
+    r.execute(f"insert into t values {_values(far)}")
+    r.execute(f"insert into u values {_values(u_rows[-2:])}")
+    assert check() == (-3, 8 + 2**40)
+    assert join_programs() == {(0, 3), (-3, 41)}

@@ -205,29 +205,36 @@ def test_q18_group_by_runs_at_lineitem_sf1(one_chip, no_compile_cache):
     assert ma.temp_size_in_bytes < 180 << 20
 
 
-def test_join_ranges_at_q3_sf1(one_chip, no_compile_cache):
+@pytest.mark.parametrize("key_bits", [64, 23])
+def test_join_ranges_at_q3_sf1(one_chip, no_compile_cache, key_bits):
     """Q3's ``lineitem`` join at SF1 — a probe of 4,194,304 rows ranked
     in a build of 262,144 by sort (``join_search``) — reads the build
     key and the end of its run at ``lo`` in ONE gather of ``[probe, 3]``
     uint32 words (ISSUE 44) where a uint64 and an int32 gather stood,
-    and every sort is still single-operand and unstable."""
+    and every sort is still single-operand and unstable. At the 23 bits
+    the plan proves of ``l_orderkey`` at SF1 (ISSUE 46) the sorts are
+    two where 64 bits take four, and the key is one word of that read."""
+    from functools import partial
+
     n, b = 4_194_304, 262_144
     assert K.join_search(b) == "sort"
     lowered, compiled = _compile(
-        K.join_ranges.__wrapped__, one_chip, ((b,), jnp.uint64),
-        ((b,), jnp.bool_), ((n,), jnp.uint64), ((n,), jnp.bool_),
+        partial(K.join_ranges.__wrapped__, key_bits=key_bits), one_chip,
+        ((b,), jnp.uint64), ((b,), jnp.bool_),
+        ((n,), jnp.uint64), ((n,), jnp.bool_),
     )
     sorts = _sorts(lowered)
-    assert sorts and all(s == (1, False) for s in sorts)
+    assert sorts == [(1, False)] * (4 if key_bits == 64 else 2)
+    words = 3 if key_bits == 64 else 2
     probe_sized = re.findall(
         rf"stablehlo\.gather.*-> tensor<({n}x[\dx]*\w+)>", lowered.as_text()
     )
-    assert probe_sized == [f"{n}x3xui32"]
+    assert probe_sized == [f"{n}x{words}xui32"]
     results = re.findall(
         r"^\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([\d,]*)\]\S* gather\(",
         compiled.as_text(), re.M,
     )
-    assert ("u32", f"{n},3") in results
+    assert ("u32", f"{n},{words}") in results
     assert not any(dt in ("u64", "s64") and dims == str(n)
                    for dt, dims in results), results
 

@@ -31,7 +31,14 @@ of 64 to 16,384 rows): the search by sort (``searchsorted`` ->
 for bit, then the pieces of the sort path each alone, the count
 kernel's variants, and ``jnp.searchsorted(method="scan")`` over the
 small haystack — the table of PERF.md, PR 42, which set
-``kernels.JOIN_SMALL_BUILD``.
+``kernels.JOIN_SMALL_BUILD``. ``--key-bits 23`` (a list) draws the keys
+below ``2**23`` and times ``join_ranges`` at that static width too —
+what ``Join.key_ranges`` hands it (PR 46) — against the 64-bit answers
+on live rows, with its build sort and merged rank alone; Q3's
+``lineitem`` join at SF1 is ``--probes 4194304 --builds 262144
+--sorted-builds 262144 --key-bits 23``, at SF5 ``--probes 16777216
+--builds 1048576 --sorted-builds 1048576 --key-bits 25`` (a build the
+count kernel cannot carry is timed by sort alone).
 
 ``--shape startwalk``: what a step reads at one index vector, as lone
 gathers (the bodies the engine had until PR 44) against one walk of
@@ -369,13 +376,15 @@ JOINRANK_PROBES = (6_291_456, 33_554_432)  # lineitem's capacity, SF1 / SF5
 JOINRANK_BUILDS = (64, 256, 1024, 4096, 16384)
 
 
-def joinrank_inputs(probe: int, build: int, seed: int):
+def joinrank_inputs(probe: int, build: int, seed: int, key_bits: int = 64):
     """Q18's ``lineitem`` join in shape: order keys of 1-7 lines each
     as the probe (live rows a prefix), and as the build a sample of
-    those keys, three quarters of the capacity live, a few twice."""
+    those keys, three quarters of the capacity live, a few twice; every
+    key below ``2**key_bits``."""
     rng = np.random.default_rng(seed)
     n_live = probe * 6_001_215 // 6_291_456
-    pk = np.sort(rng.integers(1, n_live // 4 * 32, n_live, dtype=np.int64))
+    top = min(n_live // 4 * 32, 1 << key_bits)
+    pk = np.sort(rng.integers(1, top, n_live, dtype=np.int64))
     pk = np.concatenate([pk, np.zeros(probe - n_live, np.int64)])
     bk = rng.choice(pk[:n_live], build)
     bk[: build // 16] = bk[build // 16: 2 * (build // 16)]
@@ -385,14 +394,16 @@ def joinrank_inputs(probe: int, build: int, seed: int):
             jnp.asarray(np.arange(probe) < n_live))
 
 
-def _join_ranges_by(search: str):
-    """``join_ranges``' body traced with the search forced."""
+def _join_ranges_by(search: str, key_bits: int = 64):
+    """``join_ranges``' body traced with the search forced, its keys
+    ranked at ``key_bits``."""
     kept = K.JOIN_SMALL_BUILD
 
     def body(bk, bl, pk, pl):
         K.JOIN_SMALL_BUILD = (1 << 30) if search == "count" else -1
         try:
-            return K.join_ranges.__wrapped__(bk, bl, pk, pl)
+            return K.join_ranges.__wrapped__(
+                bk, bl, pk, pl, key_bits=key_bits)
         finally:
             K.JOIN_SMALL_BUILD = kept
 
@@ -414,10 +425,12 @@ def _count_low_words(sk, pk):
         sl[:, None] < pl[None, :], sl[:, None] == pl[None, :])
 
 
-def joinrank_pieces(bk, bl, pk, pl):
+def joinrank_pieces(bk, bl, pk, pl, widths=()):
     """name -> (fn, args): what the sort path's ``join_ranges`` is made
     of at this shape, each alone and on the data the step before it
-    leaves, then the count kernel's variants and the binary scan."""
+    leaves, then the count kernel's variants and the binary scan; for
+    each of ``widths`` the build's sort and the merged rank at that
+    static key width."""
     n, m = pk.shape[0], bk.shape[0]
     sk = jnp.sort(jnp.where(bl, bk, jnp.uint64(0xFFFFFFFFFFFFFFFF)))
     both = jnp.concatenate([pk, sk])
@@ -431,6 +444,15 @@ def joinrank_pieces(bk, bl, pk, pl):
     lo = jax.jit(lambda a, v: K.searchsorted(a, v))(sk, pk)
     at = jnp.clip(lo, 0, m - 1)
     run_end = jnp.arange(1, m + 1, dtype=jnp.int32)
+    narrow = {}
+    for bits in widths:
+        word = jnp.uint32 if bits <= 32 else jnp.uint64
+        sk_w = jnp.minimum(sk, jnp.uint64((1 << bits) - 1)).astype(word)
+        narrow[f"build_argsort at {bits} bits"] = (
+            lambda k, l, b=bits: K.packed_argsort(k, b, last=~l), (bk, bl))
+        narrow[f"merge_rank whole at {bits} bits"] = (
+            lambda a, v, b=bits: K.searchsorted(a, v, key_bits=b),
+            (sk_w, pk.astype(word)))
     return {
         "build_argsort (packed_argsort of the build, 64 bits)":
             (lambda k, l: K.packed_argsort(k, 64, last=~l), (bk, bl)),
@@ -463,6 +485,7 @@ def joinrank_pieces(bk, bl, pk, pl):
             (lambda a, v: (jnp.searchsorted(a, v, method="scan"),
                            jnp.searchsorted(a, v, side="right",
                                             method="scan")), (sk, pk)),
+        **narrow,
     }
 
 
@@ -474,30 +497,53 @@ def joinrank_main(a) -> int:
     probes = [int(x) for x in a.probes.split(",")]
     builds = [int(x) for x in a.builds.split(",")]
     sorted_at = {int(x) for x in a.sorted_builds.split(",") if x}
+    widths = [int(x) for x in a.key_bits.split(",") if int(x) < 64]
     for probe in probes:
         for build in builds:
-            args = joinrank_inputs(probe, build, a.seed + build)
-            got, t = timed(_join_ranges_by("count"), args, a.reps)
-            run = {"probe": probe, "build": build, "search": "count", **t}
-            if got is not None:
-                run["matches"] = int(jnp.sum(got[2]))
-            rec["runs"].append(run)
-            print(run, flush=True)
-            if build in sorted_at:
-                ref, t = timed(_join_ranges_by("sort"), args, a.reps)
-                agree = None not in (got, ref) and all(
-                    np.array_equal(x, y) for x, y in zip(got, ref))
-                ok &= agree
-                rec["runs"].append({"probe": probe, "build": build,
-                                    "search": "sort", "agrees": agree, **t})
-                print(rec["runs"][-1], flush=True)
-                del ref
+            args = joinrank_inputs(
+                probe, build, a.seed + build, min(widths, default=64))
+            live = [np.asarray(args[1]), np.asarray(args[3])]
+            # one int32 sum carries the count kernel's two counts
+            searches = ["count"] * (build < 1 << 15) + (
+                ["sort"] * (build in sorted_at))
+            got = {}
+            for search in searches:
+                got[search], t = timed(_join_ranges_by(search), args, a.reps)
+                run = {"probe": probe, "build": build, "search": search, **t}
+                if search == "count" and got[search] is not None:
+                    run["matches"] = int(jnp.sum(got[search][2]))
+                if search == "sort" and "count" in got:
+                    run["agrees"] = None not in got.values() and all(
+                        np.array_equal(x, y) for x, y in zip(*got.values()))
+                    ok &= run["agrees"]
+                rec["runs"].append(run)
+                print(run, flush=True)
+                for bits in widths:
+                    out, t = timed(
+                        _join_ranges_by(search, bits), args, a.reps)
+                    # the same three arrays on live rows (order: the
+                    # live prefix; lo: live probes; cnt: every probe)
+                    n_live = int(live[0].sum())
+                    agree = None not in (out, got[search]) and all((
+                        np.array_equal(out[0][:n_live],
+                                       got[search][0][:n_live]),
+                        np.array_equal(np.asarray(out[1])[live[1]],
+                                       np.asarray(got[search][1])[live[1]]),
+                        np.array_equal(out[2], got[search][2])))
+                    ok &= agree
+                    rec["runs"].append({
+                        "probe": probe, "build": build, "search": search,
+                        "key_bits": bits, "agrees_with_64": agree, **t})
+                    print(rec["runs"][-1], flush=True)
+                    del out
             del got
             whole = build == builds[0]
-            for name, (fn, fargs) in joinrank_pieces(*args).items():
+            for name, (fn, fargs) in joinrank_pieces(*args, widths).items():
                 # the sort path's pieces do not depend on the build's
                 # size: once a probe; the count kernel and the scan do
                 if not whole and not name.startswith(("count", "binary")):
+                    continue
+                if build >= 1 << 15 and name.startswith(("count", "binary")):
                     continue
                 _out, t = timed(fn, fargs, a.reps)
                 rec["pieces"].append(
@@ -663,6 +709,10 @@ def main() -> int:
     ap.add_argument("--sorted-builds", default="64,1024,16384",
                     help="build capacities at which --shape joinrank "
                          "times the sort path too")
+    ap.add_argument("--key-bits", default="64",
+                    help="static key widths at which --shape joinrank "
+                         "times join_ranges beside 64 (keys are drawn "
+                         "below 2**the smallest)")
     ap.add_argument("--capacity", type=int, default=Q18_CAPACITY,
                     help="group-table capacity of --shape q18")
     ap.add_argument("--rows", type=int, default=6_291_456)

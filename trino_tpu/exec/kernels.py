@@ -410,16 +410,23 @@ def _rank_key(x: jnp.ndarray) -> tuple[jnp.ndarray, int]:
 
 
 @kernel
-def _merge_rank(a: jnp.ndarray, v: jnp.ndarray, side: str) -> jnp.ndarray:
+def _merge_rank(
+    a: jnp.ndarray, v: jnp.ndarray, side: str, key_bits: int | None = None
+) -> jnp.ndarray:
     """searchsorted by one merged sort: rank every query among the
     haystack by sorting them TOGETHER (ties: queries first for 'left',
     haystack first for 'right'), counting haystack rows ahead of each
     query with a prefix sum, and scattering the counts back to query
-    order."""
+    order. ``key_bits``: the caller's word for unsigned keys all below
+    ``2**key_bits`` — the merged sort is then one pass wherever key and
+    row index fit a word together (``packed_argsort``); without it the
+    width is the dtype's."""
     m, q = a.shape[0], v.shape[0]
     dt = jnp.promote_types(a.dtype, v.dtype)
     ka, bits = _rank_key(a.astype(dt))
     kv, _ = _rank_key(v.astype(dt))
+    if key_bits is not None:
+        bits = key_bits
     if side == "left":
         perm = packed_argsort(jnp.concatenate([kv, ka]), bits)
         is_hay = perm >= q
@@ -437,7 +444,10 @@ def _merge_rank(a: jnp.ndarray, v: jnp.ndarray, side: str) -> jnp.ndarray:
 
 
 @kernel
-def searchsorted(a: jnp.ndarray, v: jnp.ndarray, side: str = "left") -> jnp.ndarray:
+def searchsorted(
+    a: jnp.ndarray, v: jnp.ndarray, side: str = "left",
+    key_bits: int | None = None,
+) -> jnp.ndarray:
     """searchsorted with the method chosen by measurement (one v5e
     chip, 6,291,456 uint64 queries into as many keys, PR 22): the
     binary-search 'scan' — ~log2(n) serialized gather rounds over every
@@ -450,10 +460,11 @@ def searchsorted(a: jnp.ndarray, v: jnp.ndarray, side: str = "left") -> jnp.ndar
     handful of rows under millions of queries, ``join_ranges``, decides
     before it calls (``join_search``: a build of at most
     ``JOIN_SMALL_BUILD`` rows is ranked by compare-and-count and never
-    reaches this function)."""
+    reaches this function), and hands on the width of its keys where
+    the plan proved one (``key_bits``, ``_merge_rank``)."""
     if v.size <= 16384:
         return jnp.searchsorted(a, v, side=side, method="scan")
-    return _merge_rank(a, v.ravel(), side).reshape(v.shape)
+    return _merge_rank(a, v.ravel(), side, key_bits).reshape(v.shape)
 
 
 def floor_div(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -1211,6 +1222,14 @@ def _halves(x: jnp.ndarray):
     return (x >> jnp.uint64(32)).astype(jnp.uint32), x.astype(jnp.uint32)
 
 
+def _words(x: jnp.ndarray) -> list:
+    """A join key as the 32-bit words it is read and compared in (the
+    lanes the chip has), high word first: a uint64's two halves, a
+    uint32 (``join_ranges`` at a proven width of at most 32 bits)
+    itself."""
+    return [x] if x.dtype == jnp.uint32 else list(_halves(x))
+
+
 def _packed_counts(less: jnp.ndarray, equal: jnp.ndarray):
     """``(lo, hi)`` from the ``[build rows, probe rows]`` compares of a
     sorted build with each probe key: a build key being less than or
@@ -1234,8 +1253,15 @@ def _count_ranges(sorted_key: jnp.ndarray, probe_key: jnp.ndarray):
     """``(lo, hi)`` of every probe key in a sorted build of a few rows
     by compare-and-count: ``lo[i] = #{j : sorted_key[j] < probe_key[i]}``
     — which is ``searchsorted(sorted_key, probe_key, "left")`` — and
-    ``hi[i] = lo[i] + #{j : sorted_key[j] == probe_key[i]}``, the keys
-    compared as 32-bit halves (the lanes the chip has)."""
+    ``hi[i] = lo[i] + #{j : sorted_key[j] == probe_key[i]}``, uint64
+    keys compared as 32-bit halves (the lanes the chip has), uint32
+    keys (``join_ranges`` at a proven width of at most 32 bits) as the
+    one word they are."""
+    if sorted_key.dtype == jnp.uint32:
+        return _packed_counts(
+            sorted_key[:, None] < probe_key[None, :],
+            sorted_key[:, None] == probe_key[None, :],
+        )
     sh, sl = _halves(sorted_key)
     ph, pl = _halves(probe_key)
     high_eq = sh[:, None] == ph[None, :]
@@ -1248,25 +1274,31 @@ def _count_ranges(sorted_key: jnp.ndarray, probe_key: jnp.ndarray):
 def _run_end_at(sorted_key, run_end, probe_key, lo):
     """``run_end[lo]`` where ``sorted_key[lo] == probe_key``, else
     ``lo``: the build key and its run's end read at one walk of 32-bit
-    words, the key compared as halves (the lanes the chip has)."""
+    words, a uint64 key compared as halves (the lanes the chip has), a
+    uint32 key as its one word."""
     n_build = sorted_key.shape[0]
     words = jnp.stack(
-        [*_halves(sorted_key), run_end.astype(jnp.uint32)], axis=1
+        [*_words(sorted_key), run_end.astype(jnp.uint32)], axis=1
     )
     with site("at_walk"):
         read = words[jnp.clip(lo, 0, n_build - 1)]
-    high, low = _halves(probe_key)
-    found = (lo < n_build) & (read[:, 0] == high) & (read[:, 1] == low)
-    return jnp.where(found, read[:, 2].astype(jnp.int32), lo)
+    probe_words = _words(probe_key)
+    found = lo < n_build
+    for i, word in enumerate(probe_words):
+        found = found & (read[:, i] == word)
+    return jnp.where(
+        found, read[:, len(probe_words)].astype(jnp.int32), lo
+    )
 
 
-@jax.jit
+@partial(jax.jit, static_argnames=("key_bits",))
 @kernel
 def join_ranges(
     build_key: jnp.ndarray,
     build_live: jnp.ndarray,
     probe_key: jnp.ndarray,
     probe_live: jnp.ndarray,
+    key_bits: int = 64,
 ):
     """Sorted-range probe: the LookupSource analog.
 
@@ -1275,9 +1307,26 @@ def join_ranges(
     re-verify matches after expansion). Rows with live=False never
     match; the caller has already excluded NULL keys.
 
+    ``key_bits`` (static) is the caller's proof that every LIVE key of
+    either side is below ``2**key_bits`` (the plan's exact key range,
+    ``Join.key_ranges``, the keys shifted to its origin). A dead or
+    NULL row may hold anything — a Filter's dead rows hold real values
+    outside a narrowed range — so both sides are cut to the width
+    first: a dead row then holds some value inside it, and it ranks
+    nothing (the build's sort puts it last by its flag, its sorted key
+    is the sentinel, a dead probe's count is zero). Key and row index
+    then share one word wherever they fit (``packed_argsort``): one
+    single-operand sort for the build and one for the merged rank,
+    where 64 bits take the two sorts, the gather between them and the
+    composed permutation of an LSD radix; at 32 bits or fewer the keys
+    are one uint32 word in every compare and read.
+
     Returns (order, lo, cnt): ``order`` sorts the build side by key
     (dead rows last), ``lo[i]``/``cnt[i]`` give each probe row's match
-    range inside the sorted build side.
+    range inside the sorted build side — on live rows the same three
+    arrays bit for bit at every ``key_bits`` the keys fit (a dead
+    row's place in ``order``'s tail and a dead probe's ``lo`` are
+    whatever its cut key gives).
 
     How the probe is ranked in the sorted build is chosen from the
     build's static capacity (``join_search``): at most
@@ -1286,23 +1335,31 @@ def join_ranges(
     it, by ``searchsorted``. Both give the same three arrays bit for
     bit.
     """
-    # sort build: dead rows pushed past every live key
     n_build = build_key.shape[0]
-    order = packed_argsort(build_key, 64, last=~build_live)
+    word = jnp.uint32 if key_bits <= 32 else jnp.uint64
+    # the largest value of the keys' width: the dead tail's sentinel
+    top = word((1 << key_bits) - 1)
+    if key_bits < 64:
+        build_key = build_key.astype(word) & top
+        probe_key = probe_key.astype(word) & top
+    # sort build: dead rows pushed past every live key
+    order = packed_argsort(build_key, key_bits, last=~build_live)
     n_build_live = jnp.sum(build_live)
-    # dead tail keys are arbitrary; pin them to MAX so the whole array
-    # is globally sorted (binary-search precondition), then clamp the
-    # ranges to the live prefix
+    # dead tail keys are arbitrary; pin them to the width's MAX so the
+    # whole array is globally sorted (binary-search precondition), then
+    # clamp the ranges to the live prefix. A live key may EQUAL the
+    # sentinel: nothing live sorts after it, so a probe for it finds
+    # ``lo`` at its first live row (or at the live prefix's end) and a
+    # ``hi`` inside the dead tail, which the clamp brings back
     pos = jnp.arange(n_build)
     with site("build_in_order"):
-        sorted_key = jnp.where(
-            pos < n_build_live, build_key[order],
-            jnp.uint64(0xFFFFFFFFFFFFFFFF),
-        )
+        sorted_key = jnp.where(pos < n_build_live, build_key[order], top)
     if join_search(n_build) == "count":
         lo, hi = _count_ranges(sorted_key, probe_key)
     else:
-        lo = searchsorted(sorted_key, probe_key, side="left")
+        lo = searchsorted(
+            sorted_key, probe_key, side="left", key_bits=key_bits
+        )
         # the right edge without a second search: each build position
         # knows where its run of equal keys ends (suffix-min over the
         # run-last positions), and a probe that found its key at ``lo``

@@ -189,14 +189,16 @@ class _dispatching:
         if self._outer.span is not None:
             self._outer.span.attrs.update(attrs)
 
-    def note_join(self, n_build: int):
+    def note_join(self, n_build: int, key_bits: int = 64):
         """The program holds a ``kernels.join_ranges`` over a build
-        side of ``n_build`` rows: note which search that was built with
-        (``join_search``: ``count`` / ``sort``; ``build_rows``) and
-        count it."""
+        side of ``n_build`` rows, its keys ranked at ``key_bits`` bits:
+        note which search that was built with (``join_search``:
+        ``count`` / ``sort``; ``build_rows``) and the width
+        (``key_bits``: 64, or what the plan's exact key range needs),
+        and count it."""
         search = K.join_search(n_build)
-        self.note(join_search=search, build_rows=n_build)
-        telemetry.JOINS.inc(search=search)
+        self.note(join_search=search, build_rows=n_build, key_bits=key_bits)
+        telemetry.JOINS.inc(search=search, key_bits=str(key_bits))
 
     def __exit__(self, *exc):
         if self._inner is not None:
@@ -1524,6 +1526,7 @@ class LocalExecutor:
                 criteria=[(r, l) for l, r in node.criteria],
                 filter=node.filter,
                 df_range_keep=None, df_keep_frac=None,
+                key_ranges=node.key_ranges,
             )
         budget = self.hbm_budget()
         if budget and node.kind in ("inner", "left") and node.criteria:
@@ -1984,7 +1987,35 @@ class LocalExecutor:
         return kinds
 
     @staticmethod
-    def _traced_join_keys(penv, benv, criteria, kinds=None):
+    def _join_key_width(key_ranges, criteria, probe, build):
+        """``(lo, key_bits)`` — static — for the combined key of a join:
+        where the join is on ONE fixed-width integer criterion whose
+        exact range ``(lo, hi)`` the plan proves (``Join.key_ranges``,
+        under either orientation of the pair), the origin both sides
+        are shifted to and ``bit_length(hi - lo)``, the width
+        ``kernels.join_ranges`` ranks them at; for anything else — a
+        multi-column (hashed, verified) key, a two-limb decimal, a
+        float, a dictionary- or hash-coded varchar, a criterion the
+        plan proved nothing of — ``(0, 64)``: the key as it is."""
+        if not key_ranges or len(criteria) != 1:
+            return 0, 64
+        (l, r), = criteria
+        rng = key_ranges.get((l, r)) or key_ranges.get((r, l))
+        if rng is None:
+            return 0, 64
+        for col in (probe.column(l), build.column(r)):
+            if (
+                col.dictionary is not None or col.hash_pool is not None
+                or jnp.ndim(col.data) != 1
+                or np.dtype(col.data.dtype).kind != "i"
+            ):
+                return 0, 64
+        lo, hi = rng
+        bits = max(1, int(hi - lo).bit_length())
+        return (lo, bits) if bits < 64 else (0, 64)
+
+    @staticmethod
+    def _traced_join_keys(penv, benv, criteria, kinds=None, lo=0):
         """Combined uint64 keys for probe/build sides from traced envs.
 
         Single fixed-width key -> exact; multi-column (including
@@ -1993,7 +2024,11 @@ class LocalExecutor:
         expansion). Hash-coded varchar keys ('hash' kind) contribute
         their hash lane only — the id lane is row identity, not value
         identity. The returned ``pairs`` are 1D (probe, build) part
-        arrays for the verification loop.
+        arrays for the verification loop. ``lo`` (``_join_key_width``)
+        is the origin a single integer key is shifted to, as
+        ``stage._shift_key`` shifts a group key: a bijection on the
+        proven range, whose live keys then lie in ``[0, hi - lo]``; a
+        dead or NULL row may wrap.
         """
         pv = bv = None
         p_parts: list = []
@@ -2010,8 +2045,8 @@ class LocalExecutor:
                 p_parts.extend(K.limb_parts(pd))
                 b_parts.extend(K.limb_parts(bd))
         if len(p_parts) == 1:
-            pk, _ = K.normalize_key(p_parts[0], None)
-            bk, _ = K.normalize_key(b_parts[0], None)
+            pk, _ = K.normalize_key(_shifted(p_parts[0], lo), None)
+            bk, _ = K.normalize_key(_shifted(b_parts[0], lo), None)
             verify = False
         else:
             pk = K.hash_columns([(d, None) for d in p_parts])
@@ -2020,30 +2055,38 @@ class LocalExecutor:
         pairs = list(zip(p_parts, b_parts))
         return pk, bk, pv, bv, pairs, verify
 
-    def _join_count(self, criteria, probe: Page, build: Page):
+    def _join_count(
+        self, criteria, probe: Page, build: Page, key_ranges=None
+    ):
         """Join phase A: sorted build order + per-probe match ranges +
         total match count — ONE jitted program, one host sync (the
         output-capacity decision, the reference's build-side barrier).
+        ``key_ranges``: the plan node's (``Join.key_ranges``), from
+        which the program takes its key's origin and width — both part
+        of the program's cache key: another range, another program.
         """
+        crit = list(criteria)
+        key_lo, key_bits = self._join_key_width(
+            key_ranges, crit, probe, build
+        )
         key = (
-            "joinA", tuple(criteria),
+            "joinA", tuple(criteria), key_lo, key_bits,
             self._layout_sig(probe), self._layout_sig(build),
         )
         fn = self._jit_cache.get(key)
         with _dispatching("join_count", fn is None) as dispatch:
-            dispatch.note_join(build.capacity)
+            dispatch.note_join(build.capacity, key_bits)
             if fn is None:
-                crit = list(criteria)
                 kinds = self._join_key_kinds(probe, build, crit)
 
                 def fa(penv, pmask, benv, bmask):
                     pk, bk, pv, bv, _, _ = self._traced_join_keys(
-                        penv, benv, crit, kinds
+                        penv, benv, crit, kinds, key_lo
                     )
                     probe_live = pmask if pv is None else (pmask & pv)
                     build_live = bmask if bv is None else (bmask & bv)
                     order, lo, cnt = K.join_ranges(
-                        bk, build_live, pk, probe_live
+                        bk, build_live, pk, probe_live, key_bits=key_bits
                     )
                     return order, lo, cnt, K.blocked_sum(cnt)
 
@@ -2168,7 +2211,9 @@ class LocalExecutor:
             return self._nested_loop_join(node, probe, build)
         self._unify_join_dicts(probe, build, node.criteria)
         probe = self._dynamic_filter(node, probe, build)
-        order, lo, cnt, total = self._join_count(node.criteria, probe, build)
+        order, lo, cnt, total = self._join_count(
+            node.criteria, probe, build, node.key_ranges
+        )
         out_cap = shapes.bucket(max(total, 1), site="join")
         # reserve the join's whole device working set (probe + build +
         # expansion output + index arrays) against the memory pool —
@@ -2781,7 +2826,7 @@ class LocalExecutor:
         needs_expand = len(node.keys) > 1 or node.filter is not None
         if needs_expand:
             order, lo, cnt, total = self._join_count(
-                node.keys, source, filt
+                node.keys, source, filt, node.key_ranges
             )
             out_cap = shapes.bucket(max(total, 1), site="semi-join")
             key = (
@@ -2799,20 +2844,23 @@ class LocalExecutor:
                     self._env(source), self._env(filt), order, lo, cnt
                 )
         else:
+            crit = list(node.keys)
+            key_lo, key_bits = self._join_key_width(
+                node.key_ranges, crit, source, filt
+            )
             key = (
-                "semiA", tuple(node.keys),
+                "semiA", tuple(node.keys), key_lo, key_bits,
                 self._layout_sig(source), self._layout_sig(filt),
             )
             fn = self._jit_cache.get(key)
             with _dispatching("semi_join", fn is None) as dispatch:
-                dispatch.note_join(filt.capacity)
+                dispatch.note_join(filt.capacity, key_bits)
                 if fn is None:
-                    crit = list(node.keys)
                     kinds = self._join_key_kinds(source, filt, crit)
 
                     def fa(penv, pmask, benv, bmask):
                         pk, bk, pv2, bv2, _, _ = self._traced_join_keys(
-                            penv, benv, crit, kinds
+                            penv, benv, crit, kinds, key_lo
                         )
                         probe_live = (
                             pmask if pv2 is None else (pmask & pv2)
@@ -2821,7 +2869,8 @@ class LocalExecutor:
                             bmask if bv2 is None else (bmask & bv2)
                         )
                         _, _, cnt = K.join_ranges(
-                            bk, build_live, pk, probe_live
+                            bk, build_live, pk, probe_live,
+                            key_bits=key_bits,
                         )
                         return cnt > 0
 
@@ -3088,6 +3137,14 @@ def _expr_symbols(e: RowExpression) -> set[str]:
         elif isinstance(x, Cast):
             stack.append(x.arg)
     return out
+
+
+def _shifted(data, lo: int):
+    """An integer join key moved to its range's origin (in int64: the
+    range of a narrower column may not fit its own dtype)."""
+    if lo == 0:
+        return data
+    return data.astype(jnp.int64) - jnp.int64(lo)
 
 
 def _and_mask(a, b):

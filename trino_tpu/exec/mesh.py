@@ -51,6 +51,7 @@ from trino_tpu.exec.local import (
     _dispatching,
     _named_jit,
     _rename_out,
+    _shifted,
     _unordered_key,
 )
 from trino_tpu.expr.compiler import compile_expr, ColumnLayout
@@ -170,12 +171,14 @@ def _columns_from_leaves(leaves, meta, like: list[Column]) -> list[Column]:
     return cols
 
 
-def _make_prelude(criteria, p_meta, b_meta, n_p, verify, kinds=None):
+def _make_prelude(criteria, p_meta, b_meta, n_p, verify, kinds=None, lo=0):
     """Shared shard-local join-key builder for equi and semi joins:
     splits the flat leaves back into probe/build envs and produces
     normalized key bits, combined keys, and 3VL-aware live masks.
     ``kinds[i] == 'hash'`` marks hash-coded varchar criteria (key =
-    hash lane only)."""
+    hash lane only). ``lo`` (``LocalExecutor._join_key_width``): the
+    origin the one integer key of a join whose range the plan proves
+    is shifted to, as ``_traced_join_keys`` shifts it."""
 
     def prelude(ls):
         p_env, p_mask = _env_from_leaves(list(ls[:n_p]), p_meta)
@@ -195,9 +198,9 @@ def _make_prelude(criteria, p_meta, b_meta, n_p, verify, kinds=None):
                 continue
             # two-limb decimal keys expand into hi/lo parts
             for part in K.limb_parts(pd):
-                p_bits.append(K.normalize_key(part, None)[0])
+                p_bits.append(K.normalize_key(_shifted(part, lo), None)[0])
             for part in K.limb_parts(bd):
-                b_bits.append(K.normalize_key(part, None)[0])
+                b_bits.append(K.normalize_key(_shifted(part, lo), None)[0])
         if verify or len(p_bits) > len(criteria):
             pk = K.hash_columns([(b, None) for b in p_bits])
             bk = K.hash_columns([(b, None) for b in b_bits])
@@ -261,19 +264,20 @@ class MeshExecutor(LocalExecutor):
 
     def _run(
         self, prog, miss: bool, *args, tag: str | None = None,
-        join_build: int | None = None, **note
+        join_build: int | None = None, key_bits: int = 64, **note
     ):
         """``prog(*args)`` under a ``dispatch`` span that carries the
         program's name (with a ``build_trace`` child on a jit-cache
         miss, as the local executor's), ``note``'s attributes and,
         where the program holds a ``kernels.join_ranges``, the search
-        it was built with for a build of ``join_build`` rows, through
+        it was built with for a build of ``join_build`` rows and the
+        width its keys are ranked at (``key_bits``), through
         ``_attempt`` where the site is a retry unit."""
         with _dispatching(prog.__name__, miss) as dispatch:
             if note:
                 dispatch.note(**note)
             if join_build is not None:
-                dispatch.note_join(join_build)
+                dispatch.note_join(join_build, key_bits)
             if tag is None:
                 return prog(*args)
             return self._attempt(tag, lambda: prog(*args))
@@ -1233,13 +1237,16 @@ class MeshExecutor(LocalExecutor):
         b_leaves, b_meta = _page_leaves(build)
         n_p = len(p_leaves)
         kinds = self._join_key_kinds(probe, build, criteria)
+        key_lo, key_bits = self._join_key_width(
+            node.key_ranges, criteria, probe, build
+        )
         prelude = _make_prelude(
-            criteria, p_meta, b_meta, n_p, len(criteria) > 1, kinds
+            criteria, p_meta, b_meta, n_p, len(criteria) > 1, kinds, key_lo
         )
         leaves = p_leaves + b_leaves
         key_b = (
-            "mesh-df", tuple(criteria), self._sharded_sig(probe),
-            self._join_sig(build, replicated),
+            "mesh-df", tuple(criteria), key_lo, key_bits,
+            self._sharded_sig(probe), self._join_sig(build, replicated),
         )
         prog_b = self._mesh_jit_cache.get(key_b)
         miss = prog_b is None
@@ -1252,7 +1259,9 @@ class MeshExecutor(LocalExecutor):
                     build_live = jax.lax.all_gather(
                         build_live, axis, tiled=True
                     )
-                _, _, cnt = K.join_ranges(bk, build_live, pk, probe_live)
+                _, _, cnt = K.join_ranges(
+                    bk, build_live, pk, probe_live, key_bits=key_bits
+                )
                 keep = probe_live & (cnt > 0)
                 n_in = jnp.sum(p_mask.astype(jnp.int32)).reshape(1)
                 n_keep = jnp.sum(keep.astype(jnp.int32)).reshape(1)
@@ -1272,6 +1281,7 @@ class MeshExecutor(LocalExecutor):
                 build.capacity if replicated
                 else build.shard_capacity * build.n_shards  # all-gathered
             ),
+            key_bits=key_bits,
         )
         with telemetry.child_span("host_sync", site="mesh_dynamic_filter"):
             n_in, n_keep = jax.device_get((n_in_dev, n_keep_dev))
@@ -1537,10 +1547,12 @@ class MeshExecutor(LocalExecutor):
         return ShardedPage(list(a.names), cols, out[-1], a.n_shards)
 
     def _match_count_capacity(
-        self, key, prelude, in_specs, leaves, b_cap: int
+        self, key, prelude, in_specs, leaves, b_cap: int, key_bits: int
     ) -> int:
         """Phase A of a distributed join: per-shard match totals, one
-        host sync, padded output capacity (the build-side barrier)."""
+        host sync, padded output capacity (the build-side barrier).
+        ``key_bits``: the width ``prelude``'s keys are ranked at (part
+        of ``key`` with their origin, as of every join program's)."""
         prog = self._mesh_jit_cache.get(key)
         miss = prog is None
         if miss:
@@ -1550,13 +1562,16 @@ class MeshExecutor(LocalExecutor):
                 (_, _, _, _, pk, bk, probe_live, build_live, _, _) = (
                     prelude(ls)
                 )
-                _, _, cnt = K.join_ranges(bk, build_live, pk, probe_live)
+                _, _, cnt = K.join_ranges(
+                    bk, build_live, pk, probe_live, key_bits=key_bits
+                )
                 return jnp.sum(cnt).reshape(1)
 
             prog = self._shard_jit(fa, "join_count", in_specs, PS(axis))
             self._mesh_jit_cache[key] = prog
         totals_dev = self._run(
-            prog, miss, *leaves, tag="join-count", join_build=b_cap
+            prog, miss, *leaves, tag="join-count", join_build=b_cap,
+            key_bits=key_bits,
         )
         with telemetry.child_span("host_sync", site="mesh_join_total"):
             totals = jax.device_get(totals_dev)
@@ -1595,8 +1610,11 @@ class MeshExecutor(LocalExecutor):
         )
         p_cols = {n: c for n, c in zip(probe.names, probe.columns)}
         b_cols = {n: c for n, c in zip(build.names, build.columns)}
+        key_lo, key_bits = self._join_key_width(
+            node.key_ranges, criteria, probe, build
+        )
         prelude = _make_prelude(
-            criteria, p_meta, b_meta, n_p, verify, kinds
+            criteria, p_meta, b_meta, n_p, verify, kinds, key_lo
         )
         in_specs = (PS(axis),) * n_p + (
             (PS(),) if replicated else (PS(axis),)
@@ -1604,11 +1622,11 @@ class MeshExecutor(LocalExecutor):
 
         # phase A: per-shard match counts -> one host sync for capacity
         key_a = (
-            "mesh-joinA", tuple(criteria),
+            "mesh-joinA", tuple(criteria), key_lo, key_bits,
             self._join_sig(probe, False), self._join_sig(build, replicated),
         )
         out_cap = self._match_count_capacity(
-            key_a, prelude, in_specs, p_leaves + b_leaves, b_cap
+            key_a, prelude, in_specs, p_leaves + b_leaves, b_cap, key_bits
         )
 
         # reserve the per-device join working set (probe shard + build
@@ -1656,7 +1674,7 @@ class MeshExecutor(LocalExecutor):
             out_meta.append((s, from_probe, has_valid))
 
         key_b = (
-            "mesh-joinB", tuple(criteria), kind, out_cap,
+            "mesh-joinB", tuple(criteria), key_lo, key_bits, kind, out_cap,
             tuple(out_meta), repr(node.filter),
             self._join_sig(probe, False), self._join_sig(build, replicated),
         )
@@ -1669,7 +1687,7 @@ class MeshExecutor(LocalExecutor):
                     prelude(ls)
                 )
                 order, lo, cnt = K.join_ranges(
-                    bk, build_live, pk, probe_live
+                    bk, build_live, pk, probe_live, key_bits=key_bits
                 )
                 probe_idx, build_idx, out_live = K.expand_matches(
                     order, lo, cnt, out_cap
@@ -1753,7 +1771,7 @@ class MeshExecutor(LocalExecutor):
             self._mesh_jit_cache[key_b] = prog_b
         outs, mask = self._run(
             prog_b, miss, *p_leaves, *b_leaves, tag="join-expand",
-            join_build=b_cap,
+            join_build=b_cap, key_bits=key_bits,
         )
         cols, i = [], 0
         for s, from_probe, has_valid in out_meta:
@@ -1903,19 +1921,26 @@ class MeshExecutor(LocalExecutor):
                 ColumnLayout(types=pair_types, dictionaries=pair_dicts),
             )
 
-        prelude = _make_prelude(criteria, p_meta, b_meta, n_p, verify, kinds)
+        key_lo, key_bits = self._join_key_width(
+            node.key_ranges, criteria, sp, filt
+        )
+        prelude = _make_prelude(
+            criteria, p_meta, b_meta, n_p, verify, kinds, key_lo
+        )
         out_cap = None
         if needs_expand:
             key_a = (
-                "mesh-semiA", tuple(criteria),
+                "mesh-semiA", tuple(criteria), key_lo, key_bits,
                 self._join_sig(sp, False), self._join_sig(filt, True),
             )
             out_cap = self._match_count_capacity(
-                key_a, prelude, in_specs, p_leaves + b_leaves, filt.capacity
+                key_a, prelude, in_specs, p_leaves + b_leaves, filt.capacity,
+                key_bits,
             )
 
         key_b = (
-            "mesh-semiB", tuple(criteria), out_cap, repr(node.filter),
+            "mesh-semiB", tuple(criteria), key_lo, key_bits, out_cap,
+            repr(node.filter),
             self._join_sig(sp, False), self._join_sig(filt, True),
         )
         prog_b = self._mesh_jit_cache.get(key_b)
@@ -1927,7 +1952,7 @@ class MeshExecutor(LocalExecutor):
                     prelude(ls)
                 )
                 order, lo, cnt = K.join_ranges(
-                    bk, build_live, pk, probe_live
+                    bk, build_live, pk, probe_live, key_bits=key_bits
                 )
                 if needs_expand:
                     probe_idx, build_idx, out_live = K.expand_matches(
@@ -1956,7 +1981,7 @@ class MeshExecutor(LocalExecutor):
             self._mesh_jit_cache[key_b] = prog_b
         matched = self._run(
             prog_b, miss, *p_leaves, *b_leaves, tag="semi-join",
-            join_build=filt.capacity,
+            join_build=filt.capacity, key_bits=key_bits,
         )
         from trino_tpu import types as T
 

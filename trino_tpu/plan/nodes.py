@@ -125,6 +125,13 @@ class Join(PlanNode):
     #: (df_keep_frac); None = unknown, executors skip the filter
     df_range_keep: float | None = None
     df_keep_frac: float | None = None
+    #: stats annotation (plan.stats.annotate): for an equi criterion
+    #: whose two symbols both carry EXACT integer bounds, one (lo, hi)
+    #: that holds every live, non-NULL key of either INPUT — the
+    #: executor shifts the key to lo and ranks it at bit_length(hi - lo)
+    #: bits (kernels.join_ranges' key_bits). A criterion without one
+    #: is ranked at 64
+    key_ranges: dict[tuple[str, str], tuple[int, int]] | None = None
 
     @property
     def sources(self):
@@ -149,6 +156,8 @@ class SemiJoin(PlanNode):
     #: for EXISTS, which is always TRUE/FALSE (reference distinguishes
     #: these via SemiJoinNode vs CorrelatedJoin rewrites)
     null_aware: bool = False
+    #: as ``Join.key_ranges``, by (source symbol, filter-source symbol)
+    key_ranges: dict[tuple[str, str], tuple[int, int]] | None = None
 
     @property
     def sources(self):

@@ -110,6 +110,21 @@ def _sort_keys_back(lst):
 
 # ---- plan nodes ------------------------------------------------------------
 
+def _pair_ranges(ranges):
+    """``Join.key_ranges`` / ``SemiJoin.key_ranges``: a criterion's two
+    symbols, then its exact (lo, hi) — Python ints, which JSON carries
+    whole."""
+    if ranges is None:
+        return None
+    return [[l, r, lo, hi] for (l, r), (lo, hi) in ranges.items()]
+
+
+def _pair_ranges_back(lst):
+    if lst is None:
+        return None
+    return {(l, r): (lo, hi) for l, r, lo, hi in lst}
+
+
 def plan_to_json(node: P.PlanNode) -> dict:
     d = {"kind": type(node).__name__, "outputs": _outputs(node)}
     if isinstance(node, P.TableScan):
@@ -160,6 +175,7 @@ def plan_to_json(node: P.PlanNode) -> dict:
             filter=_expr(node.filter), distribution=node.distribution,
             df_range_keep=node.df_range_keep,
             df_keep_frac=node.df_keep_frac,
+            key_ranges=_pair_ranges(node.key_ranges),
         )
         return d
     if isinstance(node, P.SemiJoin):
@@ -169,6 +185,7 @@ def plan_to_json(node: P.PlanNode) -> dict:
             keys=[list(k) for k in node.keys],
             match_symbol=node.match_symbol, filter=_expr(node.filter),
             null_aware=node.null_aware,
+            key_ranges=_pair_ranges(node.key_ranges),
         )
         return d
     if isinstance(node, P.Window):
@@ -304,6 +321,7 @@ def plan_from_json(d: dict) -> P.PlanNode:
             distribution=d["distribution"],
             df_range_keep=d["df_range_keep"],
             df_keep_frac=d["df_keep_frac"],
+            key_ranges=_pair_ranges_back(d["key_ranges"]),
         )
     if kind == "SemiJoin":
         return P.SemiJoin(
@@ -312,6 +330,7 @@ def plan_from_json(d: dict) -> P.PlanNode:
             keys=[tuple(k) for k in d["keys"]],
             match_symbol=d["match_symbol"],
             filter=_expr_back(d["filter"]), null_aware=d["null_aware"],
+            key_ranges=_pair_ranges_back(d["key_ranges"]),
         )
     if kind == "Window":
         return P.Window(

@@ -498,6 +498,11 @@ def _intersect_sym(a: SymbolStats, b: SymbolStats) -> SymbolStats:
         ndv = min(a.ndv, b.ndv)
     a_full = a.lo is not None and a.hi is not None
     b_full = b.lo is not None and b.hi is not None
+    if a.exact != b.exact:
+        # one side's bounds are a guarantee and the other's an
+        # estimate: the guarantee alone stays one (an estimate may cut
+        # into the live keys, and the result is claimed exact)
+        a_full, b_full = a_full and a.exact, b_full and b.exact
     lo = hi = None
     if a_full and b_full:
         lo, hi = max(a.lo, b.lo), min(a.hi, b.hi)
@@ -675,6 +680,14 @@ def annotate(
       bounds for group keys — the executor packs keys into
       bit_length(hi-lo) bits, turning multi-pass lexsorts into single
       u64 sort passes (value-range key packing, BASELINE.md).
+    - ``Join.key_ranges`` / ``SemiJoin.key_ranges``: {(left symbol,
+      right symbol): (lo, hi)} for each equi criterion whose two
+      symbols BOTH carry exact integer bounds — one range that holds
+      every live, non-NULL key of either input. It is read off the
+      inputs, before the join, so it holds for every join kind (an
+      outer join's unmatched rows are rows of an input). The executor
+      ranks such a join's keys at bit_length(hi - lo) bits instead of
+      64 (``kernels.join_ranges``).
 
     Mutates nodes in place (annotation fields only) and returns plan.
     """
@@ -711,26 +724,29 @@ def annotate(
                 range_keep if known or range_keep < 1.0 else None
             )
             node.df_keep_frac = member_keep if known else None
+        if isinstance(node, (P.Join, P.SemiJoin)):
+            sides = node.sources
+            pairs = node.criteria if isinstance(node, P.Join) else node.keys
+            stats = [estimate(s, metadata, cache) for s in sides]
+            ranges = {}
+            for pair in pairs:
+                both = [
+                    _exact_int_range(st.sym(k), side.outputs.get(k))
+                    for k, st, side in zip(pair, stats, sides)
+                ]
+                if None not in both:
+                    (lo_l, hi_l), (lo_r, hi_r) = both
+                    ranges[tuple(pair)] = (min(lo_l, lo_r), max(hi_l, hi_r))
+            node.key_ranges = ranges or None
         if isinstance(node, P.Aggregate) and node.group_keys:
             src = estimate(node.source, metadata, cache)
             groups = estimate(node, metadata, cache).rows
             node.est_groups = groups
             ranges = {}
             for k in node.group_keys:
-                st = src.sym(k)
-                if not st.exact or st.lo is None or st.hi is None:
-                    continue
-                t = node.outputs.get(k)
-                if t is None or not _int_domain(t):
-                    continue
-                # int bounds stay ints through the whole stats chain;
-                # a float here means something lossy touched them —
-                # never pack on a possibly-rounded bound
-                if not (isinstance(st.lo, int) and isinstance(st.hi, int)):
-                    continue
-                lo, hi = st.lo, st.hi
-                if hi >= lo:
-                    ranges[k] = (lo, hi)
+                rng = _exact_int_range(src.sym(k), node.outputs.get(k))
+                if rng is not None:
+                    ranges[k] = rng
             node.key_ranges = ranges or None
 
     walk(plan)
@@ -750,13 +766,32 @@ def annotate(
     return plan
 
 
+def _exact_int_range(st: SymbolStats, t: T.DataType | None):
+    """``(lo, hi)`` where a key symbol's bounds are a guarantee over an
+    integer domain — what a key may be packed or ranked by — else
+    None."""
+    if not st.exact or st.lo is None or st.hi is None:
+        return None
+    if t is None or not _int_domain(t):
+        return None
+    # int bounds stay ints through the whole stats chain; a float here
+    # means something lossy touched them — never pack on a
+    # possibly-rounded bound
+    if not (isinstance(st.lo, int) and isinstance(st.hi, int)):
+        return None
+    return (st.lo, st.hi) if st.hi >= st.lo else None
+
+
 def _int_domain(t: T.DataType) -> bool:
     """Types whose storage is an integer domain where (value - lo) is
     meaningful and bounded: ints, dates, timestamps, decimals. Varchar
     uses dictionary codes (handled separately); floats excluded (bit
-    patterns are not contiguous)."""
+    patterns are not contiguous), and two-limb decimals (their storage
+    is a pair of words, not one)."""
     import numpy as np
 
     if isinstance(t, T.VarcharType) or isinstance(t, T.BooleanType):
+        return False
+    if isinstance(t, T.DecimalType) and t.is_long:
         return False
     return np.dtype(t.np_dtype).kind == "i"

@@ -789,7 +789,7 @@ class Coordinator:
         "upload_ms", "to_rows_ms", "respond_ms", "rows_out_ms",
         "direct_groupbys", "sorted_groupbys", "streamed_groupbys",
         "groupby_start_walks",
-        "small_build_joins", "sorted_joins",
+        "small_build_joins", "sorted_joins", "narrow_key_joins",
         "compactions", "compact_gather_ops",
         "mesh_exchanges", "mesh_exchange_ms", "mesh_exchange_live_bytes",
         "mesh_exchange_buffer_bytes", "mesh_gather_ms", "mesh_upload_ms",

@@ -365,7 +365,10 @@ def span_totals(root: Span) -> Dict[str, float]:
     they were built with (the ``dispatch`` span's ``gather_ops``);
     ``small_build_joins`` / ``sorted_joins`` count the programs that
     hold a ``kernels.join_ranges`` by the search it was built with
-    (the ``dispatch`` span's ``join_search``: ``count`` / ``sort``);
+    (the ``dispatch`` span's ``join_search``: ``count`` / ``sort``)
+    and ``narrow_key_joins`` those among them whose keys were ranked
+    below 64 bits, at the width the plan's exact key range needs (the
+    span's ``key_bits``);
     ``mesh_exchanges`` counts the mesh executor's ``mesh-exchange``
     spans and ``mesh_exchanges_in_place`` those among them that were
     satisfied where the rows lay (the span's ``in_place``),
@@ -394,6 +397,8 @@ def span_totals(root: Span) -> Dict[str, float]:
         if search is not None:
             field = _JOIN_SEARCH_FIELDS[search]
             out[field] = out.get(field, 0) + 1
+            if sp.attrs.get("key_bits", 64) < 64:
+                out["narrow_key_joins"] = out.get("narrow_key_joins", 0) + 1
         if sp.attrs.get("program") == "compact":
             out["compactions"] = out.get("compactions", 0) + 1
             out["compact_gather_ops"] = out.get(
@@ -715,7 +720,8 @@ STREAMED_GROUPBY_FALLBACKS = REGISTRY.counter(
 JOINS = REGISTRY.counter(
     "trino_joins_total",
     "Dispatched programs holding a kernels.join_ranges, by the search it was "
-    "built with: count (a small build) or sort")
+    "built with: count (a small build) or sort, and by the width its keys "
+    "were ranked at (key_bits: 64, or what the plan's exact key range needs)")
 LISTENER_FAILURES = REGISTRY.counter(
     "trino_event_listener_failures_total", "EventListener callbacks that raised")
 WORKER_TASKS = REGISTRY.counter(
