@@ -51,8 +51,20 @@ ROW_FIELDS = (
     "upload_ms", "spool_read_ms", "spool_write_ms", "rpc_ms",
     "task_poll_wait_ms", "task_queue_wait_ms", "host_sync_ms", "stage_ms",
     "resident_split_scans", "split_scan_ms", "dispatches",
-    "narrow_key_joins",
+    "narrow_key_joins", "wide_key_joins", "outer_joins", "anti_joins",
+    "distinct_aggregates", "revoked_joins",
 )
+#: the fields of ISSUE 48 a statement's tasks must count at least once
+#: (a join that runs in several tasks counts in each), through the
+#: fleet as through the embedded runner; the templates of the power
+#: mix count none of them
+KIND_FIELDS = ("wide_key_joins", "outer_joins", "anti_joins",
+               "distinct_aggregates", "revoked_joins")
+FULL_SCHEMA = {
+    "q09": {"wide_key_joins": 1},
+    "q13": {"outer_joins": 1},
+    "q16": {"anti_joins": 1, "distinct_aggregates": 1},
+}
 #: least joins a statement's tasks rank below 64 bits (ISSUE 46: the
 #: plan's joins; a join that runs in several tasks counts in each)
 NARROW_JOINS = {"q06": 0, "q01": 0, "q03": 2, "q18": 3}
@@ -178,6 +190,12 @@ def test_the_workers_joins_rank_at_the_width_the_coordinator_planned(
         assert joins == row["narrow_key_joins"], row
 
 
+@pytest.mark.parametrize("st", STATEMENTS, ids=IDS)
+def test_the_power_mix_counts_no_outer_anti_wide_or_distinct(st, served):
+    for _, row in served[st.key]:
+        assert [row[f] for f in KIND_FIELDS] == [0] * len(KIND_FIELDS), row
+
+
 def test_the_first_scan_of_a_table_uploads_it_once(served, fleet):
     # Q6 of 1994 ran first: lineitem's four columns went to the device
     # under its first split scan, with their bytes; its second split
@@ -279,3 +297,29 @@ def test_the_worker_child_shows_residency_and_no_result_cache_hit(
     coord = supervisor.prometheus(supervisor.http_text(
         children.entry_uri + "/v1/metrics"))
     assert coord.get("trino_scan_cache_resident_bytes", 0.0) == 0
+
+
+# (last: these statements load four more tables into the worker's scan
+# cache, which the tests above count)
+@pytest.mark.parametrize("q", sorted(FULL_SCHEMA))
+def test_the_workers_tasks_count_joins_by_kind_and_distinct_aggregates(
+        q, fleet):
+    """An outer join, an anti join, a join ranked at 64 bits and a
+    DISTINCT aggregate run in a worker's task: the ``dispatch`` spans
+    that carry them are stitched under the coordinator's tree, so the
+    fleet coordinator's row counts them as the embedded runner's does
+    (ISSUE 48), and the answer is the embedded runner's."""
+    from trino_tpu.connectors.tpch.queries import QUERIES
+
+    client = loadgen.timed_client(client_mod, fleet.entry_uri, 600.0)
+    _, rows = client.execute(QUERIES[q])
+    row = {r["query_id"]: r
+           for r in get_json(fleet.entry_uri, "/v1/query")}[client.last["id"]]
+    want = FULL_SCHEMA[q]
+    for f in KIND_FIELDS:
+        if f in want:
+            assert row[f] >= want[f], (f, row)
+        else:
+            assert row[f] == 0, (f, row)
+    embedded = QueryRunner.tpch("tiny").execute(QUERIES[q]).rows
+    assert len(rows) == len(embedded) > 0

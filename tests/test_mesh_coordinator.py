@@ -142,6 +142,44 @@ def test_mesh_joins_rank_at_the_width_the_plan_proves(st, served):
     assert (joins > 0) == (st.template in ("q03", "q18"))
 
 
+#: the fields of ISSUE 48: what each full-schema statement must count
+#: on the mesh (a mesh join counts once, on its ``join_count`` program)
+KIND_FIELDS = ("wide_key_joins", "outer_joins", "anti_joins",
+               "distinct_aggregates", "revoked_joins")
+FULL_SCHEMA = {
+    "q09": {"outer_joins": 0, "anti_joins": 0, "distinct_aggregates": 0},
+    "q13": {"outer_joins": 1, "anti_joins": 0, "distinct_aggregates": 0},
+    "q16": {"outer_joins": 0, "anti_joins": 1, "distinct_aggregates": 1},
+}
+
+
+@pytest.mark.parametrize("st", STATEMENTS, ids=IDS)
+def test_the_power_mix_counts_no_outer_anti_wide_or_distinct(st, served):
+    _, row = served[st.key]
+    assert [row[f] for f in KIND_FIELDS] == [0] * len(KIND_FIELDS), row
+
+
+@pytest.mark.parametrize("q", sorted(FULL_SCHEMA))
+def test_mesh_rows_count_joins_by_kind_and_distinct_aggregates(
+        q, mesh_coord, local_coord):
+    """The mesh executor notes what the local one notes (ISSUE 48): an
+    outer join's kind on its ``join_count`` program, an anti join on
+    the semi join under a Filter that negates its match, a chain's
+    DISTINCT aggregate calls, a join ranked at 64 bits — Q9's
+    two-column key — and the rows are the one-device runner's."""
+    from trino_tpu.connectors.tpch.queries import QUERIES
+
+    rows, row = serve(mesh_coord.uri, QUERIES[q])
+    for f, n in FULL_SCHEMA[q].items():
+        assert row[f] == n, (f, row)
+    assert row["revoked_joins"] == 0
+    assert (row["wide_key_joins"] >= 1) == (q == "q09"), row
+    local_rows, local = serve(local_coord.uri, QUERIES[q])
+    assert sorted(map(repr, rows)) == sorted(map(repr, local_rows))
+    for f, n in FULL_SCHEMA[q].items():
+        assert local[f] == n, (f, local)
+
+
 @pytest.mark.parametrize(
     "st", [s for s in STATEMENTS if s.template in ("q03", "q18")],
     ids=["q03", "q18"])

@@ -36,7 +36,12 @@ from trino_tpu.testing.golden import (
     to_sqlite,
 )
 
-BASE_PORT = 18960
+#: a range of its own (19100+): until PR 48 this file spawned its two
+#: workers on test_chaos.py's ports (18960+), and where xdist ran the two
+#: files at once the later one's workers failed to bind while its
+#: readiness probe found the other file's — two suites on one pair of
+#: workers, and test_chaos.py's fetch-fault test failing now and then
+BASE_PORT = 19100
 
 
 def _page(n=64):

@@ -37,6 +37,8 @@ FLAT_FIELDS = (
     "direct_groupbys", "sorted_groupbys", "streamed_groupbys",
     "groupby_start_walks", "compactions", "compact_gather_ops",
     "small_build_joins", "sorted_joins", "narrow_key_joins",
+    "wide_key_joins", "outer_joins", "anti_joins", "distinct_aggregates",
+    "revoked_joins", "join_revoked_ms",
 )
 PROGRAM = re.compile(
     r"^(chain_[A-Za-z_]+|join_count|join_bounds|join_expand|semi_join"
@@ -391,6 +393,95 @@ def test_query_rows_count_joins_ranked_at_their_keys_width(
         assert narrow == sum(b < 64 for b in widths)
         for b in set(widths):
             assert joins_counted(key_bits=b) - before[b] == widths.count(b)
+
+
+#: the row fields of ISSUE 48, each with the series that counts it
+KIND_FIELDS = {
+    "wide_key_joins": telemetry.WIDE_KEY_JOINS,
+    "outer_joins": telemetry.OUTER_JOINS,
+    "anti_joins": telemetry.ANTI_JOINS,
+    "distinct_aggregates": telemetry.DISTINCT_AGGREGATES,
+    "revoked_joins": telemetry.JOIN_REVOCATIONS,
+}
+
+
+@pytest.mark.parametrize("q,joins,want", [
+    # five joins, the fifth on (ps_partkey, ps_suppkey): one hashed key
+    ("q09", 5, {"wide_key_joins": 1}),
+    # the decorrelated average joins back as a LEFT join
+    ("q17", 2, {"outer_joins": 1}),
+    ("q13", 1, {"outer_joins": 1}),
+    # NOT IN: a semi join under a Filter that negates its match
+    ("q16", 2, {"anti_joins": 1, "distinct_aggregates": 1}),
+    # IN: a semi join, not an anti join
+    ("q18", 3, {}),
+    ("q03", 2, {}),
+])
+def test_query_rows_count_joins_by_kind_and_distinct_aggregates(
+        coord, q, joins, want):
+    """What kind of join a statement ran, whether its keys were ranked
+    at 64 bits and whether it evaluated a DISTINCT aggregate are on its
+    row (``wide_key_joins``, ``outer_joins``, ``anti_joins``,
+    ``distinct_aggregates``; 0 where it ran none), on the ``dispatch``
+    span of the program (``join_kind``, ``key_bits``,
+    ``distinct_aggregates``) and in a series each; a warm dispatch
+    reports the same."""
+    for _ in range(2):
+        before = {f: c.total() for f, c in KIND_FIELDS.items()}
+        qid, _ = serve(coord, QUERIES[q])
+        row = row_of(coord, qid)
+        assert row["small_build_joins"] + row["sorted_joins"] == joins, row
+        assert row["narrow_key_joins"] + row["wide_key_joins"] == joins, row
+        for f, counter in KIND_FIELDS.items():
+            assert row[f] == want.get(f, 0), (f, row)
+            assert counter.total() - before[f] == want.get(f, 0), f
+        kinds = sorted(
+            sp["attrs"]["join_kind"]
+            for sp, _ in walk(get(coord, f"/v1/query/{qid}")["spans"])
+            if sp["name"] == "dispatch" and "join_search" in sp["attrs"]
+        )
+        assert len(kinds) == joins
+        assert sum(k in ("left", "right", "full") for k in kinds) == want.get(
+            "outer_joins", 0)
+        assert kinds.count("anti") == want.get("anti_joins", 0)
+
+
+def test_a_revoked_join_shows_as_a_span_a_row_field_and_a_series(monkeypatch):
+    """A join whose estimated working set passes
+    ``query_max_memory_per_node`` runs through the spill tier
+    (``LocalExecutor._maybe_revoke_join``): the statement's tree holds a
+    ``join-revoked`` span with the estimate, the cap and ``forced``, its
+    row counts it as ``revoked_joins`` with the span's time beside it,
+    ``trino_join_revocations_total`` moves, and the answer is the
+    resident run's. (The cap and the statement are
+    tests/test_memory_governance.py's revocation case.)"""
+    from trino_tpu.exec import spill
+
+    monkeypatch.setattr(spill, "MIN_CHUNK_ROWS", 8192)
+    sql = ("select count(*) from lineitem l1, lineitem l2 "
+           "where l1.l_orderkey = l2.l_orderkey "
+           "and l1.l_linenumber = l2.l_linenumber")
+    cap = 2 << 20
+    runner = QueryRunner.tpch("tiny")
+    runner.session.properties["query_max_memory_per_node"] = str(cap)
+    c = Coordinator(runner=runner, port=0).start()
+    try:
+        before = telemetry.JOIN_REVOCATIONS.total()
+        qid, last = serve(c, sql)
+        row = row_of(c, qid)
+        spans = [sp for sp, _ in walk(get(c, f"/v1/query/{qid}")["spans"])
+                 if sp["name"] == "join-revoked"]
+    finally:
+        c.stop()
+    assert last["data"] == [list(r) for r in
+                            QueryRunner.tpch("tiny").execute(sql).rows]
+    assert row["revoked_joins"] == len(spans) >= 1, row
+    assert row["join_revoked_ms"] > 0
+    assert telemetry.JOIN_REVOCATIONS.total() - before == len(spans)
+    for sp in spans:
+        assert sp["attrs"]["cap_bytes"] == cap
+        assert sp["attrs"]["estimated_bytes"] > 0
+        assert sp["attrs"]["forced"] in (True, False)
 
 
 def test_protocol_stats_carry_queued_and_planning_time(coord):

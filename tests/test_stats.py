@@ -554,3 +554,111 @@ def test_insert_that_widens_a_join_key_range_builds_another_program():
     r.execute(f"insert into u values {_values(u_rows[-2:])}")
     assert check() == (-3, 8 + 2**40)
     assert join_programs() == {(0, 3), (-3, 41)}
+
+
+# ---- a scan's columns are described on first lookup (ISSUE 48) -------------
+
+from trino_tpu.connectors.tpch.connector import TpchConnector  # noqa: E402
+from trino_tpu.connectors.tpch.queries import QUERIES  # noqa: E402
+from trino_tpu.plan import serde, stats as stats_mod  # noqa: E402
+
+
+def _plan_text(runner, sql):
+    """The optimized, annotated plan as the fleet would ship it (every
+    annotation ``plan/serde.py`` carries) beside its EXPLAIN tree."""
+    import json
+
+    plan = runner.plan_sql(sql)
+    return (json.dumps(serde.plan_to_json(plan), sort_keys=True, default=str),
+            P.plan_tree_str(plan))
+
+
+@pytest.mark.parametrize("q", sorted(QUERIES))
+def test_lazy_scan_statistics_plan_what_eager_ones_plan(q, monkeypatch):
+    """An estimate describes a scan's column when a symbol over it is
+    first looked up. Described all at once where the scan is first met —
+    what the planner did until ISSUE 48 — every one of the 22 TPC-H
+    statements plans byte for byte the same tree with the same
+    annotations."""
+    lazy = _plan_text(QueryRunner.tpch("tiny"), QUERIES[q])
+    scan_stats = stats_mod._scan_stats
+
+    def eager(node, md):
+        out = scan_stats(node, md)
+        for sym in list(out.symbols._pending):
+            out.symbols[sym]  # a lookup describes the column
+        assert not out.symbols._pending
+        return out
+
+    monkeypatch.setattr(stats_mod, "_scan_stats", eager)
+    assert _plan_text(QueryRunner.tpch("tiny"), QUERIES[q]) == lazy
+
+
+class _RecordingTpch(TpchConnector):
+    """The tpch connector, remembering whose statistics it was asked."""
+
+    def __init__(self):
+        super().__init__()
+        self.asked: list[tuple[str, str]] = []
+
+    def column_stats(self, schema, table, column):
+        self.asked.append((table, column))
+        return super().column_stats(schema, table, column)
+
+
+@pytest.mark.parametrize("q", ["q03", "q09", "q13", "q16", "q17", "q18"])
+def test_planning_asks_statistics_of_no_column_the_plan_does_not_read(q):
+    """Planning a statement asks the connector for the statistics of
+    columns its optimized plan scans and of no other: before pruning a
+    scan assigns every column of its table, and a generator connector
+    computes a column's statistics from the whole column (the comment
+    columns are the dearest). Q13 reads ``o_comment`` and Q16
+    ``s_comment``; no other ``*_comment`` column may be asked about."""
+    conn = _RecordingTpch()
+    md = Metadata()
+    md.register_catalog("tpch", conn)
+    r = QueryRunner(md, Session(catalog="tpch", schema="tiny"))
+    plan = r.plan_sql(QUERIES[q])
+    scanned = {
+        (scan.table, col)
+        for scan in _find(plan, P.TableScan)
+        for col in scan.assignments.values()
+    }
+    asked = set(conn.asked)
+    assert asked, "the planner asked for no statistics at all"
+    assert asked <= scanned, sorted(asked - scanned)
+    allowed = {"q13": {"o_comment"}, "q16": {"s_comment"}}.get(q, set())
+    comments = {c for _, c in asked if c.endswith("_comment")}
+    assert comments <= allowed, comments
+
+
+def test_a_pending_column_keeps_the_caps_of_the_filters_it_passed():
+    """A column nobody looked up rides copies, merges and aliases
+    undescribed; described at last, its ndv is capped at the smallest
+    row estimate of the filters it passed — what an eager description
+    at the scan would hold by then."""
+    from trino_tpu.connectors.base import ColumnStats
+
+    asked = []
+
+    class Conn:
+        def column_stats(self, schema, table, column):
+            asked.append(column)
+            return ColumnStats(ndv=1000.0, lo=0, hi=999, null_fraction=0.0)
+
+    col = stats_mod._ScanColumn(Conn(), "s", "t", "c")
+    syms = stats_mod._Symbols(pending={"a": (col, None), "b": (col, None)})
+    syms.cap_ndv(500.0)
+    other = syms.copy()
+    other.cap_ndv(40.0)
+    merged = stats_mod._Symbols({"z": stats_mod.SymbolStats(ndv=3.0)}).merged(
+        other)
+    aliased = stats_mod._Symbols()
+    aliased.alias("a2", syms, "a")
+    assert asked == [] and set(merged) == {"z"}
+    assert merged["a"].ndv == 40.0 and merged["z"].ndv == 3.0
+    assert syms["a"].ndv == 500.0 and aliased["a2"].ndv == 500.0
+    assert syms.get("missing") is None
+    assert asked == ["c"]  # one column, described once
+    syms.cap_ndv(7.0)
+    assert syms["a"].ndv == 7.0 and syms["b"].ndv == 7.0

@@ -189,16 +189,36 @@ class _dispatching:
         if self._outer.span is not None:
             self._outer.span.attrs.update(attrs)
 
-    def note_join(self, n_build: int, key_bits: int = 64):
+    def note_join(self, n_build: int, key_bits: int = 64,
+                  kind: str = "inner"):
         """The program holds a ``kernels.join_ranges`` over a build
         side of ``n_build`` rows, its keys ranked at ``key_bits`` bits:
         note which search that was built with (``join_search``:
-        ``count`` / ``sort``; ``build_rows``) and the width
-        (``key_bits``: 64, or what the plan's exact key range needs),
-        and count it."""
+        ``count`` / ``sort``; ``build_rows``), the width
+        (``key_bits``: 64, or what the plan's exact key range needs)
+        and what it joins as (``join_kind``: the ``Join``'s kind,
+        ``semi``, or ``anti`` — a semi join whose match the plan
+        negates), and count it."""
         search = K.join_search(n_build)
-        self.note(join_search=search, build_rows=n_build, key_bits=key_bits)
+        self.note(join_search=search, build_rows=n_build, key_bits=key_bits,
+                  join_kind=kind)
         telemetry.JOINS.inc(search=search, key_bits=str(key_bits))
+        if key_bits >= 64:
+            telemetry.WIDE_KEY_JOINS.inc()
+        field = telemetry.JOIN_KIND_FIELDS.get(kind)
+        if field is not None:
+            telemetry.JOIN_KIND_COUNTERS[field].inc()
+
+    def note_distinct(self, chain):
+        """Count the DISTINCT aggregate calls of a chain program."""
+        n = sum(
+            call.distinct
+            for nd in chain if isinstance(nd, P.Aggregate)
+            for call in nd.aggregates.values()
+        )
+        if n:
+            self.note(distinct_aggregates=n)
+            telemetry.DISTINCT_AGGREGATES.inc(n)
 
     def __exit__(self, *exc):
         if self._inner is not None:
@@ -254,6 +274,9 @@ class LocalExecutor:
         #: joins revoked into the spill tier by memory pressure
         #: (count of MemoryRevokingScheme-analog conversions)
         self.memory_revocations = 0
+        #: match symbol of the SemiJoin about to run under a Filter
+        #: that negates it (``_note_negated_match``)
+        self._negated_match: str | None = None
         #: revocation budget in force while a revoked subtree runs
         #: (makes hbm_budget() nonzero so spill paths chunk under it)
         self._revoked_budget = 0
@@ -444,12 +467,28 @@ class LocalExecutor:
                 # not streaming (disabled or ineligible): the resident
                 # materialization must still fit the per-node cap
                 stream_scan.enforce_resident_fits(self, cur)
+            self._note_negated_match(chain[-1], cur)
             base = self.execute(cur)
             return self._run_chain(list(reversed(chain)), base)
         m = getattr(self, f"_{type(node).__name__}", None)
         if m is None:
             raise NotImplementedError(f"no executor for {type(node).__name__}")
         return m(node)
+
+    def _note_negated_match(self, above: P.PlanNode, node: P.PlanNode):
+        """``node`` is about to run under ``above``: where it is a
+        SemiJoin whose match ``above`` filters on negated, remember the
+        symbol for ``_take_negated_match``."""
+        anti = isinstance(node, P.SemiJoin) and node.negated_by(above)
+        self._negated_match = node.match_symbol if anti else None
+
+    def _take_negated_match(self, node: P.SemiJoin) -> str:
+        """``anti`` where the Filter over ``node`` negates its match,
+        else ``semi``. Asked before the sources run: a SemiJoin below
+        would take the note for its own."""
+        anti = self._negated_match == node.match_symbol
+        self._negated_match = None
+        return "anti" if anti else "semi"
 
     # ---- fused pipelines -------------------------------------------------
 
@@ -889,6 +928,7 @@ class LocalExecutor:
                 )
             else:
                 env, mask, flags, n_live_dev = fn(env_in, page.mask)
+            dispatch.note_distinct(chain)
             if out_layout.groupbys:
                 # each grouped Aggregate's path, in chain order
                 # (telemetry.span_totals counts them onto the row)
@@ -1708,21 +1748,27 @@ class LocalExecutor:
             return None
         from trino_tpu.exec import spill
 
-        est = (
-            spill.est_output_bytes(self, node.left)
-            + spill.est_output_bytes(self, node.right)
-            + spill.est_output_bytes(self, node)
-        )
+        l_bytes = spill.est_output_bytes(self, node.left)
+        r_bytes = spill.est_output_bytes(self, node.right)
+        est = l_bytes + r_bytes + spill.est_output_bytes(self, node)
         if not force and est <= max(
             cap - self.memory_pool.reserved_bytes, 0
         ):
             return None
+        if max(l_bytes, r_bytes) <= cap // 4:
+            # both sides fit a slab of the cap: the spill tier has no
+            # plan for this join (``_plan_budget_join``), it runs resident
+            return None
         prev = self._revoked_budget
         self._revoked_budget = cap
         try:
-            plan = self._plan_budget_join(node, cap)
-            if plan is not None:
-                self.memory_revocations += 1
+            with telemetry.child_span(
+                "join-revoked", estimated_bytes=int(est), cap_bytes=int(cap),
+                forced=force,
+            ):
+                plan = self._plan_budget_join(node, cap)
+            self.memory_revocations += 1
+            telemetry.JOIN_REVOCATIONS.inc()
             return plan
         finally:
             self._revoked_budget = prev
@@ -2056,7 +2102,8 @@ class LocalExecutor:
         return pk, bk, pv, bv, pairs, verify
 
     def _join_count(
-        self, criteria, probe: Page, build: Page, key_ranges=None
+        self, criteria, probe: Page, build: Page, key_ranges=None,
+        kind: str = "inner",
     ):
         """Join phase A: sorted build order + per-probe match ranges +
         total match count — ONE jitted program, one host sync (the
@@ -2075,7 +2122,7 @@ class LocalExecutor:
         )
         fn = self._jit_cache.get(key)
         with _dispatching("join_count", fn is None) as dispatch:
-            dispatch.note_join(build.capacity, key_bits)
+            dispatch.note_join(build.capacity, key_bits, kind)
             if fn is None:
                 kinds = self._join_key_kinds(probe, build, crit)
 
@@ -2212,7 +2259,7 @@ class LocalExecutor:
         self._unify_join_dicts(probe, build, node.criteria)
         probe = self._dynamic_filter(node, probe, build)
         order, lo, cnt, total = self._join_count(
-            node.criteria, probe, build, node.key_ranges
+            node.criteria, probe, build, node.key_ranges, node.kind
         )
         out_cap = shapes.bucket(max(total, 1), site="join")
         # reserve the join's whole device working set (probe + build +
@@ -2796,6 +2843,7 @@ class LocalExecutor:
     # ---- semi join -------------------------------------------------------
 
     def _SemiJoin(self, node: P.SemiJoin) -> Page:
+        kind = self._take_negated_match(node)
         budget = self.hbm_budget()
         if not budget:
             self._prefetch_join_chains(node)
@@ -2815,9 +2863,11 @@ class LocalExecutor:
                 )
         source = self.execute(node.source)
         filt = self._compact(self.execute(node.filter_source))
-        return self._semi_join_pages(node, source, filt)
+        return self._semi_join_pages(node, source, filt, kind)
 
-    def _semi_join_pages(self, node: P.SemiJoin, source: Page, filt: Page) -> Page:
+    def _semi_join_pages(
+        self, node: P.SemiJoin, source: Page, filt: Page, kind: str = "semi"
+    ) -> Page:
         self._unify_join_dicts(source, filt, node.keys)
         pv = bv = None
         for lsym, rsym in node.keys:
@@ -2826,7 +2876,7 @@ class LocalExecutor:
         needs_expand = len(node.keys) > 1 or node.filter is not None
         if needs_expand:
             order, lo, cnt, total = self._join_count(
-                node.keys, source, filt, node.key_ranges
+                node.keys, source, filt, node.key_ranges, kind
             )
             out_cap = shapes.bucket(max(total, 1), site="semi-join")
             key = (
@@ -2854,7 +2904,7 @@ class LocalExecutor:
             )
             fn = self._jit_cache.get(key)
             with _dispatching("semi_join", fn is None) as dispatch:
-                dispatch.note_join(filt.capacity, key_bits)
+                dispatch.note_join(filt.capacity, key_bits, kind)
                 if fn is None:
                     kinds = self._join_key_kinds(source, filt, crit)
 

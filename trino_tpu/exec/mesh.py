@@ -264,20 +264,25 @@ class MeshExecutor(LocalExecutor):
 
     def _run(
         self, prog, miss: bool, *args, tag: str | None = None,
-        join_build: int | None = None, key_bits: int = 64, **note
+        join_build: int | None = None, key_bits: int = 64,
+        join_kind: str = "inner", distinct_of=None, **note
     ):
         """``prog(*args)`` under a ``dispatch`` span that carries the
         program's name (with a ``build_trace`` child on a jit-cache
         miss, as the local executor's), ``note``'s attributes and,
         where the program holds a ``kernels.join_ranges``, the search
         it was built with for a build of ``join_build`` rows and the
-        width its keys are ranked at (``key_bits``), through
-        ``_attempt`` where the site is a retry unit."""
+        width its keys are ranked at (``key_bits``) and what it joins
+        as (``join_kind``), and, where it is the chain ``distinct_of``,
+        that chain's DISTINCT aggregate calls, through ``_attempt``
+        where the site is a retry unit."""
         with _dispatching(prog.__name__, miss) as dispatch:
             if note:
                 dispatch.note(**note)
             if join_build is not None:
-                dispatch.note_join(join_build, key_bits)
+                dispatch.note_join(join_build, key_bits, join_kind)
+            if distinct_of is not None:
+                dispatch.note_distinct(distinct_of)
             if tag is None:
                 return prog(*args)
             return self._attempt(tag, lambda: prog(*args))
@@ -324,6 +329,7 @@ class MeshExecutor(LocalExecutor):
             while isinstance(cur, stage.FUSABLE):
                 chain.append(cur)
                 cur = cur.sources[0]
+            self._note_negated_match(chain[-1], cur)
             base = self.execute_dist(cur)
             return self._run_chain_sharded(list(reversed(chain)), base)
         if isinstance(node, P.RemoteSource):
@@ -691,7 +697,8 @@ class MeshExecutor(LocalExecutor):
                 ]
                 note["start_walks"] = sum(out_layout.start_walks.values())
             env, mask, flags = self._run(
-                prog, t_compile is not None, *leaves, tag="chain", **note
+                prog, t_compile is not None, *leaves, tag="chain",
+                distinct_of=chain, **note
             )
             if t_compile is not None:
                 program_catalog.CATALOG.note_compile_seconds(
@@ -1547,7 +1554,8 @@ class MeshExecutor(LocalExecutor):
         return ShardedPage(list(a.names), cols, out[-1], a.n_shards)
 
     def _match_count_capacity(
-        self, key, prelude, in_specs, leaves, b_cap: int, key_bits: int
+        self, key, prelude, in_specs, leaves, b_cap: int, key_bits: int,
+        kind: str,
     ) -> int:
         """Phase A of a distributed join: per-shard match totals, one
         host sync, padded output capacity (the build-side barrier).
@@ -1571,7 +1579,7 @@ class MeshExecutor(LocalExecutor):
             self._mesh_jit_cache[key] = prog
         totals_dev = self._run(
             prog, miss, *leaves, tag="join-count", join_build=b_cap,
-            key_bits=key_bits,
+            key_bits=key_bits, join_kind=kind,
         )
         with telemetry.child_span("host_sync", site="mesh_join_total"):
             totals = jax.device_get(totals_dev)
@@ -1626,7 +1634,8 @@ class MeshExecutor(LocalExecutor):
             self._join_sig(probe, False), self._join_sig(build, replicated),
         )
         out_cap = self._match_count_capacity(
-            key_a, prelude, in_specs, p_leaves + b_leaves, b_cap, key_bits
+            key_a, prelude, in_specs, p_leaves + b_leaves, b_cap, key_bits,
+            kind,
         )
 
         # reserve the per-device join working set (probe shard + build
@@ -1875,6 +1884,7 @@ class MeshExecutor(LocalExecutor):
     # ---- distributed semi join ------------------------------------------
 
     def _dist_semi(self, node: P.SemiJoin) -> ShardedPage:
+        kind = self._take_negated_match(node)
         sp = self.execute_dist(node.source)
         filt = self._broadcast_page(node.filter_source)
         self._unify_key_dicts(sp, filt, node.keys)
@@ -1888,7 +1898,9 @@ class MeshExecutor(LocalExecutor):
             # host-driven per-probe set checks: run the single-device
             # path on gathered rows, then re-shard the result
             page = self.gather(sp)
-            return self.scatter(self._semi_join_pages(node, page, filt))
+            return self.scatter(
+                self._semi_join_pages(node, page, filt, kind)
+            )
         axis = self.axis
         p_leaves, p_meta = _page_leaves(sp)
         b_leaves, b_meta = _page_leaves(filt)
@@ -1935,7 +1947,7 @@ class MeshExecutor(LocalExecutor):
             )
             out_cap = self._match_count_capacity(
                 key_a, prelude, in_specs, p_leaves + b_leaves, filt.capacity,
-                key_bits,
+                key_bits, kind,
             )
 
         key_b = (
@@ -1982,6 +1994,9 @@ class MeshExecutor(LocalExecutor):
         matched = self._run(
             prog_b, miss, *p_leaves, *b_leaves, tag="semi-join",
             join_build=filt.capacity, key_bits=key_bits,
+            # counted once a semi join: on its count program, where
+            # there is one
+            join_kind="semi" if needs_expand else kind,
         )
         from trino_tpu import types as T
 

@@ -14,7 +14,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 
 from trino_tpu import types as T
-from trino_tpu.expr.ir import AggCall, RowExpression
+from trino_tpu.expr.ir import AggCall, Call, InputRef, RowExpression
 
 __all__ = [
     "PlanNode", "TableScan", "Filter", "Project", "Aggregate", "Join",
@@ -162,6 +162,25 @@ class SemiJoin(PlanNode):
     @property
     def sources(self):
         return [self.source, self.filter_source]
+
+    def negated_by(self, above: PlanNode) -> bool:
+        """Whether ``above``, the node this one runs under, is a Filter
+        with ``not(<match symbol>)`` among its conjuncts: the plan of
+        NOT IN / NOT EXISTS, an anti join."""
+        if not isinstance(above, Filter):
+            return False
+        todo = [above.predicate]
+        while todo:
+            e = todo.pop()
+            if isinstance(e, Call) and e.name == "and":
+                todo.extend(e.args)
+            elif (
+                isinstance(e, Call) and e.name == "not"
+                and isinstance(e.args[0], InputRef)
+                and e.args[0].name == self.match_symbol
+            ):
+                return True
+        return False
 
 
 @dataclass

@@ -56,16 +56,119 @@ class SymbolStats:
         return self.hi - self.lo
 
 
+_UNKNOWN = SymbolStats()
+
+
+class _ScanColumn:
+    """One column of a scanned table, described by its connector when a
+    symbol over it is first looked up — and never, where no estimate
+    asks: an estimate is first taken before pruning, when a scan still
+    assigns every column of its table, and a generator connector
+    computes a column's statistics from the whole column (at SF5
+    ``l_comment`` alone is minutes and gigabytes)."""
+
+    __slots__ = ("_ask", "_stats")
+
+    def __init__(self, conn, schema: str, table: str, column: str):
+        self._ask = (conn, schema, table, column)
+        self._stats: SymbolStats | None = None
+
+    def stats(self) -> SymbolStats:
+        if self._stats is None:
+            conn, schema, table, column = self._ask
+            try:
+                cs = conn.column_stats(schema, table, column)
+            except Exception:
+                cs = None
+            self._stats = _UNKNOWN if cs is None else SymbolStats(
+                ndv=cs.ndv, lo=cs.lo, hi=cs.hi,
+                null_frac=cs.null_fraction,
+                exact=cs.lo is not None,
+            )
+        return self._stats
+
+
+class _Symbols(dict):
+    """symbol -> SymbolStats. Beside the entries a dict holds it
+    carries the scan columns nobody has looked up yet (``_pending``:
+    symbol -> (column, the row estimate its ndv is capped at, or
+    None)); ``get`` / ``[]`` describe such a column on first lookup
+    and move it among the entries; iteration, ``in`` and ``len`` see
+    the entries alone. Copies and merges hand pending columns on
+    undescribed, so the answer is what an eager description at the
+    scan would have given."""
+
+    __slots__ = ("_pending",)
+
+    def __init__(self, known=(), pending=()):
+        super().__init__(known)
+        self._pending: dict[str, tuple[_ScanColumn, float | None]] = dict(
+            pending
+        )
+
+    def _describe(self, sym: str) -> None:
+        hit = self._pending.pop(sym, None)
+        if hit is not None:
+            st, cap = hit[0].stats(), hit[1]
+            if cap is not None and st.ndv is not None and st.ndv > cap:
+                st = replace(st, ndv=cap)
+            super().__setitem__(sym, st)
+
+    def get(self, sym, default=None):
+        self._describe(sym)
+        return super().get(sym, default)
+
+    def __getitem__(self, sym):
+        self._describe(sym)
+        return super().__getitem__(sym)
+
+    def __setitem__(self, sym, st):
+        self._pending.pop(sym, None)
+        super().__setitem__(sym, st)
+
+    def copy(self) -> "_Symbols":
+        return _Symbols(self, self._pending)
+
+    def merged(self, other: "_Symbols") -> "_Symbols":
+        """``{**self, **other}``: on a symbol of both, ``other``'s."""
+        out = self.copy()
+        for sym in other:
+            out._pending.pop(sym, None)
+        for sym in other._pending:
+            dict.pop(out, sym, None)
+        dict.update(out, other)
+        out._pending.update(other._pending)
+        return out
+
+    def alias(self, sym: str, src: "_Symbols", name: str) -> None:
+        """``self[sym] = src[name]``, a pending column left pending."""
+        hit = src._pending.get(name)
+        if hit is not None:
+            dict.pop(self, sym, None)
+            self._pending[sym] = hit
+        else:
+            self[sym] = src.get(name, _UNKNOWN)
+
+    def cap_ndv(self, rows: float) -> None:
+        """No symbol has more distinct values than there are rows."""
+        for sym, st in self.items():
+            if st.ndv is not None and st.ndv > rows:
+                super().__setitem__(sym, replace(st, ndv=rows))
+        for sym, (col, cap) in self._pending.items():
+            self._pending[sym] = (col, rows if cap is None else min(cap, rows))
+
+
 @dataclass(frozen=True)
 class PlanStats:
     rows: float
-    symbols: dict[str, SymbolStats] = field(default_factory=dict)
+    symbols: dict[str, SymbolStats] = field(default_factory=_Symbols)
+
+    def __post_init__(self):
+        if not isinstance(self.symbols, _Symbols):
+            object.__setattr__(self, "symbols", _Symbols(self.symbols))
 
     def sym(self, name: str) -> SymbolStats:
-        return self.symbols.get(name, SymbolStats())
-
-
-_UNKNOWN = SymbolStats()
+        return self.symbols.get(name, _UNKNOWN)
 
 
 def estimate(
@@ -94,10 +197,10 @@ def _estimate(node, md, cache) -> PlanStats:
         return _filter_stats(src, node.predicate)
     if isinstance(node, P.Project):
         src = estimate(node.source, md, cache)
-        symbols = {}
+        symbols = _Symbols()
         for sym, e in node.assignments.items():
             if isinstance(e, InputRef):
-                symbols[sym] = src.sym(e.name)
+                symbols.alias(sym, src.symbols, e.name)
             else:
                 symbols[sym] = _expr_stats(e, src)
         return PlanStats(src.rows, symbols)
@@ -108,14 +211,14 @@ def _estimate(node, md, cache) -> PlanStats:
     if isinstance(node, P.SemiJoin):
         src = estimate(node.source, md, cache)
         filt = estimate(node.filter_source, md, cache)
-        symbols = dict(src.symbols)
+        symbols = src.symbols.copy()
         symbols[node.match_symbol] = SymbolStats(ndv=2.0)
         # rows unchanged: the match symbol is a column; the Filter
         # above applies its selectivity (bare-boolean-ref path)
         return PlanStats(src.rows, symbols)
     if isinstance(node, P.Window):
         src = estimate(node.source, md, cache)
-        symbols = dict(src.symbols)
+        symbols = src.symbols.copy()
         for sym, call in node.functions.items():
             symbols[sym] = _UNKNOWN
         return PlanStats(src.rows, symbols)
@@ -132,14 +235,14 @@ def _estimate(node, md, cache) -> PlanStats:
         src = estimate(node.sources[0], md, cache)
         n = getattr(node, "count", -1)
         rows = min(float(n), src.rows) if n >= 0 else src.rows
-        return PlanStats(rows, dict(src.symbols))
+        return PlanStats(rows, src.symbols.copy())
     if isinstance(node, (P.Sort, P.Output, P.Exchange)):
         src = estimate(node.sources[0], md, cache)
-        return PlanStats(src.rows, dict(src.symbols))
+        return PlanStats(src.rows, src.symbols.copy())
     if isinstance(node, P.GroupId):
         src = estimate(node.source, md, cache)
         k = max(len(node.grouping_sets), 1)
-        return PlanStats(src.rows * k, dict(src.symbols))
+        return PlanStats(src.rows * k, src.symbols.copy())
     if node.sources:
         src = estimate(node.sources[0], md, cache)
         return PlanStats(src.rows, {})
@@ -152,22 +255,12 @@ def _scan_stats(node: P.TableScan, md: Metadata) -> PlanStats:
         rows = float(conn.row_count(node.schema, node.table))
     except Exception:
         return PlanStats(1e6)
-    symbols = {}
-    for sym, col in node.assignments.items():
-        # column-by-column so generator connectors only materialize
-        # what the query touches
-        try:
-            cs = conn.column_stats(node.schema, node.table, col)
-        except Exception:
-            cs = None
-        if cs is None:
-            symbols[sym] = _UNKNOWN
-        else:
-            symbols[sym] = SymbolStats(
-                ndv=cs.ndv, lo=cs.lo, hi=cs.hi,
-                null_frac=cs.null_fraction,
-                exact=cs.lo is not None,
-            )
+    # column by column, and each on its first lookup: generator
+    # connectors only materialize what an estimate asks for
+    symbols = _Symbols(pending={
+        sym: (_ScanColumn(conn, node.schema, node.table, col), None)
+        for sym, col in node.assignments.items()
+    })
     # pushdown domains narrow what the scan actually reads: clamp the
     # symbol bounds and scale the row estimate by the range fraction.
     # The Filter the domains came from stays in the plan and re-derives
@@ -271,15 +364,13 @@ def _filter_stats(src: PlanStats, predicate: RowExpression | None) -> PlanStats:
     if predicate is None:
         return src
     rows = src.rows
-    symbols = dict(src.symbols)
+    symbols = src.symbols.copy()
     for c in _conjuncts(predicate):
         sel = _apply_conjunct(c, symbols)
         rows *= sel
     rows = max(rows, 1.0)
     # cap every ndv at the new row estimate
-    for s, st in symbols.items():
-        if st.ndv is not None and st.ndv > rows:
-            symbols[s] = replace(st, ndv=max(rows, 1.0))
+    symbols.cap_ndv(rows)
     return PlanStats(rows, symbols)
 
 
@@ -350,14 +441,14 @@ def _apply_conjunct(c: RowExpression, symbols: dict) -> float:
                 return 1.0 - st.null_frac
             return 0.9
         # NOT(x): bounds inside must not narrow — evaluate on a scratch
-        scratch = dict(symbols)
+        scratch = symbols.copy()
         return max(0.0, 1.0 - _apply_conjunct(inner, scratch))
     if isinstance(c, Call) and c.name == "or":
         # independence-union; bounds must not narrow (either branch
         # may hold)
         remaining = 1.0
         for b in c.args:
-            scratch = dict(symbols)
+            scratch = symbols.copy()
             s = _apply_conjunct(b, scratch)
             remaining *= 1.0 - s
         return min(1.0, 1.0 - remaining)
@@ -460,7 +551,7 @@ def _aggregate_stats(node: P.Aggregate, md, cache) -> PlanStats:
 def _join_stats(node: P.Join, md, cache) -> PlanStats:
     l = estimate(node.left, md, cache)
     r = estimate(node.right, md, cache)
-    symbols = {**l.symbols, **r.symbols}
+    symbols = l.symbols.merged(r.symbols)
     if node.kind == "cross" or not node.criteria:
         rows = l.rows * r.rows
     else:

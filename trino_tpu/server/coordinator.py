@@ -790,6 +790,8 @@ class Coordinator:
         "direct_groupbys", "sorted_groupbys", "streamed_groupbys",
         "groupby_start_walks",
         "small_build_joins", "sorted_joins", "narrow_key_joins",
+        "wide_key_joins", "outer_joins", "anti_joins",
+        "distinct_aggregates", "revoked_joins", "join_revoked_ms",
         "compactions", "compact_gather_ops",
         "mesh_exchanges", "mesh_exchange_ms", "mesh_exchange_live_bytes",
         "mesh_exchange_buffer_bytes", "mesh_gather_ms", "mesh_upload_ms",

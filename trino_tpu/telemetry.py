@@ -342,11 +342,18 @@ class child_span:
 
 #: span names whose occurrences are counted beside their time
 _COUNTED = {"host_sync": "host_syncs", "dispatch": "dispatches",
-            "split_scan": "resident_split_scans"}
+            "split_scan": "resident_split_scans",
+            "join_revoked": "revoked_joins"}
 
 
 #: row field counting the programs of each ``join_search``
 _JOIN_SEARCH_FIELDS = {"count": "small_build_joins", "sort": "sorted_joins"}
+
+
+#: row field counting the programs of a ``join_kind`` other than inner
+#: and semi (its series: ``JOIN_KIND_COUNTERS``, by the field's name)
+JOIN_KIND_FIELDS = {"left": "outer_joins", "right": "outer_joins",
+                    "full": "outer_joins", "anti": "anti_joins"}
 
 
 def span_totals(root: Span) -> Dict[str, float]:
@@ -368,7 +375,15 @@ def span_totals(root: Span) -> Dict[str, float]:
     (the ``dispatch`` span's ``join_search``: ``count`` / ``sort``)
     and ``narrow_key_joins`` those among them whose keys were ranked
     below 64 bits, at the width the plan's exact key range needs (the
-    span's ``key_bits``);
+    span's ``key_bits``), ``wide_key_joins`` the others (a hashed
+    multi-column key, a varchar, float or two-limb key, a side without
+    exact bounds); ``outer_joins`` / ``anti_joins`` count them by the
+    span's ``join_kind`` (``left`` / ``right`` / ``full``; ``anti``: a
+    semi join whose match the plan negates), ``distinct_aggregates``
+    sums the span's count of DISTINCT aggregate calls in a chain, and
+    ``revoked_joins`` counts the ``join-revoked`` spans: joins sent
+    through the spill tier because their estimated working set passed
+    the per-node memory cap;
     ``mesh_exchanges`` counts the mesh executor's ``mesh-exchange``
     spans and ``mesh_exchanges_in_place`` those among them that were
     satisfied where the rows lay (the span's ``in_place``),
@@ -397,8 +412,15 @@ def span_totals(root: Span) -> Dict[str, float]:
         if search is not None:
             field = _JOIN_SEARCH_FIELDS[search]
             out[field] = out.get(field, 0) + 1
-            if sp.attrs.get("key_bits", 64) < 64:
-                out["narrow_key_joins"] = out.get("narrow_key_joins", 0) + 1
+            width = ("narrow_key_joins" if sp.attrs.get("key_bits", 64) < 64
+                     else "wide_key_joins")
+            out[width] = out.get(width, 0) + 1
+            kind = JOIN_KIND_FIELDS.get(sp.attrs.get("join_kind"))
+            if kind is not None:
+                out[kind] = out.get(kind, 0) + 1
+        if "distinct_aggregates" in sp.attrs:
+            out["distinct_aggregates"] = out.get(
+                "distinct_aggregates", 0) + sp.attrs["distinct_aggregates"]
         if sp.attrs.get("program") == "compact":
             out["compactions"] = out.get("compactions", 0) + 1
             out["compact_gather_ops"] = out.get(
@@ -722,6 +744,26 @@ JOINS = REGISTRY.counter(
     "Dispatched programs holding a kernels.join_ranges, by the search it was "
     "built with: count (a small build) or sort, and by the width its keys "
     "were ranked at (key_bits: 64, or what the plan's exact key range needs)")
+WIDE_KEY_JOINS = REGISTRY.counter(
+    "trino_wide_key_joins_total",
+    "Dispatched programs holding a kernels.join_ranges whose keys were ranked "
+    "at 64 bits: a hashed multi-column key, a varchar, float or two-limb key, "
+    "or a side without exact bounds")
+OUTER_JOINS = REGISTRY.counter(
+    "trino_outer_joins_total",
+    "Dispatched join programs of kind left, right or full")
+ANTI_JOINS = REGISTRY.counter(
+    "trino_anti_joins_total",
+    "Dispatched semi-join programs whose match the plan negates "
+    "(NOT IN / NOT EXISTS)")
+JOIN_KIND_COUNTERS = {"outer_joins": OUTER_JOINS, "anti_joins": ANTI_JOINS}
+DISTINCT_AGGREGATES = REGISTRY.counter(
+    "trino_distinct_aggregates_total",
+    "DISTINCT aggregate calls in dispatched chain programs")
+JOIN_REVOCATIONS = REGISTRY.counter(
+    "trino_join_revocations_total",
+    "Joins sent through the spill tier because their estimated working set "
+    "passed query_max_memory_per_node (span join-revoked)")
 LISTENER_FAILURES = REGISTRY.counter(
     "trino_event_listener_failures_total", "EventListener callbacks that raised")
 WORKER_TASKS = REGISTRY.counter(
